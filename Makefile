@@ -142,7 +142,7 @@ release: build
 		--exclude='__pycache__' --exclude='*.pyc' \
 		--exclude='native/cpsup' \
 		containerpilot_tpu bin/cpsup docs examples README.md \
-		CHANGELOG.md pyproject.toml Makefile native
+		CHANGES.md pyproject.toml Makefile native
 
 # container image with cpsup as the PID-1 entrypoint (reference:
 # Dockerfile, makefile build-in-container targets)

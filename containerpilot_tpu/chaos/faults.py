@@ -9,7 +9,7 @@ reference ContainerPilot's design exists to absorb:
   exactly like a host that lost power. In-flight requests see resets;
   the gateway must retry them away and route around the corpse.
 - **Wedged health check**: the replica process is alive but stops
-  being serveable (``ready`` regresses — a wedged device tunnel, a
+  being serveable (``ready`` regresses — a hung device runtime, a
   deadlocked worker). Heartbeats stop, the record TTL-expires, traffic
   routes around it; recovery resumes beats and the record revives.
 - **Slow replica**: injected per-request latency via the serve-side

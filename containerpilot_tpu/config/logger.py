@@ -37,10 +37,8 @@ def _trace_fields() -> Dict[str, Any]:
     a package-level dependency on telemetry."""
     global _tracing
     if _tracing is None:
-        try:
-            from ..telemetry import tracing as _tracing_mod
-        except ImportError:  # partial install; logging must not die
-            return {}
+        from ..telemetry import tracing as _tracing_mod
+
         _tracing = _tracing_mod
     fields: Dict[str, Any] = {}
     trace_id = _tracing.current_trace_id()

@@ -181,7 +181,8 @@ class Replica:
     #: capacity until its post-promotion beat drops the field
     role: str = ROLE_ACTIVE
     #: compile-cache advertisement (``cc=<digest>:<dir>``, raw):
-    #: same-host launches adopt the dir; surfaced on /fleet
+    #: the dir in force on the replica + its warm-marker digest;
+    #: surfaced on /fleet
     compile_cache: str = ""
     #: True while this replica is evacuating its sessions (``mg=``
     #: note, active flag): routing avoids NEW pins on it whenever

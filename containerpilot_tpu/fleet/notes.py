@@ -8,9 +8,8 @@ the check output — a single line of ``name=value`` fields::
 
 Through PR 17 each field was hand-rolled twice: a producer somewhere
 in workload/ or telemetry/ prepended its own ``"x=" +`` prefix, and
-``gateway._apply_notes`` (plus ``member._survivors`` and
-``modelcfg.adopt_fleet_compile_cache``) re-spelled the name to pull
-it back out. Six fields in, producer and parser had nothing keeping
+``gateway._apply_notes`` (plus ``member._survivors``) re-spelled
+the name to pull it back out. Six fields in, producer and parser had nothing keeping
 them aligned but grep. This module is the fix: every field is a
 :class:`NoteField` — name, producer, tolerant parser — registered in
 ``FIELDS``, and both ends of the wire are driven from it. The

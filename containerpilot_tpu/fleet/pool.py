@@ -338,6 +338,11 @@ class MuxConnection:
         self.streams: Dict[int, MuxStream] = {}
         self.streams_opened = 0
         self._next_id = 1
+        #: the dialed stream pair's writer OWNS the transport for the
+        #: connection's whole life: StreamWriter.__del__ closes its
+        #: transport, so the pair must live exactly as long as the
+        #: frames ride its socket (adopt() takes it, _die() closes it)
+        self._writer: Optional[asyncio.StreamWriter] = None
         self._transport = None
         self._protocol: Optional[_MuxClientProtocol] = None
         self._pongs: Dict[bytes, asyncio.Event] = {}
@@ -353,10 +358,13 @@ class MuxConnection:
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
         """Take over the freshly upgraded socket from its stream pair:
-        swap the transport's protocol for the frame parser. Any bytes
-        the server raced onto the wire after its 101 are replayed out
-        of the StreamReader's buffer first."""
+        swap the transport's protocol for the frame parser and keep
+        the writer as the transport's owner (a dropped StreamWriter
+        closes its transport when collected). Any bytes the server
+        raced onto the wire after its 101 are replayed out of the
+        StreamReader's buffer first."""
         transport = writer.transport
+        self._writer = writer
         protocol = _MuxClientProtocol(self)
         leftover = b""
         buffered = getattr(reader, "_buffer", None)
@@ -615,8 +623,8 @@ class MuxConnection:
                 )))
             else:
                 stream.push(("err", exc))
-        if self._transport is not None:
-            self._transport.close()
+        if self._writer is not None:
+            self._writer.close()
 
     def close(self, reason: str = "connection closed") -> None:
         """Tear down (eviction, shutdown): in-flight streams fail

@@ -26,11 +26,13 @@ directions, composed:
   second connection death, shape mismatch) returns None and the
   caller falls back to its disk/init load: transfer is an
   accelerator, never a new failure mode.
-- **Shared compile cache** (workload/modelcfg.py): replicas advertise
-  their XLA compile-cache dir through heartbeat notes (``cc=``);
-  launches on the same host adopt it and skip already-marked warmup
-  buckets, so ``compile_warmup`` seconds collapse release-over-
-  release. The marker helpers live in modelcfg next to
+- **Shared compile cache** (workload/modelcfg.py): every launch on
+  a host resolves the same XLA compile-cache dir
+  (``JAX_COMPILATION_CACHE_DIR``, else the checkout's fixed one) and
+  skips warmup buckets its marker already carries, so
+  ``compile_warmup`` seconds collapse release-over-release; replicas
+  advertise the dir and the marker's digest through heartbeat notes
+  (``cc=``). The marker helpers live in modelcfg next to
   ``enable_compile_cache``; this module only defines the roles and
   the transfer wire.
 

@@ -133,12 +133,14 @@ def flash_eligible(
     'fwd' for inference prefill. A sliding window must itself be
     block-aligned for the kernels' block-skip logic."""
     min_seq = tuning.resolve_min_seq(cfg.flash_min_seq, kind=kind)
-    return (
+    flash = (
         min_seq > 0
         and seq >= min_seq
         and seq % FLASH_BLOCK == 0
         and (cfg.window == 0 or cfg.window % FLASH_BLOCK == 0)
     )
+    tuning.log_attention_path(kind, seq, cfg.window, flash)
+    return flash
 
 
 def _auto_attention(cfg: "TransformerConfig", seq: int) -> Any:

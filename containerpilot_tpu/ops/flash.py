@@ -421,8 +421,11 @@ def _from_rows(x: jax.Array, b: int, h: int) -> jax.Array:
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """None = compile the kernel for the device. Only the CPU backend
+    (the test mesh) has no Mosaic target and interprets; any other
+    backend compiles for real or fails loudly."""
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return jax.default_backend() == "cpu"
     return interpret
 
 

@@ -29,46 +29,25 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .attention import NEG_INF
 
-import inspect
-
-try:  # stable API from jax 0.6+; experimental path for older
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma across
-# jax versions; resolve once
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map).parameters
-_CHECK_KWARG = (
-    "check_vma" if "check_vma" in _SHARD_MAP_PARAMS else "check_rep"
-)
-del inspect
-
-
-_HAS_AXIS_NAMES = "axis_names" in _SHARD_MAP_PARAMS
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, auto=None):
-    """Version-compat shard_map. ``auto`` names mesh axes left to the
-    automatic partitioner inside the manual region (pp×tp composition:
-    pipe is manual, model stays auto so XLA inserts the tensor-parallel
-    collectives inside each stage). Newer jax expresses this as
-    ``axis_names`` = the manual complement; older jax as ``auto``."""
-    kwargs = {_CHECK_KWARG: False}
+    """``jax.shard_map`` with the replication check off. ``auto``
+    names mesh axes left to the automatic partitioner inside the
+    manual region (pp×tp composition: pipe is manual, model stays auto
+    so XLA inserts the tensor-parallel collectives inside each stage);
+    jax expresses that as ``axis_names`` = the manual complement."""
+    kwargs = {}
     if auto:
-        if _HAS_AXIS_NAMES:
-            kwargs["axis_names"] = frozenset(mesh.axis_names) - frozenset(
-                auto
-            )
-        else:  # pragma: no cover - older jax
-            kwargs["auto"] = frozenset(auto)
-    return _shard_map(
+        kwargs["axis_names"] = frozenset(mesh.axis_names) - frozenset(auto)
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
+        check_vma=False,
         **kwargs,
     )
+
 
 def _ring_shard_fn(
     q: jax.Array,

@@ -25,13 +25,16 @@ Consumers:
   ``flash_min_seq: AUTO`` resolves here (models/transformer.py
   flash_eligible).
 
-No table (fresh checkout, unknown platform) degrades to the previous
-behavior exactly: 128/128 blocks, crossover 1024. Override the table
-path with CONTAINERPILOT_FLASH_TABLE; ``set_table(None)`` reverts to
+A platform with no shipped table runs untuned (128/128 blocks,
+crossover 1024) and says so in the log, once; so does every
+(kind, seq) decision between the flash kernels and XLA attention
+(``log_attention_path``). Override the table path with
+CONTAINERPILOT_FLASH_TABLE; ``set_table(None)`` reverts to
 auto-discovery.
 """
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -64,11 +67,18 @@ def _table_path() -> Optional[str]:
     override = os.environ.get("CONTAINERPILOT_FLASH_TABLE")
     if override:
         return override
-    try:
-        path = os.path.join(_TUNED_DIR, f"{platform_slug()}.json")
-    except Exception:  # no backend at all
-        return None
-    return path if os.path.exists(path) else None
+    # a backend that cannot initialize raises here: nothing that asks
+    # for flash blocks can run without one, so it is not swallowed
+    slug = platform_slug()
+    path = os.path.join(_TUNED_DIR, f"{slug}.json")
+    if os.path.exists(path):
+        return path
+    log.info(
+        "no flash tuning table for %s: untuned defaults (%d/%d blocks, "
+        "crossover %d)", slug, DEFAULT_BLOCK, DEFAULT_BLOCK,
+        DEFAULT_MIN_SEQ,
+    )
+    return None
 
 
 def set_table(table: Optional[dict]) -> None:
@@ -141,6 +151,27 @@ def auto_min_seq(kind: str = "train") -> int:
         if isinstance(value, int) and value >= 0:
             return value
     return DEFAULT_MIN_SEQ
+
+
+@functools.lru_cache(maxsize=None)
+def log_attention_path(
+    kind: str, seq: int, window: int, flash: bool
+) -> None:
+    """Say, once per distinct decision, which attention path a traced
+    shape took: the pallas flash kernels with their blocks, or XLA's
+    einsum attention. Called at trace time, so a compiled program
+    costs one line, not one per step."""
+    if flash:
+        bq, bk = pick_blocks(kind, seq)
+        log.info(
+            "attention %s seq=%d window=%d: pallas flash, blocks %d/%d",
+            kind, seq, window, bq, bk,
+        )
+    else:
+        log.info(
+            "attention %s seq=%d window=%d: XLA einsum",
+            kind, seq, window,
+        )
 
 
 def resolve_min_seq(configured: int, kind: str = "train") -> int:

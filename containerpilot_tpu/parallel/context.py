@@ -29,7 +29,9 @@ from ..ops.ring_attention import ring_attention, shard_map
 def flash_parallel_config(
     cfg: TransformerConfig, mesh: Mesh
 ) -> TransformerConfig:
-    """Bind mesh-aware attention auto-selection for pjit'd training.
+    """Bind mesh-aware attention auto-selection for pjit'd training
+    and for tensor-parallel serving (``serve --tp N``'s prefill and
+    scoring forward).
 
     pallas calls don't partition under automatic pjit sharding, so the
     flash path must run under shard_map. Causal attention is

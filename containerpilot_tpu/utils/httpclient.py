@@ -40,10 +40,8 @@ def _trace_id() -> str:
     X-CP-Trace, so cross-service log/trace greps pick it up too."""
     global _tracing
     if _tracing is None:
-        try:
-            from ..telemetry import tracing as _tracing_mod
-        except ImportError:
-            return ""
+        from ..telemetry import tracing as _tracing_mod
+
         _tracing = _tracing_mod
     return _tracing.current_trace_id()
 

@@ -28,11 +28,17 @@ PEAK_BF16 = [
 
 
 def peak_flops(device_kind: str) -> float:
+    """bf16 peak FLOP/s of one chip of ``device_kind``. A kind the
+    table does not know is an error, never an assumed peak: an MFU
+    against a guessed denominator is a made-up number."""
     kind = device_kind.lower()
     for key, peak in PEAK_BF16:
         if key in kind:
             return peak
-    return 197e12  # assume v5e-class if unrecognized
+    raise ValueError(
+        f"no published bf16 peak for device kind {device_kind!r}; "
+        "add it to workload/flops.py PEAK_BF16 with its source"
+    )
 
 
 def count_params(params: Any) -> int:

@@ -5,31 +5,47 @@ apart and score/serve a differently-shaped model than was trained.
 """
 from __future__ import annotations
 
+import os
 from typing import Any, Iterable, Optional, Tuple
 
 import jax
 
 
-def enable_compile_cache(path: str = "") -> Optional[str]:
-    """Opt-in persistent XLA compilation cache, shared by every
-    workload CLI (env: ``CONTAINERPILOT_COMPILE_CACHE=<dir>``, or an
-    explicit ``path`` — e.g. one adopted from a fleet peer's
-    heartbeat advertisement, see ``adopt_fleet_compile_cache``).
+#: where the persistent XLA compile cache lives when nobody placed it
+#: from outside: ONE fixed, git-ignored directory inside the checkout,
+#: the same for the CLIs, the tests and chip_smoke.py. The path is
+#: part of the cache's key, so it is never built from a temporary
+#: name, a pid or the time.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".compile_cache",
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent XLA compilation cache and return its
+    directory; every workload CLI (serve, serve_dist, train,
+    evaluate) calls this once at start-up, and nothing else in the
+    program sets a cache directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache lives THERE:
+    jax reads the variable itself and this function sets no other
+    directory. Where it is not set the cache goes to
+    ``DEFAULT_COMPILE_CACHE``.
 
     The supervisor's whole failure story is crash→restart→resume; the
     dominant cost of a reincarnation is recompiling the exact
-    programs the dead process already compiled. With the cache on
-    shared storage a restarted trainer or pod member re-warms from
-    cached executables, directly shrinking the restart window the
-    supervisor's budgets (and a serving pod's downtime) pay for.
-    Returns the cache dir when enabled, else None."""
-    import os
-
-    path = path or os.environ.get("CONTAINERPILOT_COMPILE_CACHE", "")
+    programs the dead process already compiled. With the cache a
+    restarted trainer or replica re-warms from cached executables,
+    directly shrinking the restart window the supervisor's budgets
+    (and a serving pod's downtime) pay for."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
     if not path:
-        return None
+        path = DEFAULT_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
     # default min-compile-time gate (1s) would skip most of a tiny
     # model's programs; anything over half a second is worth a disk hit
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
@@ -59,6 +75,7 @@ def warmup_fingerprint(
     slot_window: int = 0,
     draft_layers: int = 0,
     speculate: int = 0,
+    mesh: Optional[dict] = None,
 ) -> str:
     """Stable hash of everything that shapes the warmup program set:
     a marker written under one fingerprint must never skip warmup for
@@ -73,7 +90,11 @@ def warmup_fingerprint(
             # marker must never skip a tpu launch's warmup (shared
             # NFS cache dirs make this a real shape)
             "backend": jax.default_backend(),
-            "jax": getattr(jax, "__version__", ""),
+            # ...and the host's devices: XLA keys an executable on the
+            # topology it was compiled for, so a one-chip host's
+            # marker must not skip a four-chip host's warmup
+            "devices": [jax.devices()[0].device_kind, jax.device_count()],
+            "jax": jax.__version__,
             "vocab": getattr(cfg, "vocab_size", 0),
             "d_model": getattr(cfg, "d_model", 0),
             "n_heads": getattr(cfg, "n_heads", 0),
@@ -94,6 +115,9 @@ def warmup_fingerprint(
             "slot_window": slot_window,
             "draft_layers": draft_layers,
             "speculate": speculate,
+            # the mesh the params are sharded over: a --tp 4 server's
+            # programs are not a one-device server's
+            "mesh": mesh,
         },
         sort_keys=True,
     )
@@ -105,7 +129,6 @@ def load_warm_buckets(cache_dir: str, fingerprint: str) -> set:
     this cache dir; tolerant of a missing/torn marker (empty set —
     worst case the launch warms up fully, never a crash)."""
     import json as json_mod
-    import os
 
     if not cache_dir:
         return set()
@@ -125,7 +148,6 @@ def mark_warm_buckets(
     (atomic tmp+rename write; concurrent markers last-write-win,
     which only costs a redundant warmup, never a wrong skip)."""
     import json as json_mod
-    import os
 
     if not cache_dir:
         return
@@ -148,13 +170,11 @@ def mark_warm_buckets(
 def compile_cache_note(cache_dir: str) -> str:
     """The heartbeat advertisement VALUE (``<digest>:<quoted dir>``,
     carried as the ``cc=`` field by ``fleet/notes.py``) a FleetMember
-    appends for a replica serving with a compile cache: peers on the
-    same host adopt the dir, and the digest (over the warm-bucket
-    marker) tells readers when the warm set moved. Empty when no
-    cache dir is configured."""
+    appends for a replica: the compile-cache directory in force and a
+    digest over its warm-bucket marker, which tells readers when the
+    warm set moved. Empty when no cache dir is given."""
     import hashlib
     import json as json_mod
-    import os
 
     from ..fleet.notes import encode_compile_cache
 
@@ -178,59 +198,6 @@ def parse_compile_cache_note(raw: object) -> Tuple[str, str]:
     from ..fleet.notes import parse_compile_cache
 
     return parse_compile_cache(raw)
-
-
-def _local_addresses() -> set:
-    """Addresses that mean "this host" for cache adoption."""
-    import socket
-
-    local = {"127.0.0.1", "localhost", "0.0.0.0", "::1", ""}
-    try:
-        hostname = socket.gethostname()
-        local.add(hostname)
-        local.update(
-            info[4][0]
-            for info in socket.getaddrinfo(hostname, None)
-        )
-    except OSError:
-        # a host that can't resolve itself still adopts loopback
-        # advertisements; remote ones are skipped either way
-        return local
-    return local
-
-
-def adopt_fleet_compile_cache(
-    backend: Any, service_name: str
-) -> Optional[str]:
-    """Scan the catalog for a peer replica advertising a compile
-    cache dir on THIS host (``cc=`` heartbeat field) and enable it
-    for this process. Returns the adopted dir, or None when nobody
-    advertises one that exists locally — a launch that shares a
-    host with a warm peer reuses its compiled executables (and its
-    warm-bucket marker) instead of compiling from scratch. Only
-    SAME-HOST advertisements are considered: a remote peer's path
-    that happens to exist locally is a different host's cache (the
-    warmup fingerprint's platform field is the second guard, for
-    genuinely shared NFS dirs)."""
-    import os
-
-    from ..fleet import notes as notes_mod
-
-    try:
-        instances = backend.instances(service_name)
-    except Exception:
-        return None
-    local = _local_addresses()
-    for inst in instances:
-        if getattr(inst, "address", "") not in local:
-            continue
-        fields = notes_mod.split_note(getattr(inst, "notes", ""))
-        _digest, cache_dir = notes_mod.parse_field(
-            "cc", fields.get("cc", "")
-        )
-        if cache_dir and os.path.isdir(cache_dir):
-            return enable_compile_cache(cache_dir)
-    return None
 
 
 def derive_d_ff(d_model: int) -> int:

@@ -135,19 +135,13 @@ class InferenceServer:
                 "'decode'"
             )
         self.role = role
-        # persistent XLA compile cache dir this replica serves with
-        # (advertised through heartbeat notes so same-host launches
-        # adopt it); warmup consults its warm-bucket marker and skips
-        # buckets a previous process already compiled. Enabled HERE,
-        # not only in the CLI: a warm-bucket marker must never be
-        # written by a process whose compiles didn't actually land in
-        # the disk cache — that marker would promise executables a
-        # later launch won't find
+        # the directory this process's persistent XLA compile cache is
+        # in force at (modelcfg.enable_compile_cache's return — the
+        # server itself NEVER sets a cache directory): warmup consults
+        # the warm-bucket marker there and skips buckets a previous
+        # process already compiled, and heartbeats advertise it
+        # (cc=). Empty = no marker, no advertisement.
         self.compile_cache_dir = compile_cache_dir
-        if compile_cache_dir:
-            from .modelcfg import enable_compile_cache
-
-            enable_compile_cache(compile_cache_dir)
         # the cc= heartbeat advertisement, computed once at warmup
         # end (executor-wrapped): heartbeats must never pay marker
         # file I/O on the serving loop
@@ -1001,9 +995,32 @@ class InferenceServer:
                 }
         return None
 
+    def _device_info(self) -> Dict[str, Any]:
+        """What jax runs this replica on, as jax reports it, and
+        where the parameters actually sit: the ids of the devices
+        holding parameter shards, and every local device's
+        ``bytes_in_use`` (None where the backend keeps no memory
+        stats, e.g. the CPU)."""
+        devices = jax.devices()
+        holders = set()
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            if isinstance(leaf, jax.Array):
+                holders.update(d.id for d in leaf.devices())
+        return {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+            "param_devices": sorted(holders),
+            "bytes_in_use": [
+                (d.memory_stats() or {}).get("bytes_in_use")
+                for d in jax.local_devices()
+            ],
+        }
+
     async def _model_info(self, _req: Request) -> Response:
         body = json.dumps(
             {
+                "device": self._device_info(),
                 "vocab_size": self.cfg.vocab_size,
                 "d_model": self.cfg.d_model,
                 "n_heads": self.cfg.n_heads,
@@ -1948,13 +1965,14 @@ class InferenceServer:
                 if self.draft_cfg is not None else 0
             ),
             speculate=self.speculate,
+            mesh=self._mesh_info(),
         )
 
     def compile_cache_note(self) -> str:
         """The ``cc=`` heartbeat field's value (the name is owned by
         ``fleet/notes.py``): this replica's compile-cache
-        dir + warm-marker digest, so same-host launches adopt the dir
-        and skip warm buckets. Computed ONCE at warmup end (the
+        dir + warm-marker digest, so readers see when the warm set
+        moved. Computed ONCE at warmup end (the
         marker only changes there) and cached — a heartbeat must
         never pay marker file I/O on the serving loop. Empty without
         a cache dir — fleets not sharing a cache pay zero note

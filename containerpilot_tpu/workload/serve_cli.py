@@ -198,8 +198,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "client error",
     )
     # cold-start collapse knobs (fleet/standby.py, docs/60): boot as
-    # promotable warm capacity, fetch weights from a warm peer, and
-    # adopt a same-host peer's XLA compile cache
+    # promotable warm capacity, fetch weights from a warm peer
     parser.add_argument(
         "--standby", action="store_true",
         help="boot as a warm STANDBY: load weights, warmup-compile, "
@@ -226,14 +225,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "failure falls back to the normal --checkpoint-dir/init "
         "load",
     )
-    parser.add_argument(
-        "--adopt-compile-cache", default=True,
-        action=argparse.BooleanOptionalAction,
-        help="when joining a fleet without "
-        "CONTAINERPILOT_COMPILE_CACHE set, adopt a same-host peer's "
-        "advertised compile-cache dir (its cc= heartbeat field) so "
-        "this launch skips already-compiled warmup buckets",
-    )
     return parser
 
 
@@ -242,14 +233,16 @@ def _serving_mesh(tp: int, cp: int = 1):
     builds a pure tensor-parallel mesh over the first N local
     devices; --cp adds a seq axis for context-parallel prefill
     (params shard over model and replicate over seq, so the SAME
-    mesh serves both the ring prefill and the tp decode); otherwise
-    the default factoring over all local devices."""
+    mesh serves both the ring prefill and the tp decode). Without
+    either flag a replica is ONE device, the first, whatever the host
+    holds: nothing is sharded, a restored checkpoint lands where a
+    fresh init does, and the other chips stay free."""
     from ..parallel import MeshPlan, make_mesh
 
     tp, cp = max(tp, 1), max(cp, 1)
-    if tp == 1 and cp == 1:
-        return make_mesh()
     devices = jax.devices()
+    if tp == 1 and cp == 1:
+        return make_mesh(devices[:1], plan=MeshPlan(data=1, model=1))
     if tp * cp > len(devices):
         raise SystemExit(
             f"--tp {tp} x --cp {cp} exceeds the {len(devices)} "
@@ -307,6 +300,16 @@ def load_model(args: argparse.Namespace):
     # fresh-init shard, the LoRA adapter, AND the --cp ring must
     # share a device set or cross-mesh ops are uncompilable
     mesh = _serving_mesh(tp, cp)
+    if tp > 1 and cp == 1:
+        # a pallas kernel does not partition under automatic sharding
+        # (Mosaic refuses it at compile time on the chip; the CPU
+        # tests' interpreted kernels never showed it): a prompt at or
+        # past the flash crossover must run the kernel under
+        # shard_map over the head-sharded model axis, the same
+        # binding the mesh-parallel trainer uses
+        from ..parallel.context import flash_parallel_config
+
+        cfg = flash_parallel_config(cfg, mesh)
     params = None
     if args.checkpoint_dir:
         # shared with the evaluate CLI (workload/modelcfg.py):
@@ -347,10 +350,7 @@ def load_model(args: argparse.Namespace):
 def main() -> int:
     import logging
 
-    from .modelcfg import (
-        adopt_fleet_compile_cache,
-        enable_compile_cache,
-    )
+    from .modelcfg import enable_compile_cache
     from .serve import InferenceServer
 
     # the server's operational lines (listening, warm/accepting
@@ -370,20 +370,10 @@ def main() -> int:
             raise SystemExit(
                 "--fleet-catalog resolved to no discovery backend"
             )
-    # compile cache: the env knob first; failing that, adopt a
-    # same-host fleet peer's advertised dir (cc= heartbeat field) so
-    # this launch re-warms from its compiled executables — BEFORE
-    # model load, so every compile this process does lands in it
+    # compile cache (workload/modelcfg.py): placed from outside by
+    # JAX_COMPILATION_CACHE_DIR, else the checkout's fixed directory —
+    # BEFORE model load, so every compile this process does lands in it
     cache_dir = enable_compile_cache()
-    if (
-        cache_dir is None and backend is not None
-        and getattr(args, "adopt_compile_cache", True)
-    ):
-        cache_dir = adopt_fleet_compile_cache(
-            backend, args.fleet_service
-        )
-        if cache_dir:
-            print(f"adopted fleet compile cache {cache_dir}")
     # peer weight transfer (fleet/standby.py): fetch the params from
     # a warm peer over cp-mux/1 — digest-verified, one resume redial
     # — INSTEAD of paying the checkpoint restore; the init-only tree
@@ -449,7 +439,7 @@ def main() -> int:
         cp_mesh=cp_mesh, cp_min_len=getattr(args, "cp_min_len", 0),
         mux=args.mux,
         role=role,
-        compile_cache_dir=cache_dir or "",
+        compile_cache_dir=cache_dir,
     )
     member = None
     if backend is not None:

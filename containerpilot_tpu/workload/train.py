@@ -38,6 +38,13 @@ import jax.numpy as jnp
 def main() -> int:
     from .modelcfg import enable_compile_cache
 
+    # the supervisor collects this process's stderr: which tuning
+    # table and which attention path each compiled shape took are
+    # logged there, once
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s %(name)s %(message)s",
+    )
     enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--steps", type=int, default=100)
@@ -84,8 +91,10 @@ def main() -> int:
     parser.add_argument("--microbatches", type=int, default=4,
                         help="pipeline microbatches (batch must divide)")
     parser.add_argument("--tensor-parallel", type=int, default=0,
-                        help="model-axis size when pipelining "
-                        "(0 = all remaining devices go to data)")
+                        help="model-axis size of the mesh; the "
+                        "remaining devices go to data (0 = 1 when "
+                        "pipelining, else the default factoring: the "
+                        "largest power of two up to 4)")
     parser.add_argument("--progress-file", default="")
     parser.add_argument("--control-socket", default="")
     parser.add_argument("--learning-rate", type=float, default=3e-4)
@@ -154,25 +163,25 @@ def main() -> int:
         loss_chunk=args.loss_chunk,
     )
     rules = None
-    if args.pipeline_stages > 1:
-        if args.loss_chunk:
-            raise SystemExit(
-                "--loss-chunk does not apply to the pipelined loss "
-                "(pipeline_loss_fn computes its own whole-logits CE)"
-            )
-        # dp x pp x tp: layers shard over pipe stages, tensor
-        # parallelism stays live inside each stage (parallel/pipeline.py)
+    pipe = max(args.pipeline_stages, 1)
+    if pipe > 1 and args.loss_chunk:
+        raise SystemExit(
+            "--loss-chunk does not apply to the pipelined loss "
+            "(pipeline_loss_fn computes its own whole-logits CE)"
+        )
+    if pipe > 1 or args.tensor_parallel > 0:
+        # an explicit factoring: dp x tp, or dp x pp x tp (layers
+        # shard over pipe stages, tensor parallelism stays live inside
+        # each stage — parallel/pipeline.py)
         n_dev = len(jax.devices())
         tp = args.tensor_parallel or 1
-        if n_dev % (args.pipeline_stages * tp):
+        if n_dev % (pipe * tp):
             raise SystemExit(
                 f"{n_dev} devices not divisible by pipeline-stages x "
-                f"tensor-parallel = {args.pipeline_stages} x {tp}"
+                f"tensor-parallel = {pipe} x {tp}"
             )
         mesh = make_mesh(plan=MeshPlan(
-            data=n_dev // (args.pipeline_stages * tp),
-            model=tp,
-            pipe=args.pipeline_stages,
+            data=n_dev // (pipe * tp), model=tp, pipe=pipe,
         ))
     else:
         mesh = make_mesh()
@@ -386,9 +395,14 @@ def main() -> int:
         flops_per_token = train_flops_per_token(
             cfg, n_params, args.seq_len
         )
-    chip_peak = peak_flops(jax.devices()[0].device_kind) * len(
-        jax.devices()
-    )
+    # MFU exists only against a published peak: on a TPU an unknown
+    # device kind is an error (peak_flops raises); off-TPU (the CPU
+    # test mesh) no MFU is reported at all
+    chip_peak = None
+    if jax.devices()[0].platform == "tpu":
+        chip_peak = peak_flops(jax.devices()[0].device_kind) * len(
+            jax.devices()
+        )
 
     data_rng = jax.random.PRNGKey(1)
     t0 = time.monotonic()
@@ -443,20 +457,32 @@ def main() -> int:
                 # export and the log line, so they can never disagree
                 rate = (step + 1 - start_step) / (time.monotonic() - t0)
                 tokens_s = rate * args.batch * args.seq_len
-                mfu = tokens_s * flops_per_token / chip_peak
+                metrics = {
+                    "training_steps_total": 10,
+                    "training_loss": float(loss),
+                    "training_tokens_per_sec": tokens_s,
+                }
+                mfu_note = ""
+                if chip_peak is not None:
+                    mfu = tokens_s * flops_per_token / chip_peak
+                    metrics["training_mfu"] = mfu
+                    mfu_note = f", mfu={mfu:.3f}"
                 if client is not None and (step + 1) % 10 == 0:
                     try:
-                        client.put_metric({
-                            "training_steps_total": 10,
-                            "training_loss": float(loss),
-                            "training_tokens_per_sec": tokens_s,
-                            "training_mfu": mfu,
-                        })
+                        client.put_metric(metrics)
                     except Exception:  # cpcheck: disable=CP-SWALLOW supervisor may be reloading; never die
                         pass
                 print(f"step {step + 1}: loss={float(loss):.4f} "
-                      f"({rate:.1f} steps/s, {tokens_s:.0f} tok/s, "
-                      f"mfu={mfu:.3f})")
+                      f"({rate:.1f} steps/s, {tokens_s:.0f} tok/s"
+                      f"{mfu_note})")
+                if step == start_step:
+                    # where the state actually sits, per local device
+                    # (None where the backend keeps no memory stats)
+                    in_use = [
+                        (d.memory_stats() or {}).get("bytes_in_use")
+                        for d in jax.local_devices()
+                    ]
+                    print(f"device bytes_in_use: {in_use}")
             if eval_enabled and (step + 1) % args.eval_every == 0:
                 if args.lora_rank > 0:
                     from ..models.lora import apply_lora

@@ -39,9 +39,6 @@ sys.path.insert(
 )
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 
 from containerpilot_tpu.discovery import FileCatalogBackend  # noqa: E402

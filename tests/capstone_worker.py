@@ -177,8 +177,9 @@ def main() -> int:
         enable_compile_cache,
     )
 
-    # honors CONTAINERPILOT_COMPILE_CACHE exactly like the real
-    # workload CLIs: a reincarnated worker re-warms from cached
+    # the same compile cache as the real workload CLIs
+    # (JAX_COMPILATION_CACHE_DIR when the test placed one, else the
+    # checkout's): a reincarnated worker re-warms from cached
     # executables, which is both the feature's purpose and what keeps
     # the crash-resume capstones' restart windows short
     enable_compile_cache()

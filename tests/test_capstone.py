@@ -43,19 +43,12 @@ def _free_port() -> int:
 def _sub_env() -> dict:
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)  # exactly 1 CPU device per process
-    # conftest's in-process cache env must not leak: subprocess cache
-    # behavior is controlled ONLY by CONTAINERPILOT_COMPILE_CACHE
-    # (enable_compile_cache), so dedicated-cache tests stay cold
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
     # pod boots across this suite recompile the same tiny-model
-    # program sets; the workload CLIs' opt-in persistent compile
-    # cache (modelcfg.enable_compile_cache) turns every boot after
-    # the first into cache re-warms — exactly the crash->restart
-    # path it exists for, and minutes off the suite on one core
-    env.setdefault(
-        "CONTAINERPILOT_COMPILE_CACHE", "/tmp/cp_test_compile_cache"
-    )
+    # program sets; conftest exported the ONE compile cache dir
+    # (JAX_COMPILATION_CACHE_DIR), which the workload CLIs'
+    # enable_compile_cache honours, so every boot after the first is
+    # a cache re-warm — exactly the crash->restart path the cache
+    # exists for, and minutes off the suite on one core
     return env
 
 
@@ -175,7 +168,7 @@ def test_supervised_multiprocess_training_with_crash_and_resume(
     # the restart half of the story is exactly what the shared XLA
     # compile cache exists for: the reincarnated worker re-warms from
     # cached executables instead of recompiling the train step
-    env["CONTAINERPILOT_COMPILE_CACHE"] = str(tmp_path / "xla-cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
 
     catalog = subprocess.Popen(
         [sys.executable, "-m", "containerpilot_tpu",

@@ -1366,8 +1366,10 @@ def test_dead_mux_conn_fails_streams_once_each_arming_retry(run, tmp_path):
         healthy.route("POST", "/v1/generate", handler_healthy)
         await doomed.start_tcp("127.0.0.1", 0)
         await healthy.start_tcp("127.0.0.1", 0)
-        _register(backend, "aaa", doomed.bound_port)  # tie -> doomed
-        _register(backend, "bbb", healthy.bound_port)
+        # the doomed replica is the whole fleet while the two
+        # requests dispatch (least-loaded routing would split a
+        # two-replica fleet: the first dispatch already counts)
+        _register(backend, "aaa", doomed.bound_port)
         gw = FleetGateway(
             backend, "svc", "127.0.0.1", 0, poll_interval=5.0,
             hedge=False, retry_backoff=0.01, affinity="none",
@@ -1386,6 +1388,8 @@ def test_dead_mux_conn_fails_streams_once_each_arming_retry(run, tmp_path):
             if hits["doomed"] == 2:
                 break
             await asyncio.sleep(0.01)
+        _register(backend, "bbb", healthy.bound_port)
+        await gw._poll_once()  # noqa: SLF001 — the retry's target
         await doomed.abort()  # SIGKILL semantics: RST, flush nothing
         results = await asyncio.gather(*posts)
         retried = _counter(gw._m_retried, "aaa")  # noqa: SLF001

@@ -59,4 +59,14 @@ def test_frozen_params_bill_4_flops():
 def test_peak_flops_known_generations():
     assert peak_flops("TPU v5 lite") == 197e12
     assert peak_flops("TPU v4") == 275e12
-    assert peak_flops("weird-device") == 197e12
+
+
+def test_peak_flops_unknown_device_is_an_error():
+    """An MFU against an assumed peak is a made-up number: a device
+    kind the table does not know raises, it never defaults."""
+    import pytest
+
+    with pytest.raises(ValueError, match="weird-device"):
+        peak_flops("weird-device")
+    with pytest.raises(ValueError):
+        peak_flops("cpu")

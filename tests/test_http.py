@@ -454,6 +454,40 @@ def test_mux_upgrade_negotiation_and_ping(run):
     assert mux_conns == 1 and conns == 1
 
 
+def test_mux_adopted_transport_outlives_the_stream_pair(run):
+    """The upgrade dials with an asyncio stream pair and then swaps
+    the transport's protocol for the frame parser. On Python 3.12
+    ``StreamWriter.__del__`` CLOSES its transport, so a connection
+    that merely borrowed the transport died with EOF the moment the
+    dial's locals were collected (every gateway->replica connection,
+    right after the 101). The connection must OWN the pair: alive
+    across a collection, closed only by its own close()."""
+    import gc
+
+    async def scenario():
+        server = await _start_server()
+        pool, conn = await _mux_connect(server.bound_port)
+        gc.collect()
+        await asyncio.sleep(0.05)  # let a close, if any, land as EOF
+        alive = not conn.dead and not conn._transport.is_closing()
+        writer = getattr(conn, "_writer", None)
+        owns = writer is not None and writer.transport is conn._transport
+        ponged = await conn.ping(5.0)
+        status = 0
+        if alive:
+            stream = await conn.open_stream("GET", "/ok")
+            status, _ = await stream.response_head(5.0)
+        pool.close_all()
+        closed = conn.dead and conn._transport.is_closing()
+        await server.stop()
+        return alive, owns, ponged, status, closed
+
+    alive, owns, ponged, status, closed = run(scenario(), timeout=30)
+    assert alive, "the adopted transport was closed under the connection"
+    assert owns and ponged and status == 200
+    assert closed  # and close() closes what it owns
+
+
 def test_mux_streams_interleave_on_one_connection(run):
     """A fast stream opened AFTER a slow one completes first — the
     whole point of multiplexing: responses interleave per stream, on

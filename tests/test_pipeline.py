@@ -1,0 +1,199 @@
+"""GPipe pipeline parallelism on the virtual 8-device CPU mesh: forward
+parity, input validation, and composition with data, tensor and expert
+parallelism. A file of its own: the two parity tests are among the
+longest in the suite, and the driver runs tier-1 one file per worker."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from containerpilot_tpu.models.transformer import (
+    TransformerConfig,
+    forward,
+    init_params,
+)
+from containerpilot_tpu.parallel import MeshPlan, make_mesh
+
+
+def test_pipeline_parallel_forward_parity():
+    """GPipe-style pipeline over 4 stages must reproduce the plain
+    forward exactly (same params, dense model)."""
+    import numpy as _np
+    from jax.sharding import Mesh
+
+    from containerpilot_tpu.parallel.pipeline import (
+        pipeline_forward_with_aux,
+        pipeline_loss_fn,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
+        max_seq_len=32, dtype=jnp.float32,
+    )
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    mesh = Mesh(_np.asarray(jax.devices()[:4]), ("pipe",))
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
+    )
+    ref = forward(params, tokens, cfg)
+    out, aux = pipeline_forward_with_aux(
+        params, tokens, cfg, mesh, n_microbatches=4
+    )
+    np.testing.assert_allclose(
+        np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
+    )
+    assert float(aux) == 0.0  # dense model: no MoE aux
+
+    # training path: grads flow through ppermute/fori_loop
+    grads = jax.grad(
+        lambda p: pipeline_loss_fn(p, tokens, cfg, mesh, n_microbatches=4)
+    )(params)
+    flat, _ = jax.tree_util.tree_flatten(grads)
+    assert all(bool(jnp.isfinite(g).all()) for g in flat)
+    # layer grads are nonzero (the pipeline actually trained all stages)
+    assert float(jnp.abs(grads["layers"]["wq"]).sum()) > 0
+
+
+def test_pipeline_validates_inputs():
+    import numpy as _np
+    from jax.sharding import Mesh
+
+    from containerpilot_tpu.parallel.pipeline import (
+        pipeline_forward_with_aux,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=3, d_ff=64,
+        max_seq_len=32,
+    )
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    mesh = Mesh(_np.asarray(jax.devices()[:4]), ("pipe",))
+    tokens = jnp.zeros((8, 8), jnp.int32)
+    with pytest.raises(ValueError, match="not divisible by 4 stages"):
+        pipeline_forward_with_aux(params, tokens, cfg, mesh)
+    cfg2 = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
+        max_seq_len=32,
+    )
+    params2 = init_params(jax.random.PRNGKey(0), cfg2)
+    with pytest.raises(ValueError, match="microbatches"):
+        pipeline_forward_with_aux(
+            params2, jnp.zeros((6, 8), jnp.int32), cfg2, mesh,
+            n_microbatches=4,
+        )
+
+
+def test_pipeline_composes_with_data_parallelism():
+    """dp x pp: a ("data", "pipe") mesh shards microbatch contents over
+    data while stages stream over pipe; parity with the plain forward."""
+    import numpy as _np
+    from jax.sharding import Mesh
+
+    from containerpilot_tpu.parallel.pipeline import (
+        pipeline_forward_with_aux,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
+        max_seq_len=32, dtype=jnp.float32,
+    )
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    mesh = Mesh(
+        _np.asarray(jax.devices()[:8]).reshape(2, 4), ("data", "pipe")
+    )
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
+    )
+    ref = forward(params, tokens, cfg)
+    out, _aux = pipeline_forward_with_aux(
+        params, tokens, cfg, mesh, n_microbatches=4
+    )
+    np.testing.assert_allclose(
+        np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
+    )
+    # grads flow through the data-sharded specs and the aux pmean
+    from containerpilot_tpu.parallel.pipeline import pipeline_loss_fn
+
+    grads = jax.grad(
+        lambda p: pipeline_loss_fn(p, tokens, cfg, mesh, n_microbatches=4)
+    )(params)
+    flat, _ = jax.tree_util.tree_flatten(grads)
+    assert all(bool(jnp.isfinite(g).all()) for g in flat)
+    # microbatch size must divide the data axis
+    with pytest.raises(ValueError, match="data axis"):
+        pipeline_forward_with_aux(
+            params, tokens[:4], cfg, mesh, n_microbatches=4
+        )
+
+
+def test_pipeline_composes_with_tensor_parallelism():
+    """dp x pp x tp: layers shard over pipe stages while the model axis
+    stays live (auto-partitioned) inside each stage; forward parity with
+    the unpipelined model and a full pipelined train step."""
+    from containerpilot_tpu.parallel import (
+        init_train_state as _init,
+        make_pipeline_train_step,
+    )
+    from containerpilot_tpu.parallel.pipeline import (
+        pipeline_forward_with_aux,
+        pipeline_sharding_rules,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
+        max_seq_len=32, dtype=jnp.float32,
+    )
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    mesh = make_mesh(jax.devices()[:8], plan=MeshPlan(2, 2, pipe=2))
+    assert mesh.axis_names == ("data", "pipe", "model")
+
+    # in-stage tp specs survive the pipe composition
+    rules = pipeline_sharding_rules(cfg, mesh)
+    assert tuple(rules["layers"]["wq"]) == ("pipe", None, "model", None)
+
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
+    )
+    ref = forward(params, tokens, cfg)
+    # auto-axis shard_map must run under jit (the eager impl path does
+    # not support auto axes) — which is the only real usage anyway
+    out, _aux = jax.jit(
+        lambda p, t: pipeline_forward_with_aux(p, t, cfg, mesh, 4)
+    )(params, tokens)
+    np.testing.assert_allclose(
+        np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
+    )
+
+    state = _init(jax.random.PRNGKey(0), cfg, mesh, rules=rules)
+    step = make_pipeline_train_step(cfg, mesh, n_microbatches=4)
+    batch = jax.random.randint(
+        jax.random.PRNGKey(2), (8, 13), 0, cfg.vocab_size, jnp.int32
+    )
+    state, loss = step(state, batch)
+    assert bool(jnp.isfinite(loss))
+    assert int(state.step) == 1
+
+
+def test_pipeline_composes_with_expert_parallelism():
+    """pp x ep x dp: switch-MoE experts shard over the auto model axis
+    inside each pipeline stage."""
+    from containerpilot_tpu.parallel import (
+        init_train_state as _init,
+        make_pipeline_train_step,
+    )
+    from containerpilot_tpu.parallel.pipeline import pipeline_sharding_rules
+
+    cfg = TransformerConfig(
+        vocab_size=128, d_model=64, n_heads=2, n_layers=4, d_ff=128,
+        max_seq_len=32, moe_experts=2, dtype=jnp.float32,
+    )
+    mesh = make_mesh(jax.devices()[:8], plan=MeshPlan(2, 2, pipe=2))
+    rules = pipeline_sharding_rules(cfg, mesh)
+    assert tuple(rules["layers"]["moe_w_in"]) == ("pipe", "model", None, None)
+    state = _init(jax.random.PRNGKey(0), cfg, mesh, rules=rules)
+    step = make_pipeline_train_step(cfg, mesh, n_microbatches=4)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(2), (8, 33), 0, cfg.vocab_size, jnp.int32
+    )
+    state, loss = step(state, tokens)
+    assert bool(jnp.isfinite(loss))

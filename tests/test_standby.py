@@ -334,10 +334,12 @@ def test_warm_bucket_marker_roundtrip_and_tolerance(tmp_path):
 
 
 def test_warmup_skips_marked_buckets(run, tmp_path, monkeypatch):
-    """Two same-shaped servers sharing a compile cache dir: the first
-    warms and marks; the second's warmup drives ZERO decode compiles
-    (the marker skip — its compile_warmup seconds collapse, which is
-    the cold-start lever the shared cache exists for)."""
+    """Two same-shaped servers sharing a warm-bucket marker dir: the
+    first warms and marks; the second's warmup drives ZERO decode
+    compiles (the marker skip — its compile_warmup seconds collapse,
+    which is the cold-start lever the shared cache exists for). The
+    marker dir here is the test's own, kept apart from jax's compile
+    cache: the server never sets a cache directory."""
     import jax
 
     from containerpilot_tpu.models import decode as decode_mod
@@ -352,11 +354,7 @@ def test_warmup_skips_marked_buckets(run, tmp_path, monkeypatch):
         return real_generate(*args, **kwargs)
 
     monkeypatch.setattr(decode_mod, "generate", counting_generate)
-    # the server ENABLES its cache dir at construction (the marker
-    # must never promise executables the disk cache doesn't hold);
-    # restore the suite's per-user cache afterwards so later tests
-    # don't write compiles into this test's doomed tmpdir
-    prev_cache = jax.config.jax_compilation_cache_dir
+    cache_in_force = jax.config.jax_compilation_cache_dir
 
     async def scenario():
         first = InferenceServer(
@@ -381,10 +379,9 @@ def test_warmup_skips_marked_buckets(run, tmp_path, monkeypatch):
         )
         assert adv_dir == str(tmp_path)
 
-    try:
-        run(scenario(), timeout=300)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_cache)
+    run(scenario(), timeout=300)
+    # the constructor argument placed the marker, not jax's cache
+    assert jax.config.jax_compilation_cache_dir == cache_in_force
 
 
 def test_slow_boot_hook_parks_warmup_as_compile_badput(run):

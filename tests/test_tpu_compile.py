@@ -1,0 +1,202 @@
+"""The main path's pallas kernels compile for the v5e, without a chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is described, not attached (`jax.experimental.topologies`), so
+what it would refuse on the machine with the chip it refuses here, at
+no chip time: a slice not aligned to the tiling, more fast memory than
+a kernel may use, a program that does not fit 16 GB. These are the
+shapes chip_smoke.py's main path runs (the 1.2B-class serving prefill
+at 1024 tokens, the flagship training step's attention at batch 8 x
+seq 2048, blocks from ops/tuned/tpu-v5-lite.json) plus the windowed
+forward and the int8 GEMM at the serving model's FFN width. Nothing
+runs, so nothing here says anything about results or times.
+
+The kernels default to interpret mode on the CPU backend the tests
+run on, so each compile passes `interpret=False` itself. The
+persistent compile cache is switched off around the module: an entry
+written for a described chip cannot be read back without one, and the
+next run would warn and recompile.
+"""
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from containerpilot_tpu.ops.flash import (
+    flash_attention,
+    flash_attention_forward,
+)
+from containerpilot_tpu.ops.quant import int8_matmul_pallas
+
+KERNEL = "tpu_custom_call"
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topology():
+    """A described four-chip v5e host, or skip where the topology
+    cannot be described (no TPU compiler in the installation)."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — any failure = no compiler
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def chip(topology):
+    """One described v5e device."""
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert KERNEL in compiled.as_text(), "the pallas kernel is not in it"
+    mem = compiled.memory_analysis()
+    used = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    )
+    assert used < HBM_BYTES, f"{used} bytes do not fit one v5e chip"
+    return compiled
+
+
+def _qkv(chip, batch, seq, heads, head_dim=128):
+    shape = jax.ShapeDtypeStruct(
+        (batch, seq, heads, head_dim), jnp.bfloat16, sharding=chip
+    )
+    return shape, shape, shape
+
+
+@pytest.mark.parametrize(
+    "batch,seq,heads,blocks,window",
+    [
+        # serving prefill of the 1.2B-class model at the flash crossover
+        (1, 1024, 16, (256, 512), 0),
+        # the same model at its full context
+        (1, 2048, 16, (512, 512), 0),
+        # sliding window: kv blocks older than the window are skipped
+        (1, 8192, 16, (512, 512), 1024),
+    ],
+    ids=["prefill-1024", "prefill-2048", "window-1024-of-8192"],
+)
+def test_flash_forward_compiles(chip, batch, seq, heads, blocks, window):
+    fn = functools.partial(
+        flash_attention_forward, block_q=blocks[0], block_k=blocks[1],
+        interpret=False, window=window,
+    )
+    _compile(fn, *_qkv(chip, batch, seq, heads))
+
+
+def test_flash_forward_gqa_compiles(chip):
+    """The serving kernel reads grouped kv heads natively."""
+    q, _, _ = _qkv(chip, 1, 1024, 16)
+    k, v, _ = _qkv(chip, 1, 1024, 4)
+    _compile(
+        functools.partial(
+            flash_attention_forward, block_q=256, block_k=512,
+            interpret=False,
+        ),
+        q, k, v,
+    )
+
+
+def test_flash_backward_compiles(chip):
+    """The flagship training step's attention, fwd + bwd through the
+    custom_vjp: batch 8, seq 2048, 8 heads, 'train' blocks 512/512."""
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, block_q=512, block_k=512, interpret=False
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip, 8, 2048, 8)
+    )
+    # forward, dq, and dk/dv kernels
+    assert compiled.as_text().count(KERNEL) >= 3
+
+
+def test_int8_gemm_compiles(chip):
+    """A padded decode microbatch through the serving model's FFN:
+    128 x 2048 . 2048 x 6144, per-column scales."""
+    m, k, n = 128, 2048, 6144
+    _compile(
+        functools.partial(int8_matmul_pallas, interpret=False),
+        jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=chip),
+        jax.ShapeDtypeStruct((k, n), jnp.int8, sharding=chip),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=chip),
+    )
+
+
+def test_tp_serving_prefill_needs_shard_map(topology, monkeypatch):
+    """`serve --tp 4`, a prompt at the flash crossover: Mosaic refuses
+    to partition a kernel automatically, so the serving prefill must
+    bind its attention through flash_parallel_config (serve_cli's
+    load_model does). Interpreted kernels partition like any XLA op,
+    which is how the CPU tests never saw this; the test steers the
+    model's own interpret default to the chip's."""
+    from jax.sharding import NamedSharding
+
+    from containerpilot_tpu.models.decode import prefill
+    from containerpilot_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+    from containerpilot_tpu.ops import flash
+    from containerpilot_tpu.parallel import MeshPlan, make_mesh
+    from containerpilot_tpu.parallel.context import flash_parallel_config
+    from containerpilot_tpu.parallel.sharding import param_sharding_rules
+
+    monkeypatch.setattr(flash, "_resolve_interpret", lambda i: False)
+    mesh = make_mesh(
+        list(topology.devices), plan=MeshPlan(data=1, model=4)
+    )
+    # the 1.2B-class serving widths, depth cut to 2 (one scan body)
+    cfg = TransformerConfig(
+        vocab_size=32768, d_model=2048, n_heads=16, n_layers=2,
+        d_ff=6144, max_seq_len=2048, flash_min_seq=1024,
+    )
+    shapes = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)
+    )
+    params = jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)
+        ),
+        shapes, param_sharding_rules(cfg, mesh),
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (1, 1024), jnp.int32,
+        sharding=NamedSharding(mesh, jax.sharding.PartitionSpec()),
+    )
+
+    def lower(config):
+        return jax.jit(
+            lambda p, t: prefill(p, t, config, 2048)
+        ).lower(params, tokens)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lower(cfg)
+    compiled = lower(flash_parallel_config(cfg, mesh)).compile()
+    assert KERNEL in compiled.as_text()

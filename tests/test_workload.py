@@ -353,15 +353,6 @@ def test_zero1_shards_moments_and_matches_plain_step():
         )
 
 
-def test_graft_entry_points():
-    import __graft_entry__ as graft
-
-    fn, args = graft.entry()
-    out = jax.jit(fn)(*args)
-    assert out.shape[-1] == 256
-    graft.dryrun_multichip(8)
-
-
 def test_ring_attention_matches_single_device():
     """Context-parallel ring attention over a 4-way seq axis must match
     single-device causal attention exactly in structure and closely in
@@ -1716,190 +1707,6 @@ def test_distributed_two_process_catalog_rendezvous(tmp_path):
         server.wait(timeout=10)
 
 
-def test_pipeline_parallel_forward_parity():
-    """GPipe-style pipeline over 4 stages must reproduce the plain
-    forward exactly (same params, dense model)."""
-    import numpy as _np
-    from jax.sharding import Mesh
-
-    from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
-        pipeline_loss_fn,
-    )
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
-        max_seq_len=32, dtype=jnp.float32,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = Mesh(_np.asarray(jax.devices()[:4]), ("pipe",))
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
-    )
-    ref = forward(params, tokens, cfg)
-    out, aux = pipeline_forward_with_aux(
-        params, tokens, cfg, mesh, n_microbatches=4
-    )
-    np.testing.assert_allclose(
-        np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
-    )
-    assert float(aux) == 0.0  # dense model: no MoE aux
-
-    # training path: grads flow through ppermute/fori_loop
-    grads = jax.grad(
-        lambda p: pipeline_loss_fn(p, tokens, cfg, mesh, n_microbatches=4)
-    )(params)
-    flat, _ = jax.tree_util.tree_flatten(grads)
-    assert all(bool(jnp.isfinite(g).all()) for g in flat)
-    # layer grads are nonzero (the pipeline actually trained all stages)
-    assert float(jnp.abs(grads["layers"]["wq"]).sum()) > 0
-
-
-def test_pipeline_validates_inputs():
-    import numpy as _np
-    from jax.sharding import Mesh
-
-    from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
-    )
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=3, d_ff=64,
-        max_seq_len=32,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = Mesh(_np.asarray(jax.devices()[:4]), ("pipe",))
-    tokens = jnp.zeros((8, 8), jnp.int32)
-    with pytest.raises(ValueError, match="not divisible by 4 stages"):
-        pipeline_forward_with_aux(params, tokens, cfg, mesh)
-    cfg2 = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
-        max_seq_len=32,
-    )
-    params2 = init_params(jax.random.PRNGKey(0), cfg2)
-    with pytest.raises(ValueError, match="microbatches"):
-        pipeline_forward_with_aux(
-            params2, jnp.zeros((6, 8), jnp.int32), cfg2, mesh,
-            n_microbatches=4,
-        )
-
-
-def test_pipeline_composes_with_data_parallelism():
-    """dp x pp: a ("data", "pipe") mesh shards microbatch contents over
-    data while stages stream over pipe; parity with the plain forward."""
-    import numpy as _np
-    from jax.sharding import Mesh
-
-    from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
-    )
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
-        max_seq_len=32, dtype=jnp.float32,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = Mesh(
-        _np.asarray(jax.devices()[:8]).reshape(2, 4), ("data", "pipe")
-    )
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
-    )
-    ref = forward(params, tokens, cfg)
-    out, _aux = pipeline_forward_with_aux(
-        params, tokens, cfg, mesh, n_microbatches=4
-    )
-    np.testing.assert_allclose(
-        np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
-    )
-    # grads flow through the data-sharded specs and the aux pmean
-    from containerpilot_tpu.parallel.pipeline import pipeline_loss_fn
-
-    grads = jax.grad(
-        lambda p: pipeline_loss_fn(p, tokens, cfg, mesh, n_microbatches=4)
-    )(params)
-    flat, _ = jax.tree_util.tree_flatten(grads)
-    assert all(bool(jnp.isfinite(g).all()) for g in flat)
-    # microbatch size must divide the data axis
-    with pytest.raises(ValueError, match="data axis"):
-        pipeline_forward_with_aux(
-            params, tokens[:4], cfg, mesh, n_microbatches=4
-        )
-
-
-def test_pipeline_composes_with_tensor_parallelism():
-    """dp x pp x tp: layers shard over pipe stages while the model axis
-    stays live (auto-partitioned) inside each stage; forward parity with
-    the unpipelined model and a full pipelined train step."""
-    from containerpilot_tpu.parallel import (
-        init_train_state as _init,
-        make_pipeline_train_step,
-    )
-    from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
-        pipeline_sharding_rules,
-    )
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
-        max_seq_len=32, dtype=jnp.float32,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = make_mesh(jax.devices()[:8], plan=MeshPlan(2, 2, pipe=2))
-    assert mesh.axis_names == ("data", "pipe", "model")
-
-    # in-stage tp specs survive the pipe composition
-    rules = pipeline_sharding_rules(cfg, mesh)
-    assert tuple(rules["layers"]["wq"]) == ("pipe", None, "model", None)
-
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
-    )
-    ref = forward(params, tokens, cfg)
-    # auto-axis shard_map must run under jit (the eager impl path does
-    # not support auto axes) — which is the only real usage anyway
-    out, _aux = jax.jit(
-        lambda p, t: pipeline_forward_with_aux(p, t, cfg, mesh, 4)
-    )(params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
-    )
-
-    state = _init(jax.random.PRNGKey(0), cfg, mesh, rules=rules)
-    step = make_pipeline_train_step(cfg, mesh, n_microbatches=4)
-    batch = jax.random.randint(
-        jax.random.PRNGKey(2), (8, 13), 0, cfg.vocab_size, jnp.int32
-    )
-    state, loss = step(state, batch)
-    assert bool(jnp.isfinite(loss))
-    assert int(state.step) == 1
-
-
-def test_pipeline_composes_with_expert_parallelism():
-    """pp x ep x dp: switch-MoE experts shard over the auto model axis
-    inside each pipeline stage."""
-    from containerpilot_tpu.parallel import (
-        init_train_state as _init,
-        make_pipeline_train_step,
-    )
-    from containerpilot_tpu.parallel.pipeline import pipeline_sharding_rules
-
-    cfg = TransformerConfig(
-        vocab_size=128, d_model=64, n_heads=2, n_layers=4, d_ff=128,
-        max_seq_len=32, moe_experts=2, dtype=jnp.float32,
-    )
-    mesh = make_mesh(jax.devices()[:8], plan=MeshPlan(2, 2, pipe=2))
-    rules = pipeline_sharding_rules(cfg, mesh)
-    assert tuple(rules["layers"]["moe_w_in"]) == ("pipe", "model", None, None)
-    state = _init(jax.random.PRNGKey(0), cfg, mesh, rules=rules)
-    step = make_pipeline_train_step(cfg, mesh, n_microbatches=4)
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(2), (8, 33), 0, cfg.vocab_size, jnp.int32
-    )
-    state, loss = step(state, tokens)
-    assert bool(jnp.isfinite(loss))
-
-
 def test_memory_efficient_attention_value_and_grad():
     """Flash-algorithm training attention: forward and ALL THREE input
     gradients must match the einsum reference."""
@@ -3168,295 +2975,6 @@ def test_inference_server_reports_mesh(run):
     info, gen = run(scenario())
     assert info["mesh"] == {"data": 1, "model": 8}
     assert len(gen["tokens"][0]) == 4
-
-
-def test_compile_cache_env_populates_and_reuses(tmp_path):
-    """CONTAINERPILOT_COMPILE_CACHE: a workload CLI run persists its
-    compiled programs, and a fresh process reads them back (cache-hit
-    logging on) — the reincarnation-warmup lever the supervisor's
-    restart story leans on."""
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    wrapper = tmp_path / "train_cpu.py"
-    wrapper.write_text(
-        "import sys\n"
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        f"sys.path.insert(0, {repo!r})\n"
-        "from containerpilot_tpu.workload.train import main\n"
-        "sys.exit(main())\n"
-    )
-    cache = tmp_path / "xla-cache"
-    argv = [
-        sys.executable, "-u", str(wrapper),
-        "--steps", "2", "--batch", "2", "--seq-len", "16",
-        "--d-model", "32", "--n-layers", "1", "--n-heads", "2",
-        "--vocab", "64",
-    ]
-    env = dict(os.environ, CONTAINERPILOT_COMPILE_CACHE=str(cache))
-    env.pop("XLA_FLAGS", None)
-    # the dedicated cache dir must be the ONLY cache in play
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
-    first = subprocess.run(
-        argv, env=env, capture_output=True, text=True, timeout=240,
-    )
-    assert first.returncode == 0, first.stdout[-2000:] + first.stderr[-2000:]
-    entries = list(cache.iterdir())
-    assert entries, "compile cache never populated"
-    # second process must HIT the persisted entries, not just write new
-    env["JAX_EXPLAIN_CACHE_MISSES"] = "true"
-    before = {e.name for e in entries}
-    second = subprocess.run(
-        argv, env=env, capture_output=True, text=True, timeout=240,
-    )
-    assert second.returncode == 0, second.stderr[-2000:]
-    after = {e.name for e in cache.iterdir()}
-    assert before <= after  # nothing evicted; hits don't rewrite
-
-
-def test_continuous_deployment_reload_serves_new_checkpoint(tmp_path):
-    """The documented continuous-deployment loop
-    (examples/serving-pod.json5): ONE supervisor runs a trainer
-    writing checkpoints to a shared dir alongside an inference server
-    that started before any checkpoint existed; when training lands,
-    a control-socket reload reincarnates the server, which restores
-    the new weights — scores for a fixed input change, and the
-    supervisor log names the served step."""
-    import json
-    import os
-    import signal
-    import subprocess
-    import sys
-    import time as time_mod
-    import urllib.request
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-    def wrapper(name, module):
-        path = tmp_path / name
-        path.write_text(
-            "import sys\n"
-            "import jax\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
-            f"sys.path.insert(0, {repo!r})\n"
-            f"from containerpilot_tpu.workload.{module} import main\n"
-            "sys.exit(main())\n"
-        )
-        return str(path)
-
-    import socket as socket_mod
-
-    with socket_mod.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        http_port = s.getsockname()[1]
-    ck = tmp_path / "ck"
-    ctl = tmp_path / "cp.socket"
-    model_flags = ["--d-model", "32", "--n-layers", "1",
-                   "--n-heads", "2", "--vocab", "64"]
-    config = {
-        "stopTimeout": "5s",
-        "control": {"socket": str(ctl)},
-        "logging": {"level": "INFO", "format": "default",
-                    "output": "stdout"},
-        "jobs": [
-            {
-                "name": "trainer",
-                # gated on a file the TEST creates after scoring the
-                # pre-training weights — deterministic ordering on a
-                # box where job startup times race
-                "exec": ["/bin/sh", "-c",
-                         "while [ ! -f "
-                         + __import__("shlex").quote(
-                             str(tmp_path / "train-gate")
-                         )
-                         + " ]; do sleep 0.2; done; exec "
-                         + __import__("shlex").join(
-                             [sys.executable, "-u",
-                              wrapper("train_cpu.py", "train"),
-                              "--steps", "4", "--batch", "2",
-                              "--seq-len", "16",
-                              "--checkpoint-dir", str(ck),
-                              "--checkpoint-every", "1"]
-                             + model_flags
-                         )],
-                "restarts": "never",
-            },
-            {
-                "name": "server",
-                "exec": [sys.executable, "-u",
-                         wrapper("serve_cpu.py", "serve"),
-                         "--host", "127.0.0.1",
-                         "--port", str(http_port),
-                         "--max-len", "32",
-                         "--checkpoint-dir", str(ck)] + model_flags,
-                "restarts": "never",
-            },
-        ],
-    }
-    cfg_path = tmp_path / "cd.json5"
-    cfg_path.write_text(json.dumps(config))
-    env = dict(os.environ, PYTHONPATH=repo)
-    env.pop("XLA_FLAGS", None)
-    log_fh = open(tmp_path / "sup.log", "w")
-    sup = subprocess.Popen(
-        [sys.executable, "-m", "containerpilot_tpu",
-         "-config", str(cfg_path)],
-        cwd=repo, env=env, stdout=log_fh, stderr=subprocess.STDOUT,
-    )
-
-    def score():
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{http_port}/v1/score",
-            data=json.dumps({"tokens": [[1, 2, 3, 4]]}).encode(),
-            headers={"Content-Type": "application/json"},
-        )
-        with urllib.request.urlopen(req, timeout=60) as resp:
-            return json.loads(resp.read().decode())
-
-    def wait_health(deadline_s):
-        deadline = time_mod.monotonic() + deadline_s
-        while True:
-            try:
-                urllib.request.urlopen(
-                    f"http://127.0.0.1:{http_port}/health", timeout=2
-                )
-                return
-            except Exception:
-                assert sup.poll() is None, (
-                    tmp_path / "sup.log"
-                ).read_text()[-3000:]
-                assert time_mod.monotonic() < deadline, (
-                    tmp_path / "sup.log"
-                ).read_text()[-3000:]
-                time_mod.sleep(0.5)
-
-    try:
-        wait_health(300)
-        before = score()  # fresh-init weights (training is gated off)
-        (tmp_path / "train-gate").write_text("go")
-        from containerpilot_tpu.parallel import latest_step
-
-        deadline = time_mod.monotonic() + 300
-        while (latest_step(str(ck)) or 0) < 4:
-            assert time_mod.monotonic() < deadline, (
-                tmp_path / "sup.log"
-            ).read_text()[-3000:]
-            time_mod.sleep(0.5)
-
-        # the documented CD step: reload; the new generation's server
-        # restores the freshly trained checkpoint
-        from containerpilot_tpu.client import ControlClient
-
-        ControlClient(str(ctl)).reload()
-        # the OLD server keeps draining (and answering) for up to
-        # stopTimeout — don't race it: wait for the NEW generation's
-        # own markers (it restored the checkpoint, then bound the
-        # port — which it can only do once the old one released it)
-        deadline = time_mod.monotonic() + 300
-        while True:
-            log_text = (tmp_path / "sup.log").read_text()
-            if (
-                "serving checkpoint step 4" in log_text
-                and log_text.count("accepting traffic") >= 2
-            ):
-                break
-            assert sup.poll() is None, log_text[-3000:]
-            assert time_mod.monotonic() < deadline, log_text[-3000:]
-            time_mod.sleep(0.5)
-        wait_health(300)
-        after = score()
-        assert after["logprobs"] != before["logprobs"], (
-            "reload did not swap weights"
-        )
-    finally:
-        if sup.poll() is None:
-            sup.send_signal(signal.SIGTERM)
-            try:
-                sup.wait(timeout=60)
-            except subprocess.TimeoutExpired:
-                sup.kill()
-        log_fh.close()
-
-
-def test_trainer_graceful_preemption(tmp_path):
-    """SIGTERM mid-run: the trainer finishes the in-flight step,
-    checkpoints, exits 0; a restart resumes from that exact step —
-    the TPU-maintenance / supervisor-stop path."""
-    import json
-    import os
-    import signal
-    import subprocess
-    import sys
-    import time as time_mod
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    wrapper = tmp_path / "train_cpu.py"
-    wrapper.write_text(
-        "import sys\n"
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        f"sys.path.insert(0, {repo!r})\n"
-        "from containerpilot_tpu.workload.train import main\n"
-        "sys.exit(main())\n"
-    )
-    ckpt = tmp_path / "ckpt"
-    progress = tmp_path / "progress.json"
-    argv = [
-        sys.executable, "-u", str(wrapper),
-        "--steps", "500000", "--batch", "2", "--seq-len", "16",
-        "--d-model", "32", "--n-layers", "1", "--n-heads", "2",
-        "--vocab", "64",
-        "--checkpoint-dir", str(ckpt), "--checkpoint-every", "100000",
-        "--progress-file", str(progress),
-    ]
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.Popen(
-        argv, env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True,
-    )
-    try:
-        deadline = time_mod.monotonic() + 240
-        while True:
-            if progress.exists():
-                try:
-                    if json.loads(progress.read_text())["step"] >= 5:
-                        break
-                except (ValueError, KeyError):
-                    pass
-            assert time_mod.monotonic() < deadline, "trainer never progressed"
-            assert proc.poll() is None, proc.stdout.read()[-2000:]
-            time_mod.sleep(0.05)
-        proc.send_signal(signal.SIGTERM)
-        out, _ = proc.communicate(timeout=120)
-    finally:
-        if proc.poll() is None:
-            proc.kill()
-    assert proc.returncode == 0, out[-2000:]
-    assert "preempted: checkpoint saved at step" in out, out[-2000:]
-
-    from containerpilot_tpu.parallel import latest_step
-
-    saved = latest_step(str(ckpt))
-    assert saved is not None and saved >= 5
-    # the preemption message names the saved step — the save cannot be
-    # explained by the (100000-step) periodic cadence alone
-    assert f"checkpoint saved at step {saved}" in out, out[-2000:]
-
-    # restart resumes from exactly the preemption step and completes
-    finish = subprocess.run(
-        argv[:argv.index("500000")] + [str(saved + 3)]
-        + argv[argv.index("500000") + 1:],
-        env=env, capture_output=True, text=True, timeout=240,
-    )
-    assert finish.returncode == 0, finish.stdout[-2000:]
-    assert f"resumed from checkpoint at step {saved}" in finish.stdout, (
-        finish.stdout[-2000:]
-    )
 
 
 @pytest.mark.parametrize("seq", [16, 17])  # 17: chunk-padding path
