@@ -37,11 +37,13 @@ import threading
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from ..telemetry.goodput import EnginePhases
 from .digest import prefix_fingerprint
 
 
-def _tree_nbytes(host_tree: Any) -> int:
-    """Total bytes of a host pytree's array leaves."""
+def tree_nbytes(host_tree: Any) -> int:
+    """Total bytes of a pytree's array leaves (host or device: an
+    array knows its size without a transfer)."""
     import jax
 
     return sum(
@@ -79,6 +81,12 @@ class HostSpillTier:
             "refused": 0,       # entries larger than the whole budget
             "misses": 0,        # take() of a key not (or no longer) here
         }
+        #: where the two transfers below are accounted as
+        #: ``kvtier.spill`` and ``kvtier.readmit`` with their bytes:
+        #: the slot engine's accumulator once a prefix cache under an
+        #: engine attached it (PrefixCache.attach_phases), until then
+        #: the tier's own
+        self.phases = EnginePhases()
 
     def __len__(self) -> int:
         with self._lock:
@@ -129,8 +137,10 @@ class HostSpillTier:
 
         # device -> host OUTSIDE the lock: a multi-ms transfer must
         # not block concurrent match scans
-        host = jax.device_get(cache)
-        nbytes = _tree_nbytes(host)
+        with self.phases.span("kvtier.spill"):
+            host = jax.device_get(cache)
+        nbytes = tree_nbytes(host)
+        self.phases.spill_bytes += nbytes
         if nbytes > self.max_bytes:
             self.stats["refused"] += 1
             return False
@@ -158,7 +168,7 @@ class HostSpillTier:
         ``take``/``reuse_admission`` path a locally-spilled one
         takes, which is what makes handoff byte-parity hold by
         construction."""
-        nbytes = _tree_nbytes(host_tree)
+        nbytes = tree_nbytes(host_tree)
         if nbytes > self.max_bytes:
             self.stats["refused"] += 1
             return 0
@@ -202,8 +212,10 @@ class HostSpillTier:
             self.stats["misses"] += 1
             return None
         self.stats["readmitted"] += 1
+        self.phases.readmit_bytes += entry[1]
         # host -> device outside the lock, same rationale as put()
-        return jax.device_put(entry[0])
+        with self.phases.span("kvtier.readmit"):
+            return jax.device_put(entry[0])
 
     def snapshot(self) -> Dict[str, int]:
         """Stats + size for surfaces (``/v1/model``)."""

@@ -92,11 +92,13 @@ def _kv_dequant(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 
 def _logits(params: Params, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    x = _rms_norm(x, params["norm_out"])
-    return jnp.einsum(
-        "bsd,dv->bsv", x, maybe_dequant_top(params, "unembed", cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm_out"])
+        return jnp.einsum(
+            "bsd,dv->bsv", x,
+            maybe_dequant_top(params, "unembed", cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
 
 
 def prefill(
@@ -133,9 +135,7 @@ def prefill(
     else:
         attn_fn = causal_attention
 
-    def body(carry, layer_params):
-        layer_params = maybe_dequant_layer(layer_params, cfg.dtype)
-        q, k, v = _qkv(carry, layer_params, cfg)
+    def attend(q, k, v):
         if cfg.kv_int8:
             # attention reads the quantization roundtrip, exactly what
             # any later decode reads from the cache — prefill,
@@ -159,33 +159,42 @@ def prefill(
             attn = attn_fn(
                 q, repeat_kv(k, cfg.n_heads), repeat_kv(v, cfg.n_heads)
             )
+        return attn, k, v
+
+    def body(carry, layer_params):
+        layer_params = maybe_dequant_layer(layer_params, cfg.dtype)
+        q, k, v = _qkv(carry, layer_params, cfg)
+        with jax.named_scope("attn"), jax.named_scope("attn.scores"):
+            attn, k, v = attend(q, k, v)
         out, _aux = _ffn(
             _attn_out(carry, attn, layer_params, cfg), layer_params, cfg
         )
         return out, (k, v)  # cache stores the unrepeated kv heads
 
-    x, (ks, vs) = lax.scan(body, x, params["layers"])
-    cache = init_cache(cfg, b, max_len)
-    length = cache["k"].shape[2]
-    writes = {"k": ks, "v": vs}
-    if cfg.kv_int8:
-        writes["k"], writes["k_scale"] = _kv_quant(ks)
-        writes["v"], writes["v_scale"] = _kv_quant(vs)
-    if s > length:
-        # ring cache smaller than the prompt: keep the last `length`
-        # positions, each at its slot p % length (static scatter)
-        import numpy as _np
+    with jax.named_scope("layers"):
+        x, (ks, vs) = lax.scan(body, x, params["layers"])
+    with jax.named_scope("attn"), jax.named_scope("attn.kv_write"):
+        cache = init_cache(cfg, b, max_len)
+        length = cache["k"].shape[2]
+        writes = {"k": ks, "v": vs}
+        if cfg.kv_int8:
+            writes["k"], writes["k_scale"] = _kv_quant(ks)
+            writes["v"], writes["v_scale"] = _kv_quant(vs)
+        if s > length:
+            # ring cache smaller than the prompt: keep the last `length`
+            # positions, each at its slot p % length (static scatter)
+            import numpy as _np
 
-        slots = _np.arange(s - length, s) % length
-        for name, arr in writes.items():
-            cache[name] = cache[name].at[:, :, slots].set(
-                arr[:, :, s - length:]
-            )
-    else:
-        for name, arr in writes.items():
-            cache[name] = lax.dynamic_update_slice(
-                cache[name], arr, (0,) * cache[name].ndim
-            )
+            slots = _np.arange(s - length, s) % length
+            for name, arr in writes.items():
+                cache[name] = cache[name].at[:, :, slots].set(
+                    arr[:, :, s - length:]
+                )
+        else:
+            for name, arr in writes.items():
+                cache[name] = lax.dynamic_update_slice(
+                    cache[name], arr, (0,) * cache[name].ndim
+                )
     cache["pos"] = jnp.asarray(s, jnp.int32)
     logits = _logits(params, x[:, -1:, :], cfg)
     return logits[:, 0, :], cache
@@ -324,78 +333,88 @@ def decode_chunk(
         else:
             layer_params = maybe_dequant_layer(layer_params, cfg.dtype)
             q, k, v = _qkv(x, layer_params, cfg, offset=pos)
-        if kv_int8:
-            k_q, k_s = _kv_quant(k)
-            v_q, v_s = _kv_quant(v)
-        if ring:
-            # the chunk's own k/v also read through the quantization
-            # roundtrip, so chunked decode matches sequential steps
-            # (which read their keys back from the quantized ring)
-            cached_k = (
-                _kv_dequant(k_cache, kv_layer["k_scale"], cfg.dtype)
-                if kv_int8 else k_cache
-            )
-            cached_v = (
-                _kv_dequant(v_cache, kv_layer["v_scale"], cfg.dtype)
-                if kv_int8 else v_cache
-            )
-            chunk_k = _kv_dequant(k_q, k_s, cfg.dtype) if kv_int8 else k
-            chunk_v = _kv_dequant(v_q, v_s, cfg.dtype) if kv_int8 else v
-            keys = jnp.concatenate([cached_k, chunk_k], axis=1)
-            values = jnp.concatenate([cached_v, chunk_v], axis=1)
-            slots = jnp.mod(pos + q_idx, length)
-            new_kv = dict(kv_layer)
+        with jax.named_scope("attn"), jax.named_scope("attn.kv_write"):
             if kv_int8:
-                new_kv["k"] = k_cache.at[:, slots].set(k_q)
-                new_kv["v"] = v_cache.at[:, slots].set(v_q)
-                new_kv["k_scale"] = kv_layer["k_scale"].at[:, slots].set(k_s)
-                new_kv["v_scale"] = kv_layer["v_scale"].at[:, slots].set(v_s)
+                k_q, k_s = _kv_quant(k)
+                v_q, v_s = _kv_quant(v)
+            if ring:
+                # the chunk's own k/v also read through the quantization
+                # roundtrip, so chunked decode matches sequential steps
+                # (which read their keys back from the quantized ring)
+                cached_k = (
+                    _kv_dequant(k_cache, kv_layer["k_scale"], cfg.dtype)
+                    if kv_int8 else k_cache
+                )
+                cached_v = (
+                    _kv_dequant(v_cache, kv_layer["v_scale"], cfg.dtype)
+                    if kv_int8 else v_cache
+                )
+                chunk_k = (
+                    _kv_dequant(k_q, k_s, cfg.dtype) if kv_int8 else k
+                )
+                chunk_v = (
+                    _kv_dequant(v_q, v_s, cfg.dtype) if kv_int8 else v
+                )
+                keys = jnp.concatenate([cached_k, chunk_k], axis=1)
+                values = jnp.concatenate([cached_v, chunk_v], axis=1)
+                slots = jnp.mod(pos + q_idx, length)
+                new_kv = dict(kv_layer)
+                if kv_int8:
+                    new_kv["k"] = k_cache.at[:, slots].set(k_q)
+                    new_kv["v"] = v_cache.at[:, slots].set(v_q)
+                    new_kv["k_scale"] = (
+                        kv_layer["k_scale"].at[:, slots].set(k_s)
+                    )
+                    new_kv["v_scale"] = (
+                        kv_layer["v_scale"].at[:, slots].set(v_s)
+                    )
+                else:
+                    new_kv["k"] = k_cache.at[:, slots].set(k)
+                    new_kv["v"] = v_cache.at[:, slots].set(v)
             else:
-                new_kv["k"] = k_cache.at[:, slots].set(k)
-                new_kv["v"] = v_cache.at[:, slots].set(v)
-        else:
-            new_kv = dict(kv_layer)
-            if kv_int8:
-                new_kv["k"] = lax.dynamic_update_slice(
-                    k_cache, k_q, (0, pos, 0, 0)
-                )
-                new_kv["v"] = lax.dynamic_update_slice(
-                    v_cache, v_q, (0, pos, 0, 0)
-                )
-                new_kv["k_scale"] = lax.dynamic_update_slice(
-                    kv_layer["k_scale"], k_s, (0, pos, 0)
-                )
-                new_kv["v_scale"] = lax.dynamic_update_slice(
-                    kv_layer["v_scale"], v_s, (0, pos, 0)
-                )
-                keys = _kv_dequant(
-                    new_kv["k"], new_kv["k_scale"], cfg.dtype
-                )
-                values = _kv_dequant(
-                    new_kv["v"], new_kv["v_scale"], cfg.dtype
-                )
-            else:
-                new_kv["k"] = lax.dynamic_update_slice(
-                    k_cache, k, (0, pos, 0, 0)
-                )
-                new_kv["v"] = lax.dynamic_update_slice(
-                    v_cache, v, (0, pos, 0, 0)
-                )
-                keys, values = new_kv["k"], new_kv["v"]
-        k_full = repeat_kv(keys, cfg.n_heads)
-        v_full = repeat_kv(values, cfg.n_heads)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk",
-            q.astype(jnp.float32) * cfg.head_dim ** -0.5,
-            k_full.astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )  # [b, h, m, length(+m)]
-        scores = jnp.where(valid[None, None, :, :], scores, NEG_INF)
-        weights = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum(
-            "bhqk,bkhd->bqhd", weights, v_full,
-            preferred_element_type=jnp.float32,
-        ).astype(cfg.dtype)
+                new_kv = dict(kv_layer)
+                if kv_int8:
+                    new_kv["k"] = lax.dynamic_update_slice(
+                        k_cache, k_q, (0, pos, 0, 0)
+                    )
+                    new_kv["v"] = lax.dynamic_update_slice(
+                        v_cache, v_q, (0, pos, 0, 0)
+                    )
+                    new_kv["k_scale"] = lax.dynamic_update_slice(
+                        kv_layer["k_scale"], k_s, (0, pos, 0)
+                    )
+                    new_kv["v_scale"] = lax.dynamic_update_slice(
+                        kv_layer["v_scale"], v_s, (0, pos, 0)
+                    )
+                    keys = _kv_dequant(
+                        new_kv["k"], new_kv["k_scale"], cfg.dtype
+                    )
+                    values = _kv_dequant(
+                        new_kv["v"], new_kv["v_scale"], cfg.dtype
+                    )
+                else:
+                    new_kv["k"] = lax.dynamic_update_slice(
+                        k_cache, k, (0, pos, 0, 0)
+                    )
+                    new_kv["v"] = lax.dynamic_update_slice(
+                        v_cache, v, (0, pos, 0, 0)
+                    )
+                    keys, values = new_kv["k"], new_kv["v"]
+        with jax.named_scope("attn"), jax.named_scope("attn.scores"):
+            k_full = repeat_kv(keys, cfg.n_heads)
+            v_full = repeat_kv(values, cfg.n_heads)
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk",
+                q.astype(jnp.float32) * cfg.head_dim ** -0.5,
+                k_full.astype(jnp.float32),
+                preferred_element_type=jnp.float32,
+            )  # [b, h, m, length(+m)]
+            scores = jnp.where(valid[None, None, :, :], scores, NEG_INF)
+            weights = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum(
+                "bhqk,bkhd->bqhd", weights, v_full,
+                preferred_element_type=jnp.float32,
+            ).astype(cfg.dtype)
         if fused:
             x = fused_attn_out(x, attn, layer_params, cfg)
             x = fused_mlp(x, layer_params, cfg)
@@ -407,7 +426,8 @@ def decode_chunk(
     kv_in = {
         name: cache[name] for name in cache if name != "pos"
     }
-    x, new_kv = lax.scan(body, x, (params["layers"], kv_in))
+    with jax.named_scope("layers"):
+        x, new_kv = lax.scan(body, x, (params["layers"], kv_in))
     logits = _logits(params, x, cfg)  # [b, m, vocab]
     return logits, {**new_kv, "pos": pos + m}
 
@@ -584,6 +604,7 @@ def _sampling_scan(cfg, max_new_tokens: int, greedy: bool,
     def scan(params, cache, logits, row_keys, temperature, top_k,
              top_p, eos_id, pad_id, min_new, presence, frequency,
              bias_idx, bias_val):
+        @jax.named_scope("sample")
         def sample(logits, step_idx, counts):
             if penalized:
                 logits = apply_token_penalties(

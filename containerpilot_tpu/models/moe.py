@@ -23,17 +23,20 @@ def _route(x: jax.Array, router_w: jax.Array):
     """Top-1 switch routing shared by the drop-free and capacity
     layers: returns (probs, gate, onehot, aux_loss)."""
     n_experts = router_w.shape[-1]
-    router_logits = jnp.einsum(
-        "bsd,de->bse", x.astype(jnp.float32), router_w.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [b,s,E]
-    expert_idx = jnp.argmax(probs, axis=-1)  # [b,s]
-    gate = jnp.max(probs, axis=-1)  # [b,s]
-    onehot = jax.nn.one_hot(expert_idx, n_experts, dtype=jnp.float32)
-    fraction = jnp.mean(onehot, axis=(0, 1))
-    router_mean = jnp.mean(probs, axis=(0, 1))
-    aux_loss = n_experts * jnp.sum(fraction * router_mean)
+    # callers sit under the ``mlp`` scope (transformer._ffn)
+    with jax.named_scope("mlp.router"):
+        router_logits = jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32),
+            router_w.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)  # [b,s,E]
+        expert_idx = jnp.argmax(probs, axis=-1)  # [b,s]
+        gate = jnp.max(probs, axis=-1)  # [b,s]
+        onehot = jax.nn.one_hot(expert_idx, n_experts, dtype=jnp.float32)
+        fraction = jnp.mean(onehot, axis=(0, 1))
+        router_mean = jnp.mean(probs, axis=(0, 1))
+        aux_loss = n_experts * jnp.sum(fraction * router_mean)
     return probs, gate, onehot, aux_loss
 
 
@@ -59,12 +62,15 @@ def moe_layer(
     # — the TPU MXU accumulates bf16 inputs in f32 internally, and the
     # CPU backend's batched dot lacks the bf16->f32 widening variant
     dt = x.dtype
-    expert_in = jnp.einsum("bse,bsd->besd", onehot.astype(dt), x)
-    hidden = jnp.einsum("besd,edf->besf", expert_in, w_in.astype(dt))
-    hidden = jax.nn.gelu(hidden.astype(jnp.float32)).astype(dt)
-    expert_out = jnp.einsum("besf,efd->besd", hidden, w_out.astype(dt))
-    combine = (onehot * gate[..., None]).astype(dt)
-    out = jnp.einsum("bse,besd->bsd", combine, expert_out)
+    with jax.named_scope("mlp.experts"):
+        expert_in = jnp.einsum("bse,bsd->besd", onehot.astype(dt), x)
+        hidden = jnp.einsum("besd,edf->besf", expert_in, w_in.astype(dt))
+        hidden = jax.nn.gelu(hidden.astype(jnp.float32)).astype(dt)
+        expert_out = jnp.einsum(
+            "besf,efd->besd", hidden, w_out.astype(dt)
+        )
+        combine = (onehot * gate[..., None]).astype(dt)
+        out = jnp.einsum("bse,besd->bsd", combine, expert_out)
     return out, aux_loss
 
 

@@ -98,11 +98,12 @@ def maybe_dequant_layer(
 def embed_lookup(params: Any, tokens: jax.Array, dtype: Any) -> jax.Array:
     """Embedding gather that dequantizes only the gathered rows when
     the table is stored int8."""
-    if "embed" in params:
-        return params["embed"].astype(dtype)[tokens]
-    rows = params["embed_q"][tokens].astype(jnp.float32)
-    scales = params["embed_s"][tokens][..., 0][..., None]  # [., 1]
-    return (rows * scales).astype(dtype)
+    with jax.named_scope("embed"):
+        if "embed" in params:
+            return params["embed"].astype(dtype)[tokens]
+        rows = params["embed_q"][tokens].astype(jnp.float32)
+        scales = params["embed_s"][tokens][..., 0][..., None]  # [., 1]
+        return (rows * scales).astype(dtype)
 
 
 def maybe_dequant_top(params: Any, key: str, dtype: Any) -> jax.Array:
@@ -176,13 +177,18 @@ def fused_qkv(
     from .transformer import _rms_norm, _rope
 
     b, s, d = x.shape
-    h = _rms_norm(x, layer_params["norm_attn"]).reshape(b * s, d)
     hd = cfg.head_dim
-    q = _fused_proj(h, layer_params, "wq").reshape(b, s, cfg.n_heads, hd)
-    k = _fused_proj(h, layer_params, "wk").reshape(b, s, cfg.kv_heads, hd)
-    v = _fused_proj(h, layer_params, "wv").reshape(b, s, cfg.kv_heads, hd)
-    q = _rope(q, cfg.rope_theta, offset)
-    k = _rope(k, cfg.rope_theta, offset)
+    with jax.named_scope("attn"), jax.named_scope("attn.qkv"):
+        h = _rms_norm(x, layer_params["norm_attn"]).reshape(b * s, d)
+        q = _fused_proj(h, layer_params, "wq").reshape(
+            b, s, cfg.n_heads, hd)
+        k = _fused_proj(h, layer_params, "wk").reshape(
+            b, s, cfg.kv_heads, hd)
+        v = _fused_proj(h, layer_params, "wv").reshape(
+            b, s, cfg.kv_heads, hd)
+    with jax.named_scope("attn"), jax.named_scope("attn.rope"):
+        q = _rope(q, cfg.rope_theta, offset)
+        k = _rope(k, cfg.rope_theta, offset)
     return q, k, v
 
 
@@ -195,10 +201,11 @@ def fused_attn_out(
     """Output projection + residual, int8-fused (wo is [h, hd, d]:
     the h*hd axes flatten to the GEMM's k)."""
     b, s, h, hd = attn.shape
-    out = _fused_proj(
-        attn.reshape(b * s, h * hd), layer_params, "wo"
-    ).reshape(b, s, -1)
-    return x + out
+    with jax.named_scope("attn"), jax.named_scope("attn.out"):
+        out = _fused_proj(
+            attn.reshape(b * s, h * hd), layer_params, "wo"
+        ).reshape(b, s, -1)
+        return x + out
 
 
 def fused_mlp(
@@ -208,12 +215,13 @@ def fused_mlp(
     from .transformer import _rms_norm
 
     b, s, d = x.shape
-    h = _rms_norm(x, layer_params["norm_mlp"]).reshape(b * s, d)
-    gate = _fused_proj(h, layer_params, "w_gate").astype(jnp.float32)
-    up = _fused_proj(h, layer_params, "w_up").astype(jnp.float32)
-    act = (jax.nn.silu(gate) * up).astype(cfg.dtype)
-    down = _fused_proj(act, layer_params, "w_down").reshape(b, s, d)
-    return x + down
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, layer_params["norm_mlp"]).reshape(b * s, d)
+        gate = _fused_proj(h, layer_params, "w_gate").astype(jnp.float32)
+        up = _fused_proj(h, layer_params, "w_up").astype(jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(cfg.dtype)
+        down = _fused_proj(act, layer_params, "w_down").reshape(b, s, d)
+        return x + down
 
 
 # ---------------------------------------------------------------------------

@@ -270,26 +270,27 @@ def _round_step_body(params, state, vstep):
     def body(carry, _):
         pool, tok, done, idx, counts = carry
         logits, pool = vstep(params, pool, tok[:, None])  # [S,1,V]
-        keys = jax.vmap(jax.random.fold_in)(row_keys, idx)
-        masked = apply_token_penalties(
-            logits[:, 0, :], counts, state["presence"],
-            state["frequency"],
-        )
-        # always-on operand (the pool program is ONE compile):
-        # idx -1 rows add exactly zero, bitwise-neutral
-        masked = apply_logit_bias(
-            masked, state["bias_idx"], state["bias_val"]
-        )
-        masked = mask_eos_before_min(
-            masked, idx, state["min_new"], eos_id
-        )
-        nxt = sample_logits(
-            masked, keys, state["temperature"], state["top_k"],
-            state["top_p"],
-        ).astype(jnp.int32)
-        nxt = jnp.where(done, pad_id, nxt)
-        done = done | (nxt == eos_id)
-        counts = count_token(counts, nxt, ~done)
+        with jax.named_scope("sample"):
+            keys = jax.vmap(jax.random.fold_in)(row_keys, idx)
+            masked = apply_token_penalties(
+                logits[:, 0, :], counts, state["presence"],
+                state["frequency"],
+            )
+            # always-on operand (the pool program is ONE compile):
+            # idx -1 rows add exactly zero, bitwise-neutral
+            masked = apply_logit_bias(
+                masked, state["bias_idx"], state["bias_val"]
+            )
+            masked = mask_eos_before_min(
+                masked, idx, state["min_new"], eos_id
+            )
+            nxt = sample_logits(
+                masked, keys, state["temperature"], state["top_k"],
+                state["top_p"],
+            ).astype(jnp.int32)
+            nxt = jnp.where(done, pad_id, nxt)
+            done = done | (nxt == eos_id)
+            counts = count_token(counts, nxt, ~done)
         return (pool, nxt, done, idx + 1, counts), nxt
 
     return body
@@ -313,12 +314,15 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
 
     def run(params, pool, state):
         body = _round_step_body(params, state, vstep)
-        (pool, last, done, idx, counts), toks = lax.scan(
-            body,
-            (pool, state["last"], state["done"], state["step_idx"],
-             state["counts"]),
-            None, length=chunk,
-        )
+        # ``steps`` names what the step loop itself does around its
+        # body (on the chip: copies of the whole pool, PERF.md s.5)
+        with jax.named_scope("steps"):
+            (pool, last, done, idx, counts), toks = lax.scan(
+                body,
+                (pool, state["last"], state["done"], state["step_idx"],
+                 state["counts"]),
+                None, length=chunk,
+            )
         new_state = dict(
             state, last=last, done=done, counts=counts,
             step_idx=idx,
@@ -376,11 +380,12 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
             )
             return (r + 1, pool, last, done, idx, counts, out)
 
-        r, pool, last, done, idx, counts, out = lax.while_loop(
-            cond, round_body,
-            (jnp.int32(0), pool, state["last"], state["done"],
-             state["step_idx"], state["counts"], out0),
-        )
+        with jax.named_scope("steps"):
+            r, pool, last, done, idx, counts, out = lax.while_loop(
+                cond, round_body,
+                (jnp.int32(0), pool, state["last"], state["done"],
+                 state["step_idx"], state["counts"], out0),
+            )
         new_state = dict(
             state, last=last, done=done, counts=counts, step_idx=idx,
         )
@@ -444,6 +449,7 @@ def _jitted_first_sample(cfg: TransformerConfig):
     """Sample token 0 from prefill logits with generate's key
     schedule (fold_in(row_key, 0))."""
 
+    @jax.named_scope("sample")
     def first(logits, row_key, temperature, top_k, top_p, eos_id,
               min_new, bias_idx, bias_val):
         # counts are empty at sample 0, so penalties are a no-op here

@@ -203,8 +203,17 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 
 
 def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * lax.rsqrt(var + 1e-6).astype(x.dtype)) * scale.astype(x.dtype)
+    # named scopes (here and below) are the layer map's names in the
+    # compiled programs' op metadata: a profiler trace attributes
+    # device time by them (docs/90-observability.md). Metadata only.
+    with jax.named_scope("norm"):
+        var = jnp.mean(
+            jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True
+        )
+        return (
+            (x * lax.rsqrt(var + 1e-6).astype(x.dtype))
+            * scale.astype(x.dtype)
+        )
 
 
 def _rope(x: jax.Array, theta: float, offset: Any = 0) -> jax.Array:
@@ -235,15 +244,17 @@ def _qkv(
     GQA) or broadcast to full heads via ``repeat_kv`` for attention.
     """
     dt = cfg.dtype
-    h = _rms_norm(x, layer_params["norm_attn"])
-    q = jnp.einsum("bsd,dhk->bshk", h, layer_params["wq"].astype(dt),
-                   preferred_element_type=jnp.float32).astype(dt)
-    k = jnp.einsum("bsd,dhk->bshk", h, layer_params["wk"].astype(dt),
-                   preferred_element_type=jnp.float32).astype(dt)
-    v = jnp.einsum("bsd,dhk->bshk", h, layer_params["wv"].astype(dt),
-                   preferred_element_type=jnp.float32).astype(dt)
-    q = _rope(q, cfg.rope_theta, offset)
-    k = _rope(k, cfg.rope_theta, offset)
+    with jax.named_scope("attn"), jax.named_scope("attn.qkv"):
+        h = _rms_norm(x, layer_params["norm_attn"])
+        q = jnp.einsum("bsd,dhk->bshk", h, layer_params["wq"].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+        k = jnp.einsum("bsd,dhk->bshk", h, layer_params["wk"].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+        v = jnp.einsum("bsd,dhk->bshk", h, layer_params["wv"].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+    with jax.named_scope("attn"), jax.named_scope("attn.rope"):
+        q = _rope(q, cfg.rope_theta, offset)
+        k = _rope(k, cfg.rope_theta, offset)
     return q, k, v
 
 
@@ -263,10 +274,11 @@ def _attn_out(
 ) -> jax.Array:
     """Output projection + residual."""
     dt = cfg.dtype
-    attn_out = jnp.einsum("bshk,hkd->bsd", attn,
-                          layer_params["wo"].astype(dt),
-                          preferred_element_type=jnp.float32).astype(dt)
-    return x + attn_out
+    with jax.named_scope("attn"), jax.named_scope("attn.out"):
+        attn_out = jnp.einsum("bshk,hkd->bsd", attn,
+                              layer_params["wo"].astype(dt),
+                              preferred_element_type=jnp.float32).astype(dt)
+        return x + attn_out
 
 
 def _mlp(
@@ -274,15 +286,18 @@ def _mlp(
 ) -> jax.Array:
     """SwiGLU block + residual."""
     dt = cfg.dtype
-    h = _rms_norm(x, layer_params["norm_mlp"])
-    gate = jnp.einsum("bsd,df->bsf", h, layer_params["w_gate"].astype(dt),
-                      preferred_element_type=jnp.float32)
-    up = jnp.einsum("bsd,df->bsf", h, layer_params["w_up"].astype(dt),
-                    preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gate) * up).astype(dt)
-    down = jnp.einsum("bsf,fd->bsd", act, layer_params["w_down"].astype(dt),
-                      preferred_element_type=jnp.float32).astype(dt)
-    return x + down
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, layer_params["norm_mlp"])
+        gate = jnp.einsum("bsd,df->bsf", h,
+                          layer_params["w_gate"].astype(dt),
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("bsd,df->bsf", h, layer_params["w_up"].astype(dt),
+                        preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(dt)
+        down = jnp.einsum("bsf,fd->bsd", act,
+                          layer_params["w_down"].astype(dt),
+                          preferred_element_type=jnp.float32).astype(dt)
+        return x + down
 
 
 def _ffn(
@@ -293,23 +308,24 @@ def _ffn(
     if cfg.moe_experts > 0:
         from .moe import moe_layer, moe_layer_capacity
 
-        h = _rms_norm(x, layer_params["norm_mlp"])
-        if cfg.moe_train_capacity > 0:
-            out, aux = moe_layer_capacity(
-                h,
-                layer_params["router"],
-                layer_params["moe_w_in"],
-                layer_params["moe_w_out"],
-                cfg.moe_train_capacity,
-            )
-        else:
-            out, aux = moe_layer(
-                h,
-                layer_params["router"],
-                layer_params["moe_w_in"],
-                layer_params["moe_w_out"],
-            )
-        return x + out, aux
+        with jax.named_scope("mlp"):
+            h = _rms_norm(x, layer_params["norm_mlp"])
+            if cfg.moe_train_capacity > 0:
+                out, aux = moe_layer_capacity(
+                    h,
+                    layer_params["router"],
+                    layer_params["moe_w_in"],
+                    layer_params["moe_w_out"],
+                    cfg.moe_train_capacity,
+                )
+            else:
+                out, aux = moe_layer(
+                    h,
+                    layer_params["router"],
+                    layer_params["moe_w_in"],
+                    layer_params["moe_w_out"],
+                )
+            return x + out, aux
     return _mlp(x, layer_params, cfg), jnp.zeros((), jnp.float32)
 
 
@@ -327,7 +343,8 @@ def _layer(
         # the point of GQA; everything else gets full heads
         k = repeat_kv(k, cfg.n_heads)
         v = repeat_kv(v, cfg.n_heads)
-    attn = attn_fn(q, k, v)
+    with jax.named_scope("attn"), jax.named_scope("attn.scores"):
+        attn = attn_fn(q, k, v)
     x = _attn_out(x, attn, layer_params, cfg)
     return _ffn(x, layer_params, cfg)
 
@@ -364,9 +381,12 @@ def forward_hidden(
             )
         else:
             body = jax.checkpoint(body)
-    (x, aux), _ = lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params["layers"]
-    )
+    # ``layers`` names what the scan itself does around the blocks
+    # (slicing the stacked weights, stacking the backward's residuals)
+    with jax.named_scope("layers"):
+        (x, aux), _ = lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), params["layers"]
+        )
     return _rms_norm(x, params["norm_out"]), aux
 
 
@@ -376,10 +396,12 @@ def forward_with_aux(
     """tokens: [batch, seq] int32 -> (logits [batch, seq, vocab] f32,
     aux_loss scalar — MoE load balance; zero for dense models)."""
     x, aux = forward_hidden(params, tokens, cfg)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, maybe_dequant_top(params, "unembed", cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x,
+            maybe_dequant_top(params, "unembed", cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
     return logits, aux
 
 
@@ -395,8 +417,11 @@ def _ce_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
     core shared by the whole-logits and chunked losses, so a change
     to the objective (z-loss, label smoothing, soft-capping) cannot
     silently apply to only one path."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(
+            logp, targets[..., None], axis=-1
+        )[..., 0]
 
 
 def next_token_loss(
@@ -431,7 +456,8 @@ def _chunked_next_token_loss(
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         targets = jnp.pad(targets, ((0, 0), (0, pad)))
     mask = (jnp.arange(n * chunk) < s)[None, :]  # [1, n*chunk]
-    unembed = maybe_dequant_top(params, "unembed", cfg.dtype)
+    with jax.named_scope("head"):
+        unembed = maybe_dequant_top(params, "unembed", cfg.dtype)
 
     x_chunks = x.reshape(b, n, chunk, d).swapaxes(0, 1)  # [n,b,c,d]
     t_chunks = targets.reshape(b, n, chunk).swapaxes(0, 1)
@@ -440,15 +466,19 @@ def _chunked_next_token_loss(
     @jax.checkpoint
     def piece(total, inputs):
         xc, tc, mc = inputs
-        logits = jnp.einsum(
-            "bcd,dv->bcv", xc, unembed,
-            preferred_element_type=jnp.float32,
-        )
-        return total + jnp.sum(_ce_nll(logits, tc) * mc), None
+        with jax.named_scope("head"):
+            logits = jnp.einsum(
+                "bcd,dv->bcv", xc, unembed,
+                preferred_element_type=jnp.float32,
+            )
+        with jax.named_scope("loss"):
+            return total + jnp.sum(_ce_nll(logits, tc) * mc), None
 
-    total, _ = lax.scan(
-        piece, jnp.zeros((), jnp.float32), (x_chunks, t_chunks, m_chunks)
-    )
+    with jax.named_scope("loss.chunks"):
+        total, _ = lax.scan(
+            piece, jnp.zeros((), jnp.float32),
+            (x_chunks, t_chunks, m_chunks),
+        )
     return total / (b * s) + cfg.moe_aux_weight * aux
 
 

@@ -223,6 +223,9 @@ def _fwd_rows(
 
     return pl.pallas_call(
         kernel,
+        # stable kernel names: a profiler trace finds the three flash
+        # kernels by them (docs/90-observability.md)
+        name="flash_fwd",
         grid=(rows, s // block_q, n_kv_grid),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda r, i, j: (r, i, 0)),
@@ -349,6 +352,7 @@ def _bwd_rows(
             _dq_kernel, block_q=block_q, block_k=block_k, scale=scale,
             window=window, total_kv=total_kv,
         ),
+        name="flash_bwd_dq",
         grid=(rows, s // block_q, n_kv_grid),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda r, i, j: (r, i, 0)),
@@ -376,6 +380,7 @@ def _bwd_rows(
             _dkdv_kernel, block_q=block_q, block_k=block_k, scale=scale,
             window=window, total_q=total_q,
         ),
+        name="flash_bwd_dkdv",
         grid=(rows, s // block_k, n_q_grid),
         in_specs=[
             pl.BlockSpec((1, block_k, hd), lambda r, j, i: (r, j, 0)),

@@ -137,6 +137,7 @@ def int8_matmul_pallas(
     kernel = functools.partial(_int8_matmul_kernel, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="int8_matmul",  # stable: read from profiler traces
         grid=(m // block_m, n // block_n, n_k),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, ki: (i, ki)),

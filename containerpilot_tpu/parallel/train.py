@@ -381,10 +381,13 @@ def make_train_step(
 
     def step_fn(state: TrainState, tokens: jax.Array):
         loss, grads = grads_of(state.params, tokens)
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        # the layer map's name for clip + AdamW + the parameters'
+        # update in the step program's op metadata
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 params=new_params,
@@ -498,10 +501,11 @@ def make_lora_train_step(
         loss, grads = jax.value_and_grad(loss_of)(
             state.params, base, tokens
         )
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_lora = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_lora = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 params=new_lora,
@@ -555,10 +559,11 @@ def make_pipeline_train_step(
         loss, grads = jax.value_and_grad(pipeline_loss_fn)(
             state.params, tokens, cfg, mesh, n_microbatches
         )
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 params=new_params,

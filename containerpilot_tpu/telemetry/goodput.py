@@ -33,6 +33,17 @@ it down. This module is the accounting layer:
   (stamped BEFORE ``/health`` flips 200: a scale-up replica's badput
   is visible from its very first scrape, never an ``idle`` lie), and
   a draining replica's last in-flight decodes are costed as drain.
+- **Engine phases, per cycle.** ``EnginePhases`` (owned by the
+  ledger as ``ledger.engine``) partitions every cycle of the slot
+  engine's worker thread into named phases (``engine.wait_work``,
+  ``engine.admit`` and its children, ``engine.dispatch``,
+  ``engine.fetch``, ``engine.deliver``). Each boundary adds seconds
+  and a count here AND opens a ``jax.profiler.TraceAnnotation`` of
+  the same name, so a profiler trace shows the phase on the
+  ``slot-engine`` line, on the device events' clock. Work is
+  O(phases) per cycle (a cycle is one chunk or one fused window),
+  never per token or per slot. Served as the ``engine`` block of
+  ``GET /v1/goodput``; the stage machine above does not change.
 - **One wire format.** ``note()`` encodes the cumulative totals as a
   ``gp=`` field on the TTL heartbeat (the duck-typed channel
   occupancy and ``kv=`` already ride); ``parse_note`` is the
@@ -51,6 +62,8 @@ report. docs/90-observability.md is the runbook.
 """
 from __future__ import annotations
 
+import contextlib
+import os
 import threading
 import time
 from collections import deque
@@ -59,12 +72,17 @@ from typing import Any, Dict, Iterable, List, Mapping, Optional, Tuple
 __all__ = [
     "BADPUT_STAGES",
     "DeviceTimeLedger",
+    "ENGINE_CYCLE_PHASES",
+    "ENGINE_PHASES",
+    "EnginePhases",
     "NOTE_FIELDS",
     "PRODUCTIVE_STAGES",
     "STAGES",
     "find_scheduling_gaps",
     "merge_note_max",
+    "name_os_thread",
     "parse_note",
+    "process_start_monotonic",
     "productive_fraction",
     "sum_stage_totals",
 ]
@@ -91,6 +109,159 @@ NOTE_FIELDS = STAGES + ("dispatches", "tokens_out")
 #: recent idle segments retained for scheduling-gap detection (each
 #: is two floats; the ring bounds memory like the trace rings do)
 IDLE_SPANS_KEPT = 128
+
+
+#: the phases that PARTITION a cycle of the slot engine's worker
+#: thread: at any instant exactly one is open, so their seconds sum
+#: to the worker's wall time
+ENGINE_CYCLE_PHASES = (
+    "engine.wait_work", "engine.admit", "engine.dispatch",
+    "engine.fetch", "engine.deliver",
+)
+#: every phase name: the cycle phases, then the children nested
+#: inside ``engine.admit`` (and ``kvtier.*`` inside those)
+ENGINE_PHASES = ENGINE_CYCLE_PHASES + (
+    "engine.admit.reuse", "kvtier.readmit", "engine.admit.prefill",
+    "engine.admit.store", "kvtier.spill", "engine.admit.first_token",
+)
+#: the accumulator's plain counters, in ``snapshot()`` order
+_ENGINE_COUNTERS = (
+    "admissions", "queue_wait_s", "dispatches_fused",
+    "dispatches_single", "store_bytes", "spill_bytes",
+    "readmit_bytes",
+)
+
+
+def process_start_monotonic() -> float:
+    """When this process started, on ``time.monotonic``'s clock: the
+    kernel's start stamp of the process (``/proc/self/stat``, ticks
+    since boot) against the time since boot now. The ledger of a
+    server starts here, so ``boot`` holds interpreter start, the jax
+    import and weight init, not only what follows the ledger's own
+    construction. Where the kernel gives no stamp: now."""
+    now = time.monotonic()
+    try:
+        with open("/proc/self/stat") as fh:
+            # the command name (field 2) may hold spaces: split after it
+            fields = fh.read().rpartition(")")[2].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+    return now - age if 0.0 <= age < 86400.0 * 365 else now
+
+
+def name_os_thread(name: str) -> None:
+    """Give the CALLING thread an OS-level name (at most 15 bytes).
+    The profiler names a host line by the thread's OS name at the
+    thread's first event, and Python before 3.14 leaves every thread
+    with the process's: call this first thing in a thread whose line
+    should be found by name (``slot-engine``). Best effort."""
+    try:
+        import ctypes
+
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass
+
+
+class EnginePhases:
+    """Seconds and counts per phase of the slot engine's worker
+    thread, plus the admission/dispatch/byte counters beside them.
+
+    Two faces. ``switch`` is the cycle cursor: it closes the open
+    cycle phase at ``now`` and opens the next, with the caller's own
+    ``perf_counter`` read as the boundary, so the cycle phases tile
+    the worker's wall time with no gap (one add and one count per
+    boundary). ``span`` is a context manager for the children inside
+    an admission. Both open a ``jax.profiler.TraceAnnotation`` of the
+    phase's name (a flag test when no trace is running; absent where
+    jax is). Written by the engine's worker thread, read by the HTTP
+    thread through ``snapshot``: plain float adds under the GIL, no
+    lock on the decode loop."""
+
+    def __init__(self) -> None:
+        self.phase_s: Dict[str, float] = {p: 0.0 for p in ENGINE_PHASES}
+        self.phase_n: Dict[str, int] = {p: 0 for p in ENGINE_PHASES}
+        self.admissions = 0
+        #: sum over admissions of (admitted - enqueued)
+        self.queue_wait_s = 0.0
+        self.dispatches_fused = 0
+        self.dispatches_single = 0
+        self.store_bytes = 0
+        self.spill_bytes = 0
+        self.readmit_bytes = 0
+        self._open: Optional[str] = None
+        self._since = 0.0
+        self._open_annotation: Any = None
+        self._annotation_class: Any = False  # resolved on first use
+
+    def _annotate(self, phase: str, **args: Any) -> Any:
+        """An entered TraceAnnotation, or None where jax is absent."""
+        if self._annotation_class is False:
+            try:
+                from jax.profiler import TraceAnnotation
+            except ImportError:
+                TraceAnnotation = None
+            self._annotation_class = TraceAnnotation
+        if self._annotation_class is None:
+            return None
+        annotation = self._annotation_class(phase, **args)
+        annotation.__enter__()
+        return annotation
+
+    def switch(self, phase: str, now: float, **args: Any) -> None:
+        """Close the open cycle phase at ``now`` (the caller's
+        ``time.perf_counter`` read) and open ``phase``."""
+        self.close(now)
+        self._open, self._since = phase, now
+        self._open_annotation = self._annotate(phase, **args)
+
+    def dispatched(self, now: float, fused: bool) -> None:
+        """Open ``engine.dispatch`` at ``now`` and count the dispatch
+        as the fused window program's or the single chunk's."""
+        self.switch("engine.dispatch", now, fused=int(fused))
+        if fused:
+            self.dispatches_fused += 1
+        else:
+            self.dispatches_single += 1
+
+    def close(self, now: float) -> None:
+        """Close the open cycle phase, if any (the worker's exit)."""
+        if self._open is None:
+            return
+        if self._open_annotation is not None:
+            self._open_annotation.__exit__(None, None, None)
+            self._open_annotation = None
+        self.phase_s[self._open] += max(now - self._since, 0.0)
+        self.phase_n[self._open] += 1
+        self._open = None
+
+    @contextlib.contextmanager
+    def span(self, phase: str, **args: Any):
+        """A child phase: nested inside whatever is open."""
+        annotation = self._annotate(phase, **args)
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.phase_s[phase] += time.perf_counter() - t0
+            self.phase_n[phase] += 1
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+
+    def snapshot(self) -> Dict[str, Any]:
+        """The ``engine`` block of ``GET /v1/goodput``. The open
+        cycle phase is NOT folded in: a reader takes deltas between
+        two snapshots, and a phase counts when it closes."""
+        out: Dict[str, Any] = {
+            "phase_s": {p: round(s, 6) for p, s in self.phase_s.items()},
+            "phase_n": dict(self.phase_n),
+        }
+        for name in _ENGINE_COUNTERS:
+            value = getattr(self, name)
+            out[name] = round(value, 6) if isinstance(value, float) else value
+        return out
 
 
 class DeviceTimeLedger:
@@ -125,6 +296,9 @@ class DeviceTimeLedger:
         #: production the process dies and its note stops updating;
         #: in-process harnesses must see the same final totals)
         self._frozen: Optional[float] = None
+        #: the slot engine's per-cycle phase accumulator (the engine
+        #: takes it from here; ``goodput_payload`` serves it)
+        self.engine = EnginePhases()
 
     # -- recording (boundary events only) ------------------------------
 
@@ -421,6 +595,7 @@ def goodput_payload(
         scheduling_gaps=find_scheduling_gaps(
             tracer.recent(), ledger.idle_spans()
         ),
+        engine=ledger.engine.snapshot(),
     )
     return payload
 

@@ -39,7 +39,10 @@ prefill, or the SSE relay. This module is the per-request answer:
   timings are a handful of floats written at admission/harvest
   boundaries (see ``serve_slots``) and converted to spans once, when
   the request finishes — batched per request, not per token or per
-  round.
+  round. Still true with the engine's per-cycle phases
+  (``goodput.EnginePhases``, PR 24): those live beside the ledger,
+  cost O(phases) per chunk or fused window, and never touch this
+  module either.
 
 Stage glossary (docs/90-observability.md is the runbook):
 

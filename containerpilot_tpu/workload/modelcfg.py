@@ -49,6 +49,13 @@ def enable_compile_cache() -> str:
     # default min-compile-time gate (1s) would skip most of a tiny
     # model's programs; anything over half a second is worth a disk hit
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    # the programs' named scopes are op METADATA, which the cache key
+    # leaves out by default: an executable loaded from the cache then
+    # carries the scopes of whichever build compiled it first, and a
+    # profiler trace is read by those scopes (PR 24: the parent's
+    # replica, given the change's cache, traced with the change's
+    # scopes). Keyed with its metadata, a build traces as itself.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

@@ -109,6 +109,7 @@ class InferenceServer:
         role: str = "active",
         compile_cache_dir: str = "",
         prefill_floor_s: float = 0.0,
+        started_at: Optional[float] = None,
     ) -> None:
         self.cfg = cfg
         self.params = params
@@ -154,14 +155,16 @@ class InferenceServer:
         self._weights_lock: Optional[asyncio.Lock] = None
         # device-time ledger (telemetry/goodput.py): every wall-second
         # of this replica's life attributed to exactly one stage,
-        # starting NOW in ``boot`` — weight setup, engine construction
-        # and port binding are costed before warmup() moves the ledger
-        # to compile_warmup and, finally, idle (before /health flips
-        # 200, so a scale-up replica's badput is visible from its very
-        # first scrape)
+        # starting in ``boot`` at ``started_at`` (a time.monotonic
+        # stamp; the CLI passes the PROCESS's start, so interpreter
+        # start, the jax import and weight init are costed too) or,
+        # without one, now — engine construction and port binding are
+        # costed before warmup() moves the ledger to compile_warmup
+        # and, finally, idle (before /health flips 200, so a scale-up
+        # replica's badput is visible from its very first scrape)
         from ..telemetry.goodput import DeviceTimeLedger
 
-        self.ledger = DeviceTimeLedger()
+        self.ledger = DeviceTimeLedger(now=started_at)
         # maintenance drain: /health goes 503 and NEW generate/
         # completions are rejected with 503 + Retry-After while
         # everything already admitted (including running slot-engine

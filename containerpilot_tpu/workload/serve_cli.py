@@ -350,6 +350,7 @@ def load_model(args: argparse.Namespace):
 def main() -> int:
     import logging
 
+    from ..telemetry.goodput import process_start_monotonic
     from .modelcfg import enable_compile_cache
     from .serve import InferenceServer
 
@@ -440,6 +441,9 @@ def main() -> int:
         mux=args.mux,
         role=role,
         compile_cache_dir=cache_dir,
+        # the ledger's ``boot`` starts with the process, not here
+        # after jax start and weight init
+        started_at=process_start_monotonic(),
     )
     member = None
     if backend is not None:

@@ -28,6 +28,8 @@ from typing import Any, List, Optional, Tuple
 
 # import-light by design (no jax): just the fingerprint/codec helpers
 from ..kvtier import digest as kvdigest
+from ..kvtier.spill import tree_nbytes
+from ..telemetry.goodput import EnginePhases
 
 #: shorter matches aren't worth a device call. Tied to the digest's
 #: FP_TOKENS BY CONSTRUCTION: the spill tier indexes keys by their
@@ -60,6 +62,17 @@ class PrefixCache:
         #: digest so readers can tell fresh from stale
         self.version = 0
         self._digest_memo: Tuple[int, str] = (-1, "")
+        # stores add their bytes, and the tier its transfers, to the
+        # slot engine's phase accumulator once an engine attaches it;
+        # until then to one of the cache's own
+        self.attach_phases(EnginePhases())
+
+    def attach_phases(self, phases: Any) -> None:
+        """Account this cache's stores, and its spill tier's
+        transfers, in the engine's phase accumulator."""
+        self.phases = phases
+        if self.spill is not None:
+            self.spill.phases = phases
 
     def __len__(self) -> int:
         with self._lock:
@@ -147,6 +160,7 @@ class PrefixCache:
         return adopted
 
     def store(self, key: Tuple[int, ...], cache: Any) -> None:
+        self.phases.store_bytes += tree_nbytes(cache)
         evicted: List[Tuple[Tuple[int, ...], Any]] = []
         with self._lock:
             self._cache[key] = cache

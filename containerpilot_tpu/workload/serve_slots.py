@@ -56,6 +56,7 @@ from ..models.decode import (
 from ..models.slots import append_chunk
 from ..models.stepprog import make_step_program
 from ..models.transformer import TransformerConfig
+from ..telemetry.goodput import EnginePhases, name_os_thread
 from .serve_prefix import MIN_REUSE as PREFIX_MIN_REUSE
 
 log = logging.getLogger("containerpilot.serve.slots")
@@ -94,6 +95,9 @@ class _Request:
     # allocation-free; the caller converts the stamps to spans once,
     # after the future resolves (tracing.add_engine_spans).
     timings: Optional[dict] = None
+    # submit's perf_counter stamp (the engine's clock): one float per
+    # request, read once at admission for the phases' queue_wait_s
+    enqueued: float = 0.0
     future: Future = field(default_factory=Future)
 
 
@@ -203,6 +207,19 @@ class SlotEngine:
         # token. None (direct engine construction, benches) costs one
         # attribute load at those boundaries.
         self.ledger = ledger
+        # engine phases (telemetry/goodput.py EnginePhases): every
+        # cycle of the worker thread is partitioned into named phases
+        # whose seconds and counts land in the ledger's accumulator
+        # (``/v1/goodput`` ``engine``) and, under a profiler trace,
+        # on the ``slot-engine`` line. The boundaries are the loop's
+        # own perf_counter reads; the work is O(phases) per cycle
+        # (a chunk or a fused window), never per token or per slot.
+        # A bare engine (no ledger) keeps its own accumulator.
+        self.phases = ledger.engine if ledger is not None else EnginePhases()
+        if prefix_cache is not None:
+            # store/spill/readmit happen inside admissions: their
+            # seconds and bytes belong to the same accumulator
+            prefix_cache.attach_phases(self.phases)
         # dispatch accounting for the dispatches/token series (the
         # number the ROADMAP's megakernel item must drive down): one
         # int bump per device dispatch (prefill or chunk round), one
@@ -307,6 +324,7 @@ class SlotEngine:
         )
         if timings is not None:
             timings["enqueued"] = time.monotonic()
+        req.enqueued = time.perf_counter()
         # atomic with stop()'s drain: either this put lands before the
         # drain (and gets cancelled there) or the stopped check raises
         with self._submit_lock:
@@ -382,10 +400,11 @@ class SlotEngine:
             from .serve_prefix import reuse_admission
 
             pc.readmit_seconds = 0.0
-            hit = reuse_admission(
-                pc, req.tokens, cfg, self.params,
-                chunk_len=self.prefill_chunk,
-            )
+            with self.phases.span("engine.admit.reuse"):
+                hit = reuse_admission(
+                    pc, req.tokens, cfg, self.params,
+                    chunk_len=self.prefill_chunk,
+                )
             if hit is not None:
                 logits, row_cache = hit
             if pc.readmit_seconds > 0.0:
@@ -398,69 +417,86 @@ class SlotEngine:
                 if self.ledger is not None:
                     self.ledger.carve("kv_readmit", pc.readmit_seconds)
         if row_cache is None:
-            if (
-                self.prefill_floor_s > 0.0
-                and len(req.tokens) >= PREFIX_MIN_REUSE
-            ):
-                # the synthetic floor: pay it on the worker thread —
-                # exactly where real prefill compute would run — then
-                # carve the seconds out of the ledger's prefill stage
-                # so productive_fraction keeps measuring real device
-                # work. The trace's prefill span (admitted ->
-                # prefill_done) still carries the hit, so
-                # dominant-stage attribution names it. Warmup's
-                # short dummy prompt stays under the reuse floor and
-                # skips this.
-                time.sleep(self.prefill_floor_s)  # cpcheck: disable=CP-HOTREACH the synthetic floor IS the work; see comment above
-                if self.ledger is not None:
-                    self.ledger.carve("idle", self.prefill_floor_s)
-            if (
-                self.cp_mesh is not None
-                and len(req.tokens) >= self.cp_min_len
-            ):
-                import numpy as _np
-
-                from ..parallel.context import cp_prefill_with_remainder
-
-                logits, row_cache = cp_prefill_with_remainder(
-                    self.params,
-                    _np.asarray([req.tokens], _np.int32),
-                    cfg, self.cp_mesh, self.max_len,
-                    prefill_chunk=self.prefill_chunk,
-                )
-            elif (
-                self.prefill_chunk > 0
-                and len(req.tokens) > self.prefill_chunk
-            ):
-                from ..models.decode import chunked_prefill
-
-                logits, row_cache = chunked_prefill(
-                    self.params, jnp.asarray([req.tokens], jnp.int32),
-                    cfg, self.max_len, chunk_len=self.prefill_chunk,
-                )
-            else:
-                # host->device transfer only on the path that uses it
-                prompt = jnp.asarray([req.tokens], jnp.int32)
-                logits, row_cache = _jitted_prefill(
-                    cfg, self.max_len
-                )(self.params, prompt)
+            with self.phases.span("engine.admit.prefill"):
+                logits, row_cache = self._cold_prefill(req)
         if use_pc:
             # store the completed prompt's cache for future turns
             # (standalone buffer — see the __init__ soundness note)
-            pc.store(tuple(req.tokens), row_cache)
+            with self.phases.span("engine.admit.store"):
+                pc.store(tuple(req.tokens), row_cache)
         return logits, row_cache
 
-    def _admit(self, slot_id: int, req: _Request) -> None:
+    def _cold_prefill(self, req: _Request):
+        """The prefill dispatch for a prompt with no reusable prefix:
+        cp-ring, chunked, or plain (``engine.admit.prefill``)."""
+        cfg = self.cfg
+        if (
+            self.prefill_floor_s > 0.0
+            and len(req.tokens) >= PREFIX_MIN_REUSE
+        ):
+            # the synthetic floor: pay it on the worker thread —
+            # exactly where real prefill compute would run — then
+            # carve the seconds out of the ledger's prefill stage
+            # so productive_fraction keeps measuring real device
+            # work. The trace's prefill span (admitted ->
+            # prefill_done) still carries the hit, so
+            # dominant-stage attribution names it. Warmup's
+            # short dummy prompt stays under the reuse floor and
+            # skips this.
+            time.sleep(self.prefill_floor_s)  # cpcheck: disable=CP-HOTREACH the synthetic floor IS the work; see comment above
+            if self.ledger is not None:
+                self.ledger.carve("idle", self.prefill_floor_s)
+        if (
+            self.cp_mesh is not None
+            and len(req.tokens) >= self.cp_min_len
+        ):
+            import numpy as _np
+
+            from ..parallel.context import cp_prefill_with_remainder
+
+            logits, row_cache = cp_prefill_with_remainder(
+                self.params,
+                _np.asarray([req.tokens], _np.int32),
+                cfg, self.cp_mesh, self.max_len,
+                prefill_chunk=self.prefill_chunk,
+            )
+        elif (
+            self.prefill_chunk > 0
+            and len(req.tokens) > self.prefill_chunk
+        ):
+            from ..models.decode import chunked_prefill
+
+            logits, row_cache = chunked_prefill(
+                self.params, jnp.asarray([req.tokens], jnp.int32),
+                cfg, self.max_len, chunk_len=self.prefill_chunk,
+            )
+        else:
+            # host->device transfer only on the path that uses it
+            prompt = jnp.asarray([req.tokens], jnp.int32)
+            logits, row_cache = _jitted_prefill(
+                cfg, self.max_len
+            )(self.params, prompt)
+        return logits, row_cache
+
+    def _admit(self, slot_id: int, req: _Request, now: float) -> None:
         """Prefill the prompt (engine policy) and hand the result to
         the step program, which samples token 0 with generate's exact
         key schedule and writes the whole admission row into its
-        device-resident state in one dispatch."""
+        device-resident state in one dispatch. ``now`` is the
+        worker's perf_counter read at the admission's start (the
+        ``engine.admit`` boundary)."""
         if req.timings is not None:
             req.timings["admitted"] = time.monotonic()
         if self.ledger is not None:
             self.ledger.enter("prefill")
+        phases = self.phases
+        phases.admissions += 1
+        phases.queue_wait_s += max(now - req.enqueued, 0.0)
         logits, row_cache = self._prefill(req)
-        first_host = self.program.admit(slot_id, req, logits, row_cache)
+        with phases.span("engine.admit.first_token"):
+            first_host = self.program.admit(
+                slot_id, req, logits, row_cache
+            )
         state = _Slot(req=req, emitted=[first_host])
         if first_host == req.eos_id or req.max_new <= 1:
             state.finished = True
@@ -567,13 +603,28 @@ class SlotEngine:
                 budgets[i] = max(s.req.max_new - len(s.emitted), 0)
         return budgets
 
-    # cpcheck: hotpath — the continuous-batching decode loop; a steady
-    # window must ship zero host syncs beyond the program's one fetch
     def _run(self) -> None:
+        # the profiler names this thread's line by its OS name, taken
+        # at the thread's first event: before any jax call
+        name_os_thread("slot-engine")
+        try:
+            self._cycles()
+        finally:
+            self.phases.close(time.perf_counter())
+
+    # cpcheck: hotpath — the continuous-batching decode loop; a steady
+    # window must ship zero host syncs beyond the program's one fetch.
+    # Every cycle is partitioned into the phases of telemetry/goodput.py
+    # ENGINE_CYCLE_PHASES; a boundary is one of the loop's own
+    # perf_counter reads (t0, tj) handed to ``phases``, so the phases
+    # tile the thread's wall time
+    def _cycles(self) -> None:
         # one-window lookahead: the step-program handle of a window
         # already dispatched for the NEXT cycle (None = serial)
         pending = None
         program = self.program
+        phases = self.phases
+        windowed = self.window > 1
         while not self._stopped.is_set():
             t0 = time.perf_counter()
             jax_s = 0.0  # time inside jax calls this cycle
@@ -596,11 +647,14 @@ class SlotEngine:
                 try:
                     block = not any_active
                     while free:
+                        if block:
+                            phases.switch("engine.wait_work", t0)
                         req = self._queue.get(block=block, timeout=None)
                         if req is None:  # stop sentinel
                             return
                         block = False
                         t0 = time.perf_counter()  # exclude idle wait
+                        phases.switch("engine.admit", t0)
                         admitted = True
                         if (
                             req.cancel is not None
@@ -609,7 +663,7 @@ class SlotEngine:
                             req.future.cancel()  # left before admission
                             continue
                         try:
-                            self._admit(free.pop(0), req)
+                            self._admit(free.pop(0), req, t0)
                         except Exception as exc:  # noqa: BLE001
                             if not req.future.done():
                                 req.future.set_exception(exc)
@@ -633,6 +687,7 @@ class SlotEngine:
                     and not self._cancel_pending()
                 )
                 tj = time.perf_counter()
+                phases.dispatched(tj, fused and windowed)
                 try:
                     handle = program.dispatch(self._budgets(), fused)
                 except Exception as exc:  # noqa: BLE001
@@ -662,6 +717,7 @@ class SlotEngine:
                 and not self._cancel_pending()
             ):
                 tj = time.perf_counter()
+                phases.dispatched(tj, windowed)
                 try:
                     pending = program.dispatch(self._budgets(), True)
                 except Exception as exc:  # noqa: BLE001
@@ -671,6 +727,7 @@ class SlotEngine:
                 jax_s += time.perf_counter() - tj
                 self.dispatches += program.dispatch_cost
             tj = time.perf_counter()
+            phases.switch("engine.fetch", tj)
             try:
                 # the ONE deliberate sync per window lives inside
                 # program.tokens; everything after it overlaps the
@@ -680,7 +737,11 @@ class SlotEngine:
                 self._fail_and_rebuild(exc)
                 pending = None
                 continue
-            jax_s += time.perf_counter() - tj
+            tj, fetched = time.perf_counter(), tj
+            jax_s += tj - fetched
+            # append, notify, harvest (and the next cycle's sweep and
+            # free-slot scan, up to its first boundary)
+            phases.switch("engine.deliver", tj)
             for i, state in enumerate(self._active):
                 if state is None:
                     continue

@@ -360,9 +360,10 @@ def main() -> int:
         # comparable by construction
         from .modelcfg import average_eval_loss
 
-        return average_eval_loss(
-            params, cfg, dataset.n_eval_batches, dataset.eval_batch
-        )
+        with jax.profiler.TraceAnnotation("train.eval"):
+            return average_eval_loss(
+                params, cfg, dataset.n_eval_batches, dataset.eval_batch
+            )
 
     # profiler window: skip step 1 (compile) and capture a few steady
     # steps — the standard "pick a mesh, profile, iterate" loop
@@ -405,6 +406,11 @@ def main() -> int:
         )
 
     data_rng = jax.random.PRNGKey(1)
+    # the loop's phases are profiler annotations (a flag test when no
+    # trace runs): a trace taken with --profile-dir, or by a launcher
+    # around this process, shows them on this thread's line, on the
+    # device events' clock (docs/90-observability.md)
+    annotate = jax.profiler.TraceAnnotation
     t0 = time.monotonic()
     try:
         for step in range(start_step, args.steps):
@@ -428,7 +434,8 @@ def main() -> int:
                 jax.profiler.start_trace(args.profile_dir)
                 profiling = True
             if prefetcher is not None:
-                _pstep, tokens = prefetcher.next()
+                with annotate("train.next_batch"):
+                    _pstep, tokens = prefetcher.next()
             else:
                 # stateless per-step key: a resumed run continues the
                 # data stream exactly where the crashed run left off
@@ -437,19 +444,27 @@ def main() -> int:
                     k, (args.batch, args.seq_len + 1), 0, cfg.vocab_size,
                     jnp.int32,
                 )
-            state, loss = train_step(state, tokens)
+            with jax.profiler.StepTraceAnnotation(
+                "train.step", step_num=step + 1
+            ):
+                state, loss = train_step(state, tokens)
             if step + 1 == profile_stop and profiling:
                 loss.block_until_ready()  # close the window on real work
                 jax.profiler.stop_trace()
                 profiling = False
                 print(f"profiler trace written to {args.profile_dir}")
             if args.checkpoint_dir and (step + 1) % args.checkpoint_every == 0:
-                save_checkpoint(args.checkpoint_dir, step + 1, state,
-                                wait=not args.checkpoint_async)
+                with annotate("train.checkpoint"):
+                    save_checkpoint(args.checkpoint_dir, step + 1, state,
+                                    wait=not args.checkpoint_async)
             if args.progress_file:
+                with annotate("train.loss_sync"):
+                    # the one sync a step pays: the progress file
+                    # wants the loss as a number
+                    loss_now = float(loss)
                 tmp = args.progress_file + ".tmp"
                 with open(tmp, "w") as f:
-                    json.dump({"step": step + 1, "loss": float(loss),
+                    json.dump({"step": step + 1, "loss": loss_now,
                                "time": time.time()}, f)
                 os.replace(tmp, args.progress_file)
             if (step + 1) % 10 == 0 or step == start_step:

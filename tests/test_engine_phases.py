@@ -1,0 +1,348 @@
+"""Engine phases and model scopes (ISSUE 24): the slot engine's
+worker thread accounts every cycle in named phases
+(telemetry/goodput.py ``EnginePhases``, the ``engine`` block of
+``/v1/goodput``), the same names land on the ``slot-engine`` line of a
+profiler trace, the trainer's loop annotates its steps, and the step
+programs carry the layer map's names as ``jax.named_scope``s. All on
+the CPU at toy widths: counts and names only, never a device time."""
+import glob
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from containerpilot_tpu.kvtier import HostSpillTier
+from containerpilot_tpu.models.decode import _jitted_extend, _jitted_prefill
+from containerpilot_tpu.models.slots import (
+    _jitted_chunk,
+    _jitted_window,
+    init_slot_state,
+    slot_cache,
+)
+from containerpilot_tpu.models.transformer import (
+    TransformerConfig,
+    init_params,
+)
+from containerpilot_tpu.telemetry.goodput import (
+    ENGINE_CYCLE_PHASES,
+    ENGINE_PHASES,
+    DeviceTimeLedger,
+    EnginePhases,
+    goodput_payload,
+    process_start_monotonic,
+)
+from containerpilot_tpu.telemetry.tracing import TraceRecorder
+from containerpilot_tpu.workload.serve_prefix import PrefixCache
+from containerpilot_tpu.workload.serve_slots import SlotEngine
+
+CFG = TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+    max_seq_len=512, dtype=jnp.float32,
+)
+CHUNK, WINDOW = 8, 4
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _engine(params, max_len=64, **kw):
+    return SlotEngine(
+        CFG, params, max_len, slots=2, chunk=CHUNK, window=WINDOW, **kw
+    )
+
+
+def _cycle_seconds(phases: EnginePhases) -> float:
+    return sum(phases.phase_s[p] for p in ENGINE_CYCLE_PHASES)
+
+
+def test_cycle_phases_sum_to_the_workers_wall_time(params):
+    """The cycle phases tile the worker thread's life: waiting,
+    admitting, dispatching, fetching and delivering add up to the
+    time between the thread's start and its exit, within 2 %."""
+    built = time.perf_counter()
+    eng = _engine(params)  # the worker starts as this returns
+    t0 = time.perf_counter()
+    try:
+        first = eng.submit([1, 2, 3, 4], max_new=24)
+        second = eng.submit([5, 6, 7], max_new=12)
+        assert len(first.result(timeout=120)) == 24
+        assert len(second.result(timeout=120)) == 12
+        time.sleep(0.05)  # a stretch of engine.wait_work
+    finally:
+        eng.stop()
+    end = time.perf_counter()
+    total = _cycle_seconds(eng.phases)
+    assert total <= end - built
+    assert total >= 0.98 * (end - t0), (total, end - t0, eng.phases.phase_s)
+    for phase in ENGINE_CYCLE_PHASES:
+        assert eng.phases.phase_n[phase] >= 1, phase
+        assert eng.phases.phase_s[phase] > 0.0, phase
+
+
+def test_counts_move_per_window_not_per_token(params):
+    """A 256-token decode moves the dispatch, fetch and deliver counts
+    by the number of windows it rode (chunk x window tokens each), and
+    the admission counts by one: nothing is counted per token."""
+    eng = _engine(params, max_len=512)
+    try:
+        eng.submit([1, 2, 3], max_new=2).result(timeout=120)  # compile
+        before = dict(eng.phases.phase_n)
+        out = eng.submit([9, 8, 7, 6], max_new=256).result(timeout=300)
+        assert len(out) == 256
+        time.sleep(0.05)
+    finally:
+        eng.stop()
+    moved = {p: eng.phases.phase_n[p] - before[p] for p in ENGINE_PHASES}
+    windows = -(-256 // (CHUNK * WINDOW))  # 8 fused windows
+    # the admission's own single-chunk round, the fused windows, and
+    # at most one lookahead window dispatched past the end
+    assert windows <= moved["engine.fetch"] <= windows + 3, moved
+    assert moved["engine.deliver"] == moved["engine.fetch"], moved
+    assert windows <= moved["engine.dispatch"] <= windows + 4, moved
+    assert moved["engine.admit"] == 1, moved
+    assert moved["engine.admit.first_token"] == 1, moved
+    assert moved["engine.admit.prefill"] == 1, moved
+
+
+def test_admissions_and_dispatch_kinds_are_counted(params):
+    eng = _engine(params)
+    try:
+        futures = [
+            eng.submit([1 + i, 2, 3], max_new=6) for i in range(5)
+        ]
+        for f in futures:
+            f.result(timeout=120)
+    finally:
+        eng.stop()
+    phases = eng.phases
+    assert phases.admissions == 5
+    assert phases.phase_n["engine.admit"] == 5
+    # five requests on two slots: the later ones waited in the queue
+    assert phases.queue_wait_s > 0.0
+    dispatched = phases.dispatches_fused + phases.dispatches_single
+    assert dispatched == phases.phase_n["engine.dispatch"]
+    # an admission keeps its round on the single-chunk program
+    assert phases.dispatches_single >= 1
+    snap = phases.snapshot()
+    assert set(snap["phase_s"]) == set(ENGINE_PHASES)
+    assert snap["admissions"] == 5
+
+
+def test_store_spill_and_readmit_bytes_under_a_prefix_cache(params):
+    """With a one-entry prefix cache over a spill tier, a second
+    session's admission evicts and spills the first's row, and the
+    first session's next turn readmits it: the bytes and the
+    ``kvtier.*`` phases move with them."""
+    pc = PrefixCache(1, spill=HostSpillTier(1 << 22))
+    ledger = DeviceTimeLedger()
+    eng = _engine(params, prefix_cache=pc, ledger=ledger)
+    first = list(range(1, 21))
+    try:
+        eng.submit(first, max_new=4).result(timeout=120)
+        eng.submit(list(range(21, 41)), max_new=4).result(timeout=120)
+        eng.submit(first + [5, 6, 7], max_new=4).result(timeout=120)
+    finally:
+        eng.stop()
+    phases = ledger.engine
+    assert eng.phases is phases  # the ledger's accumulator, not a copy
+    assert phases.phase_n["engine.admit.store"] == 3
+    assert phases.phase_n["engine.admit.reuse"] == 3
+    assert phases.phase_n["kvtier.spill"] >= 2
+    assert phases.phase_n["kvtier.readmit"] == 1
+    assert phases.store_bytes > phases.spill_bytes > 0
+    assert 0 < phases.readmit_bytes < phases.spill_bytes
+    # what was spilled is still in the tier or came back
+    assert phases.spill_bytes == (
+        pc.spill.bytes_used + phases.readmit_bytes
+    )
+    # children nest inside the admission they belong to
+    assert phases.phase_s["engine.admit"] >= (
+        phases.phase_s["engine.admit.store"]
+        + phases.phase_s["engine.admit.first_token"]
+    )
+
+
+OLD_GOODPUT_KEYS = {
+    "stage", "uptime_s", "stages_s", "productive_s",
+    "productive_fraction", "transitions", "first_productive_at",
+    "role", "ready", "draining", "dispatches", "tokens_out",
+    "dispatches_per_token", "scheduling_gaps",
+}
+
+
+def test_goodput_body_keeps_every_old_key_and_gains_engine():
+    ledger = DeviceTimeLedger()
+    ledger.engine.switch("engine.dispatch", time.perf_counter())
+    ledger.engine.close(time.perf_counter())
+    body = goodput_payload(
+        ledger, TraceRecorder("replica"), 3, 24,
+        role="replica", ready=True, draining=False,
+    )
+    assert OLD_GOODPUT_KEYS <= set(body)
+    assert set(body) - OLD_GOODPUT_KEYS == {"engine"}
+    assert set(body["stages_s"]) == {
+        "boot", "compile_warmup", "idle", "prefill", "decode",
+        "kv_readmit", "drain",
+    }
+    engine = body["engine"]
+    assert set(engine) == {
+        "phase_s", "phase_n", "admissions", "queue_wait_s",
+        "dispatches_fused", "dispatches_single", "store_bytes",
+        "spill_bytes", "readmit_bytes",
+    }
+    assert engine["phase_n"]["engine.dispatch"] == 1
+
+
+def test_ledger_boot_starts_with_the_process():
+    """``serve_cli`` hands the ledger the process's start: ``boot``
+    then holds interpreter start and the jax import, which a ledger
+    built after them cannot see."""
+    started = process_start_monotonic()
+    now = time.monotonic()
+    assert started < now
+    assert now - started < 3600.0  # this test process is minutes old
+    ledger = DeviceTimeLedger(now=started)
+    snap = ledger.snapshot()
+    assert snap["stage"] == "boot"
+    assert snap["stages_s"]["boot"] == pytest.approx(
+        snap["uptime_s"], abs=0.01
+    )
+    assert snap["uptime_s"] >= now - started - 0.01
+
+
+def _host_lines(trace_dir):
+    """line name -> event names, over the host plane of the newest
+    trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(
+        f"{trace_dir}/plugins/profile/*/*.xplane.pb"
+    ))
+    assert paths, f"no trace under {trace_dir}"
+    lines = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            lines.setdefault(line.name, set()).update(
+                ev.name for ev in line.events
+            )
+    return lines
+
+
+def test_profiler_trace_holds_the_phases_on_the_slot_engine_line(
+    params, tmp_path
+):
+    eng = _engine(params)
+    try:
+        eng.submit([1, 2, 3], max_new=2).result(timeout=120)  # compile
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.submit([4, 5, 6, 7], max_new=40).result(timeout=120)
+            eng.submit([8, 9], max_new=9).result(timeout=120)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    lines = _host_lines(str(tmp_path))
+    assert "slot-engine" in lines, sorted(lines)
+    names = lines["slot-engine"]
+    for phase in ("engine.admit", "engine.admit.prefill",
+                  "engine.admit.first_token", "engine.dispatch",
+                  "engine.fetch", "engine.deliver"):
+        assert phase in names, (phase, sorted(
+            n for n in names if n.startswith("engine")))
+
+
+def test_profiler_trace_holds_train_step_for_a_two_step_trainer(tmp_path):
+    from containerpilot_tpu.workload.train import main
+
+    progress = tmp_path / "progress.json"
+    argv = sys.argv
+    sys.argv = [
+        "train", "--steps", "2", "--batch", "2", "--seq-len", "16",
+        "--d-model", "64", "--n-layers", "1", "--n-heads", "4",
+        "--vocab", "64", "--progress-file", str(progress),
+    ]
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        assert main() == 0
+    finally:
+        sys.argv = argv
+        jax.profiler.stop_trace()
+    names = set().union(*_host_lines(str(tmp_path / "trace")).values())
+    assert "train.step" in names
+    assert "train.loss_sync" in names
+
+
+def _scope_tokens(text: str):
+    """Every name inside the ``loc("...")`` paths of a lowered
+    module: path components, and the names transforms wrap
+    (``transpose(jvp(layers))`` holds ``layers``)."""
+    names = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        names.update(re.findall(r"[A-Za-z_][\w.]*", path))
+    return names
+
+
+def _lowered(kind, params):
+    slots, max_len = 2, 48
+    if kind in ("chunk", "window"):
+        pool = slot_cache(CFG, slots, max_len)
+        state = init_slot_state(CFG, slots)
+        if kind == "chunk":
+            return _jitted_chunk(CFG, slots, CHUNK).lower(
+                params, pool, state)
+        return _jitted_window(CFG, slots, CHUNK, WINDOW).lower(
+            params, pool, state, jnp.zeros((slots,), jnp.int32))
+    prompt = jnp.zeros((1, 16), jnp.int32)
+    if kind == "prefill":
+        return _jitted_prefill(CFG, max_len).lower(params, prompt)
+    if kind == "extend":
+        _logits, cache = _jitted_prefill(CFG, max_len)(params, prompt)
+        return _jitted_extend(CFG).lower(
+            params, cache, jnp.zeros((1, 4), jnp.int32))
+    from containerpilot_tpu.parallel import (
+        init_train_state,
+        make_mesh,
+        make_train_step,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+        max_seq_len=64, remat=True, loss_chunk=8,
+    )
+    mesh = make_mesh(jax.devices()[:1])
+    state = init_train_state(jax.random.PRNGKey(0), cfg, mesh)
+    return jax.jit(make_train_step(cfg, mesh)).lower(
+        state, jnp.zeros((2, 17), jnp.int32))
+
+
+@pytest.mark.parametrize("kind,wanted", [
+    ("chunk", {"embed", "attn", "attn.qkv", "attn.rope", "attn.kv_write",
+               "attn.scores", "attn.out", "mlp", "head", "sample",
+               "steps"}),
+    ("window", {"attn", "attn.kv_write", "attn.scores", "mlp", "head",
+                "sample", "steps", "layers"}),
+    ("prefill", {"embed", "attn", "attn.qkv", "attn.kv_write",
+                 "attn.scores", "mlp", "head", "layers"}),
+    ("extend", {"attn", "attn.kv_write", "attn.scores", "mlp", "head"}),
+    ("train", {"embed", "attn", "attn.qkv", "attn.scores", "attn.out",
+               "mlp", "norm", "head", "loss", "optimizer", "layers"}),
+])
+def test_lowered_programs_name_the_layer_maps_scopes(kind, wanted, params):
+    names = _scope_tokens(_lowered(kind, params).as_text(debug_info=True))
+    assert wanted <= names, sorted(wanted - names)
+
+
+@pytest.mark.parametrize("kind", ["chunk", "window"])
+def test_decode_programs_are_still_named_jit_run(kind, params):
+    """benchmark/layer_metrics/decode_programs.py finds the decode
+    programs by this module name."""
+    text = _lowered(kind, params).as_text()
+    assert re.search(r"module @jit_run\b", text), text[:200]
