@@ -272,6 +272,44 @@ def decode_step(
     return logits[:, 0, :], new_cache
 
 
+def _grouped_attention(
+    q: jax.Array, keys: jax.Array, values: jax.Array, valid: jax.Array,
+    dtype,
+) -> jax.Array:
+    """Masked attention of q [b, m, n_heads, d] over keys/values
+    [b, length, kv_heads, d] AS THE CACHE STORES THEM; valid is
+    [m, length]. Returns [b, m, n_heads, d] in ``dtype``.
+
+    The n_heads axis is viewed as [kv_heads, group], so query head
+    h = kv * group + g reads kv head h // group (``repeat_kv``'s
+    order) and the cache is never repeated to n_heads. The keys enter
+    the score contraction in their own dtype with float32 accumulation
+    (bf16 x bf16 products are exact in float32) and the
+    head_dim ** -0.5 scale is applied to the float32 scores, so they
+    are never widened. The softmax weights stay float32 and meet the
+    stored values at HIGHEST precision: on the TPU the values' widening
+    folds into the contraction's fusion (no float32 copy reaches
+    memory, tests/test_tpu_compile.py) and the extra MXU passes hide
+    behind the read of the cache, while weights rounded to bf16 first
+    cost half as much error again in a decode step's output (PERF.md,
+    PR 26). A decode step reads each stored key and value once."""
+    b, m, n_heads, d = q.shape
+    kv_heads = keys.shape[2]
+    qg = q.reshape(b, m, kv_heads, n_heads // kv_heads, d)
+    scores = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", qg, keys,
+        preferred_element_type=jnp.float32,
+    ) * d ** -0.5  # [b, kv_heads, group, m, length]
+    scores = jnp.where(valid[None, None, None], scores, NEG_INF)
+    weights = jax.nn.softmax(scores, axis=-1)
+    attn = jnp.einsum(
+        "bhgqk,bkhd->bqhgd", weights, values,
+        preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST,
+    )
+    return attn.astype(dtype).reshape(b, m, n_heads, d)
+
+
 def decode_chunk(
     params: Params, cache: Cache, tokens: jax.Array, cfg: TransformerConfig
 ) -> Tuple[jax.Array, Cache]:
@@ -283,6 +321,16 @@ def decode_chunk(
     predicts position ``pos + i + 1``. Within the chunk attention is
     causal; everything already cached is visible. Numerics match m
     sequential ``decode_step`` calls (and therefore the full forward).
+
+    Attention is ``_grouped_attention``: the query heads are viewed as
+    [kv_heads, group] and contracted with the layer's keys and values
+    in the layout the cache stores them in (the ring concatenated with
+    the chunk, or the ``_kv_dequant`` output, on those paths),
+    accumulating in float32. A decode step is bound by the bytes it
+    reads, and the cache is the only operand that grows with
+    ``max_len``: repeating it to n_heads and widening it to float32 in
+    memory first made every step write and re-read about twenty times
+    the cache's size (PERF.md, PR 26), so neither copy is ever built.
     """
     pos = cache["pos"]
     b, m = tokens.shape
@@ -401,20 +449,7 @@ def decode_chunk(
                     )
                     keys, values = new_kv["k"], new_kv["v"]
         with jax.named_scope("attn"), jax.named_scope("attn.scores"):
-            k_full = repeat_kv(keys, cfg.n_heads)
-            v_full = repeat_kv(values, cfg.n_heads)
-            scores = jnp.einsum(
-                "bqhd,bkhd->bhqk",
-                q.astype(jnp.float32) * cfg.head_dim ** -0.5,
-                k_full.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-            )  # [b, h, m, length(+m)]
-            scores = jnp.where(valid[None, None, :, :], scores, NEG_INF)
-            weights = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum(
-                "bhqk,bkhd->bqhd", weights, v_full,
-                preferred_element_type=jnp.float32,
-            ).astype(cfg.dtype)
+            attn = _grouped_attention(q, keys, values, valid, cfg.dtype)
         if fused:
             x = fused_attn_out(x, attn, layer_params, cfg)
             x = fused_mlp(x, layer_params, cfg)

@@ -15,6 +15,16 @@ per (config, S, chunk, K) that loops K chunk-rounds on device with
 early exit (``decode_slots_window``) so the host pays one dispatch
 per K rounds — no recompiles as traffic changes.
 
+Every step reads the whole pool ([S, layers, 1, length, kv_heads,
+head_dim], live positions or not), so what a step costs is what
+attention does with those bytes: ``decode_chunk`` contracts the
+query heads, grouped as [kv_heads, group], with each layer's keys and
+values as the pool stores them (bf16, kv heads only, float32
+accumulation). Nothing the size of the pool is repeated to n_heads or
+written out in float32 on the way: tests/test_decode_gqa.py pins that
+on the chunk program's lowered text, tests/test_tpu_compile.py on the
+program the v5e's compiler makes of it.
+
 Sampling reproduces ``generate``'s schedule exactly: per-row key =
 ``jax.random.split(PRNGKey(seed), 1)[0]``, sample i uses
 ``fold_in(row_key, i)`` with sample 0 drawn from the prefill logits —

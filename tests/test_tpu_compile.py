@@ -18,7 +18,9 @@ written for a described chip cannot be read back without one, and the
 next run would warn and recompile.
 """
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -200,3 +202,62 @@ def test_tp_serving_prefill_needs_shard_map(topology, monkeypatch):
         lower(cfg)
     compiled = lower(flash_parallel_config(cfg, mesh)).compile()
     assert KERNEL in compiled.as_text()
+
+
+def test_decode_chunk_keeps_the_cache_as_stored(chip):
+    """The slot engine's chunk program at the benchmark's attention
+    shapes (Mistral-7B: 32 heads over 8 kv heads of 128, 16 slots x
+    4096 positions, bf16; depth, vocabulary and FFN cut, they do not
+    touch attention), compiled for the v5e: no instruction of the
+    optimised program may produce a tensor as large as a layer's
+    cache repeated to 32 heads, nor a float32 one as large as the
+    layer's cache. Those two (`broadcast f32[16,4096,8,4,128]`,
+    `convert_bitcast_fusion f32[16,4096,8,128]`) were 1.9 of the 2.8
+    device seconds of a traced serving window before decode_chunk
+    contracted the cache as stored (PERF.md, PR 26); the keys' change
+    of layout has to stay inside the contraction's fusion."""
+    from containerpilot_tpu.models.slots import (
+        _jitted_chunk,
+        init_slot_state,
+        slot_cache,
+    )
+    from containerpilot_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+
+    slots, length = 16, 4096
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=4096, n_heads=32, n_kv_heads=8,
+        n_layers=2, d_ff=1024, max_seq_len=length,
+    )
+    shapes = jax.eval_shape(
+        lambda: (
+            init_params(jax.random.PRNGKey(0), cfg),
+            slot_cache(cfg, slots, length),
+            init_slot_state(cfg, slots),
+        )
+    )
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+    text = _jitted_chunk(cfg, slots, 8).lower(*shapes).compile().as_text()
+    assert text.startswith("HloModule jit_run")
+    layer_cache = slots * length * cfg.kv_heads * cfg.head_dim
+    found = []
+    for name, elem, dims in re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]+)\]", text, re.M
+    ):
+        dims = [int(n) for n in dims.split(",")]
+        if length not in dims or cfg.head_dim not in dims:
+            continue
+        size = math.prod(dims)
+        found.append((name, elem, dims))
+        assert size < layer_cache * (cfg.n_heads // cfg.kv_heads), (
+            f"{name}: the cache repeated to n_heads, {elem}{dims}"
+        )
+        assert elem != "f32" or size < layer_cache, (
+            f"{name}: a float32 copy of the cache, {dims}"
+        )
+    assert any(elem == "bf16" for _, elem, _ in found), "no cache found"
