@@ -52,6 +52,15 @@ def tree_nbytes(host_tree: Any) -> int:
     )
 
 
+def latent_nbytes(tree: Any) -> int:
+    """The bytes of a cache entry that are latent rows: its ``ckv``
+    and ``kpe`` leaves (models/mla_moe.py); 0 for any other tree."""
+    if not isinstance(tree, dict):
+        return 0
+    return sum(tree_nbytes(tree[name])
+               for name in ("ckv", "kpe") if name in tree)
+
+
 class HostSpillTier:
     """Byte-budgeted host-RAM LRU of evicted KV cache entries."""
 
@@ -141,6 +150,7 @@ class HostSpillTier:
             host = jax.device_get(cache)
         nbytes = tree_nbytes(host)
         self.phases.spill_bytes += nbytes
+        self.phases.latent_spill_bytes += latent_nbytes(host)
         if nbytes > self.max_bytes:
             self.stats["refused"] += 1
             return False
@@ -213,6 +223,7 @@ class HostSpillTier:
             return None
         self.stats["readmitted"] += 1
         self.phases.readmit_bytes += entry[1]
+        self.phases.latent_readmit_bytes += latent_nbytes(entry[0])
         # host -> device outside the lock, same rationale as put()
         with self.phases.span("kvtier.readmit"):
             return jax.device_put(entry[0])

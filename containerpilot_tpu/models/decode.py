@@ -33,7 +33,7 @@ from .transformer import (
     Params,
     TransformerConfig,
     _attn_out,
-    _ffn,
+    _mlp,
     _qkv,
     _rms_norm,
     flash_eligible,
@@ -60,7 +60,16 @@ def init_cache(
 
     With ``cfg.kv_int8`` k/v store as int8 with a per-(token, head)
     scale over the head_dim axis — KV memory halves vs bf16,
-    composing with both levers above."""
+    composing with both levers above.
+
+    A configuration of another family (``cfg.family``, e.g.
+    models/mla_moe.py's latent cache) makes its own tree; ``prefill``
+    and ``decode_chunk`` hand over the same way, so everything built
+    on these three (generate, the prefix cache's extend, the slot
+    pool) serves either family."""
+    family = getattr(cfg, "family", None)
+    if family is not None:
+        return family.init_cache(cfg, batch, max_len)
     length = max_len if cfg.window <= 0 else min(cfg.window, max_len)
     shape = (cfg.n_layers, batch, length, cfg.kv_heads, cfg.head_dim)
     cache: Cache = {
@@ -108,12 +117,9 @@ def prefill(
 
     tokens: [batch, prompt_len] int32; prompt_len <= max_len.
     """
-    if cfg.moe_experts > 0 and cfg.moe_train_capacity > 0:
-        raise ValueError(
-            "incremental decoding requires a serving config with "
-            "moe_train_capacity=0 (capacity routing is sequence-length "
-            "dependent and cannot match decode)"
-        )
+    family = getattr(cfg, "family", None)
+    if family is not None:
+        return family.prefill(params, tokens, cfg, max_len)
     b, s = tokens.shape
     x = embed_lookup(params, tokens, cfg.dtype)
 
@@ -166,7 +172,7 @@ def prefill(
         q, k, v = _qkv(carry, layer_params, cfg)
         with jax.named_scope("attn"), jax.named_scope("attn.scores"):
             attn, k, v = attend(q, k, v)
-        out, _aux = _ffn(
+        out = _mlp(
             _attn_out(carry, attn, layer_params, cfg), layer_params, cfg
         )
         return out, (k, v)  # cache stores the unrepeated kv heads
@@ -332,6 +338,9 @@ def decode_chunk(
     memory first made every step write and re-read about twenty times
     the cache's size (PERF.md, PR 26), so neither copy is ever built.
     """
+    family = getattr(cfg, "family", None)
+    if family is not None:
+        return family.decode_chunk(params, cache, tokens, cfg)
     pos = cache["pos"]
     b, m = tokens.shape
     length = cache["k"].shape[2]
@@ -455,7 +464,7 @@ def decode_chunk(
             x = fused_mlp(x, layer_params, cfg)
         else:
             x = _attn_out(x, attn, layer_params, cfg)
-            x, _aux = _ffn(x, layer_params, cfg)
+            x = _mlp(x, layer_params, cfg)
         return x, new_kv
 
     kv_in = {

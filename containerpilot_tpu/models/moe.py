@@ -1,142 +1,121 @@
-"""Mixture-of-Experts: switch-style routing with expert parallelism.
+"""Routed experts for the serving family of ``models/mla_moe.py``.
 
-TPU-first formulation: top-1 (switch) routing expressed entirely as
-one-hot einsums — dispatch and combine are batched matmuls the MXU
-eats, no gathers/scatters, fully static shapes. Routing is per-token
-and drop-free (see moe_layer). Expert weights carry a leading expert
-axis sharded over the mesh's ``model`` axis (expert parallelism); XLA
-inserts the all-to-alls at the dispatch and combine einsums.
-
-Aux load-balancing loss is the standard switch formulation: E *
-sum_e(fraction_of_tokens_e * mean_router_prob_e), minimized at uniform
-routing.
+``route_topk``: sigmoid scores over ALL experts in float32, top k,
+renormalise, scale. ``sparse_experts``: the part of the result that
+the experts HELD here give: assignments sorted by expert, cut into
+blocks of one expert each, one grouped SwiGLU per block that exists;
+no capacity, no token dropped, an expert nobody chose is never read.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 
-def _route(x: jax.Array, router_w: jax.Array):
-    """Top-1 switch routing shared by the drop-free and capacity
-    layers: returns (probs, gate, onehot, aux_loss)."""
-    n_experts = router_w.shape[-1]
-    # callers sit under the ``mlp`` scope (transformer._ffn)
+def route_topk(
+    h: jax.Array,         # [n, d_model]
+    router_w: jax.Array,  # [d_model, all experts]
+    k: int,
+    scale: float,
+    normalise: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3's router without groups or bias (arXiv:2412.19437,
+    eq. 12-15): ``s_e = sigmoid(h . w_e)`` in float32 over every
+    expert, the ``k`` largest, gates ``scale * s_e / sum of the k``.
+    Returns (expert ids [n, k] int32, gates [n, k] float32). The sum
+    is over all ``k`` chosen experts, held here or not."""
     with jax.named_scope("mlp.router"):
-        router_logits = jnp.einsum(
-            "bsd,de->bse", x.astype(jnp.float32),
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "nd,de->ne", h.astype(jnp.float32),
             router_w.astype(jnp.float32),
             preferred_element_type=jnp.float32,
-        )
-        probs = jax.nn.softmax(router_logits, axis=-1)  # [b,s,E]
-        expert_idx = jnp.argmax(probs, axis=-1)  # [b,s]
-        gate = jnp.max(probs, axis=-1)  # [b,s]
-        onehot = jax.nn.one_hot(expert_idx, n_experts, dtype=jnp.float32)
-        fraction = jnp.mean(onehot, axis=(0, 1))
-        router_mean = jnp.mean(probs, axis=(0, 1))
-        aux_loss = n_experts * jnp.sum(fraction * router_mean)
-    return probs, gate, onehot, aux_loss
+            precision=jax.lax.Precision.HIGHEST,
+        ))
+        top, idx = jax.lax.top_k(scores, k)
+        if normalise:
+            top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), top * scale
 
 
-def moe_layer(
-    x: jax.Array,
-    router_w: jax.Array,  # [d_model, n_experts]
-    w_in: jax.Array,      # [n_experts, d_model, d_ff]
-    w_out: jax.Array,     # [n_experts, d_ff, d_model]
+def expert_block(n_tokens: int, k: int, n_experts: int) -> int:
+    """Rows of one block of ``sparse_experts``: about twice what an
+    expert expects of ``n_tokens`` (so that most experts fill one
+    block), a power of two from 16 (a bf16 tile's rows) to 128."""
+    expected = 2.0 * n_tokens * k / n_experts
+    block = 16
+    while block < expected and block < 128:
+        block *= 2
+    return block
+
+
+def sparse_experts(
+    h: jax.Array,       # [n, d_model], compute dtype
+    idx: jax.Array,     # [n, k] chosen experts, global ids
+    gate: jax.Array,    # [n, k] float32
+    w_gate: jax.Array,  # [held, d_model, f]
+    w_up: jax.Array,    # [held, d_model, f]
+    w_down: jax.Array,  # [held, f, d_model]
+    held_lo: int,
+    n_experts: int,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output [b,s,d], aux_loss scalar). x in compute dtype.
+    """``sum_e gate_e * SwiGLU_e(h)`` over the chosen experts that are
+    HELD here, ``held_lo <= e < held_lo + held``. Returns (the sum
+    [n, d_model] float32, assignments per held expert [held] int32).
 
-    Routing is per-token and drop-free (no capacity bound), so the
-    result for any token depends only on that token's features — which
-    is what makes incremental decoding bit-identical to the full
-    forward. The cost is dense dispatch (each expert processes the full
-    masked sequence). For bounded expert compute during training use
-    ``moe_layer_capacity``; decoding always uses this drop-free layer
-    (models/decode.py rejects capacity configs).
-    """
-    _probs, gate, onehot, aux_loss = _route(x, router_w)
+    The (token, expert) assignments are sorted by expert, the ones
+    for experts held elsewhere last. Each held expert's run is cut
+    into blocks of ``expert_block`` rows; a loop over the blocks THAT
+    EXIST (a dynamic trip count) gathers a block's token rows, runs
+    them through that one expert's three matrices and adds the gated
+    result to its tokens' rows. Work and weight bytes follow the
+    assignments: nothing is computed for an expert nobody chose, no
+    token is dropped whatever the imbalance, and all shapes are
+    static."""
+    n, d = h.shape
+    k = idx.shape[1]
+    held = w_gate.shape[0]
+    total = n * k
+    block = expert_block(n, k, n_experts)
+    dt = h.dtype
+    with jax.named_scope("mlp.dispatch"):
+        local = idx.reshape(total) - held_lo
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        counts = jnp.sum(
+            key[:, None] == jnp.arange(held)[None, :], axis=0,
+            dtype=jnp.int32)
+        starts = jnp.cumsum(counts) - counts
+        blocks = (counts + block - 1) // block
+        block_end = jnp.cumsum(blocks)
+        gates = gate.reshape(total)
 
-    # note: no preferred_element_type=f32 on the batched expert einsums
-    # — the TPU MXU accumulates bf16 inputs in f32 internally, and the
-    # CPU backend's batched dot lacks the bf16->f32 widening variant
-    dt = x.dtype
-    with jax.named_scope("mlp.experts"):
-        expert_in = jnp.einsum("bse,bsd->besd", onehot.astype(dt), x)
-        hidden = jnp.einsum("besd,edf->besf", expert_in, w_in.astype(dt))
-        hidden = jax.nn.gelu(hidden.astype(jnp.float32)).astype(dt)
-        expert_out = jnp.einsum(
-            "besf,efd->besd", hidden, w_out.astype(dt)
-        )
-        combine = (onehot * gate[..., None]).astype(dt)
-        out = jnp.einsum("bse,besd->bsd", combine, expert_out)
-    return out, aux_loss
+    def body(j, out):
+        with jax.named_scope("mlp.dispatch"):
+            e = jnp.sum(j >= block_end).astype(jnp.int32)
+            first = starts[e] + (j - (block_end[e] - blocks[e])) * block
+            offs = first + jnp.arange(block)
+            live = offs < starts[e] + counts[e]
+            assignment = order[jnp.minimum(offs, total - 1)]
+            token = assignment // k
+            rows = h[token]
+        with jax.named_scope("mlp.experts"):
+            def pick(w):
+                return jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
 
+            up = jnp.einsum("bd,df->bf", rows, pick(w_up).astype(dt),
+                            preferred_element_type=jnp.float32)
+            act = jax.nn.silu(jnp.einsum(
+                "bd,df->bf", rows, pick(w_gate).astype(dt),
+                preferred_element_type=jnp.float32)) * up
+            y = jnp.einsum("bf,fd->bd", act.astype(dt),
+                           pick(w_down).astype(dt),
+                           preferred_element_type=jnp.float32)
+        with jax.named_scope("mlp.combine"):
+            weight = jnp.where(live, gates[assignment], 0.0)
+            return out.at[token].add(y * weight[:, None])
 
-def moe_layer_capacity(
-    x: jax.Array,
-    router_w: jax.Array,  # [d_model, n_experts]
-    w_in: jax.Array,      # [n_experts, d_model, d_ff]
-    w_out: jax.Array,     # [n_experts, d_ff, d_model]
-    capacity_factor: float,
-) -> Tuple[jax.Array, jax.Array]:
-    """Capacity-bounded switch MoE: each expert processes at most
-    ``ceil(capacity_factor * s / E)`` tokens per batch row; overflow
-    tokens drop to the residual (standard switch training).
-
-    Dispatch is **sparse**: every token knows its queue position within
-    its expert (a cumsum over the routing one-hot), so tokens scatter
-    straight into static-shape ``[E, capacity, d]`` blocks and results
-    gather back by the same slot index. Expert compute AND
-    dispatch/combine are O(E*capacity*d) / O(s*d) — no ``[b,s,E,C]``
-    one-hot dispatch tensor, no O(s*E*C*d) dispatch einsums. Shapes are
-    fully static, so XLA tiles the expert GEMMs on the MXU and (with
-    the expert axis sharded over ``model``) inserts all-to-alls at the
-    scatter/gather boundaries.
-
-    Inference must use the drop-free ``moe_layer`` (capacity depends on
-    sequence length, so this routing cannot match incremental decode —
-    models/decode.py enforces that).
-    """
-    import math
-
-    b, s, d = x.shape
-    n_experts = router_w.shape[-1]
-    capacity = max(1, math.ceil(capacity_factor * s / n_experts))
-
-    probs, gate, onehot, aux_loss = _route(x, router_w)
-    expert_idx = jnp.argmax(probs, axis=-1)  # [b,s]
-
-    # queue position of each token within its expert, per batch row
-    pos = jnp.sum(
-        (jnp.cumsum(onehot, axis=1) - 1.0) * onehot, axis=-1
-    ).astype(jnp.int32)  # [b,s]
-    keep = pos < capacity
-    # flat slot in the [E*C] dispatch buffer; overflow tokens get an
-    # out-of-range slot, which the scatter drops and the gather fills 0
-    slot = jnp.where(keep, expert_idx * capacity + pos, n_experts * capacity)
-
-    dt = x.dtype
-
-    def dispatch_row(x_row: jax.Array, slot_row: jax.Array) -> jax.Array:
-        buf = jnp.zeros((n_experts * capacity, d), dt)
-        return buf.at[slot_row].set(x_row, mode="drop")
-
-    expert_in = jax.vmap(dispatch_row)(x, slot).reshape(
-        b, n_experts, capacity, d
-    )
-    hidden = jnp.einsum("becd,edf->becf", expert_in, w_in.astype(dt))
-    hidden = jax.nn.gelu(hidden.astype(jnp.float32)).astype(dt)
-    expert_out = jnp.einsum("becf,efd->becd", hidden, w_out.astype(dt))
-
-    def gather_row(flat_row: jax.Array, slot_row: jax.Array) -> jax.Array:
-        return jnp.take(
-            flat_row, slot_row, axis=0, mode="fill", fill_value=0
-        )
-
-    out = jax.vmap(gather_row)(
-        expert_out.reshape(b, n_experts * capacity, d), slot
-    )
-    out = out * (gate * keep).astype(dt)[..., None]
-    return out, aux_loss
+    out = jax.lax.fori_loop(
+        0, block_end[-1], body, jnp.zeros((n, d), jnp.float32))
+    return out, counts

@@ -2,13 +2,13 @@
 run the same forward/decode code on it.
 
 ``quantize_model_params`` converts every large matmul weight (attention
-projections, MLP/MoE, embed/unembed) to int8 with broadcast-ready
+projections, MLP, embed/unembed) to int8 with broadcast-ready
 per-output-channel scales; norms stay float. Two execution paths:
 
 - **dense dequant** (``maybe_dequant_layer``): rebuild one layer's
   bf16 weights inside the scan body — quantized and full-precision
   params flow through identical math. Used for training-size token
-  counts and any non-tile-aligned/MoE model.
+  counts and any non-tile-aligned model.
 - **fused int8** (``fused_qkv``/``fused_attn_out``/``fused_mlp``):
   the decode step's projections run through ops/quant.py's pallas
   dequant-GEMM, so weights stream from HBM as int8 and upcast in
@@ -35,8 +35,6 @@ _LAYER_QUANT_AXES: Dict[str, Tuple[int, ...]] = {
     "w_gate": (1,),    # [L, d, f]
     "w_up": (1,),
     "w_down": (1,),    # [L, f, d]
-    "moe_w_in": (2,),  # [L, E, d, f]: reduce d (per expert)
-    "moe_w_out": (2,), # [L, E, f, d]
 }
 
 _TOP_QUANT_AXES: Dict[str, Tuple[int, ...]] = {
@@ -84,7 +82,7 @@ def maybe_dequant_layer(
     """Rebuild a dense layer-params dict from a quantized one (no-op
     for full-precision input). Runs inside the layer scan body, so only
     one layer's weights are ever dense at a time."""
-    if "wq_q" not in layer_params and "moe_w_in_q" not in layer_params:
+    if "wq_q" not in layer_params:
         return layer_params
     dense = dict(layer_params)
     for key in _LAYER_QUANT_AXES:
@@ -137,7 +135,7 @@ def can_fuse_int8(
     layers: Dict[str, jax.Array], cfg: Any, rows: int
 ) -> bool:
     """True when the decode-step projections can run through the fused
-    int8 pallas GEMM: dense (non-MoE) quantized weights, a
+    int8 pallas GEMM: quantized weights, a
     weight-streaming-bound row count, and tile-aligned dims."""
     if "wq_q" not in layers or "w_gate_q" not in layers:
         return False

@@ -40,6 +40,19 @@ replaces the full row INCLUDING its position, so a reused slot holds
 nothing of its previous occupant — what makes windows compose with
 the pool). Emitted tokens are masked to pad after eos, same as
 ``generate``.
+
+The programs here are a contract over (configuration, step function,
+cache tree), not over one model. A configuration of another family
+(``cfg.family``: models/mla_moe.py) keeps a BATCHED cache, whose batch
+axis is the slot axis and whose ``pos`` is one number per row: the
+family brings its own ``slot_cache`` and ``insert_row``, and its step is one
+``decode_chunk`` over the whole pool instead of a vmap of one-row
+steps, so a layer that routes tokens to experts sees every row of the
+step at once. A pool may carry a ``stats`` leaf (what the step
+function counted, e.g. routed experts): the chunk and window programs
+zero it on entry and return its value as a fourth output, beside the
+tokens and fetched with them; a pool without one returns ``None``
+there, and its compiled program is what it was.
 """
 from __future__ import annotations
 
@@ -56,6 +69,7 @@ from .decode import (
     apply_logit_bias,
     apply_token_penalties,
     count_token,
+    decode_chunk,
     decode_step,
     init_cache,
     mask_eos_before_min,
@@ -206,7 +220,10 @@ def retire_slot(state: dict, slot: int, out_sharding=None) -> dict:
 def slot_cache(cfg: TransformerConfig, slots: int, max_len: int) -> Cache:
     """A pool of ``slots`` single-row caches, stacked on a leading
     slot axis (k/v: [S, layers, 1, length, kv_heads, head_dim];
-    pos: [S])."""
+    pos: [S]); a batched family's own pool (see the module's note)."""
+    family = getattr(cfg, "family", None)
+    if family is not None:
+        return family.slot_cache(cfg, slots, max_len)
     row = init_cache(cfg, 1, max_len)
     return jax.tree.map(
         lambda x: jnp.broadcast_to(
@@ -241,6 +258,10 @@ def _jitted_insert(cfg: TransformerConfig, out_sharding=None):
 
         return jax.tree.map(put, pool, row)
 
+    family = getattr(cfg, "family", None)
+    if family is not None:
+        insert = family.insert_row
+
     return jax.jit(
         insert, donate_argnums=(0,), out_shardings=out_sharding
     )
@@ -255,9 +276,22 @@ def insert_row(pool: Cache, row: Cache, slot: int,
     )
 
 
+def _zero_stats(pool: Cache) -> Cache:
+    """A pool's ``stats`` leaf counts from the program's entry."""
+    if "stats" not in pool:
+        return pool
+    return {**pool, "stats": jnp.zeros_like(pool["stats"])}
+
+
 def _vstep(cfg: TransformerConfig):
     """The single-row decode step vmapped over the slot axis — the
-    shared device kernel of the chunk AND fused-window programs."""
+    shared device kernel of the chunk AND fused-window programs. A
+    batched family's pool IS a cache of S rows: one ``decode_chunk``
+    over it, tokens [S, 1] -> logits [S, 1, V]."""
+    if getattr(cfg, "family", None) is not None:
+        return lambda params, pool, token: decode_chunk(
+            params, pool, token, cfg
+        )
     return jax.vmap(
         lambda params, cache, token: decode_step(
             params, cache, token, cfg
@@ -323,6 +357,7 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
     vstep = _vstep(cfg)
 
     def run(params, pool, state):
+        pool = _zero_stats(pool)
         body = _round_step_body(params, state, vstep)
         # ``steps`` names what the step loop itself does around its
         # body (on the chip: copies of the whole pool, PERF.md s.5)
@@ -337,7 +372,7 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
             state, last=last, done=done, counts=counts,
             step_idx=idx,
         )
-        return pool, new_state, toks.T  # [S, chunk]
+        return pool, new_state, toks.T, pool.get("stats")  # [S, chunk]
 
     return jax.jit(
         run, donate_argnums=(1, 2), out_shardings=out_sharding
@@ -367,6 +402,7 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
     vstep = _vstep(cfg)
 
     def run(params, pool, state, budget):
+        pool = _zero_stats(pool)
         body = _round_step_body(params, state, vstep)
         pad = state["pad_id"].astype(jnp.int32)
         out0 = jnp.broadcast_to(
@@ -399,7 +435,7 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
         new_state = dict(
             state, last=last, done=done, counts=counts, step_idx=idx,
         )
-        return pool, new_state, out, r
+        return pool, new_state, out, r, pool.get("stats")
 
     return jax.jit(
         run, donate_argnums=(1, 2), out_shardings=out_sharding
@@ -413,6 +449,7 @@ def decode_slots_chunk(
     cfg: TransformerConfig,
     chunk: int,
     out_sharding=None,
+    with_stats: bool = False,
 ):
     """Advance the whole pool ``chunk`` tokens; see _jitted_chunk.
     ``state`` is the device-resident per-slot sampling dict
@@ -422,11 +459,13 @@ def decode_slots_chunk(
     request). Returns (pool, state, tokens [S, chunk]); the pool AND
     the whole state dict are donated. ``out_sharding`` pins every
     output's placement (see _jitted_insert) — the pod passes
-    fully-replicated."""
+    fully-replicated. ``with_stats`` appends the pool's ``stats``
+    (None where it has none)."""
     slots = int(state["last"].shape[0])
-    return _jitted_chunk(cfg, slots, chunk, out_sharding)(
+    out = _jitted_chunk(cfg, slots, chunk, out_sharding)(
         params, pool, state
     )
+    return out if with_stats else out[:3]
 
 
 def decode_slots_window(
@@ -438,6 +477,7 @@ def decode_slots_window(
     rounds: int,
     budget,
     out_sharding=None,
+    with_stats: bool = False,
 ):
     """Advance the whole pool up to ``rounds`` chunk-rounds in ONE
     host->device dispatch (see _jitted_window): the device loops over
@@ -449,9 +489,10 @@ def decode_slots_window(
     state are donated, ``out_sharding`` pins output placement exactly
     like decode_slots_chunk's."""
     slots = int(state["last"].shape[0])
-    return _jitted_window(cfg, slots, chunk, rounds, out_sharding)(
+    out = _jitted_window(cfg, slots, chunk, rounds, out_sharding)(
         params, pool, state, jnp.asarray(budget, jnp.int32)
     )
+    return out if with_stats else out[:4]
 
 
 @functools.lru_cache(maxsize=8)

@@ -39,6 +39,13 @@ depend on host-side decisions about N's tokens — the plain programs;
 false for draft/verify, whose next round needs the acceptance
 result).
 
+The protocol is a contract over (configuration, step function, cache
+tree): nothing here reads a model's widths. The configuration's family
+supplies the cache and the step (models/slots.py); a family whose step
+counts something per round (the routed experts of models/mla_moe.py)
+returns the counts with the tokens, and ``tokens`` adds them up on the
+host (``expert_stats``): no dispatch and no sync of their own.
+
 Implementations: :class:`PlainStepProgram` (models/slots.py's chunk +
 fused-window programs), ``models.quantized.QuantizedStepProgram``
 (the same programs over int8 weights — the forward dequantizes per
@@ -95,6 +102,9 @@ class PlainStepProgram:
         self.chunk = chunk
         self.rounds = rounds
         self.out_sharding = out_sharding
+        #: the pool's ``stats`` leaf summed over every fetched round
+        #: (None until a round brings one)
+        self.stats_total = None
         self.reset()
 
     def reset(self) -> None:
@@ -140,31 +150,48 @@ class PlainStepProgram:
     # zero host syncs (the budgets upload is async and per-window)
     def dispatch(self, budgets, fused: bool):
         if fused and self.rounds > 1:
-            self._pool, self._state, toks, run = decode_slots_window(
+            (self._pool, self._state, toks, run,
+             stats) = decode_slots_window(
                 self.params, self._pool, self._state, self.cfg,
                 self.chunk, self.rounds, budgets, self.out_sharding,
+                with_stats=True,
             )
-            return toks, run
-        self._pool, self._state, toks = decode_slots_chunk(
+            return toks, run, stats
+        self._pool, self._state, toks, stats = decode_slots_chunk(
             self.params, self._pool, self._state, self.cfg,
-            self.chunk, self.out_sharding,
+            self.chunk, self.out_sharding, with_stats=True,
         )
-        return toks, None
+        return toks, None, stats
 
     # cpcheck: hotpath — the one deliberate sync per window
     def tokens(self, handle):
-        toks, run = handle
+        toks, run, stats = handle
         if run is None:
-            toks_host = np.asarray(jax.device_get(toks))  # cpcheck: disable=CP-HOTSYNC the per-window token fetch
+            toks_host, stats = jax.device_get((toks, stats))  # cpcheck: disable=CP-HOTSYNC the per-window token fetch
             rounds_run = 1
         else:
-            toks_host, run_host = jax.device_get((toks, run))  # cpcheck: disable=CP-HOTSYNC the per-window token fetch
+            toks_host, run_host, stats = jax.device_get((toks, run, stats))  # cpcheck: disable=CP-HOTSYNC the per-window token fetch
             rounds_run = int(run_host)
             toks_host = toks_host[:, : rounds_run * self.chunk]
+        if stats is not None:
+            # already on the host: fetched with the tokens above
+            stats = stats.astype(np.int64)
+            self.stats_total = stats if self.stats_total is None \
+                else self.stats_total + stats
         valid = np.full(
             (self.slots,), rounds_run * self.chunk, np.int64
         )
         return toks_host, valid, rounds_run
+
+    def expert_stats(self):
+        """What the expert layers of the decode rounds fetched so far
+        routed, under ``/v1/model`` ``experts``'s names; None for a
+        family without routed experts."""
+        describe = getattr(
+            getattr(self.cfg, "family", None), "describe_stats", None)
+        if describe is None:
+            return None
+        return describe(self.cfg, self.stats_total)
 
 
 def make_step_program(

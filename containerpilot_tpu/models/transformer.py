@@ -80,22 +80,8 @@ class TransformerConfig:
     # decode KV cache to a ring of `window` entries (models/decode.py)
     # and the attention FLOPs to O(s*window).
     window: int = 0
-    # mixture-of-experts: 0 = dense SwiGLU; >0 replaces the MLP with
-    # switch-routed experts (models/moe.py — drop-free routing, expert
-    # axis sharded over the mesh's "model" axis for expert parallelism)
-    moe_experts: int = 0
-    moe_aux_weight: float = 0.01
-    # >0 enables capacity-bounded expert compute for TRAINING (tokens
-    # past ceil(factor*s/E) per expert drop to the residual — standard
-    # switch training). Inference/serving configs must leave this 0:
-    # capacity routing can't match incremental decode.
-    moe_train_capacity: float = 0.0
 
     def __post_init__(self) -> None:
-        if self.moe_train_capacity > 0 and self.moe_experts == 0:
-            raise ValueError(
-                "moe_train_capacity requires moe_experts > 0"
-            )
         if self.remat not in (True, False, "full", "dots", "none"):
             raise ValueError(
                 f"remat must be True/False/'full'/'dots'/'none', "
@@ -175,7 +161,7 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 
     ks = jax.random.split(k_attn, 4)
     km = jax.random.split(k_mlp, 3)
-    layers: Dict[str, Any] = {
+    layers = {
         # attention projections, stacked over layers
         "wq": dense(ks[0], (L, d, h, hd), d),
         "wk": dense(ks[1], (L, d, kv, hd), d),
@@ -183,16 +169,11 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         "wo": dense(ks[3], (L, h, hd, d), h * hd),
         "norm_attn": jnp.ones((L, d), jnp.float32),
         "norm_mlp": jnp.ones((L, d), jnp.float32),
+        # SwiGLU
+        "w_gate": dense(km[0], (L, d, f), d),
+        "w_up": dense(km[1], (L, d, f), d),
+        "w_down": dense(km[2], (L, f, d), f),
     }
-    if cfg.moe_experts > 0:
-        E = cfg.moe_experts
-        layers["router"] = dense(km[0], (L, d, E), d)
-        layers["moe_w_in"] = dense(km[1], (L, E, d, f), d)
-        layers["moe_w_out"] = dense(km[2], (L, E, f, d), f)
-    else:
-        layers["w_gate"] = dense(km[0], (L, d, f), d)
-        layers["w_up"] = dense(km[1], (L, d, f), d)
-        layers["w_down"] = dense(km[2], (L, f, d), f)
     return {
         "embed": jax.random.normal(k_emb, (cfg.vocab_size, d), jnp.float32)
         * 0.02,
@@ -300,40 +281,10 @@ def _mlp(
         return x + down
 
 
-def _ffn(
-    x: jax.Array, layer_params: Dict[str, jax.Array], cfg: TransformerConfig
-):
-    """The feed-forward half: dense SwiGLU or switch-routed experts.
-    Returns (x, aux_loss)."""
-    if cfg.moe_experts > 0:
-        from .moe import moe_layer, moe_layer_capacity
-
-        with jax.named_scope("mlp"):
-            h = _rms_norm(x, layer_params["norm_mlp"])
-            if cfg.moe_train_capacity > 0:
-                out, aux = moe_layer_capacity(
-                    h,
-                    layer_params["router"],
-                    layer_params["moe_w_in"],
-                    layer_params["moe_w_out"],
-                    cfg.moe_train_capacity,
-                )
-            else:
-                out, aux = moe_layer(
-                    h,
-                    layer_params["router"],
-                    layer_params["moe_w_in"],
-                    layer_params["moe_w_out"],
-                )
-            return x + out, aux
-    return _mlp(x, layer_params, cfg), jnp.zeros((), jnp.float32)
-
-
 def _layer(
     x: jax.Array, layer_params: Dict[str, jax.Array], cfg: TransformerConfig
 ):
-    """One transformer block. x: [batch, seq, d_model] in compute dtype.
-    Returns (x, aux_loss)."""
+    """One transformer block. x: [batch, seq, d_model] in compute dtype."""
     layer_params = maybe_dequant_layer(layer_params, cfg.dtype)
     q, k, v = _qkv(x, layer_params, cfg)
     attn_fn = cfg.attention_fn or _auto_attention(cfg, q.shape[1])
@@ -346,27 +297,25 @@ def _layer(
     with jax.named_scope("attn"), jax.named_scope("attn.scores"):
         attn = attn_fn(q, k, v)
     x = _attn_out(x, attn, layer_params, cfg)
-    return _ffn(x, layer_params, cfg)
+    return _mlp(x, layer_params, cfg)
 
 
 def forward_hidden(
     params: Params, tokens: jax.Array, cfg: TransformerConfig
-):
-    """tokens: [batch, seq] int32 -> (final normed hidden
-    [batch, seq, d_model], aux_loss scalar) — everything up to (not
-    including) the unembed projection, so losses may stream the vocab
-    projection in pieces (chunked cross-entropy) instead of
-    materializing [batch, seq, vocab] logits.
+) -> jax.Array:
+    """tokens: [batch, seq] int32 -> final normed hidden
+    [batch, seq, d_model] — everything up to (not including) the
+    unembed projection, so losses may stream the vocab projection in
+    pieces (chunked cross-entropy) instead of materializing
+    [batch, seq, vocab] logits.
 
     The layer stack is a lax.scan over stacked layer params: one
     compiled block body, L iterations, rematerialization-friendly.
     """
     x = embed_lookup(params, tokens, cfg.dtype)
 
-    def body(carry, layer_params):
-        x, aux = carry
-        x, layer_aux = _layer(x, layer_params, cfg)
-        return (x, aux + layer_aux), None
+    def body(x, layer_params):
+        return _layer(x, layer_params, cfg), None
 
     if cfg.remat and cfg.remat != "none":
         # remat="dots" keeps the MXU outputs (the expensive matmuls)
@@ -384,32 +333,24 @@ def forward_hidden(
     # ``layers`` names what the scan itself does around the blocks
     # (slicing the stacked weights, stacking the backward's residuals)
     with jax.named_scope("layers"):
-        (x, aux), _ = lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["layers"]
-        )
-    return _rms_norm(x, params["norm_out"]), aux
-
-
-def forward_with_aux(
-    params: Params, tokens: jax.Array, cfg: TransformerConfig
-):
-    """tokens: [batch, seq] int32 -> (logits [batch, seq, vocab] f32,
-    aux_loss scalar — MoE load balance; zero for dense models)."""
-    x, aux = forward_hidden(params, tokens, cfg)
-    with jax.named_scope("head"):
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x,
-            maybe_dequant_top(params, "unembed", cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-    return logits, aux
+        x, _ = lax.scan(body, x, params["layers"])
+    return _rms_norm(x, params["norm_out"])
 
 
 def forward(
     params: Params, tokens: jax.Array, cfg: TransformerConfig
 ) -> jax.Array:
     """tokens: [batch, seq] int32 -> logits [batch, seq, vocab] f32."""
-    return forward_with_aux(params, tokens, cfg)[0]
+    family = getattr(cfg, "family", None)
+    if family is not None:  # another family's own forward (serving only)
+        return family.forward(params, tokens, cfg)
+    x = forward_hidden(params, tokens, cfg)
+    with jax.named_scope("head"):
+        return jnp.einsum(
+            "bsd,dv->bsv", x,
+            maybe_dequant_top(params, "unembed", cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
 
 
 def _ce_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -424,17 +365,10 @@ def _ce_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
         )[..., 0]
 
 
-def next_token_loss(
-    logits: jax.Array,
-    aux: jax.Array,
-    tokens: jax.Array,
-    cfg: TransformerConfig,
-) -> jax.Array:
-    """Next-token CE over logits for tokens[:, :-1], plus weighted MoE
-    aux — shared by the plain and pipelined losses."""
-    return jnp.mean(_ce_nll(logits, tokens[:, 1:])) + (
-        cfg.moe_aux_weight * aux
-    )
+def next_token_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """Next-token CE over logits for tokens[:, :-1] — shared by the
+    plain and pipelined losses."""
+    return jnp.mean(_ce_nll(logits, tokens[:, 1:]))
 
 
 def _chunked_next_token_loss(
@@ -446,7 +380,7 @@ def _chunked_next_token_loss(
     loss head is [b, loss_chunk, vocab] (the backward recomputes each
     chunk's logits — one extra unembed matmul, a few percent of step
     FLOPs, against gigabytes of saved HBM at real vocab sizes)."""
-    x, aux = forward_hidden(params, tokens[:, :-1], cfg)
+    x = forward_hidden(params, tokens[:, :-1], cfg)
     targets = tokens[:, 1:]
     b, s, d = x.shape
     chunk = min(cfg.loss_chunk, s)
@@ -479,16 +413,15 @@ def _chunked_next_token_loss(
             piece, jnp.zeros((), jnp.float32),
             (x_chunks, t_chunks, m_chunks),
         )
-    return total / (b * s) + cfg.moe_aux_weight * aux
+    return total / (b * s)
 
 
 def loss_fn(
     params: Params, tokens: jax.Array, cfg: TransformerConfig
 ) -> jax.Array:
-    """Next-token cross-entropy (+ weighted MoE aux loss when routed).
-    ``cfg.loss_chunk > 0`` streams the vocab projection in sequence
-    chunks instead of materializing full logits."""
+    """Next-token cross-entropy. ``cfg.loss_chunk > 0`` streams the
+    vocab projection in sequence chunks instead of materializing full
+    logits."""
     if cfg.loss_chunk > 0:
         return _chunked_next_token_loss(params, tokens, cfg)
-    logits, aux = forward_with_aux(params, tokens[:, :-1], cfg)
-    return next_token_loss(logits, aux, tokens, cfg)
+    return next_token_loss(forward(params, tokens[:, :-1], cfg), tokens)

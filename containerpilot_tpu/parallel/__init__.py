@@ -23,7 +23,7 @@ from .distributed import initialize_from_catalog, initialize_from_env
 from .watchdog import StepWatchdog
 from .mesh import MeshPlan, make_mesh
 from .pipeline import (
-    pipeline_forward_with_aux,
+    pipeline_forward,
     pipeline_loss_fn,
     pipeline_sharding_rules,
 )
@@ -74,7 +74,7 @@ __all__ = [
     "initialize_from_catalog",
     "initialize_from_env",
     "StepWatchdog",
-    "pipeline_forward_with_aux",
+    "pipeline_forward",
     "pipeline_loss_fn",
     "pipeline_sharding_rules",
 ]

@@ -22,15 +22,12 @@ data parallelism on an outer ``data`` axis.
 
 Logits are numerically equivalent to the unpipelined forward — same
 math, tolerance-level float differences from microbatched reduction
-tiling. For MoE models the aux load-balance loss is the mean of
-per-*microbatch* statistics rather than the full-batch statistic (the
-loss is nonlinear in batch partitioning) — the standard behavior of
-microbatched MoE training.
+tiling.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Tuple
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -49,18 +46,13 @@ from ..ops.ring_attention import shard_map  # version-compat wrapper
 
 def _stage_fn(
     x: jax.Array, local_layers: Any, cfg: TransformerConfig
-) -> Tuple[jax.Array, jax.Array]:
+) -> jax.Array:
     """Apply this stage's layer slice: scan over local layers."""
-
-    def body(carry, layer_params):
-        x, aux = carry
-        x, layer_aux = _layer(x, layer_params, cfg)
-        return (x, aux + layer_aux), None
-
-    (x, aux), _ = lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), local_layers
+    x, _ = lax.scan(
+        lambda x, layer_params: (_layer(x, layer_params, cfg), None),
+        x, local_layers,
     )
-    return x, aux
+    return x
 
 
 def _pipeline_body(
@@ -71,7 +63,6 @@ def _pipeline_body(
     axis_name: str,
     n_stages: int,
     n_microbatches: int,
-    data_axis: str = None,
 ):
     """Per-device body under shard_map; ``layers`` leaves are the local
     [L/S, ...] slices."""
@@ -81,12 +72,12 @@ def _pipeline_body(
     ticks = n_microbatches + n_stages - 1
 
     def tick(t, carry):
-        acts, outputs, aux = carry
+        acts, outputs = carry
         # stage 0 ingests microbatch t (clamped; masked when t >= M)
         feed_idx = jnp.clip(t, 0, n_microbatches - 1)
         fresh = lax.dynamic_index_in_dim(x_mb, feed_idx, 0, keepdims=False)
         my_in = jnp.where(stage == 0, fresh, acts)
-        y, stage_aux = _stage_fn(my_in, layers, cfg)
+        y = _stage_fn(my_in, layers, cfg)
         # the last stage banks microbatch t-S+1's result once it's real
         out_idx = jnp.clip(t - (n_stages - 1), 0, n_microbatches - 1)
         is_valid = (t >= n_stages - 1) & (stage == n_stages - 1)
@@ -94,33 +85,20 @@ def _pipeline_body(
             outputs, y.astype(outputs.dtype), out_idx, 0
         )
         outputs = jnp.where(is_valid, banked, outputs)
-        # every stage contributes aux for the ticks where it held a
-        # real microbatch (stage s is busy during ticks s..s+M-1)
-        busy = (t >= stage) & (t < stage + n_microbatches)
-        aux = aux + jnp.where(busy, stage_aux, 0.0)
         acts = lax.ppermute(y, axis_name, perm)
-        return acts, outputs, aux
+        return acts, outputs
 
     acts0 = jnp.zeros((mb, s, d), cfg.dtype)
     outputs0 = jnp.zeros((n_microbatches, mb, s, d), cfg.dtype)
-    aux0 = jnp.zeros((), jnp.float32)
-    _acts, outputs, aux = lax.fori_loop(
-        0, ticks, tick, (acts0, outputs0, aux0)
-    )
+    _acts, outputs = lax.fori_loop(0, ticks, tick, (acts0, outputs0))
     # broadcast the last stage's results to every device
-    outputs = lax.psum(
+    return lax.psum(
         jnp.where(stage == n_stages - 1, outputs, 0.0).astype(jnp.float32),
         axis_name,
     ).astype(cfg.dtype)
-    aux = lax.psum(aux, axis_name)
-    if data_axis is not None:
-        # the aux out_spec is replicated, so it must agree across the
-        # data axis: average the per-shard statistics
-        aux = lax.pmean(aux, data_axis)
-    return outputs, aux
 
 
-def pipeline_forward_with_aux(
+def pipeline_forward(
     params: Params,
     tokens: jax.Array,
     cfg: TransformerConfig,
@@ -131,7 +109,7 @@ def pipeline_forward_with_aux(
     """Forward through pipeline-sharded layers.
 
     tokens: [batch, seq]; batch must divide by n_microbatches; n_layers
-    by the pipe axis size. Returns (logits, aux) like forward_with_aux.
+    by the pipe axis size. Returns logits like forward.
     """
     if axis_name not in mesh.axis_names:
         raise ValueError(f"mesh has no {axis_name!r} axis: {mesh.axis_names}")
@@ -196,21 +174,19 @@ def pipeline_forward_with_aux(
             axis_name=axis_name,
             n_stages=n_stages,
             n_microbatches=n_microbatches,
-            data_axis=data_axis,
         ),
         mesh=mesh,
         in_specs=(layer_specs, x_spec),
-        out_specs=(x_spec, P()),
+        out_specs=x_spec,
         auto=auto or None,
     )
-    outputs, aux = fn(params["layers"], x_mb)
+    outputs = fn(params["layers"], x_mb)
     x = outputs.reshape(b, s, -1)
     x = _rms_norm(x, params["norm_out"])
-    logits = jnp.einsum(
+    return jnp.einsum(
         "bsd,dv->bsv", x, params["unembed"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,
     )
-    return logits, aux / n_microbatches
 
 
 def pipeline_loss_fn(
@@ -221,10 +197,10 @@ def pipeline_loss_fn(
     n_microbatches: int = 4,
 ) -> jax.Array:
     """Next-token CE through the pipeline (drop-in for loss_fn)."""
-    logits, aux = pipeline_forward_with_aux(
+    logits = pipeline_forward(
         params, tokens[:, :-1], cfg, mesh, n_microbatches
     )
-    return next_token_loss(logits, aux, tokens, cfg)
+    return next_token_loss(logits, tokens)
 
 
 def pipeline_sharding_rules(cfg: Any = None, mesh: Mesh = None) -> Any:

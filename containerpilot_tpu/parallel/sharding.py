@@ -28,10 +28,6 @@ def param_sharding_rules(
 ) -> Dict[str, Any]:
     """PartitionSpec pytree matching models.transformer.init_params.
 
-    With an MoE config (cfg.moe_experts > 0) the feed-forward specs are
-    expert-parallel: the expert axis shards over ``model`` and XLA
-    inserts all-to-alls at the dispatch/combine einsums.
-
     Under GQA, wk/wv's kv-head axis may be smaller than the model axis;
     when ``mesh`` is provided and kv_heads doesn't divide by it, those
     two (small) tensors replicate instead of crashing placement.
@@ -51,26 +47,12 @@ def param_sharding_rules(
         "wo": P(None, "model", None, None),
         "norm_attn": P(None, None),  # replicated
         "norm_mlp": P(None, None),
+        # [L, d, ff]: column-parallel
+        "w_gate": P(None, None, "model"),
+        "w_up": P(None, None, "model"),
+        # [L, ff, d]: row-parallel
+        "w_down": P(None, "model", None),
     }
-    if cfg is not None and getattr(cfg, "moe_experts", 0) > 0:
-        layers.update(
-            {
-                "router": P(None, None, None),  # replicated router
-                # [L, E, d, ff] / [L, E, ff, d]: experts over model axis
-                "moe_w_in": P(None, "model", None, None),
-                "moe_w_out": P(None, "model", None, None),
-            }
-        )
-    else:
-        layers.update(
-            {
-                # [L, d, ff]: column-parallel
-                "w_gate": P(None, None, "model"),
-                "w_up": P(None, None, "model"),
-                # [L, ff, d]: row-parallel
-                "w_down": P(None, "model", None),
-            }
-        )
     return {
         "embed": P("model", None),  # vocab sharded
         "layers": layers,

@@ -129,6 +129,10 @@ _ENGINE_COUNTERS = (
     "admissions", "queue_wait_s", "dispatches_fused",
     "dispatches_single", "store_bytes", "spill_bytes",
     "readmit_bytes",
+    # the part of the three above that is LATENT rows (a cache whose
+    # leaves are ``ckv``/``kpe``, models/mla_moe.py); 0 for keys and
+    # values per head
+    "latent_store_bytes", "latent_spill_bytes", "latent_readmit_bytes",
 )
 
 
@@ -191,6 +195,9 @@ class EnginePhases:
         self.store_bytes = 0
         self.spill_bytes = 0
         self.readmit_bytes = 0
+        self.latent_store_bytes = 0
+        self.latent_spill_bytes = 0
+        self.latent_readmit_bytes = 0
         self._open: Optional[str] = None
         self._since = 0.0
         self._open_annotation: Any = None

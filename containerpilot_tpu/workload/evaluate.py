@@ -47,7 +47,6 @@ def main() -> int:
     parser.add_argument("--n-kv-heads", type=int, default=0)
     parser.add_argument("--vocab", type=int, default=32_000)
     parser.add_argument("--window", type=int, default=0)
-    parser.add_argument("--moe-experts", type=int, default=0)
     parser.add_argument("--loss-chunk", type=int, default=0)
     parser.add_argument(
         "--eval-holdout", type=int, required=True,
@@ -81,7 +80,6 @@ def main() -> int:
         n_layers=args.n_layers,
         d_ff=derive_d_ff(args.d_model),
         max_seq_len=args.seq_len,
-        moe_experts=args.moe_experts,
         window=args.window,
         loss_chunk=args.loss_chunk,
     )

@@ -54,8 +54,6 @@ def train_flops_per_token(
 
     - sliding window: the attention term scales with
       min(seq, window) — the kernels skip out-of-window blocks;
-    - MoE: only 1 of E experts executes per token (top-1 switch
-      routing), so the inactive experts' parameters don't bill;
     - ``n_frozen`` (LoRA base): frozen params do forward + grad
       propagation but no weight-gradient matmul — 4 FLOPs/param
       instead of 6. Without these corrections the MFU gauge reads a
@@ -68,15 +66,9 @@ def train_flops_per_token(
     """
     w = float(seq if cfg.window <= 0 else min(seq, cfg.window))
     attn_span = w - w * (w - 1.0) / (2.0 * seq)
-    active = float(n_params)
-    if getattr(cfg, "moe_experts", 0) > 1:
-        expert_total = (
-            2.0 * cfg.n_layers * cfg.moe_experts * cfg.d_model * cfg.d_ff
-        )
-        active -= expert_total * (1.0 - 1.0 / cfg.moe_experts)
-    frozen = min(float(n_frozen), active)
+    frozen = min(float(n_frozen), float(n_params))
     return (
-        6.0 * (active - frozen)
+        6.0 * (n_params - frozen)
         + 4.0 * frozen
         + 12.0 * cfg.n_layers * cfg.d_model * attn_span
     )

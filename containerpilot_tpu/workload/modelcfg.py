@@ -109,8 +109,14 @@ def warmup_fingerprint(
             "n_layers": getattr(cfg, "n_layers", 0),
             "d_ff": getattr(cfg, "d_ff", 0),
             "window": getattr(cfg, "window", 0),
-            "moe_experts": getattr(cfg, "moe_experts", 0),
             "kv_int8": bool(getattr(cfg, "kv_int8", False)),
+            # a model read from a file (--model-config): the file's
+            # content and the share of the experts held. A marker
+            # written for one share, or one edit of the file, must
+            # not skip another's warm-up
+            "model_file": getattr(cfg, "source_digest", ""),
+            "held_experts": [getattr(cfg, "held_lo", 0),
+                             getattr(cfg, "held_n", 0)],
             "max_len": max_len,
             "slots": slots,
             "slot_chunk": slot_chunk,
@@ -205,6 +211,39 @@ def parse_compile_cache_note(raw: object) -> Tuple[str, str]:
     from ..fleet.notes import parse_compile_cache
 
     return parse_compile_cache(raw)
+
+
+def load_model_file(path: str, max_len: int) -> Any:
+    """The model configuration a ``--model-config`` file describes,
+    built from the published ``config.json`` keys the file holds (the
+    benchmark's configuration files are such files, with their notes
+    beside the keys). The architecture is told by its keys:
+    ``kv_lora_rank`` is latent attention over routed experts
+    (models/mla_moe.py). A file of another architecture is refused
+    with its name; nothing is guessed."""
+    import hashlib
+    import json as json_mod
+
+    try:
+        with open(path, "rb") as fh:
+            raw = fh.read()
+        config = json_mod.loads(raw)
+    except (OSError, ValueError) as exc:
+        raise SystemExit(f"--model-config {path}: {exc}") from None
+    if "kv_lora_rank" not in config or "n_routed_experts" not in config:
+        raise SystemExit(
+            f"--model-config {path}: model_type "
+            f"{config.get('model_type')!r} has no builder here (latent "
+            "attention with routed experts is the one family read "
+            "from a file; the flagship block still takes its flags)"
+        )
+    from ..models.mla_moe import from_published
+
+    digest = hashlib.blake2b(raw, digest_size=8).hexdigest()
+    try:
+        return from_published(config, max_len, digest)
+    except (KeyError, ValueError) as exc:
+        raise SystemExit(f"--model-config {path}: {exc!r}") from None
 
 
 def derive_d_ff(d_model: int) -> int:

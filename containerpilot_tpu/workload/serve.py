@@ -1076,6 +1076,12 @@ class InferenceServer:
                     self.slot_engine.stats
                     if self.slot_engine is not None else None
                 ),
+                # routed experts of the decode rounds (a held share
+                # of them: models/mla_moe.py); None for a dense model
+                "experts": (
+                    self.slot_engine.expert_stats()
+                    if self.slot_engine is not None else None
+                ),
                 # SSE streaming rides the slot engine's chunks
                 "stream": self.slot_engine is not None,
                 "draining": self.draining,

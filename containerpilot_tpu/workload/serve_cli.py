@@ -33,6 +33,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "multiplexed transport); --no-mux keeps this replica plain "
         "HTTP/1.1 and gateways fall back per-replica",
     )
+    parser.add_argument(
+        "--model-config", default="",
+        help="build the model from a file of published config.json "
+        "keys (modelcfg.load_model_file) instead of the width flags "
+        "below; its weights are made and held in bfloat16. Rejects "
+        "the flags that only the flagship block has",
+    )
     parser.add_argument("--max-len", type=int, default=512)
     parser.add_argument("--d-model", type=int, default=256)
     parser.add_argument("--n-layers", type=int, default=2)
@@ -40,9 +47,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
     parser.add_argument("--n-kv-heads", type=int, default=0,
                         help="GQA kv heads (0 = full multi-head); must "
                         "match the checkpoint being served")
-    parser.add_argument("--moe-experts", type=int, default=0,
-                        help="switch-MoE experts; must match the "
-                        "checkpoint being served")
     parser.add_argument("--window", type=int, default=0,
                         help="sliding-window attention; must match the "
                         "checkpoint being served. Decode KV memory "
@@ -260,8 +264,8 @@ def _validate_tp(cfg: TransformerConfig, tp: int) -> None:
     """Every axis the partition rules put on the model axis must
     divide by tp — fail with a clean message at startup, not a raw
     ValueError deep inside device_put/orbax (sharding.py
-    param_sharding_rules: heads, d_ff, vocab, and MoE experts are
-    model-sharded; GQA KV replicates when tp does not divide it)."""
+    param_sharding_rules: heads, d_ff and vocab are model-sharded;
+    GQA KV replicates when tp does not divide it)."""
     for name, size in (
         ("n_heads", cfg.n_heads),
         ("d_ff", cfg.d_ff),
@@ -269,10 +273,30 @@ def _validate_tp(cfg: TransformerConfig, tp: int) -> None:
     ):
         if size % tp:
             raise SystemExit(f"--tp {tp} must divide {name} ({size})")
-    if cfg.moe_experts > 1 and cfg.moe_experts % tp:
-        raise SystemExit(
-            f"--tp {tp} must divide moe_experts ({cfg.moe_experts})"
-        )
+
+
+def _load_model_file(args: argparse.Namespace):
+    """``--model-config``: the configuration from the file, seeded
+    weights from the family's own ``init_params``, one device. What
+    only the flagship block implements is refused by name."""
+    from .modelcfg import load_model_file
+
+    for flag, on in (
+        ("--checkpoint-dir", args.checkpoint_dir),
+        ("--int8", args.int8), ("--kv-int8", args.kv_int8),
+        ("--lora-dir", args.lora_dir), ("--window", args.window),
+        ("--draft-layers", args.draft_layers),
+        ("--tp", (getattr(args, "tp", 1) or 1) > 1),
+        ("--cp", (getattr(args, "cp", 1) or 1) > 1),
+        ("--weights-from", getattr(args, "weights_from", "")),
+    ):
+        if on:
+            raise SystemExit(
+                f"--model-config does not compose with {flag} yet"
+            )
+    cfg = load_model_file(args.model_config, args.max_len)
+    params = cfg.family.init_params(jax.random.PRNGKey(0), cfg)
+    return cfg, params, _serving_mesh(1, 1)
 
 
 def load_model(args: argparse.Namespace):
@@ -280,6 +304,8 @@ def load_model(args: argparse.Namespace):
     Returns (cfg, params, mesh) — the ONE mesh everything landed on
     (checkpoint restore, shard, LoRA merge, and the --cp ring must
     share a device set or cross-mesh ops are uncompilable)."""
+    if getattr(args, "model_config", ""):
+        return _load_model_file(args)
     cfg = TransformerConfig(
         vocab_size=args.vocab,
         d_model=args.d_model,
@@ -288,7 +314,6 @@ def load_model(args: argparse.Namespace):
         n_layers=args.n_layers,
         d_ff=derive_d_ff(args.d_model),
         max_seq_len=args.max_len,
-        moe_experts=args.moe_experts,
         window=args.window,
         kv_int8=args.kv_int8,
     )

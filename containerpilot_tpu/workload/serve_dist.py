@@ -1811,10 +1811,6 @@ def main() -> int:
                         "attention). Static config, so lockstep "
                         "dispatch is unchanged; composes with "
                         "--kv-int8 but not --draft-layers")
-    parser.add_argument("--moe-experts", type=int, default=0,
-                        help="switch-MoE experts; must match the "
-                        "checkpoint being served and divide by the "
-                        "model-parallel axis (experts shard over it)")
     parser.add_argument("--int8", action="store_true",
                         help="weight-only int8: ~4x smaller resident "
                         "params on every host (each process quantizes "
@@ -1955,7 +1951,6 @@ def main() -> int:
         n_layers=args.n_layers,
         d_ff=derive_d_ff(args.d_model),
         max_seq_len=args.max_len,
-        moe_experts=args.moe_experts,
         kv_int8=args.kv_int8,
         window=args.window,
     )
@@ -1980,13 +1975,6 @@ def main() -> int:
     if cfg.n_heads % n_model:
         raise SystemExit(
             f"model axis {n_model} must divide n_heads {cfg.n_heads}"
-        )
-    if cfg.moe_experts > 1 and cfg.moe_experts % n_model:
-        # experts shard over the model axis (the ep x tp layout) —
-        # every process must fail here, not mid-rendezvous
-        raise SystemExit(
-            f"model axis {n_model} must divide moe_experts "
-            f"({cfg.moe_experts})"
         )
     mesh = make_mesh(
         jax.devices(),
@@ -2074,7 +2062,6 @@ def main() -> int:
                     if args.prefix_cache > 0 else None
                 ),
                 "prefill_chunk": args.prefill_chunk or None,
-                "moe_experts": cfg.moe_experts,
                 "int8": args.int8,
                 "lora": (
                     {"rank": args.lora_rank}

@@ -28,7 +28,7 @@ from typing import Any, List, Optional, Tuple
 
 # import-light by design (no jax): just the fingerprint/codec helpers
 from ..kvtier import digest as kvdigest
-from ..kvtier.spill import tree_nbytes
+from ..kvtier.spill import latent_nbytes, tree_nbytes
 from ..telemetry.goodput import EnginePhases
 
 #: shorter matches aren't worth a device call. Tied to the digest's
@@ -161,6 +161,7 @@ class PrefixCache:
 
     def store(self, key: Tuple[int, ...], cache: Any) -> None:
         self.phases.store_bytes += tree_nbytes(cache)
+        self.phases.latent_store_bytes += latent_nbytes(cache)
         evicted: List[Tuple[Tuple[int, ...], Any]] = []
         with self._lock:
             self._cache[key] = cache

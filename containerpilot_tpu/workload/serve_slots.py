@@ -365,6 +365,13 @@ class SlotEngine:
             "tokens_out": self.tokens_out,
         }
 
+    def expert_stats(self) -> Optional[dict]:
+        """What the step program's expert layers routed in the decode
+        rounds fetched so far (``/v1/model`` ``experts``); None for a
+        model without routed experts."""
+        describe = getattr(self.program, "expert_stats", None)
+        return describe() if describe is not None else None
+
     def round_times_ms(self) -> List[float]:
         """Wall time of recent decode-only rounds (ms): dispatch +
         token fetch + host bookkeeping, admission rounds excluded.

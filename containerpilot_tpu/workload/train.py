@@ -65,11 +65,6 @@ def main() -> int:
                         "over sequence chunks of N instead of "
                         "materializing [batch, seq, vocab] logits "
                         "(0 = whole-logits loss)")
-    parser.add_argument("--moe-experts", type=int, default=0,
-                        help="switch-MoE experts (0 = dense MLP)")
-    parser.add_argument("--moe-capacity", type=float, default=0.0,
-                        help="capacity factor for bounded expert compute "
-                        "during training (0 = drop-free routing)")
     parser.add_argument("--vocab", type=int, default=1024)
     parser.add_argument("--data-dir", default="",
                         help="token shards (shard_*.npy; workload/data.py)"
@@ -157,8 +152,6 @@ def main() -> int:
         n_layers=args.n_layers,
         d_ff=derive_d_ff(args.d_model),
         max_seq_len=args.seq_len,
-        moe_experts=args.moe_experts,
-        moe_train_capacity=args.moe_capacity,
         window=args.window,
         loss_chunk=args.loss_chunk,
     )

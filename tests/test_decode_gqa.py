@@ -36,7 +36,7 @@ from containerpilot_tpu.models.slots import (
 from containerpilot_tpu.models.transformer import (
     TransformerConfig,
     _attn_out,
-    _ffn,
+    _mlp,
     _qkv,
     init_params,
 )
@@ -99,7 +99,7 @@ def _plain_decode_chunk(params, cache, tokens, cfg):
             jnp.concatenate([cached_v, v], axis=1), valid,
         ).astype(cfg.dtype)
         x = _attn_out(x, attn, lp, cfg)
-        x, _aux = _ffn(x, lp, cfg)
+        x = _mlp(x, lp, cfg)
     return _logits(params, x, cfg)
 
 

@@ -193,7 +193,8 @@ def test_goodput_body_keeps_every_old_key_and_gains_engine():
     assert set(engine) == {
         "phase_s", "phase_n", "admissions", "queue_wait_s",
         "dispatches_fused", "dispatches_single", "store_bytes",
-        "spill_bytes", "readmit_bytes",
+        "spill_bytes", "readmit_bytes", "latent_store_bytes",
+        "latent_spill_bytes", "latent_readmit_bytes",
     }
     assert engine["phase_n"]["engine.dispatch"] == 1
 

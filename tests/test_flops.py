@@ -10,10 +10,9 @@ from containerpilot_tpu.workload.flops import (
 )
 
 
-def _cfg(window=0, moe_experts=0):
+def _cfg(window=0):
     return types.SimpleNamespace(
         n_layers=4, d_model=256, d_ff=1024, window=window,
-        moe_experts=moe_experts,
     )
 
 

@@ -22,7 +22,7 @@ def test_pipeline_parallel_forward_parity():
     from jax.sharding import Mesh
 
     from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
+        pipeline_forward,
         pipeline_loss_fn,
     )
 
@@ -36,13 +36,12 @@ def test_pipeline_parallel_forward_parity():
         jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
     )
     ref = forward(params, tokens, cfg)
-    out, aux = pipeline_forward_with_aux(
+    out = pipeline_forward(
         params, tokens, cfg, mesh, n_microbatches=4
     )
     np.testing.assert_allclose(
         np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
     )
-    assert float(aux) == 0.0  # dense model: no MoE aux
 
     # training path: grads flow through ppermute/fori_loop
     grads = jax.grad(
@@ -59,7 +58,7 @@ def test_pipeline_validates_inputs():
     from jax.sharding import Mesh
 
     from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
+        pipeline_forward,
     )
 
     cfg = TransformerConfig(
@@ -70,14 +69,14 @@ def test_pipeline_validates_inputs():
     mesh = Mesh(_np.asarray(jax.devices()[:4]), ("pipe",))
     tokens = jnp.zeros((8, 8), jnp.int32)
     with pytest.raises(ValueError, match="not divisible by 4 stages"):
-        pipeline_forward_with_aux(params, tokens, cfg, mesh)
+        pipeline_forward(params, tokens, cfg, mesh)
     cfg2 = TransformerConfig(
         vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
         max_seq_len=32,
     )
     params2 = init_params(jax.random.PRNGKey(0), cfg2)
     with pytest.raises(ValueError, match="microbatches"):
-        pipeline_forward_with_aux(
+        pipeline_forward(
             params2, jnp.zeros((6, 8), jnp.int32), cfg2, mesh,
             n_microbatches=4,
         )
@@ -90,7 +89,7 @@ def test_pipeline_composes_with_data_parallelism():
     from jax.sharding import Mesh
 
     from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
+        pipeline_forward,
     )
 
     cfg = TransformerConfig(
@@ -105,13 +104,13 @@ def test_pipeline_composes_with_data_parallelism():
         jax.random.PRNGKey(1), (8, 12), 0, cfg.vocab_size, jnp.int32
     )
     ref = forward(params, tokens, cfg)
-    out, _aux = pipeline_forward_with_aux(
+    out = pipeline_forward(
         params, tokens, cfg, mesh, n_microbatches=4
     )
     np.testing.assert_allclose(
         np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
     )
-    # grads flow through the data-sharded specs and the aux pmean
+    # grads flow through the data-sharded specs
     from containerpilot_tpu.parallel.pipeline import pipeline_loss_fn
 
     grads = jax.grad(
@@ -121,7 +120,7 @@ def test_pipeline_composes_with_data_parallelism():
     assert all(bool(jnp.isfinite(g).all()) for g in flat)
     # microbatch size must divide the data axis
     with pytest.raises(ValueError, match="data axis"):
-        pipeline_forward_with_aux(
+        pipeline_forward(
             params, tokens[:4], cfg, mesh, n_microbatches=4
         )
 
@@ -135,7 +134,7 @@ def test_pipeline_composes_with_tensor_parallelism():
         make_pipeline_train_step,
     )
     from containerpilot_tpu.parallel.pipeline import (
-        pipeline_forward_with_aux,
+        pipeline_forward,
         pipeline_sharding_rules,
     )
 
@@ -157,8 +156,8 @@ def test_pipeline_composes_with_tensor_parallelism():
     ref = forward(params, tokens, cfg)
     # auto-axis shard_map must run under jit (the eager impl path does
     # not support auto axes) — which is the only real usage anyway
-    out, _aux = jax.jit(
-        lambda p, t: pipeline_forward_with_aux(p, t, cfg, mesh, 4)
+    out = jax.jit(
+        lambda p, t: pipeline_forward(p, t, cfg, mesh, 4)
     )(params, tokens)
     np.testing.assert_allclose(
         np.asarray(ref), np.asarray(out), rtol=2e-4, atol=2e-4
@@ -172,28 +171,3 @@ def test_pipeline_composes_with_tensor_parallelism():
     state, loss = step(state, batch)
     assert bool(jnp.isfinite(loss))
     assert int(state.step) == 1
-
-
-def test_pipeline_composes_with_expert_parallelism():
-    """pp x ep x dp: switch-MoE experts shard over the auto model axis
-    inside each pipeline stage."""
-    from containerpilot_tpu.parallel import (
-        init_train_state as _init,
-        make_pipeline_train_step,
-    )
-    from containerpilot_tpu.parallel.pipeline import pipeline_sharding_rules
-
-    cfg = TransformerConfig(
-        vocab_size=128, d_model=64, n_heads=2, n_layers=4, d_ff=128,
-        max_seq_len=32, moe_experts=2, dtype=jnp.float32,
-    )
-    mesh = make_mesh(jax.devices()[:8], plan=MeshPlan(2, 2, pipe=2))
-    rules = pipeline_sharding_rules(cfg, mesh)
-    assert tuple(rules["layers"]["moe_w_in"]) == ("pipe", "model", None, None)
-    state = _init(jax.random.PRNGKey(0), cfg, mesh, rules=rules)
-    step = make_pipeline_train_step(cfg, mesh, n_microbatches=4)
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(2), (8, 33), 0, cfg.vocab_size, jnp.int32
-    )
-    state, loss = step(state, tokens)
-    assert bool(jnp.isfinite(loss))

@@ -976,10 +976,9 @@ def test_pod_restores_checkpoint_in_lockstep(tmp_path):
             fh.close()
 
 
-def test_pod_serves_moe_int8_lora(tmp_path):
+def test_pod_serves_int8_lora_window(tmp_path):
     """The load-time model knobs compose on the pod in ONE boot:
-    ``--moe-experts`` (experts shard over the model axis, all-to-alls
-    in lockstep), ``--lora-dir`` (adapter restored through orbax's
+    ``--lora-dir`` (adapter restored through orbax's
     global barriers and merged before quantization), ``--int8``
     (weight-only; every process quantizes its shards identically),
     and ``--window`` (sliding-window attention: the pod's slot pool
@@ -1002,8 +1001,7 @@ def test_pod_serves_moe_int8_lora(tmp_path):
 
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_heads=2, n_layers=1,
-        d_ff=derive_d_ff(32), max_seq_len=48, moe_experts=2,
-        window=8,
+        d_ff=derive_d_ff(32), max_seq_len=48, window=8,
     )
     one_dev = make_mesh(jax.devices()[:1], plan=MeshPlan(1, 1))
 
@@ -1023,7 +1021,7 @@ def test_pod_serves_moe_int8_lora(tmp_path):
 
     model_flags = [
         "--max-len", "48", "--d-model", "32", "--n-layers", "1",
-        "--n-heads", "2", "--vocab", "64", "--moe-experts", "2",
+        "--n-heads", "2", "--vocab", "64",
         "--int8", "--lora-dir", str(lora_dir), "--lora-rank", "4",
         "--window", "8",
     ]
@@ -1065,7 +1063,7 @@ def test_pod_serves_moe_int8_lora(tmp_path):
             f"{base_url}/v1/model", timeout=30
         ) as resp:
             info = json.loads(resp.read().decode())
-        assert info["moe_experts"] == 2 and info["int8"] is True
+        assert info["int8"] is True
         assert info["lora"] == {"rank": 4}
         assert info["window"] == 8
 

@@ -261,3 +261,78 @@ def test_decode_chunk_keeps_the_cache_as_stored(chip):
             f"{name}: a float32 copy of the cache, {dims}"
         )
     assert any(elem == "bf16" for _, elem, _ in found), "no cache found"
+
+
+def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
+    """The slot engine's chunk program of the benchmark's A.X-K1
+    configuration at its real size (benchmark/configs/ax-k1-serve.json:
+    published widths, 12 held experts of 192, six layers, 64 slots x
+    3,072 positions), compiled for the v5e: weights, pool and
+    temporaries fit the chip, and outside its fused computations the
+    program produces nothing as large as one layer's held experts or
+    one dense matrix. A scan over stacked per-layer leaves did (the
+    compiler copied each layer's 1.06 GB of experts out of the stack on
+    every step, PERF.md PR 27), which is why the family's layers are
+    separate leaves, unrolled."""
+    from containerpilot_tpu.models.slots import (
+        _jitted_chunk,
+        init_slot_state,
+        slot_cache,
+    )
+    from containerpilot_tpu.workload.modelcfg import load_model_file
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    slots, length = 64, 3072
+    cfg = load_model_file(
+        os.path.join(root, "benchmark", "configs", "ax-k1-serve.json"),
+        length)
+    shapes = jax.eval_shape(
+        lambda: (
+            cfg.family.init_params(None, cfg),
+            slot_cache(cfg, slots, length),
+            init_slot_state(cfg, slots),
+        )
+    )
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+    compiled = _jitted_chunk(cfg, slots, 8).lower(*shapes).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 8.3e9 < memory.argument_size_in_bytes < 10.5e9
+    assert held < 0.8 * HBM_BYTES
+    # computations that a fusion calls live inside it: what they
+    # produce never reaches memory
+    text = compiled.as_text()
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    fused = {
+        called for lines in bodies.values() for line in lines
+        if " fusion(" in line
+        for called in re.findall(r"calls=%?([\w.\-]+)", line)
+    }
+    one_expert_matrix = cfg.d_model * cfg.moe_d_ff
+    for name, lines in bodies.items():
+        if name in fused:
+            continue
+        for line in lines:
+            found = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* "
+                r"(copy|fusion|dynamic-slice|transpose)\(", line)
+            if not found or "scatter" in line:
+                continue  # the latents' in-place write is a scatter fusion
+            size = math.prod(int(n) for n in found.group(2).split(","))
+            assert size < 4 * one_expert_matrix, (
+                f"{found.group(1)}: {found.group(3)} of bf16"
+                f"[{found.group(2)}] outside a fusion"
+            )

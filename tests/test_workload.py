@@ -1522,72 +1522,6 @@ def test_decode_bench_plumbing():
     assert adm["admission_speedup_x"] > 0
 
 
-def test_moe_forward_and_training():
-    """Switch-MoE model: finite forward, aux loss present, loss drops
-    under training, expert weights actually expert-parallel."""
-    cfg = TransformerConfig(
-        vocab_size=128, d_model=64, n_heads=4, n_layers=2, d_ff=128,
-        max_seq_len=64, moe_experts=4,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    assert "moe_w_in" in params["layers"] and "w_gate" not in params["layers"]
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size, jnp.int32
-    )
-    from containerpilot_tpu.models.transformer import forward_with_aux
-
-    logits, aux = forward_with_aux(params, tokens, cfg)
-    assert logits.shape == (2, 16, cfg.vocab_size)
-    assert bool(jnp.isfinite(logits).all())
-    assert float(aux) > 0.0  # load-balance loss is live
-
-    mesh = make_mesh(jax.devices()[:8])
-    state = init_train_state(jax.random.PRNGKey(0), cfg, mesh,
-                             learning_rate=1e-2)
-    step = make_train_step(cfg, mesh, learning_rate=1e-2)
-    batch = jax.random.randint(
-        jax.random.PRNGKey(2), (4, 33), 0, cfg.vocab_size, jnp.int32
-    )
-    first = None
-    for _ in range(6):
-        state, loss = step(state, batch)
-        if first is None:
-            first = float(loss)
-    assert float(loss) < first, (first, float(loss))
-    # expert axis sharded over the 4-way model axis (expert parallelism)
-    spec = state.params["layers"]["moe_w_in"].sharding.spec
-    assert spec[1] == "model", spec
-
-
-def test_moe_decode_parity():
-    """Incremental decode equals full forward for the MoE model too."""
-    from containerpilot_tpu.models.decode import decode_step, prefill
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
-        max_seq_len=32, moe_experts=2, dtype=jnp.float32,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    # drop-free routing means parity must hold for EVERY prompt, not
-    # just a lucky seed — sweep several
-    for seed in (1, 7, 23):
-        tokens = jax.random.randint(
-            jax.random.PRNGKey(seed), (1, 8), 0, cfg.vocab_size, jnp.int32
-        )
-        full = forward(params, tokens, cfg)
-        logits, cache = prefill(params, tokens[:, :4], cfg, max_len=16)
-        np.testing.assert_allclose(
-            np.asarray(logits), np.asarray(full[:, 3]), rtol=2e-4, atol=2e-4,
-            err_msg=f"seed {seed} prefill",
-        )
-        for i in range(4, 8):
-            logits, cache = decode_step(params, cache, tokens[:, i], cfg)
-            np.testing.assert_allclose(
-                np.asarray(logits), np.asarray(full[:, i]), rtol=2e-4,
-                atol=2e-4, err_msg=f"seed {seed} position {i}",
-            )
-
-
 def test_distributed_initialize_from_catalog_single_process(tmp_path):
     """The catalog rendezvous path: process 0 registers the coordinator
     and initializes; (multi-process needs multiple hosts, so we drive
@@ -1989,112 +1923,6 @@ def test_int8_fused_decode_matches_dense_dequant():
                 np.asarray(logits), np.asarray(quant_fwd[:, i]),
                 rtol=2e-3, atol=2e-3, err_msg=f"position {i}",
             )
-
-
-def test_int8_moe_quantization():
-    """MoE expert weights quantize too."""
-    from containerpilot_tpu.models.quantized import quantize_model_params
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
-        max_seq_len=32, moe_experts=2, dtype=jnp.float32,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    pq = quantize_model_params(params)
-    assert "moe_w_in_q" in pq["layers"]
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (1, 8), 0, 64, jnp.int32
-    )
-    full = forward(params, tokens, cfg)
-    quant = forward(pq, tokens, cfg)
-    rel = float(jnp.max(jnp.abs(full - quant)) / jnp.max(jnp.abs(full)))
-    assert rel < 0.08, rel
-
-
-def test_moe_capacity_training_mode():
-    """Capacity-bounded MoE: trains (loss drops), matches drop-free
-    routing when capacity is ample, diverges under pressure, and is
-    refused by the decode path."""
-    import dataclasses
-
-    from containerpilot_tpu.models.decode import prefill
-
-    base = TransformerConfig(
-        vocab_size=128, d_model=64, n_heads=4, n_layers=2, d_ff=128,
-        max_seq_len=64, moe_experts=2, dtype=jnp.float32,
-    )
-    params = init_params(jax.random.PRNGKey(0), base)
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (2, 16), 0, base.vocab_size, jnp.int32
-    )
-    free = forward(params, tokens, base)
-    ample = dataclasses.replace(base, moe_train_capacity=8.0)
-    np.testing.assert_allclose(
-        np.asarray(free), np.asarray(forward(params, tokens, ample)),
-        rtol=1e-4, atol=1e-4,
-    )  # capacity >= every queue: identical routing
-    tight = dataclasses.replace(base, moe_train_capacity=0.5)
-    squeezed = forward(params, tokens, tight)
-    assert float(jnp.max(jnp.abs(free - squeezed))) > 1e-3  # drops happened
-
-    # trains end-to-end
-    mesh = make_mesh(jax.devices()[:8], plan=MeshPlan(data=4, model=2))
-    state = init_train_state(jax.random.PRNGKey(0), tight, mesh,
-                             learning_rate=1e-2)
-    step = make_train_step(tight, mesh, learning_rate=1e-2)
-    batch = jax.random.randint(
-        jax.random.PRNGKey(2), (4, 33), 0, base.vocab_size, jnp.int32
-    )
-    first = None
-    for _ in range(5):
-        state, loss = step(state, batch)
-        if first is None:
-            first = float(loss)
-    assert float(loss) < first
-
-    with pytest.raises(ValueError, match="moe_train_capacity"):
-        prefill(params, tokens[:, :8], tight, max_len=32)
-
-
-def test_moe_capacity_requires_experts():
-    with pytest.raises(ValueError, match="requires moe_experts"):
-        TransformerConfig(moe_train_capacity=1.0)
-
-
-def test_moe_sparse_dispatch_flops_scale_with_capacity():
-    """The capacity layer's compiled FLOPs must scale with the capacity
-    bound, not with E x s — evidence that dispatch is sparse
-    gather/scatter, not the dense one-hot einsums."""
-    from containerpilot_tpu.models.moe import moe_layer, moe_layer_capacity
-
-    b, s, d, f, E = 2, 256, 64, 128, 8
-    rng = jax.random.PRNGKey(0)
-    x = jax.random.normal(rng, (b, s, d), jnp.float32)
-    router = jax.random.normal(jax.random.PRNGKey(1), (d, E), jnp.float32)
-    w_in = jax.random.normal(jax.random.PRNGKey(2), (E, d, f), jnp.float32)
-    w_out = jax.random.normal(jax.random.PRNGKey(3), (E, f, d), jnp.float32)
-
-    def flops(fn, *args):
-        compiled = jax.jit(fn).lower(*args).compile()
-        (analysis,) = [compiled.cost_analysis()] if isinstance(
-            compiled.cost_analysis(), dict
-        ) else [compiled.cost_analysis()[0]]
-        return analysis["flops"]
-
-    dense = flops(
-        lambda x: moe_layer(x, router, w_in, w_out)[0], x
-    )
-    tight = flops(
-        lambda x: moe_layer_capacity(x, router, w_in, w_out, 1.0)[0], x
-    )
-    double = flops(
-        lambda x: moe_layer_capacity(x, router, w_in, w_out, 2.0)[0], x
-    )
-    # drop-free dense dispatch does E x s expert work; capacity 1.0
-    # does ~s total expert work — at E=8 that's a large gap
-    assert tight < dense / 3, (tight, dense)
-    # expert compute tracks the capacity bound
-    assert tight < double, (tight, double)
 
 
 def test_fsdp_shards_params_and_matches_plain_step():
@@ -2892,35 +2720,6 @@ def test_tensor_parallel_generate_parity():
         )
 
 
-def test_tensor_parallel_moe_generate_parity():
-    """Expert-parallel serving: an MoE model's experts shard over the
-    model axis with the rest of the TP rules, and sharded decode
-    byte-matches single-device — the ep x tp serving composition."""
-    import numpy as np
-
-    from containerpilot_tpu.models.decode import generate
-    from containerpilot_tpu.models.transformer import init_params
-    from containerpilot_tpu.parallel import (
-        MeshPlan,
-        make_mesh,
-        shard_params,
-    )
-
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=64, n_heads=4, n_layers=2, d_ff=128,
-        max_seq_len=32, dtype=jnp.float32, moe_experts=4,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = make_mesh(jax.devices()[:4], plan=MeshPlan(data=1, model=4))
-    sharded = shard_params(params, mesh, cfg)
-    prompt = jax.random.randint(
-        jax.random.PRNGKey(11), (2, 5), 0, cfg.vocab_size, jnp.int32
-    )
-    single = generate(params, prompt, cfg, max_new_tokens=6, max_len=32)
-    ep = generate(sharded, prompt, cfg, max_new_tokens=6, max_len=32)
-    np.testing.assert_array_equal(np.asarray(single), np.asarray(ep))
-
-
 def test_inference_server_reports_mesh(run):
     """/v1/model surfaces the device mesh TP-sharded params live on,
     and serving works end-to-end on sharded params."""
@@ -3009,23 +2808,6 @@ def test_chunked_loss_matches_whole_logits(seq):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=1e-6
         )
-
-
-def test_chunked_loss_matches_with_moe_aux():
-    import dataclasses
-
-    base = TransformerConfig(
-        vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
-        max_seq_len=32, dtype=jnp.float32, moe_experts=2,
-    )
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(2), (2, 13), 0, base.vocab_size, jnp.int32
-    )
-    params = init_params(jax.random.PRNGKey(0), base)
-    whole = float(jax.jit(lambda p: loss_fn(p, tokens, base))(params))
-    chunked = dataclasses.replace(base, loss_chunk=4)
-    got = float(jax.jit(lambda p: loss_fn(p, tokens, chunked))(params))
-    np.testing.assert_allclose(got, whole, rtol=1e-6)
 
 
 def test_generate_stop_sequences(run):

@@ -1,5 +1,5 @@
 """The workload's entry points end to end: the driver's
-`__graft_entry__` (single-chip forward + twelve sharded layouts on the
+`__graft_entry__` (single-chip forward + eleven sharded layouts on the
 virtual 8-device CPU mesh) and the train/serve CLIs as real
 subprocesses under the supervisor (continuous deployment, graceful
 preemption). A file of its own: these are the longest workload tests,
