@@ -5,8 +5,10 @@ TPU-first inference for the flagship transformer:
 - static shapes throughout — the cache is allocated at ``max_len`` and
   masked by position, so XLA compiles exactly two programs (prefill and
   decode step) regardless of generation length;
-- the decode loop is a ``lax.scan`` over steps, the layer stack a
-  ``lax.scan`` over stacked layer params (same as training);
+- the decode loop is a ``lax.scan`` over steps; prefill's layer stack
+  is a ``lax.scan`` over stacked layer params (same as training),
+  a decode step's is unrolled so that each layer writes and reads its
+  own part of the cache where it lies (``decode_chunk``);
 - greedy or temperature sampling.
 
 Numerics are identical to the full forward: the parity test asserts
@@ -61,6 +63,11 @@ def init_cache(
     With ``cfg.kv_int8`` k/v store as int8 with a per-(token, head)
     scale over the head_dim axis — KV memory halves vs bf16,
     composing with both levers above.
+
+    ``pos`` is one number: every row stands at the same position. The
+    slot pool (models/slots.py ``slot_cache``) keeps the same leaves
+    one per layer, with ``pos`` one number per row, and
+    ``decode_chunk`` takes either.
 
     A configuration of another family (``cfg.family``, e.g.
     models/mla_moe.py's latent cache) makes its own tree; ``prefill``
@@ -284,7 +291,8 @@ def _grouped_attention(
 ) -> jax.Array:
     """Masked attention of q [b, m, n_heads, d] over keys/values
     [b, length, kv_heads, d] AS THE CACHE STORES THEM; valid is
-    [m, length]. Returns [b, m, n_heads, d] in ``dtype``.
+    [m, length], or [b or 1, m, length] where rows stand at different
+    positions. Returns [b, m, n_heads, d] in ``dtype``.
 
     The n_heads axis is viewed as [kv_heads, group], so query head
     h = kv * group + g reads kv head h // group (``repeat_kv``'s
@@ -306,7 +314,9 @@ def _grouped_attention(
         "bqhgd,bkhd->bhgqk", qg, keys,
         preferred_element_type=jnp.float32,
     ) * d ** -0.5  # [b, kv_heads, group, m, length]
-    scores = jnp.where(valid[None, None, None], scores, NEG_INF)
+    scores = jnp.where(
+        jnp.expand_dims(valid, (-3, -4)), scores, NEG_INF
+    )
     weights = jax.nn.softmax(scores, axis=-1)
     attn = jnp.einsum(
         "bhgqk,bkhd->bqhgd", weights, values,
@@ -323,10 +333,26 @@ def decode_chunk(
     step of speculative decoding (m = speculate+1), and the general
     multi-token incremental step.
 
-    ``tokens[:, i]`` sits at position ``pos + i``; ``logits[:, i]``
-    predicts position ``pos + i + 1``. Within the chunk attention is
-    causal; everything already cached is visible. Numerics match m
-    sequential ``decode_step`` calls (and therefore the full forward).
+    ``tokens[:, i]`` sits at position ``pos + i`` of its row;
+    ``logits[:, i]`` predicts position ``pos + i + 1``. ``pos`` is one
+    number, or one per row ([batch], the slot pool: a row past the end
+    of its cache writes nothing, a ring row wraps). Within the chunk
+    attention is causal; everything already cached is visible.
+    Numerics match m sequential ``decode_step`` calls (and therefore
+    the full forward).
+
+    The layers are unrolled, and each writes the chunk's keys and
+    values INTO its leaf of the cache and reads the leaf where it lies.
+    A ``lax.scan`` that took the cache as ``xs`` and gave it back as
+    ``ys`` copied every layer's slice out of the stack and a whole new
+    stack back on every step; with the stack in the scan's carry the
+    write is in place, but the slice at a traced layer index is still
+    copied out, because the contraction changes the keys' layout inside
+    its fusion and the chip's compiler will not fuse the slice on top
+    of that (PERF.md, PR 28; tests/test_tpu_compile.py pins the
+    program). A cache stacked over layers (``init_cache``) is split
+    into its layers on the way in and stacked again on the way out;
+    the slot pool's leaves, one per layer, pass straight through.
 
     Attention is ``_grouped_attention``: the query heads are viewed as
     [kv_heads, group] and contracted with the layer's keys and values
@@ -343,7 +369,15 @@ def decode_chunk(
         return family.decode_chunk(params, cache, tokens, cfg)
     pos = cache["pos"]
     b, m = tokens.shape
-    length = cache["k"].shape[2]
+    # a cache is stacked over layers (init_cache, prefill); the slot
+    # pool holds one leaf per layer (models/slots.py), and each layer
+    # below writes and reads its own leaf where it lies
+    stacked = not isinstance(cache["k"], (list, tuple))
+    kv = {
+        name: list(leaves) for name, leaves in cache.items()
+        if name != "pos"
+    }
+    length = kv["k"][0].shape[1]
     ring = cfg.window > 0
     if ring and m > length:
         raise ValueError(
@@ -351,8 +385,9 @@ def decode_chunk(
             "window ring; chunk at most `window` tokens"
         )
     x = embed_lookup(params, tokens, cfg.dtype)  # [b, m, d]
+    # one position for every row, or one per row: [1 or b, m]
     q_idx = jnp.arange(m)
-    q_pos = pos + q_idx
+    q_pos = pos.reshape(-1, 1) + q_idx
     if ring:
         # ring slot j holds the newest position p < pos with
         # p % length == j (negative = never written); a query at
@@ -360,20 +395,25 @@ def decode_chunk(
         # own causal prefix — the chunk k/v are CONCATENATED after the
         # ring so in-chunk keys are never read from slots they are
         # about to overwrite
-        j = jnp.arange(length)
-        ring_pos = pos - 1 - jnp.mod(pos - 1 - j, length)
+        before = pos.reshape(-1, 1) - 1
+        ring_pos = before - jnp.mod(before - jnp.arange(length), length)
         ring_ok = (
-            (ring_pos[None, :] >= 0)
-            & (ring_pos[None, :] > q_pos[:, None] - cfg.window)
+            (ring_pos[:, None, :] >= 0)
+            & (ring_pos[:, None, :] > q_pos[:, :, None] - cfg.window)
         )
         chunk_ok = (
             (q_idx[None, :] <= q_idx[:, None])
             & (q_idx[:, None] - q_idx[None, :] < cfg.window)
         )
-        valid = jnp.concatenate([ring_ok, chunk_ok], axis=1)
+        valid = jnp.concatenate(
+            [ring_ok, jnp.broadcast_to(chunk_ok, (len(ring_ok), m, m))],
+            axis=2,
+        )
+        write_idx = jnp.mod(q_pos, length)
     else:
-        key_pos = jnp.arange(length)
-        valid = key_pos[None, :] <= q_pos[:, None]  # [m, length]
+        valid = jnp.arange(length) <= q_pos[:, :, None]  # [1 or b, m, length]
+        write_idx = q_pos
+    rows = jnp.arange(b)[:, None]
     # int8-quantized dense models run their projections through the
     # fused dequant pallas GEMM: decode is weight-streaming bound, so
     # reading int8 instead of dequantized bf16 halves the HBM traffic
@@ -381,99 +421,66 @@ def decode_chunk(
 
     kv_int8 = cfg.kv_int8
 
-    def body(carry, inputs):
-        x = carry
-        layer_params, kv_layer = inputs
-        k_cache, v_cache = kv_layer["k"], kv_layer["v"]
-        if fused:
-            q, k, v = fused_qkv(x, layer_params, cfg, offset=pos)
-        else:
-            layer_params = maybe_dequant_layer(layer_params, cfg.dtype)
-            q, k, v = _qkv(x, layer_params, cfg, offset=pos)
-        with jax.named_scope("attn"), jax.named_scope("attn.kv_write"):
-            if kv_int8:
-                k_q, k_s = _kv_quant(k)
-                v_q, v_s = _kv_quant(v)
-            if ring:
-                # the chunk's own k/v also read through the quantization
-                # roundtrip, so chunked decode matches sequential steps
-                # (which read their keys back from the quantized ring)
-                cached_k = (
-                    _kv_dequant(k_cache, kv_layer["k_scale"], cfg.dtype)
-                    if kv_int8 else k_cache
-                )
-                cached_v = (
-                    _kv_dequant(v_cache, kv_layer["v_scale"], cfg.dtype)
-                    if kv_int8 else v_cache
-                )
-                chunk_k = (
-                    _kv_dequant(k_q, k_s, cfg.dtype) if kv_int8 else k
-                )
-                chunk_v = (
-                    _kv_dequant(v_q, v_s, cfg.dtype) if kv_int8 else v
-                )
-                keys = jnp.concatenate([cached_k, chunk_k], axis=1)
-                values = jnp.concatenate([cached_v, chunk_v], axis=1)
-                slots = jnp.mod(pos + q_idx, length)
-                new_kv = dict(kv_layer)
-                if kv_int8:
-                    new_kv["k"] = k_cache.at[:, slots].set(k_q)
-                    new_kv["v"] = v_cache.at[:, slots].set(v_q)
-                    new_kv["k_scale"] = (
-                        kv_layer["k_scale"].at[:, slots].set(k_s)
-                    )
-                    new_kv["v_scale"] = (
-                        kv_layer["v_scale"].at[:, slots].set(v_s)
-                    )
-                else:
-                    new_kv["k"] = k_cache.at[:, slots].set(k)
-                    new_kv["v"] = v_cache.at[:, slots].set(v)
-            else:
-                new_kv = dict(kv_layer)
-                if kv_int8:
-                    new_kv["k"] = lax.dynamic_update_slice(
-                        k_cache, k_q, (0, pos, 0, 0)
-                    )
-                    new_kv["v"] = lax.dynamic_update_slice(
-                        v_cache, v_q, (0, pos, 0, 0)
-                    )
-                    new_kv["k_scale"] = lax.dynamic_update_slice(
-                        kv_layer["k_scale"], k_s, (0, pos, 0)
-                    )
-                    new_kv["v_scale"] = lax.dynamic_update_slice(
-                        kv_layer["v_scale"], v_s, (0, pos, 0)
-                    )
-                    keys = _kv_dequant(
-                        new_kv["k"], new_kv["k_scale"], cfg.dtype
-                    )
-                    values = _kv_dequant(
-                        new_kv["v"], new_kv["v_scale"], cfg.dtype
-                    )
-                else:
-                    new_kv["k"] = lax.dynamic_update_slice(
-                        k_cache, k, (0, pos, 0, 0)
-                    )
-                    new_kv["v"] = lax.dynamic_update_slice(
-                        v_cache, v, (0, pos, 0, 0)
-                    )
-                    keys, values = new_kv["k"], new_kv["v"]
-        with jax.named_scope("attn"), jax.named_scope("attn.scores"):
-            attn = _grouped_attention(q, keys, values, valid, cfg.dtype)
-        if fused:
-            x = fused_attn_out(x, attn, layer_params, cfg)
-            x = fused_mlp(x, layer_params, cfg)
-        else:
-            x = _attn_out(x, attn, layer_params, cfg)
-            x = _mlp(x, layer_params, cfg)
-        return x, new_kv
+    def write(leaf, new):
+        """``new`` [b, m, ...] into one layer's leaf [b, length, ...],
+        in place. One position for every row is one block; positions
+        per row scatter, a ring wraps, and a row past the end of a
+        linear cache (a dead slot of the pool) writes nothing."""
+        if pos.ndim == 0 and not ring:
+            return lax.dynamic_update_slice(
+                leaf, new, (0, pos) + (0,) * (new.ndim - 2)
+            )
+        return leaf.at[rows, write_idx].set(new, mode="drop")
 
-    kv_in = {
-        name: cache[name] for name in cache if name != "pos"
-    }
+    def read(layer, name):
+        """The layer's keys or values as attention contracts them."""
+        if not kv_int8:
+            return kv[name][layer]
+        return _kv_dequant(
+            kv[name][layer], kv[name + "_scale"][layer], cfg.dtype
+        )
+
     with jax.named_scope("layers"):
-        x, new_kv = lax.scan(body, x, (params["layers"], kv_in))
+        for layer in range(cfg.n_layers):
+            layer_params = jax.tree.map(
+                lambda w: w[layer], params["layers"]
+            )
+            if fused:
+                q, k, v = fused_qkv(x, layer_params, cfg, offset=pos)
+            else:
+                layer_params = maybe_dequant_layer(layer_params, cfg.dtype)
+                q, k, v = _qkv(x, layer_params, cfg, offset=pos)
+            with jax.named_scope("attn"), jax.named_scope("attn.kv_write"):
+                new = {"k": k, "v": v}
+                if kv_int8:
+                    new["k"], new["k_scale"] = _kv_quant(k)
+                    new["v"], new["v_scale"] = _kv_quant(v)
+                if ring:
+                    # the chunk's own k/v also read through the
+                    # quantization roundtrip, so chunked decode matches
+                    # sequential steps (which read their keys back from
+                    # the quantized ring)
+                    if kv_int8:
+                        k = _kv_dequant(new["k"], new["k_scale"], cfg.dtype)
+                        v = _kv_dequant(new["v"], new["v_scale"], cfg.dtype)
+                    keys = jnp.concatenate([read(layer, "k"), k], axis=1)
+                    values = jnp.concatenate([read(layer, "v"), v], axis=1)
+                for name in kv:
+                    kv[name][layer] = write(kv[name][layer], new[name])
+                if not ring:
+                    keys, values = read(layer, "k"), read(layer, "v")
+            with jax.named_scope("attn"), jax.named_scope("attn.scores"):
+                attn = _grouped_attention(q, keys, values, valid, cfg.dtype)
+            if fused:
+                x = fused_attn_out(x, attn, layer_params, cfg)
+                x = fused_mlp(x, layer_params, cfg)
+            else:
+                x = _attn_out(x, attn, layer_params, cfg)
+                x = _mlp(x, layer_params, cfg)
     logits = _logits(params, x, cfg)  # [b, m, vocab]
-    return logits, {**new_kv, "pos": pos + m}
+    if stacked:
+        kv = {name: jnp.stack(leaves) for name, leaves in kv.items()}
+    return logits, {**kv, "pos": pos + m}
 
 
 import functools

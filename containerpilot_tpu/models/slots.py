@@ -5,25 +5,37 @@ in-flight batching.
 rows that start WHENEVER — a new request should join the decode loop
 at the next chunk boundary instead of queueing behind the current
 batch's full generation. The XLA-friendly shape for that is a fixed
-pool of S slots: every slot owns one cache row and its own position,
-the decode step is the single-row ``decode_step`` vmapped over the
-slot axis (XLA still batches the matmuls — weights stream from HBM
-once per step for all slots), and admission/harvest happen between
+pool of S slots: the pool is ONE cache of S rows whose ``pos`` is one
+number per row, the decode step is one ``decode_chunk`` over it
+(tokens [S, 1]: the matmuls are batched, weights stream from HBM once
+per step for all slots), and admission/harvest happen between
 fixed-size chunks on the host. All shapes are static: one compiled
 chunk program per (config, S, chunk), plus one fused-window program
 per (config, S, chunk, K) that loops K chunk-rounds on device with
 early exit (``decode_slots_window``) so the host pays one dispatch
 per K rounds — no recompiles as traffic changes.
 
-Every step reads the whole pool ([S, layers, 1, length, kv_heads,
-head_dim], live positions or not), so what a step costs is what
-attention does with those bytes: ``decode_chunk`` contracts the
-query heads, grouped as [kv_heads, group], with each layer's keys and
-values as the pool stores them (bf16, kv heads only, float32
-accumulation). Nothing the size of the pool is repeated to n_heads or
-written out in float32 on the way: tests/test_decode_gqa.py pins that
-on the chunk program's lowered text, tests/test_tpu_compile.py on the
-program the v5e's compiler makes of it.
+The pool's form (``slot_cache``) is private to this module and
+``decode_chunk``: ``init_cache``'s leaves, one per LAYER instead of
+stacked over layers (k/v: a list of [S, length, kv_heads, head_dim];
+with ``kv_int8`` the scales beside them), and ``pos`` [S]. A step
+writes each row's new keys and values into the layer's leaf at the
+row's own position and attention reads the leaf where it lies; the
+programs donate the pool, so nothing the size of a layer's cache is
+copied, sliced out or stacked back in a step (tests/
+test_tpu_compile.py pins that on the program the v5e's compiler
+makes; PERF.md, PR 28, has what the stacked form cost). Rows keep
+``prefill``'s format (stacked, one row): ``insert_row`` writes each
+layer's part at the slot, and rows never leave the pool.
+
+Every step reads the whole pool (live positions or not), so what a
+step costs is what attention does with those bytes: ``decode_chunk``
+contracts the query heads, grouped as [kv_heads, group], with each
+layer's keys and values as the pool stores them (bf16, kv heads only,
+float32 accumulation). Nothing the size of the pool is repeated to
+n_heads or written out in float32 on the way: tests/test_decode_gqa.py
+pins that on the chunk program's lowered text, tests/
+test_tpu_compile.py on the program the v5e's compiler makes of it.
 
 Sampling reproduces ``generate``'s schedule exactly: per-row key =
 ``jax.random.split(PRNGKey(seed), 1)[0]``, sample i uses
@@ -32,27 +44,26 @@ so a request's output is byte-identical to a solo ``generate`` call
 no matter what it shared the pool with (tested).
 
 Dead slots (finished rows not yet reused) keep decoding garbage —
-static shapes — but their writes are harmless: a linear cache's
-dynamic_update_slice clamps at the boundary, a sliding-window config's
-ring cache (decode.py) wraps within its own row, and either way the
-row is wholesale overwritten by the next admission (``insert_row``
-replaces the full row INCLUDING its position, so a reused slot holds
-nothing of its previous occupant — what makes windows compose with
-the pool). Emitted tokens are masked to pad after eos, same as
-``generate``.
+static shapes — but their writes are harmless: a linear cache's row
+that has run past its end writes NOTHING (its scatter index is out of
+range and dropped, not clamped onto the last position), a
+sliding-window config's ring cache (decode.py) wraps within its own
+row, and either way the row is wholesale overwritten by the next
+admission (``insert_row`` replaces the full row INCLUDING its
+position, so a reused slot holds nothing of its previous occupant —
+what makes windows compose with the pool). Emitted tokens are masked
+to pad after eos, same as ``generate``.
 
 The programs here are a contract over (configuration, step function,
-cache tree), not over one model. A configuration of another family
-(``cfg.family``: models/mla_moe.py) keeps a BATCHED cache, whose batch
-axis is the slot axis and whose ``pos`` is one number per row: the
-family brings its own ``slot_cache`` and ``insert_row``, and its step is one
-``decode_chunk`` over the whole pool instead of a vmap of one-row
-steps, so a layer that routes tokens to experts sees every row of the
-step at once. A pool may carry a ``stats`` leaf (what the step
-function counted, e.g. routed experts): the chunk and window programs
-zero it on entry and return its value as a fourth output, beside the
-tokens and fetched with them; a pool without one returns ``None``
-there, and its compiled program is what it was.
+cache tree), not over one model: the step is ``decode_chunk`` over the
+whole pool for every configuration. A configuration of another family
+(``cfg.family``: models/mla_moe.py) brings its own ``slot_cache`` and
+``insert_row`` for what differs in kind (its latents' leaves, its
+counters). A pool may carry a ``stats`` leaf (what the step function
+counted, e.g. routed experts): the chunk and window programs zero it
+on entry and return its value as a fourth output, beside the tokens
+and fetched with them; a pool without one returns ``None`` there, and
+its compiled program is what it was.
 """
 from __future__ import annotations
 
@@ -70,7 +81,6 @@ from .decode import (
     apply_token_penalties,
     count_token,
     decode_chunk,
-    decode_step,
     init_cache,
     mask_eos_before_min,
     sample_logits,
@@ -218,19 +228,20 @@ def retire_slot(state: dict, slot: int, out_sharding=None) -> dict:
 
 
 def slot_cache(cfg: TransformerConfig, slots: int, max_len: int) -> Cache:
-    """A pool of ``slots`` single-row caches, stacked on a leading
-    slot axis (k/v: [S, layers, 1, length, kv_heads, head_dim];
-    pos: [S]); a batched family's own pool (see the module's note)."""
+    """The pool: ONE cache of ``slots`` rows, ``init_cache``'s leaves
+    held one per layer (k/v: lists of [slots, length, kv_heads,
+    head_dim]) and ``pos`` one number per row, [S]; a family's own
+    pool where it brings one (see the module's note)."""
     family = getattr(cfg, "family", None)
     if family is not None:
         return family.slot_cache(cfg, slots, max_len)
-    row = init_cache(cfg, 1, max_len)
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(
-            x[None], (slots,) + x.shape
-        ).copy() if x.ndim else jnp.zeros((slots,), x.dtype),
-        row,
-    )
+    stacked = jax.eval_shape(lambda: init_cache(cfg, slots, max_len))
+    pool = {
+        name: [jnp.zeros(s.shape[1:], s.dtype) for _ in range(s.shape[0])]
+        for name, s in stacked.items() if name != "pos"
+    }
+    pool["pos"] = jnp.zeros((slots,), jnp.int32)
+    return pool
 
 
 @functools.lru_cache(maxsize=8)
@@ -246,17 +257,19 @@ def _jitted_insert(cfg: TransformerConfig, out_sharding=None):
     bit-identical by construction)."""
 
     def insert(pool: Cache, row: Cache, slot: jax.Array) -> Cache:
-        def put(big, small):
-            if big.ndim == 1:  # pos: [S] <- scalar
-                return lax.dynamic_update_slice(
-                    big, small[None].astype(big.dtype), (slot,)
-                )
-            return lax.dynamic_update_slice(
-                big, small[None].astype(big.dtype),
-                (slot,) + (0,) * small.ndim,
-            )
-
-        return jax.tree.map(put, pool, row)
+        new = {"pos": lax.dynamic_update_slice(
+            pool["pos"], row["pos"].reshape(1).astype(jnp.int32), (slot,)
+        )}
+        for name, layers in row.items():
+            if name != "pos":  # row[name]: [layers, 1, length, ...]
+                new[name] = [
+                    lax.dynamic_update_slice(
+                        big, small.astype(big.dtype),
+                        (slot,) + (0,) * (big.ndim - 1),
+                    )
+                    for big, small in zip(pool[name], layers)
+                ]
+        return new
 
     family = getattr(cfg, "family", None)
     if family is not None:
@@ -283,37 +296,25 @@ def _zero_stats(pool: Cache) -> Cache:
     return {**pool, "stats": jnp.zeros_like(pool["stats"])}
 
 
-def _vstep(cfg: TransformerConfig):
-    """The single-row decode step vmapped over the slot axis — the
-    shared device kernel of the chunk AND fused-window programs. A
-    batched family's pool IS a cache of S rows: one ``decode_chunk``
-    over it, tokens [S, 1] -> logits [S, 1, V]."""
-    if getattr(cfg, "family", None) is not None:
-        return lambda params, pool, token: decode_chunk(
-            params, pool, token, cfg
-        )
-    return jax.vmap(
-        lambda params, cache, token: decode_step(
-            params, cache, token, cfg
-        ),
-        in_axes=(None, 0, 0),
-    )
-
-
-def _round_step_body(params, state, vstep):
+def _round_step_body(params, state, cfg):
     """The ONE per-token step body (scan shape) shared by the chunk
     program and the fused K-round window program: both trace exactly
     this function, so a fused window is the same computation as K
     sequential chunk rounds token for token — the byte-parity
     contract between them holds by construction, not by numerical
-    luck. Carry: (pool, last_token, done, step_idx, counts)."""
+    luck. The step is one ``decode_chunk`` over the pool, a cache of
+    S rows each at its own ``pos``: tokens [S, 1] -> logits [S, 1, V],
+    every layer's keys and values written and read where they lie.
+    Carry: (pool, last_token, done, step_idx, counts)."""
     row_keys = state["keys"]
     pad_id = state["pad_id"]
     eos_id = state["eos_id"]
 
     def body(carry, _):
         pool, tok, done, idx, counts = carry
-        logits, pool = vstep(params, pool, tok[:, None])  # [S,1,V]
+        logits, pool = decode_chunk(  # [S, 1, V]
+            params, pool, tok[:, None], cfg
+        )
         with jax.named_scope("sample"):
             keys = jax.vmap(jax.random.fold_in)(row_keys, idx)
             masked = apply_token_penalties(
@@ -354,13 +355,12 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
     torn-step-index hazard cannot recur); the untouched knob leaves
     alias straight through the donation.
     """
-    vstep = _vstep(cfg)
 
     def run(params, pool, state):
         pool = _zero_stats(pool)
-        body = _round_step_body(params, state, vstep)
+        body = _round_step_body(params, state, cfg)
         # ``steps`` names what the step loop itself does around its
-        # body (on the chip: copies of the whole pool, PERF.md s.5)
+        # body (nothing the size of the pool: PERF.md s.5)
         with jax.named_scope("steps"):
             (pool, last, done, idx, counts), toks = lax.scan(
                 body,
@@ -399,11 +399,10 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
     rounds not executed leave their token columns at the slot's
     pad_id, and the state advances by exactly rounds_run chunks.
     Pool and state are donated like the chunk program's."""
-    vstep = _vstep(cfg)
 
     def run(params, pool, state, budget):
         pool = _zero_stats(pool)
-        body = _round_step_body(params, state, vstep)
+        body = _round_step_body(params, state, cfg)
         pad = state["pad_id"].astype(jnp.int32)
         out0 = jnp.broadcast_to(
             pad[:, None], (slots, rounds * chunk)

@@ -200,14 +200,18 @@ def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
 def _rope(x: jax.Array, theta: float, offset: Any = 0) -> jax.Array:
     """Rotary position embedding over the last (head_dim) axis.
     x: [batch, seq, heads, head_dim]; ``offset`` shifts the absolute
-    positions (needed by incremental decoding — models/decode.py)."""
+    positions (needed by incremental decoding — models/decode.py): one
+    number, or one per row ([batch]: the slot pool's rows each stand
+    at their own position)."""
     b, s, h, hd = x.shape
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    positions = offset + jnp.arange(s, dtype=jnp.float32)
-    angles = positions[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
+    positions = jnp.asarray(offset)[..., None] + jnp.arange(
+        s, dtype=jnp.float32
+    )  # [s] or [batch, s]
+    angles = positions[..., None] * freqs
+    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 

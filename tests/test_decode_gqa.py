@@ -217,9 +217,9 @@ def _cache_like(text, length, head_dim):
 def test_chunk_program_holds_no_repeated_or_widened_cache(kv_int8):
     """The slot engine's chunk program for a bf16 GQA configuration:
     no tensor with the cache's length and head_dim that is as large
-    as the cache repeated to n_heads, and ONE float32 tensor as large
-    as a layer's cache: the values as the float32 softmax weights'
-    operand, which jax writes as a convert before the contraction and
+    as the cache repeated to n_heads, and ONE float32 tensor a layer
+    (the layers are unrolled) as large as the layer's cache: the
+    values as the float32 softmax weights' operand, which jax writes as a convert before the contraction and
     the chip's compiler folds into it (tests/test_tpu_compile.py pins
     that no float32 copy is left in the optimised program); the keys
     are never widened. int8 KV dequantizes through float32 by design,
@@ -254,7 +254,9 @@ def test_chunk_program_holds_no_repeated_or_widened_cache(kv_int8):
             if math.prod(int(n) for n in dims.split("x") if n) >= layer_cache
             and str(length) in dims.split("x")
         ]
-        assert len(widened) == 1, f"float32 copies of the cache: {widened}"
+        assert len(widened) == cfg.n_layers, (
+            f"float32 copies of the cache: {widened}"
+        )
 
 
 def test_chunk_program_check_sees_the_old_form():

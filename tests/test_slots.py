@@ -134,7 +134,8 @@ def test_chunk_failure_recovers_pool(params):
             calls["n"] += 1
             if calls["n"] == 1:
                 # donate like the real call would, then fail
-                args[1]["k"].delete()
+                for leaf in args[1]["k"]:  # one leaf per layer
+                    leaf.delete()
                 raise RuntimeError("injected chunk failure")
             return original(*args, **kw)
 
@@ -796,3 +797,71 @@ def test_penalties_match_generate(params, engine):
         frequency_penalty=50.0,
     )
     assert len(set(got)) == len(got)
+
+
+@pytest.mark.parametrize("m", [1, 3])
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "kv_int8"])
+@pytest.mark.parametrize("ring", [False, True], ids=["linear", "ring"])
+def test_pool_rows_decode_at_their_own_positions(ring, kv_int8, m):
+    """One decode_chunk over a pool whose rows stand at DIFFERENT
+    positions gives, row for row, the logits and the cache row of
+    one-row decode_chunk calls. A linear row that has reached its end
+    (a dead slot) writes nothing and disturbs no other row; a ring row
+    past its length wraps like its one-row call. And insert_row into
+    one slot leaves every other slot's leaves bit-equal."""
+    from containerpilot_tpu.models.decode import decode_chunk, prefill
+    from containerpilot_tpu.models.slots import insert_row, slot_cache
+
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
+        d_ff=64, max_seq_len=32, window=8 if ring else 0, kv_int8=kv_int8,
+    )
+    max_len = 16
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    lengths = [3, 7, 12, max_len]  # the last fills a linear cache
+    prompts = [
+        jax.random.randint(
+            jax.random.PRNGKey(10 + i), (1, n), 0, cfg.vocab_size, jnp.int32)
+        for i, n in enumerate(lengths)
+    ]
+    rows = [prefill(params, p, cfg, max_len)[1] for p in prompts]
+
+    def host(pool):
+        return jax.tree.map(np.array, pool)
+
+    pool = slot_cache(cfg, len(rows), max_len)
+    for slot, row in enumerate(rows):
+        before = host(pool)
+        pool = insert_row(pool, row, slot, cfg)
+        after = host(pool)
+        others = [s for s in range(len(rows)) if s != slot]
+        for was, now in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+            np.testing.assert_array_equal(was[others], now[others])
+    assert list(np.asarray(pool["pos"])) == lengths
+    filled = host(pool)
+
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(2), (len(rows), m), 0, cfg.vocab_size, jnp.int32)
+    step = jax.jit(lambda p, c, t: decode_chunk(p, c, t, cfg))
+    logits, new = step(params, pool, tokens)
+    assert list(np.asarray(new["pos"])) == [n + m for n in lengths]
+    names = [name for name in new if name != "pos"]
+    for slot, row in enumerate(rows):
+        if not ring and lengths[slot] + m > max_len:
+            # past the end of a linear cache: nothing written
+            for name in names:
+                for layer in range(cfg.n_layers):
+                    np.testing.assert_array_equal(
+                        np.asarray(new[name][layer][slot]),
+                        filled[name][layer][slot])
+            continue
+        want_logits, want = step(params, row, tokens[slot:slot + 1])
+        np.testing.assert_allclose(
+            np.asarray(logits[slot], np.float32),
+            np.asarray(want_logits[0], np.float32), rtol=1e-5, atol=1e-5)
+        assert int(want["pos"]) == lengths[slot] + m
+        for name in names:
+            for layer in range(cfg.n_layers):
+                np.testing.assert_array_equal(
+                    np.asarray(new[name][layer][slot]),
+                    np.asarray(want[name][layer, 0]))

@@ -204,6 +204,82 @@ def test_tp_serving_prefill_needs_shard_map(topology, monkeypatch):
     assert KERNEL in compiled.as_text()
 
 
+def _outside_fusions(text):
+    """(computation, line) of every instruction of an optimised
+    program that is not inside a fused computation: what a fusion
+    calls lives inside it and never reaches memory. Also the lines of
+    every computation by name."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    fused = {
+        called for lines in bodies.values() for line in lines
+        if " fusion(" in line
+        for called in re.findall(r"calls=%?([\w.\-]+)", line)
+    }
+    outside = [
+        (name, line) for name, lines in bodies.items()
+        if name not in fused for line in lines
+    ]
+    return outside, bodies
+
+
+def _pool_sized_outside_fusions(text, elements):
+    """The instructions outside fused computations that produce a
+    tensor of at least ``elements`` elements, as (name, opcode, type)
+    — but for what moves nothing (parameters, tuples and their
+    elements, bitcasts, the loops themselves), what only prepares
+    WEIGHTS once a dispatch (every operand a ``params`` argument: the
+    bf16 copy of a float32 matrix, a matrix's change of layout), and
+    the IN-PLACE update: a fusion around a scatter or a
+    dynamic-update-slice whose result has its first operand's type,
+    that operand being the loop's own carried buffer."""
+    outside, bodies = _outside_fusions(text)
+    types = {}
+    for lines in bodies.values():
+        for line in lines:
+            made = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])", line)
+            if made:
+                types[made.group(1)] = made.group(2)
+    found = []
+    for _computation, line in outside:
+        made = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)", line)
+        if not made:
+            continue
+        name, kind, opcode, operands = made.groups()
+        if opcode in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                      "while", "conditional", "call"):
+            continue
+        sizes = [
+            math.prod(int(n) for n in dims.split(",") if n)
+            for dims in re.findall(r"\w+\[([\d,]*)\]", kind)
+        ]
+        if not sizes or max(sizes) < elements:
+            continue
+        operands = re.findall(r"%([\w.\-]+)", operands)
+        if operands and all(o.startswith("params_") for o in operands):
+            continue
+        if opcode == "fusion" and operands:
+            called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+            body = "\n".join(bodies[called])
+            first = operands[0]
+            if (re.search(r" (scatter|dynamic-update-slice)\(", body)
+                    and first.startswith(("get-tuple-element", "pool_"))
+                    and types.get(first) == kind.split("{")[0]):
+                continue
+        found.append((name, opcode, kind.split("{")[0]))
+    return found
+
+
 def test_decode_chunk_keeps_the_cache_as_stored(chip):
     """The slot engine's chunk program at the benchmark's attention
     shapes (Mistral-7B: 32 heads over 8 kv heads of 128, 16 slots x
@@ -263,6 +339,111 @@ def test_decode_chunk_keeps_the_cache_as_stored(chip):
     assert any(elem == "bf16" for _, elem, _ in found), "no cache found"
 
 
+def _cell_decode_shapes(chip, slot_cache):
+    """The benchmark's mistral-7b-serve cache shapes (16 slots x 4096
+    positions x 8 kv heads of 128, bf16, 4 layers; vocabulary and FFN
+    cut, they do not touch the cache) as arguments on the described
+    chip: (cfg, slots, (params, pool, state), one layer's keys)."""
+    from containerpilot_tpu.models.slots import init_slot_state
+    from containerpilot_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+
+    slots, length = 16, 4096
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=4096, n_heads=32, n_kv_heads=8,
+        n_layers=4, d_ff=1024, max_seq_len=length,
+    )
+    shapes = jax.eval_shape(
+        lambda: (
+            init_params(jax.random.PRNGKey(0), cfg),
+            slot_cache(cfg, slots, length),
+            init_slot_state(cfg, slots),
+        )
+    )
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+    return cfg, slots, shapes, slots * length * cfg.kv_heads * cfg.head_dim
+
+
+@pytest.mark.parametrize("program", ["chunk", "window"])
+def test_decode_step_moves_nothing_the_size_of_a_layers_cache(chip, program):
+    """The slot engine's chunk program and its fused window of 4
+    rounds at the benchmark's cache shapes, compiled for the v5e:
+    outside its fused computations the program produces no tensor with
+    as many elements as ONE layer's keys, but for the in-place update
+    of the pool's own leaf, and its temporaries stay under one layer's
+    keys and values beside the bf16 copy of the (float32) weights.
+    With the pool stacked on a leading slot axis and the cache as the
+    layer scan's xs/ys, six such operations (two transposes of the
+    whole pool, a slice out and a stack back for each of keys and
+    values) were 10.7 of a decode step's 17.0 ms on the chip, and the
+    temporaries held two copies of the pool (PERF.md, PR 28)."""
+    from containerpilot_tpu.models.slots import (
+        _jitted_chunk,
+        _jitted_window,
+        slot_cache,
+    )
+
+    cfg, slots, shapes, layer_keys = _cell_decode_shapes(chip, slot_cache)
+    if program == "chunk":
+        lowered = _jitted_chunk(cfg, slots, 8).lower(*shapes)
+    else:
+        budget = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+        lowered = _jitted_window(cfg, slots, 8, 4).lower(*shapes, budget)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_run")
+    assert _pool_sized_outside_fusions(text, layer_keys) == []
+    # the pool is updated where it lies: every byte of it is aliased
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * cfg.n_layers * layer_keys * 2
+    assert memory.alias_size_in_bytes >= pool_bytes
+    weights_bf16 = 2 * sum(
+        math.prod(x.shape) for x in jax.tree.leaves(shapes[0])
+    )
+    assert memory.temp_size_in_bytes < weights_bf16 + 2 * layer_keys * 2
+
+
+def test_decode_step_check_sees_the_old_form(chip):
+    """The same check on a step built the old way here (one-row caches
+    stacked on a leading slot axis, ``decode_step`` vmapped over it
+    inside the scan of steps) finds the copies, so the test above
+    cannot pass by looking past them."""
+    from containerpilot_tpu.models.decode import decode_step, init_cache
+
+    def old_pool(cfg, slots, length):
+        row = init_cache(cfg, 1, length)
+        return jax.tree.map(
+            lambda x: jnp.zeros((slots,) + x.shape, x.dtype), row)
+
+    cfg, slots, (params, pool, state), layer_keys = _cell_decode_shapes(
+        chip, old_pool)
+    step = jax.vmap(
+        lambda params, cache, token: decode_step(params, cache, token, cfg),
+        in_axes=(None, 0, 0),
+    )
+
+    def run(params, pool, last):
+        def body(carry, _):
+            pool, tok = carry
+            logits, pool = step(params, pool, tok[:, None])
+            return (pool, jnp.argmax(logits[:, 0], -1).astype(tok.dtype)), tok
+        return jax.lax.scan(body, (pool, last), None, length=8)
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, pool, state["last"]).compile()
+    found = _pool_sized_outside_fusions(compiled.as_text(), layer_keys)
+    assert len(found) >= 2, found
+    weights_bf16 = 2 * sum(
+        math.prod(x.shape) for x in jax.tree.leaves(params))
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            > weights_bf16 + 2 * layer_keys * 2)
+
+
 def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
     """The slot engine's chunk program of the benchmark's A.X-K1
     configuration at its real size (benchmark/configs/ax-k1-serve.json:
@@ -303,36 +484,16 @@ def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
     assert 8.3e9 < memory.argument_size_in_bytes < 10.5e9
     assert held < 0.8 * HBM_BYTES
-    # computations that a fusion calls live inside it: what they
-    # produce never reaches memory
-    text = compiled.as_text()
-    bodies, name = {}, None
-    for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
-        if head:
-            name = head.group(1)
-            bodies[name] = []
-        elif line.startswith("}"):
-            name = None
-        elif name is not None:
-            bodies[name].append(line)
-    fused = {
-        called for lines in bodies.values() for line in lines
-        if " fusion(" in line
-        for called in re.findall(r"calls=%?([\w.\-]+)", line)
-    }
+    outside, _bodies = _outside_fusions(compiled.as_text())
     one_expert_matrix = cfg.d_model * cfg.moe_d_ff
-    for name, lines in bodies.items():
-        if name in fused:
-            continue
-        for line in lines:
-            found = re.match(
-                r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* "
-                r"(copy|fusion|dynamic-slice|transpose)\(", line)
-            if not found or "scatter" in line:
-                continue  # the latents' in-place write is a scatter fusion
-            size = math.prod(int(n) for n in found.group(2).split(","))
-            assert size < 4 * one_expert_matrix, (
-                f"{found.group(1)}: {found.group(3)} of bf16"
-                f"[{found.group(2)}] outside a fusion"
-            )
+    for _computation, line in outside:
+        found = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* "
+            r"(copy|fusion|dynamic-slice|transpose)\(", line)
+        if not found or "scatter" in line:
+            continue  # the latents' in-place write is a scatter fusion
+        size = math.prod(int(n) for n in found.group(2).split(","))
+        assert size < 4 * one_expert_matrix, (
+            f"{found.group(1)}: {found.group(3)} of bf16"
+            f"[{found.group(2)}] outside a fusion"
+        )
