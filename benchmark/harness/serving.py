@@ -165,8 +165,11 @@ def _sample(records: List[Dict[str, Any]], seed: int, count: int) -> List[Dict[s
 
 def window(ctx: Dict[str, Any], seed: int, traced: bool) -> Dict[str, Any]:
     """One measured window of the cell's mix from ``seed`` against the
-    server that is up: counters before and after, and every request's
-    record."""
+    server that is up: every request's record, and the counters at
+    the window's two edges: before the load starts and when it
+    returns (a closed loop: the close; an open loop: the end of its
+    drain), BEFORE the profiler's export is waited for, so the export
+    is in no counter."""
     traffic = ctx["traffic"]
     vocab = int(ctx["config"]["vocab_size"])
     window_s = float(ctx["seconds"])
@@ -190,9 +193,10 @@ def window(ctx: Dict[str, Any], seed: int, traced: bool) -> Dict[str, Any]:
             float(traffic.get("drain_s", 10.0))))
     else:
         raise RunFailed(f"traffic kind {kind!r} is not a serving kind")
+    after = _snapshot(ctx)
     procs.join_trace(tracer, marks)
     return {"seed": seed, "records": records, "before": before,
-            "after": _snapshot(ctx), "marks": marks}
+            "after": after, "marks": marks}
 
 
 def judge(ctx: Dict[str, Any], records: List[Dict[str, Any]],

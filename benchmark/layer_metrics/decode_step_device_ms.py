@@ -1,6 +1,7 @@
 """Layer: model + kernels (models/, ops/). Device time of the slot
-engine's decode programs per token-step, from the device trace (see
-decode_programs.py). Source: device trace."""
+engine's decode programs (``jit_run``) per token-step, a step being one
+execution of their ``sample`` scope (decode_programs.py). Source:
+device trace."""
 import os
 
 from benchmark.harness.spec import load_module
@@ -12,6 +13,6 @@ def read(run):
     trace = run.get("trace")
     if not trace:
         return None
-    steps = programs.token_steps(trace)
+    steps = programs.token_steps(run)
     seconds = programs.decode_seconds(trace)
     return seconds * 1e3 / steps if steps and seconds else None

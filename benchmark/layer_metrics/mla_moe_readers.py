@@ -7,14 +7,8 @@ From the trace (``scoped``): inside the slot engine's decode programs
 (``jit_run``, decode_programs.py), self seconds by the INNERMOST
 dotted scope of an operation's path (``attn.scores``, ``mlp.experts``,
 ...; trace_scopes.py finds the paths), and the token-steps in the
-traced window. The family's layers are unrolled, so no loop runs once
-per layer stack (and on the v5e a ``while`` carries no path at all);
-what runs exactly once per token-step is each sparse layer's ROUTER:
-every operation under scope ``mlp.router`` executes once a step, and
-there is no loop inside it. The token-steps are, summed over the decode
-programs, the executions of each program's most-executed router
-operation (a step cut by the window's edge is counted where any of its
-router operations started inside).
+traced window, counted as for every family by the executions of the
+``sample`` scope (decode_programs.py ``token_steps``).
 
 From the counters (``experts``): what ``/v1/model`` ``experts`` moved
 by between the window's two snapshots, summed over replicas.
@@ -23,7 +17,6 @@ A program without these scopes or counters (any before PR 27, any
 other family) gives None, and so do the readers."""
 from __future__ import annotations
 
-import bisect
 import json
 import os
 import re
@@ -36,7 +29,6 @@ scopes = load_module(os.path.join(HERE, "trace_scopes.py"))
 programs = load_module(os.path.join(HERE, "decode_programs.py"))
 
 _CHILD = re.compile(r"(?:attn|mlp)\.[A-Za-z_]\w*")
-_NAME = re.compile(r"[A-Za-z_][\w.]*")
 
 
 def _self_ns(ops: List[List[Any]], lo: int, hi: int):
@@ -65,43 +57,24 @@ def scoped(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     if "_mla_moe_scoped" in run:
         return run["_mla_moe_scoped"]
     found = None
-    trace = run.get("trace")
-    path = trace and scopes.newest_xplane(os.path.join(
-        scopes.root_of_checkout(), ".benchmark_work", run["cell"], "trace"))
-    if path:
-        doc = scopes.read_xplane(path)
+    doc = scopes.xplane_of(run)
+    steps = programs.token_steps(run)
+    if doc is not None and steps:
         lo, hi = scopes.window_of(run)
         children: Dict[str, float] = {}
-        steps = 0.0
         for plane in doc["planes"]:
-            spans = sorted((m[1], m[1] + m[2], m[0]) for m in plane["modules"]
-                           if m[0].startswith(programs.DECODE_MODULE))
-            begins = [span[0] for span in spans]
-
-            def program_of(start: int) -> str:
-                i = bisect.bisect_right(begins, start) - 1
-                return spans[i][2] if i >= 0 and start < spans[i][1] else ""
-
+            program_of = programs.program_finder(plane["modules"])
             inside = [op for op in plane["ops"] if program_of(op[1])]
-            for _name, path_, self_ns in _self_ns(inside, lo, hi):
-                child = _CHILD.findall(path_ or "")
+            for _name, path, self_ns in _self_ns(inside, lo, hi):
+                child = _CHILD.findall(path or "")
                 if child:
                     children[child[-1]] = children.get(child[-1], 0.0) + self_ns / 1e9
-            # per decode program (the chunk and the fused-window program
-            # are two): executions of each router operation
-            routed: Dict[str, Dict[str, int]] = {}
-            for name, start, _dur, path_ in inside:
-                if lo <= start < hi and "mlp.router" in _NAME.findall(path_ or ""):
-                    counts = routed.setdefault(program_of(start), {})
-                    counts[name] = counts.get(name, 0) + 1
-            steps += sum(max(counts.values()) for counts in routed.values())
-        planes = max(len(doc["planes"]), 1)
-        if steps:
-            found = {
-                "children": {k: v / planes for k, v in children.items()},
-                "steps": steps / planes,
-                "decode_s": programs.decode_seconds(trace),
-            }
+        planes = len(doc["planes"])
+        found = {
+            "children": {k: v / planes for k, v in children.items()},
+            "steps": steps,
+            "decode_s": programs.decode_seconds(run["trace"]),
+        }
     run["_mla_moe_scoped"] = found
     return found
 

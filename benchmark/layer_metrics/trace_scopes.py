@@ -13,10 +13,13 @@ this file reads the ``.xplane.pb`` itself, with ``google.protobuf`` and
 the seven messages of xplane.proto described below; no jax, so it runs
 inside the harness's process.
 
-Which statistic carries the path is found, not assumed: among the
-string statistics of the operation line's events and of their metadata,
-the one whose values most often look like a path (``/`` or ``jit(``).
-On the v5e of PR 24's chip runs that is ``tf_op`` (PERF.md section 3).
+Which statistic carries the path: ``tf_op`` where the operation line's
+events or their metadata carry it with a ``jit(`` in it (the v5e,
+PERF.md section 3); else, among their string statistics, the one whose
+values most often hold ``jit(``. A ``/`` says nothing: the file names
+in ``source`` hold it too, and in PR 28's batch-decode trace they
+outvoted ``tf_op`` 32,270 to 31,535, so every operation read
+``unnamed``.
 
 A scope is matched as a whole name anywhere in the path, also inside
 what transforms wrap around it (``transpose(jvp(layers))``). An
@@ -43,6 +46,8 @@ UNNAMED = "unnamed"
 _NAME = re.compile(r"[A-Za-z_][\w.]*")
 
 DEVICE_PREFIX = "/device:TPU:"
+PATH_STAT = "tf_op"
+PATH_MARK = "jit("
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -130,7 +135,7 @@ def read_xplane(path: str, device_prefix: str = DEVICE_PREFIX) -> Dict[str, Any]
     """Per device plane: the operation events as
     ``[name, start_ns, dur_ns, path]`` and the module events as
     ``[name, start_ns, dur_ns]``; and the statistic the paths were
-    taken from (None where no statistic looks like a path)."""
+    taken from (None where no statistic holds a ``jit(``)."""
     space = _xspace_class()()
     with open(path, "rb") as fh:
         space.ParseFromString(fh.read())
@@ -168,12 +173,13 @@ def read_xplane(path: str, device_prefix: str = DEVICE_PREFIX) -> Dict[str, Any]
                 found = dict(of_metadata.get(event.metadata_id, {}))
                 found.update(strings(event.stats))
                 for stat, value in found.items():
-                    if "/" in value or "jit(" in value:
+                    if PATH_MARK in value:
                         votes[stat] = votes.get(stat, 0) + 1
                 row.append(found)
                 ops.append(row)
         planes.append({"name": plane.name, "ops": ops, "modules": modules})
-    stat = max(votes, key=votes.get) if votes else None
+    stat = PATH_STAT if PATH_STAT in votes else (
+        max(votes, key=votes.get) if votes else None)
     for plane in planes:
         for row in plane["ops"]:
             row[3] = row[3].get(stat, "") if stat else ""
@@ -246,20 +252,35 @@ def artefact_dir(run: Dict[str, Any]) -> str:
     return os.path.join(root_of_checkout(), "chiprun_out", "benchmark", run["cell"])
 
 
+def xplane_of(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+    """``read_xplane`` of the run's trace, read once a run and kept in
+    it (every reader's file loads its own copy of this module; the run
+    is the one thing they share); None where the run has no trace or no
+    device plane."""
+    if "_xplane" not in run:
+        path = run.get("trace") and newest_xplane(os.path.join(
+            root_of_checkout(), ".benchmark_work", run["cell"], "trace"))
+        doc = read_xplane(path) if path else None
+        run["_xplane"] = doc if doc and doc["planes"] else None
+    return run["_xplane"]
+
+
+def path_stat(run: Dict[str, Any]) -> Optional[str]:
+    """The trace statistic the run's paths were taken from, for the
+    traced run's result line; None where there is no device plane or no
+    statistic holds a ``jit(``."""
+    doc = xplane_of(run)
+    return doc["path_stat"] if doc else None
+
+
 def load(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     """{"modules": {module: {"scope": ..., "attn": ...}}, "path_stat"},
     averaged over the device planes; None where the run has no trace.
     What was found is also written beside the run's other artefacts
     (``chiprun_out/benchmark/<cell>/scopes.json``) for PERF.md's
     breakdown."""
-    if not run.get("trace"):
-        return None
-    root = root_of_checkout()
-    path = newest_xplane(os.path.join(root, ".benchmark_work", run["cell"], "trace"))
-    if path is None:
-        return None
-    doc = read_xplane(path)
-    if not doc["planes"]:
+    doc = xplane_of(run)
+    if doc is None:
         return None
     lo, hi = window_of(run)
     merged: Dict[str, Dict[str, Dict[str, float]]] = {}

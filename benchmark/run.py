@@ -33,7 +33,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark.harness import contract, procs, serving, training  # noqa: E402
 from benchmark.harness.procs import RunFailed  # noqa: E402
-from benchmark.harness.spec import Cell, SpecError  # noqa: E402
+from benchmark.harness.spec import Cell, SpecError, load_module  # noqa: E402
 
 CHIP = "tpu"
 ROLES = {"serve": serving.run, "train": training.run}
@@ -64,6 +64,14 @@ def _child(argv: List[str], log: str, env: Dict[str, str], timeout_s: float,
         with open(log, errors="replace") as fh:
             tail = fh.read()[-1500:]
         raise RunFailed(f"{what} exit {rc}: {tail}")
+
+
+def _say_compared(compared: Dict[str, Dict[str, Any]]) -> None:
+    """The last lines of standard error: each number ``correct``
+    compared, beside its limit."""
+    for number, c in compared.items():
+        print(f"compared {number} {json.dumps(c['value'])} limit "
+              f"{json.dumps(c['limit'])}", file=sys.stderr, flush=True)
 
 
 def make_reference(cell: Cell, ctx: Dict[str, Any]):
@@ -237,8 +245,6 @@ def run_cell(args: argparse.Namespace, platform: str = CHIP, control: str = "",
             value = got["e2e"].get(entry["name"])
             if value is not None:
                 metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
-    for line in got["verdict"]["compared"]:
-        say(phase="compared", **line)
     for mode, numbers in got["verdict"].get("controls", {}).items():
         say(phase="control", reference_in=mode, **numbers)
     for other in got["verdict"].get("more_seeds", ()):
@@ -258,6 +264,15 @@ def run_cell(args: argparse.Namespace, platform: str = CHIP, control: str = "",
         result["breakdown"] = {
             "device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"],
         }
+        # which statistic of the trace the scope readers take an
+        # operation's path from
+        result["path_stat"] = load_module(os.path.join(
+            HERE, "layer_metrics", "trace_scopes.py")).path_stat(run)
+    # every number compared, beside its limit: the result's last key
+    compared = {
+        c["number"]: {"value": c["value"], "limit": c.get("limit", c.get("at_least"))}
+        for c in got["verdict"]["compared"]}
+    result["compared"] = compared
     rehearsal = platform != CHIP
     if rehearsal and not result["device"]["memory_peak_bytes"]:
         result["device"]["memory_peak_bytes"] = 1  # the CPU keeps no stats
@@ -276,8 +291,10 @@ def run_cell(args: argparse.Namespace, platform: str = CHIP, control: str = "",
     if rehearsal:
         # a CPU run reports no result: nothing here is a device number
         print("REHEARSAL " + json.dumps(result), flush=True)
+        _say_compared(compared)
         return 3
     print(json.dumps(result), flush=True)
+    _say_compared(compared)
     return 0
 
 

@@ -35,9 +35,9 @@ try:
     last = json.loads(last)
 except ValueError:
     pass
-compared = [json.loads(l) for l in lines if l.startswith(('{"phase": "compared"', '{"phase": "control"', '{"phase": "more-seed"'))]
+controls = [json.loads(l) for l in lines if l.startswith(('{"phase": "control"', '{"phase": "more-seed"'))]
 print(json.dumps({"spec": spec, "rc": int(rc), "wall_s": round(float(t1) - float(t0), 1),
-                  "last": last, "compared": compared}))
+                  "last": last, "controls": controls}))
 PY
   tail -n 1 "$top/summary.jsonl" | cut -c1-1800
 done

@@ -40,6 +40,15 @@ def test_good_lines_pass():
     assert check(traced(), True) == []
 
 
+def test_keys_the_driver_ignores_may_follow():
+    """run.py ends every line in ``compared`` (each number beside its
+    limit) and a traced one also names its ``path_stat``."""
+    line = traced()
+    line["path_stat"] = "tf_op"
+    line["compared"] = {"mean_logit_gap": {"value": 0.0006, "limit": 0.0009}}
+    assert check(line, True) == []
+
+
 def edit(line, path, value=KeyError):
     line = copy.deepcopy(line)
     node = line
@@ -89,3 +98,55 @@ def test_each_malformed_line_is_named(is_traced, path, value, says):
 
 def test_not_an_object():
     assert contract.violations([], E2E, False, "tpu", 1)
+
+
+@pytest.mark.parametrize("name", ["decode_step_roofline.mla-moe", "decode_step_roofline.open",
+                                  "train_mfu"])
+def test_a_share_over_its_peak_is_named_whatever_follows_the_name(name):
+    """A roofline share with a suffix after ``_roofline`` (a family's or
+    an open loop's twin) is held to the ceiling like the plain one."""
+    line = traced()
+    line["metrics"] = {name: {"value": 106.0, "unit": "%"}}
+    bad = contract.violations(line, [{"name": name, "unit": "%"}], True, "tpu", 1)
+    assert any("over-counted" in b for b in bad), bad
+
+
+def _committed_cells():
+    import json
+    import os
+    from benchmark.harness.spec import Cell
+    root = os.path.join(os.path.dirname(__file__), "..", "..")
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        names = [w["name"] for w in json.load(fh)["workloads"]]
+    return [Cell(root, name) for name in names]
+
+
+@pytest.mark.parametrize("cell", _committed_cells(), ids=lambda c: c.name)
+def test_committed_cell_reports_what_its_layer_metrics_move(cell):
+    """Every cell as committed: an end-to-end metric besides
+    ``setup_s``, at least one per-layer metric, a reader file for each,
+    and each per-layer metric names an end-to-end metric this cell
+    reports (so a tail that left a cell's end-to-end list took its
+    ``moves`` with it)."""
+    reported = {m["name"] for m in cell.end_to_end()}
+    assert "setup_s" in reported and len(reported) >= 2
+    layer = cell.per_layer()
+    assert layer
+    for metric in layer:
+        assert metric["moves"] in reported, (metric["name"], metric["moves"])
+        assert callable(cell.reader(metric["name"]))
+
+
+@pytest.mark.parametrize("name,key", [("request_ttft_p95_ms", "ttft_p95_ms"),
+                                      ("request_tpot_p95_ms", "tpot_p95_ms")])
+def test_a_tail_without_a_bound_is_read_from_the_same_number(name, key):
+    """The per-layer tails are the harness's own end-to-end numbers; a
+    window in which a request failed (inf) or nothing was judged gives
+    nothing, never 0."""
+    import math
+    cell = _committed_cells()[0]
+    read = cell.reader(name)
+    assert read({"e2e": {key: 431.25}}) == 431.25
+    assert read({"e2e": {key: math.inf}}) is None
+    assert read({"e2e": {key: None}}) is None
+    assert read({"e2e": {}}) is None

@@ -3,12 +3,12 @@ phase, device time by named scope): on synthetic inputs, on a trace
 recorded on the chip (recorded/engine_scopes_trace.json.gz, see
 ``recorded_run``), and in a CPU rehearsal of a toy cell that lists
 them."""
-import gzip
 import json
 import os
 
 import pytest
 
+import recorded_runs
 import toyroot
 from benchmark.harness.spec import load_module
 from test_rehearsal import rehearsal, run_cell
@@ -46,6 +46,23 @@ def test_counter_readers_take_deltas_over_the_window():
     assert module("engine_fused_dispatch_share").read(run) == pytest.approx(0.0)
     run = counter_run(goodput(0, 0, 0, 0, 2, 4), goodput(0, 0, 0, 0, 5, 5))
     assert module("engine_fused_dispatch_share").read(run) == pytest.approx(75.0)
+
+
+def test_admit_share_is_the_phases_seconds_over_the_seconds_between_snapshots():
+    """13.2 s of admissions between two snapshots 30 s apart, on one
+    replica and as the mean of two: the engine's worker thread admits
+    44 % of the window (PERF.md section 5, batch-decode)."""
+    run = counter_run(goodput(1.0, 0.25, 10, 2.0, 5, 20),
+                      goodput(14.2, 9.0, 186, 14.0, 5, 80))
+    run["before"]["at"], run["after"]["at"] = 100.0, 130.0
+    assert module("engine_admit_share").read(run) == pytest.approx(44.0)
+    for side in ("before", "after"):
+        run[side]["goodput"] = run[side]["goodput"] * 2
+    assert module("engine_admit_share").read(run) == pytest.approx(44.0)
+    quiet = counter_run(goodput(1.0, 0.5, 7, 3.0, 2, 9), goodput(1.0, 0.5, 7, 3.0, 2, 9))
+    quiet["before"]["at"], quiet["after"]["at"] = 0.0, 30.0
+    assert module("engine_admit_share").read(quiet) == 0.0
+    assert module("engine_admit_share").read({"records": []}) is None
 
 
 @pytest.mark.parametrize("name", [
@@ -210,9 +227,7 @@ def recorded_run(tmp_path_factory):
     operations and the ``slot-engine`` line only), each operation with
     its ``tf_op`` path as a fourth element, the reduction's summary of
     the same cut, and the two ``/v1/goodput`` snapshots of that run."""
-    path = os.path.join(HERE, "recorded", "engine_scopes_trace.json.gz")
-    with gzip.open(path, "rt") as fh:
-        return json.load(fh)
+    return recorded_runs.fixture("engine_scopes_trace.json.gz")
 
 
 def test_recorded_idle_split_adds_up_and_is_mostly_named(recorded_run):

@@ -60,8 +60,9 @@ def _ops():
     """Four token-steps of two sparse layers inside one ``jit_run``:
     per layer a router op, an expert loop (a ``while`` WITHOUT a path,
     as the v5e writes it) holding a dispatch, an experts and a combine
-    op, and two attention ops; plus a prefill program with the same
-    scopes that must not be counted."""
+    op, and two attention ops; then the sampler: two operations once a
+    step and a sort three times a step (a loop inside it); plus a
+    prefill program with the same scopes that must not be counted."""
     ops = []
     for step in range(STEPS):
         t = 100 + step * STEP_NS
@@ -78,20 +79,22 @@ def _ops():
                 ["fusion.c" + tag, t + 360, 50, f"{base}/mlp/while/body/mlp.combine/scatter"],
             ]
             t += 450
+        sample = "jit(run)/steps/while/body/sample"
+        ops += [["fusion.k", t, 5, f"{sample}/jit(_threefry_fold_in)/slice"],
+                ["fusion.g", t + 5, 5, f"{sample}/select_n"]]
+        ops += [["sort.1", t + 10 + 5 * i, 5, f"{sample}/while/body/sort"]
+                for i in range(3)]
     ops.append(["fusion.p", 9000, 500, "jit(fn)/layers/mlp/mlp.experts/dot"])
+    ops.append(["fusion.q", 9500, 10, "jit(fn)/sample/select_n"])
     return ops
 
 
 @pytest.fixture
-def run(monkeypatch):
+def run():
     readers = reader("mla_moe_readers")
     doc = {"planes": [{"name": "/device:TPU:0", "ops": _ops(), "modules": [
         ["jit_run(1)", 0, 100 + STEPS * STEP_NS], ["jit_fn(2)", 8990, 600]]}],
         "path_stat": "tf_op", "path_stat_votes": {}}
-    import benchmark.layer_metrics  # noqa: F401  (namespace for load_module)
-    scopes = readers.scopes
-    monkeypatch.setattr(scopes, "newest_xplane", lambda _dir: "made-up")
-    monkeypatch.setattr(scopes, "read_xplane", lambda _path: doc)
 
     def model(rows, load):
         return {"max_len": 3072, "slot_engine": {"slots": 64}, "experts": {
@@ -102,6 +105,7 @@ def run(monkeypatch):
 
     made = {
         "cell": "made-up.cell", "config": CONFIG, "device_kind": "TPU v5 lite",
+        "_xplane": doc,  # what trace_scopes.xplane_of keeps in a run
         "trace": {"clock": "device events' extent", "first_event_ns": 0,
                   "last_event_ns": 10_000,
                   "modules": {"jit_run(1)": {"seconds": 16e-3 * STEPS},
@@ -119,7 +123,7 @@ def run(monkeypatch):
     return made, readers
 
 
-def test_steps_are_router_executions_and_time_is_by_innermost_scope(run):
+def test_steps_are_sampler_executions_and_time_is_by_innermost_scope(run):
     made, readers = run
     found = readers.scoped(made)
     assert found["steps"] == STEPS
@@ -160,7 +164,7 @@ def test_the_metrics_read_what_their_notes_say(run):
     "expert_load_max_over_mean"])
 def test_a_program_without_the_scopes_or_counters_reads_nothing(name, run):
     made, _readers = run
-    bare = {k: v for k, v in made.items() if k != "trace"}
+    bare = {k: v for k, v in made.items() if k not in ("trace", "_xplane")}
     for side in ("before", "after"):
         bare[side] = {"model": [{"max_len": 4096, "slot_engine": {"slots": 16}}]}
     assert reader(name).read(bare) is None

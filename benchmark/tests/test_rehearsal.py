@@ -70,9 +70,17 @@ def test_cells_added_as_files_run(root, workload, trace):
     ours = digest(root)
     assert all(ours[path] == sha for path, sha in theirs.items()
                if not path.startswith("benchmark/tests/"))
-    result = rehearsal(run_cell(root, workload, 3_000_000_019, trace))
+    proc = run_cell(root, workload, 3_000_000_019, trace)
+    result = rehearsal(proc)
     assert result["correct"] is True and result["failed"] == 0
+    # every number compared beside its limit: the result's last key, and
+    # the last lines of standard error
+    assert list(result)[-1] == "compared" and result["compared"]
+    said = proc.stderr.strip().splitlines()[-len(result["compared"]):]
+    assert [line.split()[1] for line in said] == list(result["compared"])
     assert result["device"]["platform"] == "cpu"
+    if trace and workload.startswith("toy-serve"):
+        assert result["path_stat"] is None  # a CPU trace has no device plane
     if trace:
         assert result["metrics"]["toy_count"]["value"] > 0
         assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]

@@ -76,8 +76,6 @@ def build(root: str, serve_launcher: str = "", train_fault: str = "") -> str:
             metric["workloads"] += train_cells
         elif metric["name"] == "serve_tokens_per_s":
             metric["workloads"] += serve_cells[:1]
-        elif metric["name"] == "ttft_p95_ms":
-            metric["workloads"] += serve_cells[1:]
         else:
             metric["workloads"] += serve_cells
     bench["per_layer"].append({
