@@ -3,7 +3,7 @@
 
 PYTHON ?= python
 
-.PHONY: all build test test-fast test-workload integration fleet-smoke trace-smoke chaos chaos-smoke bench bench-host bench-gateway bench-reuse bench-goodput bench-coldstart bench-disagg bench-migrate lint lint-baseline lint-diff clean image
+.PHONY: all build test test-fast test-workload integration fleet-smoke trace-smoke chaos chaos-smoke lint lint-baseline lint-diff clean image
 
 all: build test
 
@@ -57,65 +57,6 @@ chaos:
 	JAX_PLATFORMS=cpu $(PYTHON) -m containerpilot_tpu.chaos \
 		--suite full --json chaos-report.json
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_chaos.py -q
-
-bench:
-	$(PYTHON) bench.py
-
-# the decode loop's host-overhead + dispatch-count story on this box:
-# legacy vs device-resident engine per-round host ms, plus the fused
-# multi-round sweep (K in {1,4,8} rounds per dispatch) with
-# dispatches/token per K; meets_target pins overhead <= 0.5x legacy
-# AND K=8 dispatches/token <= 0.3x K=1
-bench-host:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.host_overhead_bench(), indent=2))"
-
-# the gateway hop's mux-vs-pooled-vs-per-dial cost on this box, plus
-# the concurrency-per-socket probe (host-side number; the CPU backend
-# is representative)
-bench-gateway:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.gateway_overhead_bench(), indent=2))"
-
-# fleet-wide KV reuse vs the session-sticky baseline on the same
-# multi-turn chat trace: tokens_reused/prompt token + shed-free TTFT
-# p50 per arm; meets_target pins reuse strictly above baseline
-bench-reuse:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.prefix_reuse_bench(), indent=2))"
-
-# disaggregated prefill/decode vs the same-size mixed fleet (docs/60):
-# decode-pool TPOT p99, per-transfer KV handoff cost, and per-role
-# productive fraction; meets_target pins the decode tail strictly
-# under mixed with handoffs completed and the decode pool's ledger
-# fraction at or above the mixed arm's
-bench-disagg:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.disagg_bench(), indent=2))"
-
-# the drain-migration yardstick (docs/60 § drain runbook): next-turn
-# latency for a session whose replica drains — warm ceiling vs
-# migrated-over-the-wire vs the re-prefill baseline; meets_target
-# pins migrated strictly below re-prefill and near warm, with bytes
-# moved and zero counted fallbacks
-bench-migrate:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.migration_bench(), indent=2))"
-
-# the device-time ledger's accounting bench (docs/90): every replica
-# wall-second attributed (|sum(stages) - uptime| <= 2%) plus the
-# dispatches/token trajectory the megakernel work must drive down
-bench-goodput:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.goodput_ledger_bench(), indent=2))"
-
-# the cold-start collapse yardstick (docs/60 § cold-start runbook):
-# cold launch vs standby promotion vs peer weight-transfer launch,
-# TTFRT + per-stage ledger breakdown from /v1/goodput; meets_target
-# pins promoted <= 0.25x cold
-bench-coldstart:
-	JAX_PLATFORMS=cpu $(PYTHON) -c "import json, bench; \
-		print(json.dumps(bench.cold_start_bench(), indent=2))"
 
 # cpcheck (AST invariant rules vs analysis/baseline.json) + compileall;
 # see docs/70-static-analysis.md. Non-zero on any non-baselined finding.

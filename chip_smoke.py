@@ -2,8 +2,8 @@
 """chip_smoke.py: the quickest proof that the system still starts on the chip.
 
 Drives the main path once, through the entry points a user types, at
-the full width of the models the repo's benches name, and checks what
-comes out:
+the widths of a 1.2B-class serving model and a d1024 trainer (the
+module's constants), and checks what comes out:
 
   probe      a child prints what jax runs on; no TPU ends the run here
   server     supervisor -> `serve` job (FleetMember, slot engine, prefix
@@ -54,9 +54,10 @@ WORK = os.path.join(ROOT, ".chip_smoke_work")
 PLATFORM = "tpu"
 SEED = 0
 
-# -- sizes: the repo's own 1.2B-class decode model as the serve flags
-# express it (bench.py _decode_setup widths; d_ff from derive_d_ff) and
-# the flagship training configuration (bench.py training_bench).
+# -- sizes: a 1.2B-class decode model as the serve flags express it
+# (d_model 2048, 16 heads, 16 layers, vocab 32768; d_ff from
+# derive_d_ff) and a d1024 / 8-layer / 2048-token training
+# configuration at batch 8.
 # Module constants so the CPU rehearsal test can shrink them.
 SERVE_MODEL = {
     "vocab": 32768, "d_model": 2048, "n_heads": 16, "n_layers": 16,

@@ -515,9 +515,8 @@ class FleetGateway:
         self.request_timeout = request_timeout
 
         self.mux = mux
-        # request tracing: on by default (the bench pins its cost at
-        # effectively-free); --no-trace is the bench's A/B control,
-        # not an operational recommendation
+        # request tracing: on by default; --no-trace is an A/B
+        # control, not an operational recommendation
         self.trace = trace
         self._tracer = tracing.TraceRecorder("gateway")
         # staleness signal for flap triage: monotonic stamp of the
@@ -633,8 +632,7 @@ class FleetGateway:
         self._m_handoff_ms = Histogram(
             "containerpilot_gateway_handoff_ms",
             "wall milliseconds per completed KV handoff (prefill "
-            "seed + replica-to-replica pull), the cost bound the "
-            "disagg bench pins",
+            "seed + replica-to-replica pull)",
             registry=self._registry,
             buckets=(1, 2.5, 5, 10, 25, 50, 100, 250, 500, 1000,
                      2500, 5000),
@@ -2544,9 +2542,8 @@ class FleetGateway:
                     continue
                 content_type = headers.get("content-type", "")
                 if "text/event-stream" not in content_type:
-                    # not a stream: a 422/503/500 error body (or a
-                    # server without --slots) — buffer and relay,
-                    # retrying the retryable statuses like the
+                    # not a stream: a 422/503/500 error body —
+                    # buffer and relay, retrying the retryable statuses like the
                     # buffered path
                     try:
                         payload = await _read_body(
@@ -2824,9 +2821,8 @@ def main() -> int:
     parser.add_argument(
         "--trace", default=True, action=argparse.BooleanOptionalAction,
         help="per-request cross-hop tracing (X-CP-Trace propagation, "
-        "/v1/traces, cp_request_stage_seconds): on by default and "
-        "effectively free (bench-pinned); --no-trace is the bench's "
-        "A/B control",
+        "/v1/traces, cp_request_stage_seconds): on by default; "
+        "--no-trace is an A/B control",
     )
     parser.add_argument(
         "--admission-queue-depth", type=int, default=256,

@@ -24,7 +24,7 @@ Thread safety: spills run on the inference executor thread while
 matching runs on the event-loop thread, so the index is locked; the
 device transfers themselves happen OUTSIDE the lock (they can take
 milliseconds, and a transfer must not block a concurrent
-``match_len`` scan). ``take`` pops atomically, so two concurrent
+``best_match`` scan). ``take`` pops atomically, so two concurrent
 readmits of one key cannot double-serve it.
 
 Single-host placement only: the pod mirror's replicated repin gives

@@ -112,12 +112,13 @@ class PlainStepProgram:
         self._state = init_slot_state(self.cfg, self.slots)
 
     def admit(self, slot: int, req, logits, row_cache) -> int:
-        """Sample token 0 with the server key convention (row 0 of
-        ``req.seed``), write the prefilled row + the whole sampling
-        state row in two dispatches, return the first token."""
+        """Sample token 0 with the server key convention (row
+        ``req.row`` of ``req.seed``), write the prefilled row + the
+        whole sampling state row in two dispatches, return the first
+        token."""
         cfg = self.cfg
         row_key = jax.random.fold_in(
-            jax.random.PRNGKey(req.seed), 0
+            jax.random.PRNGKey(req.seed), req.row
         )
         first = first_sample(
             logits, row_key, req.temperature, req.top_k, req.top_p,

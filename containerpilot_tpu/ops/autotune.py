@@ -11,7 +11,7 @@ crossover that ``TransformerConfig.flash_min_seq = AUTO`` resolves to.
     python -m containerpilot_tpu.ops.autotune \
         --seqs 1024,2048,4096,8192 --write
 
-Timing (shared with bench.py): n back-to-back dispatches closed by
+Timing: n back-to-back dispatches closed by
 ``block_until_ready``, divided by n, min over repetitions. Nothing is
 subtracted.
 """

@@ -110,8 +110,8 @@ def _largest_divisor_block(seq: int, block: int) -> int:
     """The largest block <= ``block`` dividing seq (halving from
     ``block``, floored at DEFAULT_BLOCK — the kernels require exact
     grids). Fails loudly on seq not a multiple
-    of DEFAULT_BLOCK: pick_blocks is a public helper (bench/autotune
-    call it), and silently clamping to a non-tile block (e.g. 100, or
+    of DEFAULT_BLOCK: pick_blocks is a public helper (autotune
+    calls it), and silently clamping to a non-tile block (e.g. 100, or
     a degenerate 2) would hand pallas a grid Mosaic rejects — every
     flash call site gates on seq % 128 == 0 (flash_eligible), so such
     a seq here is a caller bug, not a tuning decision."""

@@ -60,7 +60,7 @@ stage                       meaning
 ``replica.prefill``         replica: prefill + first-token sample
 ``replica.decode``          replica: decode rounds to completion
 ``replica.stream_relay``    replica: first SSE delta -> done event
-``replica.compute``         replica: non-slot decode dispatch
+``replica.compute``         replica: a beam search (the one one-shot call)
 ==========================  =========================================
 """
 from __future__ import annotations

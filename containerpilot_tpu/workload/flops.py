@@ -1,4 +1,4 @@
-"""Model-FLOPs accounting shared by bench.py and the trainer.
+"""Model-FLOPs accounting for the trainer's reported MFU.
 
 PaLM-style: a training step costs ~6 FLOPs per parameter per token
 (fwd matmul + 2x bwd) plus the attention score/value matmuls, which

@@ -24,12 +24,14 @@ API (token-level; tokenization is the caller's concern):
     GET /v1/model -> config summary
     GET /metrics  -> Prometheus exposition (requests, latency, tokens)
 
-Generation runs on a worker thread so the asyncio loop (health checks
-included) never blocks on TPU execution. The serving concerns live in
-sibling modules: serve_batcher (continuous batching), serve_prefix
-(prefix KV reuse), serve_strategies (beam/cp/chunked), serve_slots +
-models/stepprog (the step-program engine — plain, quantized, and
-speculative decode), serve_cli (flags + model loading).
+Generation runs on worker threads so the asyncio loop (health checks
+included) never blocks on TPU execution. Every sampled generate
+request rides the slot engine, one row at a time (serve_slots +
+models/stepprog: the step-program engine, plain, quantized and
+speculative decode; long prompts, context-parallel prefill and prefix
+KV reuse are the engine's admission policy, serve_prefix holding the
+cache). Beam search alone is a one-shot call (models/beam.py).
+serve_cli has the flags and the model loading.
 """
 from __future__ import annotations
 
@@ -46,10 +48,9 @@ import jax.numpy as jnp
 from ..models.transformer import TransformerConfig
 from ..telemetry import tracing
 from ..utils.http import HTTPServer, Request, Response, StreamingResponse
-from . import serve_strategies
-from .serve_batcher import Batcher, GenJob
 from .serve_cli import main  # noqa: F401  (one import path for the CLI)
-from .serve_prefix import MIN_REUSE, PrefixCache, generate_with_prefix
+from .serve_prefix import PrefixCache
+from .serve_slots import SlotEngine
 
 log = logging.getLogger("containerpilot.serve")
 
@@ -57,8 +58,6 @@ log = logging.getLogger("containerpilot.serve")
 # (chunk+1) new tokens. The construction-time max_len guard and the
 # warm request itself must agree or the guard stops protecting.
 WARMUP_PROMPT_LEN = 4
-
-_GenJob = GenJob  # pre-split name, kept for importers
 
 
 def _parse_token_rows(body: Dict[str, Any], vocab: int, min_row_len: int):
@@ -100,7 +99,7 @@ class InferenceServer:
         kv_spill_bytes: int = 0,
         prefill_chunk: int = 0,
         text: bool = False,
-        slots: int = 0,
+        slots: int = 4,
         slot_chunk: int = 8,
         slot_window: int = 4,
         cp_mesh: Any = None,
@@ -195,10 +194,9 @@ class InferenceServer:
         self.chaos_hook: Optional[
             Callable[[str], Awaitable[None]]
         ] = None
-        # context-parallel prefill: single-row prompts at least
-        # cp_min_len long ring over the mesh's seq axis
-        # (parallel.cp_generate); everything else takes the usual
-        # paths. Composition is validated at startup below.
+        # context-parallel prefill: prompts at least cp_min_len long
+        # ring their prefill over the mesh's seq axis on admission to
+        # the slot engine. Composition is validated at startup below.
         self.cp_mesh = cp_mesh
         self.cp_min_len = cp_min_len
         if cp_mesh is not None:
@@ -261,52 +259,54 @@ class InferenceServer:
             PrefixCache(prefix_cache_entries, spill=spill)
             if prefix_cache_entries > 0 else None
         )
-        # continuous decode admission: single-row requests join a
-        # running K-token chunk loop over a fixed slot pool instead of
-        # queueing behind whole generations (serve_slots.py)
-        self.slot_engine = None
+        # continuous decode admission: every sampled sequence joins a
+        # running K-token chunk loop over a fixed slot pool
+        # (serve_slots.py). ``slots`` is a capacity (KV memory scales
+        # with it), never a switch: there is no server without a pool.
+        if slots < 1:
+            raise ValueError(
+                "slots must be >= 1 (every generate request rides the "
+                "slot engine; the pool's size is its capacity)"
+            )
         if slot_window < 1:
             raise ValueError("slot_window must be >= 1")
-        if slots > 0:
-            # warmup() pushes a dummy request of 4 prompt ids +
-            # (chunk+1) new tokens through the engine; a legal but
-            # tiny --max-len must fail HERE with a clean message, not
-            # after the port is bound with a submit() traceback
-            if WARMUP_PROMPT_LEN + slot_chunk + 1 > max_len:
-                raise ValueError(
-                    f"--slots requires max_len >= slot_chunk + "
-                    f"{WARMUP_PROMPT_LEN + 1} (warmup request needs "
-                    f"{WARMUP_PROMPT_LEN} prompt ids + "
-                    f"chunk+1={slot_chunk + 1} new tokens; max_len is "
-                    f"{max_len})"
-                )
-            # fused K-round windows need a warmup request that rides
-            # at least one pure-decode cycle (chunk+2 new tokens); a
-            # max_len too tight for that clamps the engine back to
-            # one-round dispatches rather than leaving the fused
-            # program to compile under a live request behind a 200
-            # /health (the no-post-grace-compiles invariant)
-            if WARMUP_PROMPT_LEN + slot_chunk + 2 > max_len:
-                slot_window = 1
-            from .serve_slots import SlotEngine
-
-            # --cp composes: long-prompt admissions ring their
-            # prefill over the cp mesh's seq axis before joining the
-            # pool (the engine runs the same cp_prefill_with_remainder
-            # recipe the pod's --sp path does)
-            # --prefill-chunk composes (admissions longer than the
-            # chunk prefill in pieces) and so does --prefix-cache
-            # (admissions with a cached prefix rewind+extend; every
-            # admission seeds the cache) — both inside the engine
-            self.slot_engine = SlotEngine(
-                cfg, params, max_len, slots=slots, chunk=slot_chunk,
-                window=slot_window,
-                cp_mesh=self.cp_mesh, cp_min_len=self.cp_min_len,
-                prefill_chunk=prefill_chunk,
-                prefix_cache=self.prefix_cache,
-                ledger=self.ledger,
-                prefill_floor_s=prefill_floor_s,
+        # warmup() pushes a dummy request of 4 prompt ids +
+        # (chunk+1) new tokens through the engine; a legal but
+        # tiny --max-len must fail HERE with a clean message, not
+        # after the port is bound with a submit() traceback
+        if WARMUP_PROMPT_LEN + slot_chunk + 1 > max_len:
+            raise ValueError(
+                f"max_len must be >= slot_chunk + "
+                f"{WARMUP_PROMPT_LEN + 1} (warmup request needs "
+                f"{WARMUP_PROMPT_LEN} prompt ids + "
+                f"chunk+1={slot_chunk + 1} new tokens; max_len is "
+                f"{max_len})"
             )
+        # fused K-round windows need a warmup request that rides
+        # at least one pure-decode cycle (chunk+2 new tokens); a
+        # max_len too tight for that clamps the engine back to
+        # one-round dispatches rather than leaving the fused
+        # program to compile under a live request behind a 200
+        # /health (the no-post-grace-compiles invariant)
+        if WARMUP_PROMPT_LEN + slot_chunk + 2 > max_len:
+            slot_window = 1
+        # --cp composes: long-prompt admissions ring their
+        # prefill over the cp mesh's seq axis before joining the
+        # pool (the engine runs the same cp_prefill_with_remainder
+        # recipe the pod's --sp path does)
+        # --prefill-chunk composes (admissions longer than the
+        # chunk prefill in pieces) and so does --prefix-cache
+        # (admissions with a cached prefix rewind+extend; every
+        # admission seeds the cache) — both inside the engine
+        self.slot_engine = SlotEngine(
+            cfg, params, max_len, slots=slots, chunk=slot_chunk,
+            window=slot_window,
+            cp_mesh=self.cp_mesh, cp_min_len=self.cp_min_len,
+            prefill_chunk=prefill_chunk,
+            prefix_cache=self.prefix_cache,
+            ledger=self.ledger,
+            prefill_floor_s=prefill_floor_s,
+        )
         self.slot_window = slot_window
         # prompts longer than this stream through decode_chunk pieces
         # (peak prefill activations O(chunk) instead of O(prompt))
@@ -317,22 +317,18 @@ class InferenceServer:
                 SpeculativeStepProgram,
                 layer_prefix_draft,
             )
-            from .serve_slots import SlotEngine
 
             self.draft_params, self.draft_cfg = layer_prefix_draft(
                 params, cfg, draft_layers
             )
             # speculative decoding rides the slot engine as a step
-            # program (models/stepprog.py) instead of the legacy
-            # one-shot serve_strategies path: the engine brings
+            # program (models/stepprog.py): the engine brings
             # queueing/cancel/tracing and the protocol brings
             # multi-token emission per round. One slot, batch 1 —
             # the verify rollback is a per-sequence pos rewind.
-            # ledger=None deliberately: with a slot engine present
-            # it owns the prefill/decode stamps, and without one the
-            # handler-inflight window in _instrumented coarse-stamps
-            # every compute request (spec included) — a second
-            # stamping authority would fight either one.
+            # ledger=None deliberately: the slot engine owns the
+            # prefill/decode stamps, and a second stamping authority
+            # would fight it.
             self.spec_engine = SlotEngine(
                 cfg, params, max_len,
                 prefill_chunk=prefill_chunk,
@@ -378,8 +374,7 @@ class InferenceServer:
 
         ensure_build_info(self._metrics_registry, "replica")
         # the goodput ledger's metrics face: cp_device_seconds_total
-        # {stage} + the dispatches/token counter pair (engine-less
-        # servers report zeros; the ledger still accounts their life)
+        # {stage} + the dispatches/token counter pair
         ensure_goodput_gauges(
             self._metrics_registry, self.ledger, self._decode_counters
         )
@@ -450,13 +445,10 @@ class InferenceServer:
                 "completions", self._completions
             ))
         self._score_fn = None  # jitted lazily; jit caches per length
-        # continuous batching: requests queue here and the batcher
-        # coalesces whatever accumulated while the device was busy
+        # the most rows one request may carry (token rows, ``n``
+        # samples, beams): the check on input from outside. Rows past
+        # the pool's size queue in the engine like any other request.
         self.max_batch_rows = max_batch_rows
-        self._batcher = Batcher(
-            params, cfg, max_len, max_batch_rows, self._executor
-        )
-        self.batch_stats = self._batcher.stats
 
     # -- handlers -------------------------------------------------------
 
@@ -498,14 +490,19 @@ class InferenceServer:
         """(dispatches, tokens_out) for the goodput surfaces — the
         slot and speculative engines' cumulative pairs summed (each
         engine bumps dispatches once per DEVICE dispatch: one per
-        fused window, two per draft+verify round), zeros without
-        either engine."""
+        fused window, two per draft+verify round)."""
         dispatches = tokens_out = 0
-        for engine in (self.slot_engine, self.spec_engine):
-            if engine is not None:
-                dispatches += engine.dispatches
-                tokens_out += engine.tokens_out
+        for engine in self._engines():
+            dispatches += engine.dispatches
+            tokens_out += engine.tokens_out
         return dispatches, tokens_out
+
+    def _engines(self) -> List[SlotEngine]:
+        """The slot engine and, under --draft-layers, the speculative
+        one."""
+        if self.spec_engine is None:
+            return [self.slot_engine]
+        return [self.slot_engine, self.spec_engine]
 
     async def _goodput(self, _req: Request) -> Response:
         """The device-time ledger, JSON: per-stage seconds (summing
@@ -635,10 +632,9 @@ class InferenceServer:
         the single sampled token. The prefill half of a disaggregated
         handoff: the gateway calls this on the prefill pool, then
         tells the pinned decode replica to pull the entry."""
-        if self.slot_engine is None or self.prefix_cache is None:
+        if self.prefix_cache is None:
             return Response(
-                409,
-                b"prefill handoff needs --slots and --prefix-cache\n",
+                409, b"prefill handoff needs --prefix-cache\n"
             )
         if self.draining:
             return Response(
@@ -887,14 +883,6 @@ class InferenceServer:
         post-trim lengths)."""
         import time as time_mod
 
-        # without a slot engine the ledger has no prefill/decode
-        # authority; the handler inflight window stands in (coarse:
-        # whole busy window -> decode), flipped at 0<->1 boundaries
-        # only. With an engine, its boundary stamps rule and this
-        # path stays off.
-        compute_endpoint = endpoint in ("generate", "completions",
-                                        "score")
-
         async def wrapped(req: Request) -> Response:
             # splice-safe ids only (tracing.safe_id): this id is
             # echoed in answer headers and digests verbatim
@@ -935,11 +923,6 @@ class InferenceServer:
             token = tracing.activate(trace)
             t0 = time_mod.perf_counter()
             self._inflight += 1
-            if (
-                self.slot_engine is None and compute_endpoint
-                and self._inflight == 1
-            ):
-                self.ledger.enter("decode")
             try:
                 # the hook runs inside the inflight window: a request
                 # parked in an injected delay must hold off a drain's
@@ -959,11 +942,6 @@ class InferenceServer:
                 raise
             finally:
                 self._inflight -= 1
-                if (
-                    self.slot_engine is None and compute_endpoint
-                    and self._inflight == 0
-                ):
-                    self.ledger.engine_idle()
                 tracing.deactivate(token)
             resp.headers.setdefault(
                 tracing.TRACE_HEADER, trace.trace_id
@@ -1036,20 +1014,15 @@ class InferenceServer:
                     {
                         "draft_layers": self.draft_cfg.n_layers,
                         "speculate": self.speculate,
-                        # draft/verify rides the step-program engine
-                        # (not the legacy one-shot path); its
-                        # dispatch/token counters fold into the
-                        # goodput pair below
+                        # draft/verify rides the step-program
+                        # engine; its dispatch/token counters fold
+                        # into the goodput pair
                         "engine": self.spec_engine.stats,
                     }
                     if self.draft_cfg is not None
                     else None
                 ),
-                "batching": {
-                    "max_batch_rows": self.max_batch_rows,
-                    "device_calls": self.batch_stats["calls"],
-                    "rows": self.batch_stats["rows"],
-                },
+                "batching": {"max_batch_rows": self.max_batch_rows},
                 "prefix_cache": (
                     {
                         "entries": self.prefix_cache.entries,
@@ -1072,18 +1045,12 @@ class InferenceServer:
                     and self.prefix_cache.spill is not None
                     else None
                 ),
-                "slot_engine": (
-                    self.slot_engine.stats
-                    if self.slot_engine is not None else None
-                ),
+                "slot_engine": self.slot_engine.stats,
                 # routed experts of the decode rounds (a held share
                 # of them: models/mla_moe.py); None for a dense model
-                "experts": (
-                    self.slot_engine.expert_stats()
-                    if self.slot_engine is not None else None
-                ),
+                "experts": self.slot_engine.expert_stats(),
                 # SSE streaming rides the slot engine's chunks
-                "stream": self.slot_engine is not None,
+                "stream": True,
                 "draining": self.draining,
                 "cp": (
                     {
@@ -1202,42 +1169,54 @@ class InferenceServer:
             )
         if p["max_new_requested"] < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        # bucket the compiled decode length to multiples of 16 so
-        # per-request max_new variation can't churn the jit cache
-        p["max_new"] = min(
-            -(-p["max_new_requested"] // 16) * 16,
-            self.max_len - prompt_len,
-        )
         return p
 
-    @staticmethod
-    async def _timed_compute(trace, awaitable):
-        """Record one coarse ``compute`` span around a non-slot decode
-        path — the slot engine's requests get the finer
-        slot_queue_wait/prefill/decode breakdown instead."""
-        if trace is None:
-            return await awaitable
-        t0 = tracing.now()
-        try:
-            return await awaitable
-        finally:
-            trace.add_span("compute", t0, tracing.now())
+    def _beam(
+        self, tokens: List[List[int]], p: Dict[str, Any]
+    ) -> List[List[int]]:
+        """One beam search, on the inference executor thread."""
+        from ..models.beam import beam_search
+
+        # beam search is NOT prefix-consistent: the best 16-token
+        # beam's first 6 tokens are not the best 6-token continuation,
+        # so the compiled horizon is the REQUESTED length (beams are
+        # explicit requests; the compile churn is theirs)
+        out, _score = beam_search(
+            self.params, jnp.asarray(tokens, jnp.int32), self.cfg,
+            max_new_tokens=p["max_new_requested"],
+            max_len=self.max_len, beam_width=p["beam_width"],
+            eos_id=p["eos_id"], length_penalty=p["length_penalty"],
+            prefill_chunk=self.prefill_chunk,
+        )
+        return [jax.device_get(out).tolist()]
 
     async def _dispatch_generate(
-        self, tokens: List[List[int]], prompt_len: int, p: Dict[str, Any]
+        self, tokens: List[List[int]], p: Dict[str, Any]
     ) -> List[List[int]]:
-        """Route a validated generate request to the right decode
-        strategy and return the (untrimmed) generated rows."""
-        loop = asyncio.get_event_loop()
-        in_exec = loop.run_in_executor
+        """Run a validated generate request and return its generated
+        rows, in order. Three arms, each the only code that serves
+        its input: beam search (a one-shot call: beams are outside
+        the step-program protocol), the speculative engine (greedy,
+        one row, nothing that reshapes the logits, under
+        --draft-layers), and for everything else the slot engine, one
+        row at a time. Both engines' emission is already eos-capped
+        and exact in max_new (the _trim downstream is idempotent on
+        it), and both stamp request-boundary timings the trace
+        converts to slot_queue_wait/prefill/decode spans — batched,
+        nothing recorded per token."""
         trace = tracing.current_trace()
-        timed = self._timed_compute
         if p["beam_width"]:
-            return await timed(trace, in_exec(
-                self._executor, serve_strategies.run_beam, self, tokens,
-                p["max_new_requested"], p["beam_width"], p["eos_id"],
-                p["length_penalty"],
-            ))
+            t0 = tracing.now()
+            try:
+                return await asyncio.get_event_loop().run_in_executor(
+                    self._executor, self._beam, tokens, p
+                )
+            finally:
+                if trace is not None:
+                    trace.add_span("compute", t0, tracing.now())
+        timings: List[Optional[Dict[str, float]]] = [
+            {} if trace is not None else None for _ in tokens
+        ]
         if (
             self.spec_engine is not None
             and p["temperature"] <= 0.0
@@ -1246,99 +1225,47 @@ class InferenceServer:
             and not p["logit_bias"]
             and len(tokens) == 1
         ):
-            # greedy single-sequence: draft-and-verify through the
-            # speculative step program (the engine's emission is
-            # already eos-capped, and the request's exact max_new
-            # bounds it — no bucketed over-decode to trim). Output is
-            # byte-identical to speculative_generate and therefore to
-            # plain greedy decode. The engine stamps request-boundary
-            # timings the trace converts to slot_queue_wait/prefill/
-            # decode spans, same as the slot path below.
-            timings: Optional[Dict[str, float]] = (
-                {} if trace is not None else None
-            )
-            fut = self.spec_engine.submit(
+            # draft-and-verify through the speculative step program:
+            # byte-identical to plain greedy decode
+            futures = [self.spec_engine.submit(
                 tokens[0], p["max_new_requested"],
                 eos_id=p["eos_id"], seed=p["seed"],
-                timings=timings,
+                timings=timings[0],
+            )]
+        else:
+            # each row joins the running chunk loop at the next
+            # boundary and draws from fold_in(PRNGKey(seed), i); rows
+            # beyond the pool's free slots queue like any request
+            futures = [
+                self._submit_row(row, p, i, timings=timings[i])
+                for i, row in enumerate(tokens)
+            ]
+        rows = list(await asyncio.gather(
+            *[asyncio.wrap_future(fut) for fut in futures]
+        ))
+        if trace is not None:
+            # the request is as slow as its last row: that row's
+            # stamps are the request's stages (stages never overlap)
+            tracing.add_engine_spans(
+                trace, max(timings, key=lambda t: t.get("done", 0.0))
             )
-            rows = [await asyncio.wrap_future(fut)]
-            if trace is not None:
-                tracing.add_engine_spans(trace, timings)
-            return rows
-        if self.slot_engine is not None and len(tokens) == 1:
-            # joins the running chunk loop at the next boundary; output
-            # is already pad-trimmed at eos (the _trim downstream is
-            # idempotent on it). The engine stamps request-boundary
-            # timings the trace converts to slot_queue_wait/prefill/
-            # decode spans — batched, nothing recorded per token.
-            timings: Optional[Dict[str, float]] = (
-                {} if trace is not None else None
-            )
-            fut = self.slot_engine.submit(
-                tokens[0], p["max_new_requested"],
-                temperature=p["temperature"], top_k=p["top_k"],
-                top_p=p["top_p"], eos_id=p["eos_id"], seed=p["seed"],
-                min_new=p["min_new"],
-                presence_penalty=p["presence"],
-                frequency_penalty=p["frequency"],
-                logit_bias=p["logit_bias"],
-                timings=timings,
-            )
-            rows = [await asyncio.wrap_future(fut)]
-            if trace is not None:
-                tracing.add_engine_spans(trace, timings)
-            return rows
-        if (
-            self.cp_mesh is not None
-            and len(tokens) == 1
-            and prompt_len >= self.cp_min_len
-        ):
-            # long prompt: the prefill — the quadratic part — rings
-            # over the seq axis; decode runs the normal scan
-            return await timed(trace, in_exec(
-                self._executor, serve_strategies.run_cp, self,
-                tokens, p,
-            ))
-        if (
-            self.prefix_cache is not None
-            and len(tokens) == 1
-            and (
-                self.prefix_cache.match_len(tokens[0]) >= MIN_REUSE
-                or self._batcher.idle()
-            )
-        ):
-            # hit -> reuse; miss -> still seed the cache, but only when
-            # nothing is queued (otherwise continuous batching would
-            # have coalesced this request — don't trade batching
-            # throughput for a cold-path seed)
-            return await timed(trace, in_exec(
-                self._executor, generate_with_prefix, self, tokens[0],
-                p["max_new"], p["temperature"], p["top_k"], p["top_p"],
-                p["eos_id"], p["seed"], p["min_new"], p["presence"],
-                p["frequency"], p["logit_bias"],
-            ))
-        if (
-            self.prefill_chunk > 0
-            and len(tokens) == 1
-            and prompt_len > self.prefill_chunk
-        ):
-            return await timed(trace, in_exec(
-                self._executor, serve_strategies.run_chunked, self,
-                tokens, prompt_len, p["max_new"], p["temperature"],
-                p["top_k"], p["top_p"], p["eos_id"], p["seed"],
-                p["min_new"], p["presence"], p["frequency"],
-                p["logit_bias"],
-            ))
-        job = GenJob(
-            rows=tokens, prompt_len=prompt_len, max_new=p["max_new"],
+        return rows
+
+    def _submit_row(
+        self, row: List[int], p: Dict[str, Any], row_idx: int = 0,
+        **hooks: Any,
+    ):
+        """One sequence of a validated request into the slot engine;
+        ``hooks`` are submit's on_tokens / cancel / timings."""
+        return self.slot_engine.submit(
+            row, p["max_new_requested"],
             temperature=p["temperature"], top_k=p["top_k"],
             top_p=p["top_p"], eos_id=p["eos_id"], seed=p["seed"],
-            min_new=p["min_new"], presence=p["presence"],
-            frequency=p["frequency"], logit_bias=p["logit_bias"],
-            future=loop.create_future(),
+            row=row_idx, min_new=p["min_new"],
+            presence_penalty=p["presence"],
+            frequency_penalty=p["frequency"],
+            logit_bias=p["logit_bias"], **hooks,
         )
-        return await timed(trace, self._batcher.submit(job))
 
     @staticmethod
     def _trim(
@@ -1394,17 +1321,16 @@ class InferenceServer:
                     )
                 # OpenAI's n: one prompt, n independent samples. Each
                 # duplicated row draws from fold_in(seed, i) — the
-                # server's existing per-row key convention — so the
-                # samples differ under temperature (greedy duplicates
-                # are identical by definition) and ride the batcher
-                # as ONE device call.
+                # server's per-row key convention — so the samples
+                # differ under temperature (greedy duplicates are
+                # identical by definition).
                 tokens = [list(tokens[0]) for _ in range(p["n"])]
             if stream:
                 return self._generate_stream(tokens, p)
         except (ValueError, KeyError, TypeError) as exc:
             return Response(422, f"{exc}\n".encode())
 
-        generated = await self._dispatch_generate(tokens, prompt_len, p)
+        generated = await self._dispatch_generate(tokens, p)
         generated = self._trim(generated, p["max_new_requested"], p["eos_id"])
         generated = self._trim_stops(generated, p["stop"])
         self._m_tokens.inc(sum(len(r) for r in generated))
@@ -1445,11 +1371,6 @@ class InferenceServer:
         streaming surfaces. ``delta_event(delta) -> dict`` shapes each
         event; ``tail_events() -> [dict]`` may append events before
         the terminal ``done`` (e.g. a UTF-8 decoder flush)."""
-        if self.slot_engine is None:
-            raise ValueError(
-                "stream requires --slots (token streaming rides the "
-                "slot engine's chunk boundaries)"
-            )
         for knob, why in (
             ("logprobs", "echo logprobs need the full row"),
             ("beam_width", "beams have no incremental prefix"),
@@ -1480,16 +1401,8 @@ class InferenceServer:
         timings: Optional[Dict[str, float]] = (
             {} if trace is not None else None
         )
-        fut = self.slot_engine.submit(
-            row, p["max_new_requested"],
-            temperature=p["temperature"], top_k=p["top_k"],
-            top_p=p["top_p"], eos_id=p["eos_id"], seed=p["seed"],
-            min_new=p["min_new"],
-            presence_penalty=p["presence"],
-            frequency_penalty=p["frequency"],
-            logit_bias=p["logit_bias"],
-            on_tokens=on_tokens, cancel=cancel,
-            timings=timings,
+        fut = self._submit_row(
+            row, p, on_tokens=on_tokens, cancel=cancel, timings=timings
         )
         fut.add_done_callback(
             lambda _f: loop.call_soon_threadsafe(deltas.put_nowait, _DONE)
@@ -1593,7 +1506,7 @@ class InferenceServer:
         except (ValueError, KeyError, TypeError) as exc:
             return Response(422, f"{exc}\n".encode())
 
-        generated = await self._dispatch_generate([row], len(row), p)
+        generated = await self._dispatch_generate([row], p)
         generated = self._trim(generated, p["max_new_requested"], p["eos_id"])
         generated = self._trim_stops(generated, p["stop"])
         self._m_tokens.inc(len(generated[0]))
@@ -1639,8 +1552,8 @@ class InferenceServer:
         teacher-forced pass over prompt+generated. Decode is bit-equal
         to the forward (tested invariant), so these are exactly the
         probabilities the sampler saw — and the approach works
-        uniformly across every decode path (batcher, slots, prefix,
-        speculative, beam) with no decode changes. With --kv-int8 the
+        uniformly across every decode path (slots, speculative, beam)
+        with no decode changes. With --kv-int8 the
         echo is approximate (the scorer runs full-precision while
         decode read a quantized KV cache; parity there is ~5e-2, not
         bitwise). Rows pad to a 16-multiple width (capped at max_len)
@@ -1711,10 +1624,9 @@ class InferenceServer:
         The double count while a buffered request waits on its slot
         future only makes drain-waiting conservative."""
         n = self._inflight
-        for engine in (self.slot_engine, self.spec_engine):
-            if engine is not None:
-                stats = engine.stats
-                n += stats["active"] + stats["queued"]
+        for engine in self._engines():
+            stats = engine.stats
+            n += stats["active"] + stats["queued"]
         return n
 
     @property
@@ -1722,14 +1634,9 @@ class InferenceServer:
         """Fraction of decode capacity in use, the autoscaling
         signal: (active + queued slot-engine rows) / slots, so queued
         work pushes it past 1.0 — a replica can be *over*-subscribed,
-        and a scaler must see that. Without a slot engine the handler
-        count stands in (each buffered request is one unit)."""
-        if self.slot_engine is not None:
-            stats = self.slot_engine.stats
-            return (stats["active"] + stats["queued"]) / max(
-                1, stats["slots"]
-            )
-        return float(self._inflight)
+        and a scaler must see that."""
+        stats = self.slot_engine.stats
+        return (stats["active"] + stats["queued"]) / stats["slots"]
 
     def kv_note(self) -> str:
         """The ``kv=`` heartbeat field's VALUE (the name is owned by
@@ -1960,15 +1867,13 @@ class InferenceServer:
         engine = self.slot_engine
         return warmup_fingerprint(
             self.cfg, self.max_len,
-            slots=getattr(engine, "slots", 0) if engine else 0,
-            slot_chunk=getattr(engine, "chunk", 0) if engine else 0,
+            slots=engine.slots,
+            slot_chunk=engine.chunk,
             # the fused window K shapes the engine's compiled program
             # set: a marker written at K=1 must never skip the fused
             # program a K=4 launch needs (PR 13's compile-cache skip
             # stays correct only if K is part of the identity)
-            slot_window=(
-                getattr(engine, "window", 1) if engine else 0
-            ),
+            slot_window=engine.window,
             draft_layers=(
                 self.draft_cfg.n_layers
                 if self.draft_cfg is not None else 0
@@ -1989,18 +1894,19 @@ class InferenceServer:
         return self._compile_cache_note
 
     async def warmup(self) -> None:
-        """Compile the default-shaped programs before reporting healthy.
+        """Compile the programs requests run before reporting healthy:
+        one dummy request through the slot engine and, under
+        --draft-layers, the speculative programs and one through that
+        engine.
 
-        Requests with other prompt lengths still compile on first use
-        (shapes are static); the bucketed max_new keeps that churn
-        bounded. With a shared compile cache dir configured, buckets
-        a previous same-shaped process already marked warm are
-        SKIPPED — the XLA disk cache holds their executables, so the
-        first live request pays a fast cache load instead of a
-        compile, and this launch's ``compile_warmup`` seconds
-        collapse to near zero (the cold-start-collapse lever)."""
-        from ..models.decode import generate
-
+        Requests with other prompt lengths still compile their prefill
+        on first use (shapes are static). With a shared compile cache
+        dir configured, buckets a previous same-shaped process already
+        marked warm are SKIPPED — the XLA disk cache holds their
+        executables, so the first live request pays a fast cache load
+        instead of a compile, and this launch's ``compile_warmup``
+        seconds collapse to near zero (the cold-start-collapse
+        lever)."""
         # ledger: everything from here until ready flips — XLA
         # compiles AND the dummy slot-engine request driving them —
         # is compile_warmup, stamped via an override so the engine's
@@ -2015,6 +1921,8 @@ class InferenceServer:
             await self.chaos_hook("warmup")
         loop = asyncio.get_event_loop()
         fingerprint = ""
+        # buckets are "slots" and "spec"; a marker from a build that
+        # also listed the one-shot programs' "p4"/"p16" reads the same
         warm: set = set()
         if self.compile_cache_dir:
             from .modelcfg import load_warm_buckets
@@ -2024,31 +1932,8 @@ class InferenceServer:
                 None, load_warm_buckets,
                 self.compile_cache_dir, fingerprint,
             )
-
-        def run() -> None:
-            for prompt_len in (4, 16):
-                if prompt_len + 16 > self.max_len:
-                    continue
-                if f"p{prompt_len}" in warm:
-                    continue  # a same-shape process already compiled it
-                prompt = jnp.zeros((1, prompt_len), jnp.int32)
-                generate(
-                    self.params, prompt, self.cfg, max_new_tokens=16,
-                    max_len=self.max_len,
-                )
-                if self.draft_params is not None and prompt_len == 4:
-                    # the DEFAULT path for greedy traffic: one shared
-                    # rule for which spec programs must compile inside
-                    # the grace (models/speculative.py)
-                    from ..models.speculative import warm_speculative
-
-                    warm_speculative(
-                        self.params, self.draft_params, self.cfg,
-                        self.draft_cfg, self.speculate, self.max_len,
-                    )
-
-        await loop.run_in_executor(self._executor, run)
-        if self.slot_engine is not None and "slots" not in warm:
+        buckets = {"slots"}
+        if "slots" not in warm:
             # one dummy request through the engine compiles its whole
             # program set (standalone prefill, first-sample, insert,
             # the (S, chunk) chunk program and — with window > 1 —
@@ -2065,30 +1950,16 @@ class InferenceServer:
                 [0] * WARMUP_PROMPT_LEN, max_new=warm_new,
             )
             await asyncio.wrap_future(fut)
-        if self.spec_engine is not None and "spec" not in warm:
-            # same discipline for the speculative engine: one dummy
-            # generation compiles its admission glue (the per-k
-            # draft/verify variants compiled in warm_speculative
-            # above, inside the same grace)
-            spec_new = min(
-                self.speculate + 2, self.max_len - WARMUP_PROMPT_LEN
-            )
-            if spec_new >= 1:
-                fut = self.spec_engine.submit(
-                    [0] * WARMUP_PROMPT_LEN, max_new=spec_new,
-                )
-                await asyncio.wrap_future(fut)
+        if self.spec_engine is not None:
+            buckets.add("spec")
+            if "spec" not in warm:
+                await self._warm_speculative()
         if self.compile_cache_dir:
             from .modelcfg import (
                 compile_cache_note,
                 mark_warm_buckets,
             )
 
-            buckets = {"p4", "p16"}
-            if self.slot_engine is not None:
-                buckets.add("slots")
-            if self.spec_engine is not None:
-                buckets.add("spec")
             await loop.run_in_executor(
                 None, mark_warm_buckets,
                 self.compile_cache_dir, fingerprint, buckets,
@@ -2106,15 +1977,35 @@ class InferenceServer:
         self.ready = True
         log.info(
             "serve: default shapes warm%s; %s",
-            " (marker-skipped)" if warm else "",
+            " (marker-skipped)" if "slots" in warm else "",
             "standing by" if self.role == "standby"
             else "accepting traffic",
         )
 
+    async def _warm_speculative(self) -> None:
+        """The DEFAULT path for greedy traffic under --draft-layers:
+        one shared rule for which per-k draft/verify programs must
+        compile inside the grace (models/speculative.py), then one
+        dummy generation for the engine's admission glue."""
+        from ..models.speculative import warm_speculative
+
+        await asyncio.get_event_loop().run_in_executor(
+            self._executor, warm_speculative,
+            self.params, self.draft_params, self.cfg,
+            self.draft_cfg, self.speculate, self.max_len,
+        )
+        spec_new = min(
+            self.speculate + 2, self.max_len - WARMUP_PROMPT_LEN
+        )
+        if spec_new >= 1:
+            fut = self.spec_engine.submit(
+                [0] * WARMUP_PROMPT_LEN, max_new=spec_new,
+            )
+            await asyncio.wrap_future(fut)
+
     async def run(self) -> None:
         await self._server.start_tcp(self.host, self.port)
         self.port = self._server.bound_port or self.port
-        self._batcher.start()
         self._loop_probe.start()
         log.info("serve: listening on %s:%d", self.host, self.port)
         await self.warmup()
@@ -2122,14 +2013,12 @@ class InferenceServer:
     async def stop(self) -> None:
         self.ledger.freeze()
         self._loop_probe.stop()
-        await self._batcher.stop()
-        for engine in (self.slot_engine, self.spec_engine):
-            if engine is not None:
-                # joins the worker thread; run off-loop so in-flight
-                # dispatches can't block the event loop
-                await asyncio.get_event_loop().run_in_executor(
-                    None, engine.stop
-                )
+        for engine in self._engines():
+            # joins the worker thread; run off-loop so in-flight
+            # dispatches can't block the event loop
+            await asyncio.get_event_loop().run_in_executor(
+                None, engine.stop
+            )
         await self._server.stop()
 
     async def abort(self) -> None:
@@ -2144,12 +2033,10 @@ class InferenceServer:
         self.ledger.freeze()
         self._loop_probe.stop()
         await self._server.abort()
-        await self._batcher.stop()
-        for engine in (self.slot_engine, self.spec_engine):
-            if engine is not None:
-                await asyncio.get_event_loop().run_in_executor(
-                    None, engine.stop
-                )
+        for engine in self._engines():
+            await asyncio.get_event_loop().run_in_executor(
+                None, engine.stop
+            )
 
 
 if __name__ == "__main__":

@@ -21,6 +21,18 @@ from .modelcfg import (
 )
 
 
+def _slot_count(raw: str) -> int:
+    """--slots: a pool size of at least 1 (0 used to mean "no slot
+    engine"; every request rides one now)."""
+    slots = int(raw)
+    if slots < 1:
+        raise argparse.ArgumentTypeError(
+            "must be >= 1 (the slot pool's size; every generate "
+            "request rides the slot engine)"
+        )
+    return slots
+
+
 def build_arg_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(
         description="supervised inference server"
@@ -92,8 +104,8 @@ def build_arg_parser() -> argparse.ArgumentParser:
     )
     parser.add_argument(
         "--max-batch-rows", type=int, default=16,
-        help="continuous batching: max sequences coalesced into one "
-        "device call",
+        help="the most rows one request may carry: token rows, `n` "
+        "samples, beam width (each row is one slot-engine sequence)",
     )
     parser.add_argument(
         "--prefill-chunk", type=int, default=0,
@@ -121,14 +133,14 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "--vocab >= 259)",
     )
     parser.add_argument(
-        "--slots", type=int, default=0,
-        help="continuous decode admission: single-row requests join a "
-        "running chunked decode over a pool of N slots instead of "
-        "queueing behind whole generations; 0 = off. Composes "
-        "with --window (per-slot ring caches), --cp (admissions "
-        "ring long prompts), --prefill-chunk (piecewise "
-        "admission), and --prefix-cache (admissions rewind+extend "
-        "cached prefixes)",
+        "--slots", type=_slot_count, default=4,
+        help="the slot pool's size, a capacity (KV memory scales with "
+        "it): every sampled sequence joins a running chunked decode "
+        "over a pool of N slots, and sequences past N queue; at "
+        "least 1. Composes with --window (per-slot ring caches), "
+        "--cp (admissions ring long prompts), --prefill-chunk "
+        "(piecewise admission), and --prefix-cache (admissions "
+        "rewind+extend cached prefixes)",
     )
     parser.add_argument(
         "--slot-chunk", type=int, default=8,
@@ -152,11 +164,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
     )
     parser.add_argument(
         "--cp", type=int, default=1,
-        help="context-parallel prefill ways: long single-row prompts "
-        "ring their prefill over a seq axis of N local devices "
-        "(parallel.cp_generate); 1 = off. Composes with --tp (a "
-        "seq x model mesh over cp*tp devices) and --slots (engine "
-        "admissions ring long prompts); rejects "
+        help="context-parallel prefill ways: long prompts ring "
+        "their prefill over a seq axis of N local devices on "
+        "admission to the slot engine; 1 = off. Composes with --tp "
+        "(a seq x model mesh over cp*tp devices); rejects "
         "--draft-layers/--prefix-cache/--window",
     )
     parser.add_argument(

@@ -1,15 +1,16 @@
 """Prefix KV reuse for the inference server.
 
 Completed prompts' KV caches, keyed by their token tuple, LRU-bounded.
-A new single-row request reuses the longest common prefix and only
-prefills the (bucketed) suffix — the chat/agent regime where every
-turn re-sends a long shared history.
+An admission to the slot engine reuses the longest common prefix and
+only prefills the (bucketed) suffix — the chat/agent regime where
+every turn re-sends a long shared history.
 
-Thread safety: ``match_len`` runs on the asyncio event-loop thread
-(the /v1/generate dispatch condition) while the store/evict side runs
-on the inference executor thread, so every OrderedDict access holds
-``_lock`` (round-2 review: a concurrent request could previously hit
-"OrderedDict mutated during iteration" and surface as a 500).
+Thread safety: the digest, the KV export/pull verbs and the drain
+migration read the cache from the event loop's executor threads while
+the store/evict side runs on the slot engine's thread, so every
+OrderedDict access holds ``_lock`` (round-2 review: a concurrent
+request could previously hit "OrderedDict mutated during iteration"
+and surface as a 500).
 
 With a **spill tier** attached (``kvtier.HostSpillTier``), LRU
 eviction moves the entry's KV to byte-budgeted host RAM instead of
@@ -77,11 +78,6 @@ class PrefixCache:
     def __len__(self) -> int:
         with self._lock:
             return len(self._cache)
-
-    def match_len(self, row: List[int]) -> int:
-        """Longest common prefix between ``row`` and any cached prompt
-        (host-side scan; cheap relative to a device call)."""
-        return self.best_match(row)[0]
 
     def best_match(
         self, row: List[int]
@@ -223,8 +219,8 @@ class PrefixCache:
 
 
 def plan_reuse(pc: "PrefixCache", row: List[int]):
-    """The ONE reuse plan both the standalone prefix path and the
-    slot engine's admission apply: longest cached match, suffix
+    """The ONE reuse plan the slot engines' admissions apply:
+    longest cached match, suffix
     bucketed (a little of the matched prefix re-prefills so jit
     compiles one extend program per BUCKET, not per suffix length).
     Returns (reuse_len, base_cache_or_None); counts a miss when no
@@ -272,72 +268,3 @@ def reuse_admission(pc: "PrefixCache", row_tokens: List[int], cfg,
     pc.stats["hits"] += 1
     pc.stats["tokens_reused"] += reuse
     return logits, cache
-
-
-def generate_with_prefix(
-    srv: Any, row: List[int], max_new: int, temperature: float,
-    top_k: int, top_p: float, eos_id: int, seed: int,
-    min_new: int = 0,
-    presence: float = 0.0,
-    frequency: float = 0.0,
-    logit_bias: Any = None,
-) -> List[List[int]]:
-    """Single-row generation reusing the longest cached prompt prefix.
-
-    The recomputed suffix is bucketed (a little of the matched prefix
-    is re-prefilled) so jit compiles one extend program per bucket, not
-    per suffix length. Stale cache rows beyond pos are masked or
-    overwritten by design (models/decode.py), which is what makes the
-    rewind sound — and why --window (ring cache) refuses this feature.
-    Runs on the inference executor thread.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..models.decode import (
-        _jitted_prefill,
-        generate_from_cache,
-    )
-
-    pc: PrefixCache = srv.prefix_cache
-    key_row = tuple(row)
-    plen = len(row)
-    # the ONE admission-side reuse protocol (shared with both slot
-    # engines): rewind + bucketed extend, in bounded pieces when
-    # prefill_chunk applies — the standalone prefix path honors the
-    # same O(chunk) activation bound as the slot-engine paths
-    hit = reuse_admission(
-        pc, row, srv.cfg, srv.params, chunk_len=srv.prefill_chunk
-    )
-    if hit is not None:
-        logits, cache = hit
-    elif srv.prefill_chunk and plen > srv.prefill_chunk:
-        # cold long prompt: seed the prefix cache via the chunked
-        # stream so the configured prefill HBM bound still holds
-        # (the miss was already counted by reuse_admission)
-        from ..models.decode import chunked_prefill
-
-        logits, cache = chunked_prefill(
-            srv.params, jnp.asarray([row], jnp.int32), srv.cfg,
-            srv.max_len, srv.prefill_chunk,
-        )
-    else:
-        logits, cache = _jitted_prefill(srv.cfg, srv.max_len)(
-            srv.params, jnp.asarray([row], jnp.int32)
-        )
-    # store the completed prompt's cache for future turns
-    pc.store(key_row, cache)
-    # the prefix path is a device call too — keep /v1/model's batching
-    # telemetry honest when this path serves the traffic
-    srv.batch_stats["calls"] += 1
-    srv.batch_stats["rows"] += 1
-    out = generate_from_cache(
-        srv.params, cache, logits, srv.cfg,
-        max_new_tokens=max_new, temperature=temperature,
-        rng=jnp.stack([jax.random.fold_in(jax.random.PRNGKey(seed), 0)]),
-        top_k=top_k, top_p=top_p, eos_id=eos_id,
-        pos=plen, min_new_tokens=min_new,
-        presence_penalty=presence, frequency_penalty=frequency,
-        logit_bias=logit_bias,
-    )
-    return jax.device_get(out).tolist()

@@ -1,7 +1,7 @@
 """The slot engine: continuous decode admission for serving.
 
-``Batcher`` (serve_batcher.py) coalesces requests that ARRIVE
-together; this engine lets requests JOIN a running decode. A fixed
+Every sampled sequence a replica serves JOINS a running decode here
+(workload/serve.py submits a request's rows one by one). A fixed
 pool of S slots decodes in fixed-size chunks (models/slots.py — one
 compiled program set, static shapes); between dispatches the engine
 harvests finished rows and admits queued requests into free slots, so
@@ -40,7 +40,6 @@ import logging
 import queue
 import threading
 import time
-from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import List, Optional
@@ -72,6 +71,11 @@ class _Request:
     eos_id: int
     pad_id: int
     seed: int
+    # this sequence's index within its HTTP request (several token
+    # rows, or ``n`` samples of one): the row's key is
+    # fold_in(PRNGKey(seed), row), so the rows of one request draw
+    # independently and a one-row request keeps row 0's key
+    row: int = 0
     min_new: int = 0
     presence: float = 0.0
     frequency: float = 0.0
@@ -248,15 +252,6 @@ class SlotEngine:
         self.chunk = program.chunk
         self.window = getattr(program, "rounds", 1)
         self._active: List[Optional[_Slot]] = [None] * self.slots
-        # per-round wall times for decode-only rounds (no admission),
-        # seconds; bench.py's host_overhead_bench reads these through
-        # round_times_ms(). _round_host_times is the same rounds with
-        # the blocking token wait excluded — the engine's per-round
-        # HOST cost, observed directly instead of inferred by
-        # subtracting a separately-timed device loop (which a noisy
-        # shared host can skew by more than the overhead itself).
-        self._round_times: "deque[float]" = deque(maxlen=1024)
-        self._round_host_times: "deque[float]" = deque(maxlen=1024)
         self._queue: "queue.Queue[Optional[_Request]]" = queue.Queue()
         self._submit_lock = threading.Lock()
         self._stopped = threading.Event()
@@ -277,6 +272,7 @@ class SlotEngine:
         eos_id: int = -1,
         pad_id: int = 0,
         seed: int = 0,
+        row: int = 0,
         min_new: int = 0,
         presence_penalty: float = 0.0,
         frequency_penalty: float = 0.0,
@@ -287,14 +283,16 @@ class SlotEngine:
     ) -> Future:
         """Queue one sequence; resolves to its generated ids.
 
-        ``logit_bias``: a {token_id: bias} dict (generate's contract,
-        validated here so a bad request fails the submit, not the
-        pool). ``on_tokens`` (worker-thread callback) streams each
-        emitted delta; ``cancel`` (a threading.Event the caller sets,
-        e.g. on client disconnect) frees the slot at the next chunk
-        boundary — the future then resolves with whatever was
-        emitted. ``timings`` (tracing) is stamped at request
-        boundaries only — see _Request.timings."""
+        ``row`` is the sequence's index within its request: it draws
+        from ``fold_in(PRNGKey(seed), row)``. ``logit_bias``: a
+        {token_id: bias} dict (generate's contract, validated here
+        so a bad request fails the submit, not the pool).
+        ``on_tokens`` (worker-thread callback) streams each emitted
+        delta; ``cancel`` (a threading.Event the caller sets, e.g. on
+        client disconnect) frees the slot at the next chunk boundary
+        — the future then resolves with whatever was emitted.
+        ``timings`` (tracing) is stamped at request boundaries only —
+        see _Request.timings."""
         if max_new < 1:
             raise ValueError("max_new must be >= 1")
         if not 0 <= min_new <= max_new:
@@ -316,7 +314,7 @@ class SlotEngine:
             tokens=list(tokens), max_new=int(max_new),
             temperature=float(temperature), top_k=int(top_k),
             top_p=float(top_p), eos_id=int(eos_id), pad_id=int(pad_id),
-            seed=int(seed), min_new=int(min_new),
+            seed=int(seed), row=int(row), min_new=int(min_new),
             presence=float(presence_penalty),
             frequency=float(frequency_penalty),
             bias_idx=bias_idx, bias_val=bias_val,
@@ -371,22 +369,6 @@ class SlotEngine:
         model without routed experts."""
         describe = getattr(self.program, "expert_stats", None)
         return describe() if describe is not None else None
-
-    def round_times_ms(self) -> List[float]:
-        """Wall time of recent decode-only rounds (ms): dispatch +
-        token fetch + host bookkeeping, admission rounds excluded.
-        With lookahead this reflects the overlap actually achieved."""
-        return [t * 1e3 for t in list(self._round_times)]
-
-    def round_host_ms(self) -> List[float]:
-        """Host-only time of the same rounds (ms): round wall time
-        minus the time spent inside the jax calls (chunk dispatches
-        and the token fetch — where any device wait lands, whether
-        the backend blocks in ``device_get`` or, like CPU's bounded
-        in-flight queue, in the next dispatch). What remains —
-        queue/cancel checks, token copy-out, append bookkeeping,
-        streaming callbacks — is the host work each round pays."""
-        return [t * 1e3 for t in list(self._round_host_times)]
 
     # ----------------------------------------------------------- worker
 
@@ -634,7 +616,6 @@ class SlotEngine:
         windowed = self.window > 1
         while not self._stopped.is_set():
             t0 = time.perf_counter()
-            jax_s = 0.0  # time inside jax calls this cycle
             admitted = False
             if pending is None:
                 self._sweep_cancelled()
@@ -700,7 +681,6 @@ class SlotEngine:
                 except Exception as exc:  # noqa: BLE001
                     self._fail_and_rebuild(exc)
                     continue
-                jax_s += time.perf_counter() - tj
                 self.dispatches += program.dispatch_cost
             else:
                 handle, pending = pending, None
@@ -731,7 +711,6 @@ class SlotEngine:
                     self._fail_and_rebuild(exc)
                     pending = None
                     continue
-                jax_s += time.perf_counter() - tj
                 self.dispatches += program.dispatch_cost
             tj = time.perf_counter()
             phases.switch("engine.fetch", tj)
@@ -744,8 +723,7 @@ class SlotEngine:
                 self._fail_and_rebuild(exc)
                 pending = None
                 continue
-            tj, fetched = time.perf_counter(), tj
-            jax_s += tj - fetched
+            tj = time.perf_counter()
             # append, notify, harvest (and the next cycle's sweep and
             # free-slot scan, up to its first boundary)
             phases.switch("engine.deliver", tj)
@@ -768,7 +746,3 @@ class SlotEngine:
                     self._notify(req, state.emitted[before:])
                 if ended:
                     self._harvest(i)
-            if not admitted:
-                wall = time.perf_counter() - t0
-                self._round_times.append(wall)
-                self._round_host_times.append(max(wall - jax_s, 0.0))

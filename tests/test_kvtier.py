@@ -339,53 +339,49 @@ def test_spill_disabled_keeps_stats_schema_zeroed():
 
 
 def test_reuse_admission_readmits_from_spill_byte_parity():
-    """End to end on a real model: a server whose device LRU holds ONE
-    entry + a spill tier produces byte-identical tokens to a server
-    with a big device LRU — the host roundtrip must be invisible to
-    the rewind+extend protocol."""
-    from types import SimpleNamespace
-
+    """End to end on a real model: an engine whose device LRU holds
+    ONE entry + a spill tier produces byte-identical tokens to an
+    engine with a big device LRU — the host roundtrip must be
+    invisible to the rewind+extend protocol."""
     from containerpilot_tpu.models.transformer import (
         TransformerConfig,
         init_params,
     )
-    from containerpilot_tpu.workload.serve_prefix import (
-        generate_with_prefix,
-    )
+    from containerpilot_tpu.workload.serve_slots import SlotEngine
 
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
         max_seq_len=128, dtype=jnp.float32,
     )
     params = init_params(jax.random.PRNGKey(0), cfg)
-
-    def srv(pc):
-        return SimpleNamespace(
-            cfg=cfg, params=params, max_len=128, prefill_chunk=0,
-            prefix_cache=pc, batch_stats={"calls": 0, "rows": 0},
-        )
-
-    spilling = srv(PrefixCache(1, spill=HostSpillTier(1 << 20)))
-    roomy = srv(PrefixCache(4))
+    caches = {
+        "spilling": PrefixCache(1, spill=HostSpillTier(1 << 20)),
+        "roomy": PrefixCache(4),
+    }
 
     turn_a = list(range(1, 33))          # 32-token history A
     turn_b = [9] * 32                    # unrelated history B
     turn_a2 = turn_a + [50] * 16         # A's next turn
 
     outs = {}
-    for name, s in (("spilling", spilling), ("roomy", roomy)):
-        outs[name] = [
-            generate_with_prefix(s, turn_a, 8, 0.0, 0, 0.0, -1, 0),
-            generate_with_prefix(s, turn_b, 8, 0.0, 0, 0.0, -1, 0),
-            generate_with_prefix(s, turn_a2, 8, 0.0, 0, 0.0, -1, 0),
-        ]
+    for name, pc in caches.items():
+        engine = SlotEngine(
+            cfg, params, 128, slots=1, chunk=4, prefix_cache=pc
+        )
+        try:
+            outs[name] = [
+                engine.submit(turn, 8).result(timeout=120)
+                for turn in (turn_a, turn_b, turn_a2)
+            ]
+        finally:
+            engine.stop()
     assert outs["spilling"] == outs["roomy"]
-    stats = spilling.prefix_cache.stats
+    stats = caches["spilling"].stats
     # A was evicted to host RAM by B, then readmitted for turn 2
     assert stats["spilled"] >= 1, stats
     assert stats["readmitted"] == 1, stats
     assert stats["hits"] == 1, stats
     assert stats["tokens_reused"] >= 16, stats
-    # the roomy server reused straight from device: same hit account
-    assert roomy.prefix_cache.stats["hits"] == 1
-    assert roomy.prefix_cache.stats["readmitted"] == 0
+    # the roomy engine reused straight from device: same hit account
+    assert caches["roomy"].stats["hits"] == 1
+    assert caches["roomy"].stats["readmitted"] == 0

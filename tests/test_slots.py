@@ -34,16 +34,19 @@ def engine(params):
 
 def _solo(params, tokens, max_new, cfg=CFG, **kw):
     """Reference: solo generate with the SERVER's key convention (row
-    i of a request samples from fold_in(PRNGKey(seed), i) — the same
-    derivation the batcher/prefix/strategies paths use, so seeded
-    output is identical across serving configs), trimmed the way the
-    server trims (keep eos, drop the pads after it)."""
+    i of a request samples from fold_in(PRNGKey(seed), i), so seeded
+    output is identical across serving configs; ``row`` is that i),
+    trimmed the way the server trims (keep eos, drop the pads after
+    it)."""
     seed = kw.pop("seed", 0)
+    row_idx = kw.pop("row", 0)
     eos = kw.pop("eos_id", -1)
     out = generate(
         params, jnp.asarray([tokens], jnp.int32), cfg, max_new,
         MAX_LEN,
-        rng=jnp.stack([jax.random.fold_in(jax.random.PRNGKey(seed), 0)]),
+        rng=jnp.stack(
+            [jax.random.fold_in(jax.random.PRNGKey(seed), row_idx)]
+        ),
         eos_id=eos, **kw,
     )
     row = [int(t) for t in np.asarray(out)[0]]
@@ -292,6 +295,166 @@ def test_inference_server_slot_engine(run, params):
     assert outs[2]["tokens"][0] == _solo(
         params, [4, 5, 6, 7], 5, seed=2, temperature=0.5, top_k=10
     )
+
+
+def _serve(run, server, scenario, timeout=120):
+    """Boot ``server``, await ``scenario(post)`` where ``post(body)``
+    POSTs /v1/generate from a thread and returns (status, text), stop
+    the server, return what the scenario returned."""
+    import asyncio
+    import json
+    import urllib.error
+    import urllib.request
+
+    def post_sync(body):
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{server.port}/v1/generate",
+            data=json.dumps(body).encode(),
+            headers={"Content-Type": "application/json"},
+        )
+        try:
+            with urllib.request.urlopen(req, timeout=120) as resp:
+                return resp.status, resp.read().decode()
+        except urllib.error.HTTPError as exc:
+            return exc.code, exc.read().decode()
+
+    async def whole():
+        await server.run()
+        loop = asyncio.get_event_loop()
+        try:
+            return await scenario(
+                lambda body: loop.run_in_executor(None, post_sync, body)
+            )
+        finally:
+            await server.stop()
+
+    return run(whole(), timeout=timeout)
+
+
+def _default_server(params, **kwargs):
+    """A server built with NO ``slots`` argument."""
+    from containerpilot_tpu.workload.serve import InferenceServer
+
+    return InferenceServer(
+        CFG, params, "127.0.0.1", 0, max_len=MAX_LEN, **kwargs
+    )
+
+
+@pytest.mark.parametrize(
+    "n_rows,sampling",
+    [
+        (2, {}),
+        (2, {"temperature": 0.8, "top_k": 8, "seed": 5}),
+        (3, {}),
+        (3, {"temperature": 0.8, "top_k": 8, "seed": 5}),
+        # more rows than the default pool's 4 slots: the rest queue
+        # and the answer still comes back in the request's order
+        (6, {"temperature": 0.8, "top_k": 8, "seed": 9}),
+    ],
+    ids=["2-greedy", "2-sampled", "3-greedy", "3-sampled",
+         "6-sampled-over-4-slots"],
+)
+def test_multi_row_request_matches_generate_per_row(
+    run, params, n_rows, sampling
+):
+    """A /v1/generate request with several rows rides the engine row
+    by row: row i equals ``generate`` run alone on that row with key
+    fold_in(PRNGKey(seed), i), greedy and sampled alike."""
+    import json
+
+    rows = [[(7 * i + j) % 64 for j in range(1, 4)] for i in range(n_rows)]
+    server = _default_server(params)
+
+    async def scenario(post):
+        return await post(
+            {"tokens": rows, "max_new_tokens": 7, **sampling}
+        )
+
+    status, text = _serve(run, server, scenario)
+    assert status == 200, text
+    assert json.loads(text)["tokens"] == [
+        _solo(params, row, 7, row=i, **sampling)
+        for i, row in enumerate(rows)
+    ]
+    assert server.slot_engine.slots == 4  # the constructor's default
+
+
+def test_n_samples_are_the_rows_keys(run, params):
+    """``n`` = 3 sampled: three different rows, each equal to a
+    one-row engine request under the same key (row i of the seed)."""
+    import asyncio
+    import json
+
+    body = {"tokens": [[1, 2, 3]], "max_new_tokens": 8, "n": 3,
+            "temperature": 0.9, "seed": 11}
+    server = _default_server(params)
+
+    async def scenario(post):
+        answer = await post(body)
+        alone = [
+            await asyncio.wrap_future(server.slot_engine.submit(
+                [1, 2, 3], 8, temperature=0.9, seed=11, row=i
+            ))
+            for i in range(3)
+        ]
+        return answer, alone
+
+    (status, text), alone = _serve(run, server, scenario)
+    assert status == 200, text
+    samples = json.loads(text)["tokens"]
+    assert samples == alone
+    assert len({tuple(row) for row in samples}) == 3
+    assert samples == [
+        _solo(params, [1, 2, 3], 8, temperature=0.9, seed=11, row=i)
+        for i in range(3)
+    ]
+
+
+def test_default_server_long_prompt_and_prefix_hit_match_generate(
+    run, params
+):
+    """What the one-shot chunked and prefix paths existed for, on a
+    server built with no ``slots`` argument: a prompt longer than
+    --prefill-chunk (cold, in pieces) and the next turn's prefix-cache
+    hit (rewind + extend, in pieces) both equal ``generate``."""
+    import json
+
+    server = _default_server(
+        params, prefill_chunk=4, prefix_cache_entries=2
+    )
+    history = [(i * 5 + 2) % 64 for i in range(20)]  # >= MIN_REUSE
+    turn2 = history + [9, 9, 5]
+
+    async def scenario(post):
+        cold = await post({"tokens": [history], "max_new_tokens": 6})
+        hit = await post({"tokens": [turn2], "max_new_tokens": 6,
+                          "temperature": 0.7, "seed": 3})
+        return cold, hit, dict(server.prefix_cache.stats)
+
+    cold, hit, stats = _serve(run, server, scenario)
+    assert cold[0] == 200 and hit[0] == 200, (cold, hit)
+    assert json.loads(cold[1])["tokens"] == [_solo(params, history, 6)]
+    assert json.loads(hit[1])["tokens"] == [
+        _solo(params, turn2, 6, temperature=0.7, seed=3)
+    ]
+    assert stats["misses"] == 1 and stats["hits"] == 1, stats
+    assert stats["tokens_reused"] > 0, stats
+
+
+@pytest.mark.parametrize("where", ["constructor", "cli"])
+def test_zero_slots_refused(params, where, capsys):
+    """``slots`` is a capacity, never a switch: 0 (the old "no slot
+    engine" mode) is refused where it is given."""
+    if where == "constructor":
+        with pytest.raises(ValueError, match="slots must be >= 1"):
+            _default_server(params, slots=0)
+        return
+    from containerpilot_tpu.workload.serve_cli import build_arg_parser
+
+    assert build_arg_parser().parse_args([]).slots == 4
+    with pytest.raises(SystemExit):
+        build_arg_parser().parse_args(["--slots", "0"])
+    assert "--slots: must be >= 1" in capsys.readouterr().err
 
 
 def test_stream_deltas_concatenate_to_result(params, engine):
@@ -613,57 +776,34 @@ def test_server_completions_stream_matches_non_streamed(run):
 
 
 def test_server_stream_rejects_bad_compositions(run, params):
-    """stream without --slots, and stream+stop, fail with clean 422s
-    before any decode starts."""
-    import asyncio
-    import json as json_mod
-    import urllib.error
-    import urllib.request
+    """stream + stop, + logprobs, + several rows fail with clean 422s
+    before any decode starts; a server built with no ``slots``
+    argument streams like any other."""
+    server = _default_server(params)
+    base = {"tokens": [[1, 2]], "max_new_tokens": 4, "stream": True}
 
-    from containerpilot_tpu.workload.serve import InferenceServer
+    async def scenario(post):
+        import asyncio
 
-    vanilla = InferenceServer(CFG, params, "127.0.0.1", 0,
-                              max_len=MAX_LEN)
-    slotted = InferenceServer(CFG, params, "127.0.0.1", 0,
-                              max_len=MAX_LEN, slots=1)
-
-    def post_status(port, body):
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{port}/v1/generate",
-            data=json_mod.dumps(body).encode(),
-            headers={"Content-Type": "application/json"},
-        )
-        try:
-            with urllib.request.urlopen(req, timeout=120) as resp:
-                return resp.status, resp.read().decode()
-        except urllib.error.HTTPError as exc:
-            return exc.code, exc.read().decode()
-
-    async def scenario():
-        await vanilla.run()
-        await slotted.run()
+        bad = [
+            await post({**base, "stop": [[3]]}),
+            await post({**base, "logprobs": True}),
+            await post({**base, "tokens": [[1, 2], [3, 4]]}),
+        ]
         loop = asyncio.get_event_loop()
-        no_slots = await loop.run_in_executor(
-            None, lambda: post_status(
-                vanilla.port,
-                {"tokens": [[1, 2]], "max_new_tokens": 4,
-                 "stream": True},
-            )
+        events = await loop.run_in_executor(
+            None, _read_sse, server.port, base
         )
-        with_stop = await loop.run_in_executor(
-            None, lambda: post_status(
-                slotted.port,
-                {"tokens": [[1, 2]], "max_new_tokens": 4,
-                 "stream": True, "stop": [[3]]},
-            )
-        )
-        await vanilla.stop()
-        await slotted.stop()
-        return no_slots, with_stop
+        return bad, events
 
-    no_slots, with_stop = run(scenario())
-    assert no_slots[0] == 422 and "--slots" in no_slots[1]
+    (with_stop, with_logprobs, two_rows), events = _serve(
+        run, server, scenario
+    )
     assert with_stop[0] == 422 and "stop" in with_stop[1]
+    assert with_logprobs[0] == 422 and "logprobs" in with_logprobs[1]
+    assert two_rows[0] == 422 and "single row" in two_rows[1]
+    streamed = sum((e["tokens"] for e in events if "tokens" in e), [])
+    assert streamed == _solo(params, [1, 2], 4)
 
 
 def test_prefix_cache_admission_matches_generate(params):
@@ -729,7 +869,7 @@ def test_slots_reject_max_len_too_small_for_warmup(params):
     submit()'s ValueError and kill the server mid-startup."""
     from containerpilot_tpu.workload.serve import InferenceServer
 
-    with pytest.raises(ValueError, match="max_len >= slot_chunk"):
+    with pytest.raises(ValueError, match="max_len must be >= slot_chunk"):
         InferenceServer(
             CFG, params, "127.0.0.1", 0, max_len=8, slots=2,
             slot_chunk=8,

@@ -333,51 +333,69 @@ def test_warm_bucket_marker_roundtrip_and_tolerance(tmp_path):
     assert compile_cache_note("") == ""
 
 
-def test_warmup_skips_marked_buckets(run, tmp_path, monkeypatch):
-    """Two same-shaped servers sharing a warm-bucket marker dir: the
-    first warms and marks; the second's warmup drives ZERO decode
-    compiles (the marker skip — its compile_warmup seconds collapse,
-    which is the cold-start lever the shared cache exists for). The
+@pytest.mark.parametrize(
+    "marked,warms",
+    [
+        # no marker yet: warm, mark, and the next launch skips
+        (None, [1, 0]),
+        # a marker an older build wrote, which also listed the
+        # one-shot programs' buckets: read without error, skipped
+        (["p16", "p4", "slots"], [0]),
+        # one that lists nothing this build compiles: warm, then mark
+        (["p16", "p4"], [1, 0]),
+    ],
+    ids=["unmarked", "older-build-warm", "older-build-one-shot-only"],
+)
+def test_warmup_skips_marked_buckets(
+    run, tmp_path, monkeypatch, marked, warms
+):
+    """Same-shaped servers sharing a warm-bucket marker dir: a launch
+    that finds its ``slots`` bucket marked drives ZERO admissions
+    through the engine (the marker skip — its compile_warmup seconds
+    collapse, which is the cold-start lever the shared cache exists
+    for), any other launch drives exactly warmup's one dummy request
+    and marks. Warmup compiles what requests run: it never calls the
+    one-shot ``generate``, and /health turns 200 without it. The
     marker dir here is the test's own, kept apart from jax's compile
     cache: the server never sets a cache directory."""
     import jax
 
     from containerpilot_tpu.models import decode as decode_mod
+    from containerpilot_tpu.workload.modelcfg import WARM_MARKER
     from containerpilot_tpu.workload.serve import InferenceServer
 
     cfg, params = _tiny_model()
-    calls = {"n": 0}
-    real_generate = decode_mod.generate
 
-    def counting_generate(*args, **kwargs):
-        calls["n"] += 1
-        return real_generate(*args, **kwargs)
+    def no_generate(*_args, **_kwargs):
+        raise AssertionError("warmup called the one-shot generate")
 
-    monkeypatch.setattr(decode_mod, "generate", counting_generate)
+    monkeypatch.setattr(decode_mod, "generate", no_generate)
     cache_in_force = jax.config.jax_compilation_cache_dir
 
     async def scenario():
-        first = InferenceServer(
-            cfg, params, "127.0.0.1", 0, max_len=64,
-            compile_cache_dir=str(tmp_path),
-        )
-        await first.run()
-        await first.stop()
-        after_first = calls["n"]
-        assert after_first > 0
-        second = InferenceServer(
-            cfg, params, "127.0.0.1", 0, max_len=64,
-            compile_cache_dir=str(tmp_path),
-        )
-        await second.run()
-        await second.stop()
-        assert calls["n"] == after_first  # every bucket skipped
-        assert second.ready
-        # the cc= advertisement was computed once at warmup end
-        _digest, adv_dir = parse_compile_cache_note(
-            second.compile_cache_note()
-        )
-        assert adv_dir == str(tmp_path)
+        for launch, admissions in enumerate(warms):
+            server = InferenceServer(
+                cfg, params, "127.0.0.1", 0, max_len=64,
+                compile_cache_dir=str(tmp_path),
+            )
+            fingerprint = server._warmup_fingerprint()
+            if marked is not None and launch == 0:
+                (tmp_path / WARM_MARKER).write_text(
+                    json.dumps({fingerprint: marked})
+                )
+            await server.run()
+            health = await server._health(None)
+            await server.stop()
+            assert server.slot_engine.phases.admissions == admissions
+            assert server.ready and health.status == 200
+            assert load_warm_buckets(str(tmp_path), fingerprint) == (
+                {"slots"} | set(marked or ())
+            )
+            # the cc= advertisement was computed once at warmup end
+            _digest, adv_dir = parse_compile_cache_note(
+                server.compile_cache_note()
+            )
+            assert adv_dir == str(tmp_path)
 
     run(scenario(), timeout=300)
     # the constructor argument placed the marker, not jax's cache

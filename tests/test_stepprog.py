@@ -390,7 +390,7 @@ def test_speculative_program_rejects_bad_shapes():
 
 def test_server_speculative_rides_engine(run):
     """Server-level: a greedy /v1/generate on a --draft-layers server
-    routes through the speculative ENGINE (not serve_strategies),
+    routes through the speculative ENGINE (not the slot engine),
     matches plain greedy decode, and folds its dispatch/token pair
     into /v1/model + /v1/goodput."""
     import asyncio
