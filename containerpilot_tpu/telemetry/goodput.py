@@ -119,7 +119,9 @@ ENGINE_CYCLE_PHASES = (
     "engine.fetch", "engine.deliver",
 )
 #: every phase name: the cycle phases, then the children nested
-#: inside ``engine.admit`` (and ``kvtier.*`` inside those)
+#: inside ``engine.admit`` (``kvtier.readmit`` inside ``reuse``), and
+#: ``kvtier.spill``, which runs beside them on the tier's ``kv-spill``
+#: thread since the spill is deferred (kvtier/spill.py)
 ENGINE_PHASES = ENGINE_CYCLE_PHASES + (
     "engine.admit.reuse", "kvtier.readmit", "engine.admit.prefill",
     "engine.admit.store", "kvtier.spill", "engine.admit.first_token",
@@ -182,7 +184,10 @@ class EnginePhases:
     phase's name (a flag test when no trace is running; absent where
     jax is). Written by the engine's worker thread, read by the HTTP
     thread through ``snapshot``: plain float adds under the GIL, no
-    lock on the decode loop."""
+    lock on the decode loop. ``span`` alone has a second writer (the
+    spill tier's ``kv-spill`` thread closes ``kvtier.spill`` while the
+    engine closes an admission's children), so its adds take a lock:
+    a handful per admission, none per decode window."""
 
     def __init__(self) -> None:
         self.phase_s: Dict[str, float] = {p: 0.0 for p in ENGINE_PHASES}
@@ -200,6 +205,7 @@ class EnginePhases:
         self.latent_readmit_bytes = 0
         self._open: Optional[str] = None
         self._since = 0.0
+        self._span_lock = threading.Lock()
         self._open_annotation: Any = None
         self._annotation_class: Any = False  # resolved on first use
 
@@ -252,8 +258,10 @@ class EnginePhases:
         try:
             yield
         finally:
-            self.phase_s[phase] += time.perf_counter() - t0
-            self.phase_n[phase] += 1
+            spent = time.perf_counter() - t0
+            with self._span_lock:
+                self.phase_s[phase] += spent
+                self.phase_n[phase] += 1
             if annotation is not None:
                 annotation.__exit__(None, None, None)
 

@@ -1706,6 +1706,9 @@ class InferenceServer:
         from ..kvtier.handoff import plan_migration, push_kv
 
         loop = asyncio.get_event_loop()
+        # rows still on their way to the host tier land first: the
+        # enumeration reads the cache for good
+        await loop.run_in_executor(None, pc.flush)
         keys = await loop.run_in_executor(None, pc.export_keys)
         plan = plan_migration(
             keys, [(t[0], t[3]) for t in targets]

@@ -7,10 +7,12 @@ every turn re-sends a long shared history.
 
 Thread safety: the digest, the KV export/pull verbs and the drain
 migration read the cache from the event loop's executor threads while
-the store/evict side runs on the slot engine's thread, so every
-OrderedDict access holds ``_lock`` (round-2 review: a concurrent
+the store/evict side runs on the slot engine's thread and an evicted
+row lands in the spill tier on the tier's ``kv-spill`` thread, so
+every OrderedDict access holds ``_lock`` (round-2 review: a concurrent
 request could previously hit "OrderedDict mutated during iteration"
-and surface as a 500).
+and surface as a 500), and so does the one stat two threads write
+(``spill_bytes``).
 
 With a **spill tier** attached (``kvtier.HostSpillTier``), LRU
 eviction moves the entry's KV to byte-budgeted host RAM instead of
@@ -18,7 +20,11 @@ dropping it, and a later match readmits it through the SAME
 ``get``/``reuse_admission`` path — the slot engines and the rewind+
 extend protocol never see the difference, only the stats do
 (``spilled``/``readmitted``/``spill_bytes``, zeroed when the tier is
-disabled so the ``/v1/model`` schema stays stable either way).
+disabled so the ``/v1/model`` schema stays stable either way). The
+eviction only HANDS the row to the tier (``HostSpillTier.defer``): the
+copy to the host runs behind the engine's back, the row is matched and
+readmitted at every instant in between, and ``spilled`` counts it when
+it lands. ``flush()`` waits for the rows in flight.
 """
 from __future__ import annotations
 
@@ -123,6 +129,8 @@ class PrefixCache:
         cache = self.spill.take(key)
         if cache is None:
             return None
+        # back from the tier: by a device_put, or the device arrays
+        # themselves where the row was still in flight
         self.stats["readmitted"] += 1
         self.readmit_seconds += time.monotonic() - t0
         # back into the device LRU as MRU (which may spill another
@@ -150,10 +158,28 @@ class PrefixCache:
             return 0
         adopted = self.spill.put_host(key, host_tree)
         if adopted:
-            with self._lock:
-                self.version += 1
-            self.stats["spill_bytes"] = self.spill.bytes_used
+            self._tier_changed()
         return adopted
+
+    def _tier_changed(self) -> None:
+        """The spill tier's contents moved: republish the digest and
+        the tier's size (read and written under the lock: the engine's
+        thread and the ``kv-spill`` thread both come here)."""
+        with self._lock:
+            self.version += 1
+            self.stats["spill_bytes"] = self.spill.bytes_used
+
+    def _spill_landed(self, accepted: bool) -> None:
+        """On the ``kv-spill`` thread, once a deferred row is in the
+        tier (or was refused, or its copy failed)."""
+        if accepted:
+            self.stats["spilled"] += 1
+        self._tier_changed()
+
+    def flush(self, timeout: Optional[float] = None) -> bool:
+        """Wait until no evicted row is still on its way to the host
+        (``HostSpillTier.flush``); True without a tier."""
+        return self.spill is None or self.spill.flush(timeout)
 
     def store(self, key: Tuple[int, ...], cache: Any) -> None:
         self.phases.store_bytes += tree_nbytes(cache)
@@ -172,12 +198,11 @@ class PrefixCache:
                 # below the reuse floor it can never match again —
                 # not worth the host RAM or the transfer
                 continue
-            # device->host happens inside put(), outside our lock
-            if self.spill.put(k, c):
-                self.stats["spilled"] += 1
-        if evicted:
-            self.version += 1
-        self.stats["spill_bytes"] = self.spill.bytes_used
+            # hand-over only: device->host happens on the tier's
+            # thread, and the key stays matchable all the while
+            self.spill.defer(k, c, self._spill_landed)
+        # a take() before this store, or the hand-over, moved the tier
+        self._tier_changed()
 
     def export_keys(self) -> List[Tuple[int, ...]]:
         """Every migratable prompt key this cache holds, device tier

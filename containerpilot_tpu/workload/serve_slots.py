@@ -221,8 +221,9 @@ class SlotEngine:
         # A bare engine (no ledger) keeps its own accumulator.
         self.phases = ledger.engine if ledger is not None else EnginePhases()
         if prefix_cache is not None:
-            # store/spill/readmit happen inside admissions: their
-            # seconds and bytes belong to the same accumulator
+            # store and readmit happen inside admissions, the spill
+            # behind them: their seconds and bytes belong to the same
+            # accumulator
             prefix_cache.attach_phases(self.phases)
         # dispatch accounting for the dispatches/token series (the
         # number the ROADMAP's megakernel item must drive down): one
@@ -336,6 +337,10 @@ class SlotEngine:
             self._stopped.set()
         self._queue.put(None)  # wake the worker
         self._thread.join(timeout=30)
+        if self.prefix_cache is not None:
+            # the evicted rows still on their way to the host land
+            # before anyone reads the stopped engine's books
+            self.prefix_cache.flush(timeout=30)
         for slot in self._active:
             if slot is not None and not slot.req.future.done():
                 slot.req.future.cancel()
@@ -410,7 +415,9 @@ class SlotEngine:
                 logits, row_cache = self._cold_prefill(req)
         if use_pc:
             # store the completed prompt's cache for future turns
-            # (standalone buffer — see the __init__ soundness note)
+            # (standalone buffer — see the __init__ soundness note);
+            # a row this evicts is only handed to the spill tier here,
+            # its copy to the host runs on the tier's own thread
             with self.phases.span("engine.admit.store"):
                 pc.store(tuple(req.tokens), row_cache)
         return logits, row_cache

@@ -111,6 +111,45 @@ def run():
     return _run
 
 
+class SpillGate:
+    """Stands in the way of the spill tier's ``kv-spill`` worker at
+    ``jax.device_get`` (every other thread's calls pass): shut until
+    ``open()``, so "a row is in flight" is a state a test HOLDS, not a
+    race it hopes to win. Every wait has a timeout."""
+
+    WAIT = 60.0
+
+    def __init__(self, monkeypatch) -> None:
+        import threading
+
+        self.opened = threading.Event()
+        self.reached = threading.Event()  # the worker is at a copy
+        self.fail = 0  # this many copies raise once let through
+        real = jax.device_get
+
+        def gated(tree):
+            if threading.current_thread().name != "kv-spill":
+                return real(tree)
+            self.reached.set()
+            assert self.opened.wait(self.WAIT), "the gate was never opened"
+            if self.fail:
+                self.fail -= 1
+                raise RuntimeError("the copy to the host failed")
+            return real(tree)
+
+        monkeypatch.setattr(jax, "device_get", gated)
+
+    def open(self) -> None:
+        self.opened.set()
+
+
+@pytest.fixture
+def spill_gate(monkeypatch):
+    gate = SpillGate(monkeypatch)
+    yield gate
+    gate.open()  # never leave a worker behind a shut gate
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_between_modules():
     """Drop compiled executables when a test module finishes.

@@ -145,6 +145,9 @@ def test_store_spill_and_readmit_bytes_under_a_prefix_cache(params):
     try:
         eng.submit(first, max_new=4).result(timeout=120)
         eng.submit(list(range(21, 41)), max_new=4).result(timeout=120)
+        # the first row's copy to the host runs behind the engine: let
+        # it land, so the next turn readmits it by a device_put
+        assert pc.flush(timeout=120)
         eng.submit(first + [5, 6, 7], max_new=4).result(timeout=120)
     finally:
         eng.stop()
@@ -165,6 +168,80 @@ def test_store_spill_and_readmit_bytes_under_a_prefix_cache(params):
         phases.phase_s["engine.admit.store"]
         + phases.phase_s["engine.admit.first_token"]
     )
+
+
+def test_tokens_are_delivered_while_an_evicted_row_is_still_spilling(
+    params, spill_gate
+):
+    """The engine's thread only HANDS an evicted row to the spill
+    tier: with the copy to the host gated shut, the evicting request
+    and the one after it run to their last token, the row in flight
+    is readmitted from the device itself, and ``stop`` leaves nothing
+    pending and ``kvtier.spill``'s books as a waited-for copy's."""
+    tier = HostSpillTier(1 << 22)
+    pc = PrefixCache(1, spill=tier)
+    eng = _engine(params, prefix_cache=pc)
+    first, second = list(range(1, 21)), list(range(21, 41))
+    try:
+        eng.submit(first, max_new=4).result(timeout=120)
+        streamed = []
+        out = eng.submit(
+            second, max_new=12, on_tokens=streamed.extend
+        ).result(timeout=120)  # its admission evicts ``first``'s row
+        assert spill_gate.reached.wait(120)
+        assert len(out) == 12 and streamed == out
+        snap = tier.snapshot()
+        assert (snap["pending"], snap["deferred"], snap["spilled"]) == (1, 1, 0)
+        assert eng.phases.phase_n["kvtier.spill"] == 0  # still copying
+        assert eng.phases.phase_n["engine.admit.store"] == 2
+        # the next turn of the first session finds its row in flight
+        eng.submit(first + [5, 6, 7], max_new=4).result(timeout=120)
+        assert tier.stats["pending_hits"] == 1
+        assert pc.stats["hits"] == 1 and pc.stats["readmitted"] == 1
+        assert eng.phases.phase_n["kvtier.readmit"] == 0  # no device_put
+    finally:
+        spill_gate.open()
+        eng.stop()
+    snap = tier.snapshot()
+    assert snap["pending"] == 0 and snap["failed"] == 0
+    assert snap["backpressure_n"] == 0
+    # handed over: ``first`` (taken back in flight), ``second``, and
+    # ``first`` again when its longer turn was stored
+    assert snap["deferred"] == 3
+    assert snap["spilled"] == snap["entries"] == 2
+    assert pc.stats["spilled"] == 2
+    phases = eng.phases
+    # the copy the take overtook ran too; its bytes joined no book
+    assert phases.phase_n["kvtier.spill"] == 3
+    assert phases.spill_bytes == tier.bytes_used > 0
+    assert phases.readmit_bytes == 0
+    # four rows stored (the readmit's among them), two of them landed
+    assert 2 * phases.spill_bytes == phases.store_bytes
+
+
+def test_profiler_trace_holds_the_spill_on_the_kv_spill_line(
+    params, tmp_path
+):
+    """``kvtier.spill`` lies on the tier's own ``kv-spill`` line, and
+    no longer under the engine's admission."""
+    pc = PrefixCache(1, spill=HostSpillTier(1 << 22))
+    eng = _engine(params, prefix_cache=pc)
+    try:
+        eng.submit(list(range(1, 21)), max_new=2).result(timeout=120)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.submit(list(range(21, 41)), max_new=2).result(timeout=120)
+            eng.submit(list(range(41, 61)), max_new=2).result(timeout=120)
+            assert pc.flush(timeout=120)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    lines = _host_lines(str(tmp_path))
+    assert "kv-spill" in lines, sorted(lines)
+    assert "kvtier.spill" in lines["kv-spill"], sorted(lines["kv-spill"])
+    assert "engine.admit.store" in lines["slot-engine"]
+    assert "kvtier.spill" not in lines["slot-engine"]
 
 
 OLD_GOODPUT_KEYS = {
@@ -343,7 +420,10 @@ def test_lowered_programs_name_the_layer_maps_scopes(kind, wanted, params):
 
 @pytest.mark.parametrize("kind", ["chunk", "window"])
 def test_decode_programs_are_still_named_jit_run(kind, params):
-    """benchmark/layer_metrics/decode_programs.py finds the decode
-    programs by this module name."""
+    """benchmark/layer_metrics/decode_programs.py rests on two names:
+    it finds the decode programs by this module name, ``jit_run``, and
+    counts their token-steps by the executions of the scope ``sample``
+    inside them (pinned for both programs by
+    ``test_lowered_programs_name_the_layer_maps_scopes``)."""
     text = _lowered(kind, params).as_text()
     assert re.search(r"module @jit_run\b", text), text[:200]

@@ -365,6 +365,7 @@ def test_cached_latent_rows_give_the_logits_of_an_uncached_run(model, path):
         _l, other_row = _jitted_prefill(cfg, MAX_LEN)(
             params, jnp.asarray([other], jnp.int32))
         cache.store(tuple(other), other_row)  # pushes ``base`` to the host
+        assert cache.flush(timeout=30)  # the copy runs behind the store
         assert cache.stats["spilled"] == 1
         assert cache.phases.latent_spill_bytes == latent
     hit = reuse_admission(cache, wanted, cfg, params)
