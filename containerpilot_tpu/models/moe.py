@@ -1,10 +1,13 @@
-"""Routed experts for the serving family of ``models/mla_moe.py``.
+"""Routed experts for the serving families of ``models/mla_moe.py``
+and ``models/block_diffusion.py``.
 
 ``route_topk``: sigmoid scores over ALL experts in float32, top k,
-renormalise, scale. ``sparse_experts``: the part of the result that
-the experts HELD here give: assignments sorted by expert, cut into
-blocks of one expert each, one grouped SwiGLU per block that exists;
-no capacity, no token dropped, an expert nobody chose is never read.
+renormalise, scale. ``route_softmax``: the same with a softmax over all
+experts for the scores and no scale. ``sparse_experts``: the part of
+the result that the experts HELD here give: assignments sorted by
+expert, cut into blocks of one expert each, one grouped SwiGLU per
+block that exists; no capacity, no token dropped, an expert nobody
+chose is never read.
 """
 from __future__ import annotations
 
@@ -37,6 +40,29 @@ def route_topk(
         if normalise:
             top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
         return idx.astype(jnp.int32), top * scale
+
+
+def route_softmax(
+    h: jax.Array,         # [n, d_model]
+    router_w: jax.Array,  # [d_model, all experts]
+    k: int,
+    normalise: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """Qwen3-MoE's router (``norm_topk_prob``): ``p = softmax(h . w)``
+    in float32 over every expert, the ``k`` largest, gates ``p_e / sum
+    of the k``. Returns (expert ids [n, k] int32, gates [n, k]
+    float32)."""
+    with jax.named_scope("mlp.router"):
+        scores = jax.nn.softmax(jnp.einsum(
+            "nd,de->ne", h.astype(jnp.float32),
+            router_w.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        ), axis=-1)
+        top, idx = jax.lax.top_k(scores, k)
+        if normalise:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), top
 
 
 def expert_block(n_tokens: int, k: int, n_experts: int) -> int:

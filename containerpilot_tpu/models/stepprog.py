@@ -12,7 +12,9 @@ as traffic changes:
 
 - ``admit(slot, req, logits, row_cache)`` — write one prefilled
   request into ``slot`` and return its first sampled token (a host
-  int). The ENGINE computes the prefill and passes the result in:
+  int), or None where the prefill's logits give no token (block
+  diffusion: a row's first tokens come with its first whole block).
+  The ENGINE computes the prefill and passes the result in:
   prefix-cache rewind+extend, cp-ring, chunked and plain prefill stay
   engine policy, shared identically by every program.
 - ``dispatch(budgets, fused)`` — advance every live slot by up to
@@ -25,7 +27,10 @@ as traffic changes:
 - ``tokens(handle)`` — the round trip: fetch the handle's tokens (the
   one deliberate host sync per window) and return
   ``(toks [S, W], valid [S], rounds_run)`` where ``valid[i]`` bounds
-  the tokens slot i actually produced (the engine appends
+  the tokens slot i actually produced, anything from 0 to W: a step
+  need not yield one token per row (a speculative round yields up to
+  k + 1, a block-diffusion forward a whole block or nothing), and W
+  is whatever this window's fullest row produced (the engine appends
   ``toks[i, :valid[i]]`` through the shared ``append_chunk``
   convention, so eos/max_new capping stays in one place).
 - ``retire(slot)`` — free one row (harvest or cancel); pads follow
@@ -51,8 +56,13 @@ fused-window programs), ``models.quantized.QuantizedStepProgram``
 (the same programs over int8 weights — the forward dequantizes per
 layer, so composition is structural) and
 ``models.speculative.SpeculativeStepProgram`` (draft/verify rounds:
-multi-token emission per dispatch). ``make_step_program`` picks the
-right default for a params pytree.
+multi-token emission per dispatch) and
+``models.block_diffusion.BlockStepProgram`` (a pool forward reveals
+several tokens of a row's block, or none; blocks are handed over
+whole). ``make_step_program`` picks the right default for a params
+pytree. A program may also bring ``validate(req)`` (refuse what it
+cannot serve, at submit) and ``warm_new`` (the new tokens a warm-up
+request needs to reach the fused window).
 """
 from __future__ import annotations
 
@@ -206,8 +216,15 @@ def make_step_program(
 ):
     """The default step program for a params pytree: quantized params
     get the quantized program (same device programs, the composition
-    made explicit and validated), everything else the plain one."""
+    made explicit and validated), everything else the plain one. A
+    family whose step is not one token per row brings its own
+    (``cfg.family.make_step_program``: models/block_diffusion.py)."""
     from .quantized import QuantizedStepProgram, is_quantized
+
+    own = getattr(getattr(cfg, "family", None), "make_step_program", None)
+    if own is not None:
+        return own(cfg, params, max_len, slots, chunk, rounds=rounds,
+                   out_sharding=out_sharding)
 
     kind = (
         QuantizedStepProgram if is_quantized(params)

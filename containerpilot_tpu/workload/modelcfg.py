@@ -219,8 +219,10 @@ def load_model_file(path: str, max_len: int) -> Any:
     benchmark's configuration files are such files, with their notes
     beside the keys). The architecture is told by its keys:
     ``kv_lora_rank`` is latent attention over routed experts
-    (models/mla_moe.py). A file of another architecture is refused
-    with its name; nothing is guessed."""
+    (models/mla_moe.py), a ``diffusion`` group beside ``num_experts``
+    is generation by diffusion over blocks (models/block_diffusion.py).
+    A file of another architecture is refused with its name; nothing
+    is guessed."""
     import hashlib
     import json as json_mod
 
@@ -230,14 +232,18 @@ def load_model_file(path: str, max_len: int) -> Any:
         config = json_mod.loads(raw)
     except (OSError, ValueError) as exc:
         raise SystemExit(f"--model-config {path}: {exc}") from None
-    if "kv_lora_rank" not in config or "n_routed_experts" not in config:
+    if "kv_lora_rank" in config and "n_routed_experts" in config:
+        from ..models.mla_moe import from_published
+    elif "diffusion" in config and "num_experts" in config:
+        from ..models.block_diffusion import from_published
+    else:
         raise SystemExit(
             f"--model-config {path}: model_type "
             f"{config.get('model_type')!r} has no builder here (latent "
-            "attention with routed experts is the one family read "
-            "from a file; the flagship block still takes its flags)"
+            "attention with routed experts and block diffusion over "
+            "routed experts are the families read from a file; the "
+            "flagship block still takes its flags)"
         )
-    from ..models.mla_moe import from_published
 
     digest = hashlib.blake2b(raw, digest_size=8).hexdigest()
     try:

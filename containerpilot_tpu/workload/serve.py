@@ -1049,6 +1049,9 @@ class InferenceServer:
                 # routed experts of the decode rounds (a held share
                 # of them: models/mla_moe.py); None for a dense model
                 "experts": self.slot_engine.expert_stats(),
+                # generation by diffusion over blocks: the routine and
+                # its counters (models/block_diffusion.py); else None
+                "diffusion": self.slot_engine.diffusion_stats(),
                 # SSE streaming rides the slot engine's chunks
                 "stream": True,
                 "draining": self.draining,
@@ -1169,6 +1172,10 @@ class InferenceServer:
             )
         if p["max_new_requested"] < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        refuse = getattr(
+            getattr(self.cfg, "family", None), "refuse_request", None)
+        if refuse is not None:
+            refuse(p)  # what this family's decode routine does not take
         return p
 
     def _beam(
@@ -1946,8 +1953,10 @@ class InferenceServer:
             # request doesn't stall on multi-second compilation
             # behind a 200 /health
             engine = self.slot_engine
-            warm_new = engine.chunk + (
-                2 if engine.window > 1 else 1
+            # (a program whose step is not one token says itself
+            # how many new tokens reach the fused window)
+            warm_new = getattr(engine.program, "warm_new", 0) or (
+                engine.chunk + (2 if engine.window > 1 else 1)
             )
             fut = engine.submit(
                 [0] * WARMUP_PROMPT_LEN, max_new=warm_new,

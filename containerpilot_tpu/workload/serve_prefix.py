@@ -243,11 +243,15 @@ class PrefixCache:
         return encoded
 
 
-def plan_reuse(pc: "PrefixCache", row: List[int]):
+def plan_reuse(pc: "PrefixCache", row: List[int], quantum: int = 1):
     """The ONE reuse plan the slot engines' admissions apply:
     longest cached match, suffix
     bucketed (a little of the matched prefix re-prefills so jit
     compiles one extend program per BUCKET, not per suffix length).
+    ``quantum`` (a configuration's ``reuse_quantum``) is the block a
+    cached position's keys depend on: the reuse is cut down to a
+    multiple of it, and since it never passes the match, only whole
+    matched blocks are reused.
     Returns (reuse_len, base_cache_or_None); counts a miss when no
     usable base exists."""
     plen = len(row)
@@ -257,6 +261,7 @@ def plan_reuse(pc: "PrefixCache", row: List[int]):
         suffix = plen - best_len
         bucket = max(1, -(-suffix // BUCKET) * BUCKET) if suffix > 0 else 1
         reuse = plen - min(bucket, plen)
+        reuse -= reuse % quantum
     base = pc.get(best_key) if reuse > 0 and best_key is not None else None
     return (reuse, base) if base is not None else (0, None)
 
@@ -276,7 +281,8 @@ def reuse_admission(pc: "PrefixCache", row_tokens: List[int], cfg,
 
     from ..models.decode import _jitted_extend, extend_pieces
 
-    reuse, base = plan_reuse(pc, row_tokens)
+    reuse, base = plan_reuse(
+        pc, row_tokens, getattr(cfg, "reuse_quantum", 1))
     if base is None:
         pc.stats["misses"] += 1
         return None

@@ -321,6 +321,9 @@ class SlotEngine:
             bias_idx=bias_idx, bias_val=bias_val,
             on_tokens=on_tokens, cancel=cancel, timings=timings,
         )
+        validate = getattr(self.program, "validate", None)
+        if validate is not None:
+            validate(req)  # what this decode strategy cannot serve
         if timings is not None:
             timings["enqueued"] = time.monotonic()
         req.enqueued = time.perf_counter()
@@ -373,6 +376,12 @@ class SlotEngine:
         rounds fetched so far (``/v1/model`` ``experts``); None for a
         model without routed experts."""
         describe = getattr(self.program, "expert_stats", None)
+        return describe() if describe is not None else None
+
+    def diffusion_stats(self) -> Optional[dict]:
+        """The step program's block-diffusion routine and counters
+        (``/v1/model`` ``diffusion``); None for any other family."""
+        describe = getattr(self.program, "diffusion_stats", None)
         return describe() if describe is not None else None
 
     # ----------------------------------------------------------- worker
@@ -493,8 +502,11 @@ class SlotEngine:
             first_host = self.program.admit(
                 slot_id, req, logits, row_cache
             )
-        state = _Slot(req=req, emitted=[first_host])
-        if first_host == req.eos_id or req.max_new <= 1:
+        # a program whose prefill yields no token (block diffusion)
+        # returns None: the row's first tokens come from its dispatches
+        first = [] if first_host is None else [first_host]
+        state = _Slot(req=req, emitted=first)
+        if first and (first_host == req.eos_id or req.max_new <= 1):
             state.finished = True
         self._active[slot_id] = state
         # one admission = one prefill's worth of dispatches (the
@@ -502,14 +514,15 @@ class SlotEngine:
         # counted as ONE toward dispatches/token so the series tracks
         # the steady-state decode shape the megakernel work targets
         self.dispatches += 1
-        self.tokens_out += 1
+        self.tokens_out += len(first)
         if req.timings is not None:
             # prefill stage ends here: prompt prefilled, token 0
             # sampled, row inserted — everything after is decode
             req.timings["prefill_done"] = time.monotonic()
         if self.ledger is not None:
             self.ledger.enter("decode")
-        self._notify(req, [first_host])
+        if first:
+            self._notify(req, first)
 
     def _harvest(self, slot_id: int) -> None:
         state = self._active[slot_id]

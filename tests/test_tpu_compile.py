@@ -497,3 +497,61 @@ def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
             f"{found.group(1)}: {found.group(3)} of bf16"
             f"[{found.group(2)}] outside a fusion"
         )
+
+
+@pytest.mark.parametrize("program", ["chunk", "window"])
+def test_block_diffusion_pool_forward_fits_the_chip(chip, program):
+    """The block-diffusion step program's two programs at the
+    benchmark's real size (benchmark/configs/sdar-30b-a3b-serve.json:
+    published widths, all 128 experts of six layers, the whole
+    vocabulary, 64 slots x 3,072 positions, 6 pool forwards a round),
+    compiled for the v5e: weights (8.72 GB), pool (2.42 GB) and
+    temporaries fit the chip, the pool is updated in place (aliased),
+    and outside its fused computations nothing as large as a layer's
+    keys is copied or transposed."""
+    from containerpilot_tpu.models import block_diffusion as bd
+    from containerpilot_tpu.workload.modelcfg import load_model_file
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    slots, length = 64, 3072
+    cfg = load_model_file(
+        os.path.join(root, "benchmark", "configs", "sdar-30b-a3b-serve.json"),
+        length)
+    size = cfg.block_length
+    shapes = jax.eval_shape(
+        lambda: (
+            bd.init_params(None, cfg), bd.slot_cache(cfg, slots, length),
+            {"blk": jnp.zeros((slots, size), jnp.int32),
+             "hidden": jnp.ones((slots, size), jnp.bool_),
+             "step": jnp.zeros((slots,), jnp.int32),
+             "done": jnp.ones((slots,), jnp.bool_)},
+            jnp.zeros((slots,), jnp.int32),
+        )
+    )
+    params, pool, state, budget = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+    if program == "chunk":
+        compiled = bd._jitted_chunk(cfg, slots, 6).lower(
+            params, pool, state).compile()
+    else:
+        compiled = bd._jitted_window(cfg, slots, 6, 4).lower(
+            params, pool, state, budget).compile()
+    memory = compiled.memory_analysis()
+    layer_keys = slots * length * cfg.n_kv_heads * cfg.head_dim
+    assert 11.0e9 < memory.argument_size_in_bytes < 11.3e9
+    assert memory.alias_size_in_bytes >= 2 * cfg.n_layers * layer_keys * 2
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert held < 0.8 * HBM_BYTES
+    outside, _bodies = _outside_fusions(compiled.as_text())
+    for _computation, line in outside:
+        found = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* "
+            r"(copy|dynamic-slice|transpose)\(", line)
+        if found:
+            moved = math.prod(int(n) for n in found.group(2).split(","))
+            assert moved < layer_keys, (
+                f"{found.group(1)}: {found.group(3)} of bf16"
+                f"[{found.group(2)}] outside a fusion")
