@@ -119,6 +119,20 @@ def param_bytes(params: Any) -> int:
     )
 
 
+def resident_weights(params: Any) -> Dict[str, Any]:
+    """``/v1/model`` ``weights``: the form the parameters are resident
+    in. ``dtype`` is the one that holds most of the tree's bytes
+    (``int8`` under --int8, whose scales and norms stay float32)."""
+    by_dtype: Dict[str, int] = {}
+    for leaf in jax.tree_util.tree_leaves(params):
+        name = jnp.dtype(leaf.dtype).name
+        by_dtype[name] = by_dtype.get(name, 0) + leaf.nbytes
+    return {
+        "dtype": max(by_dtype, key=by_dtype.get),
+        "bytes": sum(by_dtype.values()),  # = param_bytes(params)
+    }
+
+
 # ---------------------------------------------------------------------------
 # fused int8 serving path: projections through the pallas dequant-GEMM
 # ---------------------------------------------------------------------------

@@ -183,6 +183,29 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     }
 
 
+def serving_params(params: Params, cfg: TransformerConfig) -> Params:
+    """The tree a replica serves from: every floating leaf rounded to
+    ``cfg.dtype`` once, where it lies (sharding kept). Every serving
+    program reads a weight as ``w.astype(cfg.dtype)``, so the matmuls
+    get the very bits they got from the float32 tree, without each
+    program's own copy of the weights at every dispatch.
+
+    CONSUMES ``params``: a float32 leaf is deleted as soon as its
+    rounded copy exists, so the device holds the tree plus one leaf,
+    never both trees. Trainer, evaluation and tests keep the float32
+    tree of ``init_params``."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    for i, leaf in enumerate(leaves):
+        if not jnp.issubdtype(leaf.dtype, jnp.floating):
+            continue
+        cast = leaf.astype(cfg.dtype)
+        if cast is not leaf:
+            cast.block_until_ready()
+            leaf.delete()
+        leaves[i] = cast
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
     # named scopes (here and below) are the layer map's names in the
     # compiled programs' op metadata: a profiler trace attributes

@@ -45,6 +45,7 @@ from typing import Any, Awaitable, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from ..models.quantized import resident_weights
 from ..models.transformer import TransformerConfig
 from ..telemetry import tracing
 from ..utils.http import HTTPServer, Request, Response, StreamingResponse
@@ -1009,6 +1010,9 @@ class InferenceServer:
                 "n_layers": self.cfg.n_layers,
                 "max_len": self.max_len,
                 "mesh": self._mesh_info(),
+                # the resident form: float32 as init_params draws it,
+                # the compute dtype from serve_cli.load_model
+                "weights": resident_weights(self.params),
                 "text": self.tokenizer is not None,
                 "speculative": (
                     {

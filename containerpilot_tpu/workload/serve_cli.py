@@ -12,7 +12,11 @@ import asyncio
 
 import jax
 
-from ..models.transformer import TransformerConfig, init_params
+from ..models.transformer import (
+    TransformerConfig,
+    init_params,
+    serving_params,
+)
 from .modelcfg import (
     derive_d_ff,
     merge_lora,
@@ -380,6 +384,10 @@ def load_model(args: argparse.Namespace):
             f"int8: params {before} -> {param_bytes(params)} bytes "
             f"({before / param_bytes(params):.1f}x smaller)"
         )
+    else:
+        # the resident form is the read form (the int8 path above
+        # quantizes from float32 and keeps its own)
+        params = serving_params(params, cfg)
     return cfg, params, mesh
 
 

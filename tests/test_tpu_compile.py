@@ -369,8 +369,32 @@ def _cell_decode_shapes(chip, slot_cache):
     return cfg, slots, shapes, slots * length * cfg.kv_heads * cfg.head_dim
 
 
+def _weight_converts_outside_fusions(text, params):
+    """The ``convert`` instructions outside fused computations whose
+    result has as many elements as a leaf of ``params`` (or as one
+    layer's slice of a stacked leaf): a program rounding weights it
+    was handed in another dtype."""
+    counts = set()
+    for leaf in jax.tree.leaves(params):
+        counts.add(math.prod(leaf.shape))
+        counts.add(math.prod(leaf.shape[1:]))
+    counts.discard(1)
+    outside, _bodies = _outside_fusions(text)
+    found = []
+    for _computation, line in outside:
+        made = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* convert\(",
+            line)
+        if made and math.prod(
+                int(n) for n in made.group(3).split(",") if n) in counts:
+            found.append(made.group(1, 2, 3))
+    return found
+
+
+@pytest.mark.parametrize("form", ["float32", "serving"])
 @pytest.mark.parametrize("program", ["chunk", "window"])
-def test_decode_step_moves_nothing_the_size_of_a_layers_cache(chip, program):
+def test_decode_step_moves_nothing_the_size_of_a_layers_cache(
+        chip, program, form):
     """The slot engine's chunk program and its fused window of 4
     rounds at the benchmark's cache shapes, compiled for the v5e:
     outside its fused computations the program produces no tensor with
@@ -381,7 +405,15 @@ def test_decode_step_moves_nothing_the_size_of_a_layers_cache(chip, program):
     layer scan's xs/ys, six such operations (two transposes of the
     whole pool, a slice out and a stack back for each of keys and
     values) were 10.7 of a decode step's 17.0 ms on the chip, and the
-    temporaries held two copies of the pool (PERF.md, PR 28)."""
+    temporaries held two copies of the pool (PERF.md, PR 28).
+
+    ``serving``: the tree as ``serve_cli.load_model`` hands it over
+    (``serving_params``: every leaf in the compute dtype). The program
+    then rounds no weight and holds no copy of one: its temporaries
+    stay under one layer's keys and values alone. Handed the float32
+    tree it still makes its own bf16 copy at every dispatch (22 % of a
+    batch-decode step on the chip, PERF.md, PR 36), and this check
+    sees it."""
     from containerpilot_tpu.models.slots import (
         _jitted_chunk,
         _jitted_window,
@@ -389,6 +421,14 @@ def test_decode_step_moves_nothing_the_size_of_a_layers_cache(chip, program):
     )
 
     cfg, slots, shapes, layer_keys = _cell_decode_shapes(chip, slot_cache)
+    if form == "serving":
+        shapes = (
+            jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, cfg.dtype, sharding=chip),
+                shapes[0]),
+            *shapes[1:],
+        )
     if program == "chunk":
         lowered = _jitted_chunk(cfg, slots, 8).lower(*shapes)
     else:
@@ -402,10 +442,19 @@ def test_decode_step_moves_nothing_the_size_of_a_layers_cache(chip, program):
     memory = compiled.memory_analysis()
     pool_bytes = 2 * cfg.n_layers * layer_keys * 2
     assert memory.alias_size_in_bytes >= pool_bytes
-    weights_bf16 = 2 * sum(
-        math.prod(x.shape) for x in jax.tree.leaves(shapes[0])
-    )
-    assert memory.temp_size_in_bytes < weights_bf16 + 2 * layer_keys * 2
+    converts = _weight_converts_outside_fusions(text, shapes[0])
+    if form == "serving":
+        assert converts == []
+        # no copy of the weights at all: what is left (137 MB here, a
+        # change of layout of the attention projections among it)
+        # stays under one layer's keys and values with no allowance
+        assert memory.temp_size_in_bytes < 2 * layer_keys * 2
+    else:
+        assert converts, "the float32 tree's bf16 copy went unseen"
+        weights_bf16 = 2 * sum(
+            math.prod(x.shape) for x in jax.tree.leaves(shapes[0])
+        )
+        assert memory.temp_size_in_bytes < weights_bf16 + 2 * layer_keys * 2
 
 
 def test_decode_step_check_sees_the_old_form(chip):
