@@ -16,7 +16,7 @@ incremental logits match ``forward``'s per-position logits.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -287,7 +287,7 @@ def decode_step(
 
 def _grouped_attention(
     q: jax.Array, keys: jax.Array, values: jax.Array, valid: jax.Array,
-    dtype,
+    dtype, scale: Optional[float] = None,
 ) -> jax.Array:
     """Masked attention of q [b, m, n_heads, d] over keys/values
     [b, length, kv_heads, d] AS THE CACHE STORES THEM; valid is
@@ -299,7 +299,8 @@ def _grouped_attention(
     order) and the cache is never repeated to n_heads. The keys enter
     the score contraction in their own dtype with float32 accumulation
     (bf16 x bf16 products are exact in float32) and the
-    head_dim ** -0.5 scale is applied to the float32 scores, so they
+    head_dim ** -0.5 scale (or a family's own ``scale``,
+    models/hybrid_ssm.py) is applied to the float32 scores, so they
     are never widened. The softmax weights stay float32 and meet the
     stored values at HIGHEST precision: on the TPU the values' widening
     folds into the contraction's fusion (no float32 copy reaches
@@ -313,7 +314,8 @@ def _grouped_attention(
     scores = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qg, keys,
         preferred_element_type=jnp.float32,
-    ) * d ** -0.5  # [b, kv_heads, group, m, length]
+    ) * (d ** -0.5 if scale is None else scale)
+    # [b, kv_heads, group, m, length]
     scores = jnp.where(
         jnp.expand_dims(valid, (-3, -4)), scores, NEG_INF
     )

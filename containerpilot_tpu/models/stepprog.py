@@ -49,7 +49,9 @@ tree): nothing here reads a model's widths. The configuration's family
 supplies the cache and the step (models/slots.py); a family whose step
 counts something per round (the routed experts of models/mla_moe.py)
 returns the counts with the tokens, and ``tokens`` adds them up on the
-host (``expert_stats``): no dispatch and no sync of their own.
+host (``expert_stats``; ``state_stats`` for the stepped rows of
+models/hybrid_ssm.py's recurrent state): no dispatch and no sync of
+their own.
 
 Implementations: :class:`PlainStepProgram` (models/slots.py's chunk +
 fused-window programs), ``models.quantized.QuantizedStepProgram``
@@ -194,15 +196,26 @@ class PlainStepProgram:
         )
         return toks_host, valid, rounds_run
 
+    def _described(self, describe: str):
+        """The family's ``describe`` of the summed ``stats``; None for
+        a family without it."""
+        describe = getattr(
+            getattr(self.cfg, "family", None), describe, None)
+        if describe is None:
+            return None
+        return describe(self.cfg, self.stats_total)
+
     def expert_stats(self):
         """What the expert layers of the decode rounds fetched so far
         routed, under ``/v1/model`` ``experts``'s names; None for a
         family without routed experts."""
-        describe = getattr(
-            getattr(self.cfg, "family", None), "describe_stats", None)
-        if describe is None:
-            return None
-        return describe(self.cfg, self.stats_total)
+        return self._described("describe_stats")
+
+    def state_stats(self):
+        """What a row keeps besides keys and values and how often the
+        decode rounds fetched so far stepped it (``/v1/model``
+        ``state``); None for a family without recurrent state."""
+        return self._described("describe_state")
 
 
 def make_step_program(

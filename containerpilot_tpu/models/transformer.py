@@ -206,7 +206,8 @@ def serving_params(params: Params, cfg: TransformerConfig) -> Params:
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def _rms_norm(x: jax.Array, scale: jax.Array,
+              eps: float = 1e-6) -> jax.Array:
     # named scopes (here and below) are the layer map's names in the
     # compiled programs' op metadata: a profiler trace attributes
     # device time by them (docs/90-observability.md). Metadata only.
@@ -215,7 +216,7 @@ def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
             jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True
         )
         return (
-            (x * lax.rsqrt(var + 1e-6).astype(x.dtype))
+            (x * lax.rsqrt(var + eps).astype(x.dtype))
             * scale.astype(x.dtype)
         )
 

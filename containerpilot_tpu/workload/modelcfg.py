@@ -220,9 +220,10 @@ def load_model_file(path: str, max_len: int) -> Any:
     beside the keys). The architecture is told by its keys:
     ``kv_lora_rank`` is latent attention over routed experts
     (models/mla_moe.py), a ``diffusion`` group beside ``num_experts``
-    is generation by diffusion over blocks (models/block_diffusion.py).
-    A file of another architecture is refused with its name; nothing
-    is guessed."""
+    is generation by diffusion over blocks (models/block_diffusion.py),
+    ``layer_types`` beside ``mamba_n_heads`` is state-space layers among
+    attention layers (models/hybrid_ssm.py). A file of another
+    architecture is refused with its name; nothing is guessed."""
     import hashlib
     import json as json_mod
 
@@ -236,13 +237,16 @@ def load_model_file(path: str, max_len: int) -> Any:
         from ..models.mla_moe import from_published
     elif "diffusion" in config and "num_experts" in config:
         from ..models.block_diffusion import from_published
+    elif "layer_types" in config and "mamba_n_heads" in config:
+        from ..models.hybrid_ssm import from_published
     else:
         raise SystemExit(
             f"--model-config {path}: model_type "
             f"{config.get('model_type')!r} has no builder here (latent "
-            "attention with routed experts and block diffusion over "
-            "routed experts are the families read from a file; the "
-            "flagship block still takes its flags)"
+            "attention with routed experts, block diffusion over routed "
+            "experts and state-space layers among attention layers are "
+            "the families read from a file; the flagship block still "
+            "takes its flags)"
         )
 
     digest = hashlib.blake2b(raw, digest_size=8).hexdigest()

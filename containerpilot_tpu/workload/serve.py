@@ -244,6 +244,16 @@ class InferenceServer:
                 "ring cache's stale rows are live window context, so "
                 "a shorter-prefix rewind cannot reuse them)"
             )
+        if getattr(cfg, "recurrent_state", False):
+            for flag, on in (("--prefix-cache", prefix_cache_entries > 0),
+                             ("--kv-spill-mb", kv_spill_bytes > 0)):
+                if on:
+                    raise ValueError(
+                        f"{flag} does not compose with this model: a "
+                        "recurrent state cannot be rewound to a shorter "
+                        "prefix, and no row of it is stored, spilled "
+                        "or handed off yet"
+                    )
         if kv_spill_bytes > 0 and prefix_cache_entries <= 0:
             raise ValueError(
                 "--kv-spill requires --prefix-cache (the spill tier "
@@ -1056,6 +1066,10 @@ class InferenceServer:
                 # generation by diffusion over blocks: the routine and
                 # its counters (models/block_diffusion.py); else None
                 "diffusion": self.slot_engine.diffusion_stats(),
+                # state that is not keys and values: the layer kinds,
+                # a row's bytes of it and the steps taken over it
+                # (models/hybrid_ssm.py); else None
+                "state": self.slot_engine.state_stats(),
                 # SSE streaming rides the slot engine's chunks
                 "stream": True,
                 "draining": self.draining,

@@ -371,18 +371,25 @@ class SlotEngine:
             "tokens_out": self.tokens_out,
         }
 
+    def _program_stats(self, name: str) -> Optional[dict]:
+        describe = getattr(self.program, name, None)
+        return describe() if describe is not None else None
+
     def expert_stats(self) -> Optional[dict]:
         """What the step program's expert layers routed in the decode
         rounds fetched so far (``/v1/model`` ``experts``); None for a
         model without routed experts."""
-        describe = getattr(self.program, "expert_stats", None)
-        return describe() if describe is not None else None
+        return self._program_stats("expert_stats")
 
     def diffusion_stats(self) -> Optional[dict]:
         """The step program's block-diffusion routine and counters
         (``/v1/model`` ``diffusion``); None for any other family."""
-        describe = getattr(self.program, "diffusion_stats", None)
-        return describe() if describe is not None else None
+        return self._program_stats("diffusion_stats")
+
+    def state_stats(self) -> Optional[dict]:
+        """A row's recurrent state and the decode rounds' steps of it
+        (``/v1/model`` ``state``); None for a model without any."""
+        return self._program_stats("state_stats")
 
     # ----------------------------------------------------------- worker
 

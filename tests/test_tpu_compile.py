@@ -604,3 +604,96 @@ def test_block_diffusion_pool_forward_fits_the_chip(chip, program):
             assert moved < layer_keys, (
                 f"{found.group(1)}: {found.group(3)} of bf16"
                 f"[{found.group(2)}] outside a fusion")
+
+
+def _hybrid_ssm_shapes(chip, slots, length):
+    from containerpilot_tpu.models.slots import init_slot_state, slot_cache
+    from containerpilot_tpu.workload.modelcfg import load_model_file
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = load_model_file(
+        os.path.join(root, "benchmark", "configs",
+                     "granite-4-h-small-serve.json"), length)
+    shapes = jax.eval_shape(
+        lambda: (
+            cfg.family.init_params(None, cfg),
+            slot_cache(cfg, slots, length),
+            init_slot_state(cfg, slots),
+            cfg.family.init_cache(cfg, 1, length),
+        )
+    )
+    return cfg, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+
+
+def test_hybrid_state_space_step_updates_the_state_where_it_lies(chip):
+    """The slot engine's chunk program of the benchmark's
+    granite-4.0-h-small configuration at its real size
+    (benchmark/configs/granite-4-h-small-serve.json: published widths,
+    nine mamba layers and one attention layer, 36 held experts of 72,
+    64 slots x 3,072 positions), compiled for the v5e: weights (9.51
+    GB), pool (3.26 GB: 2.44 of recurrent state, 0.81 of keys and
+    values) and temporaries fit the chip; the whole pool is aliased;
+    each mamba layer's state is read by ONE fused computation a step,
+    which gives the new state and the read through C together; and
+    outside fused computations nothing of a state's size is produced
+    and no weight as large as four expert matrices is copied, but the
+    attention layer's query projection's change of layout, once a
+    dispatch."""
+    from containerpilot_tpu.models.slots import _jitted_chunk
+
+    slots, length = 64, 3072
+    cfg, (params, pool, state, _row) = _hybrid_ssm_shapes(chip, slots, length)
+    compiled = _jitted_chunk(cfg, slots, 8).lower(params, pool, state).compile()
+    memory = compiled.memory_analysis()
+    state_elements = slots * cfg.d_inner * cfg.ssm_state
+    pool_bytes = (cfg.n_mamba * state_elements * 4
+                  + 2 * slots * length * cfg.n_kv_heads * cfg.head_dim * 2)
+    assert 12.7e9 < memory.argument_size_in_bytes < 12.9e9
+    assert memory.alias_size_in_bytes >= pool_bytes
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert held < 0.85 * HBM_BYTES
+    assert memory.temp_size_in_bytes < state_elements * 4
+    text = compiled.as_text()
+    outside, bodies = _outside_fusions(text)
+    state_type = f"f32[{slots},{cfg.ssm_heads},{cfg.ssm_head_dim},{cfg.ssm_state}]"
+    updates = 0
+    for _computation, line in outside:
+        made = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)", line)
+        if not made:
+            continue
+        name, kind, opcode, operands = made.groups()
+        if state_type in kind and opcode not in (
+                "parameter", "get-tuple-element", "tuple", "bitcast",
+                "while", "conditional", "call"):
+            # the update: the state in, (the read through C, the state) out
+            assert opcode == "fusion" and kind.startswith("("), line[:200]
+            assert kind.count(state_type) == 1
+            updates += 1
+        found = re.match(r"bf16\[([\d,]+)\]", kind)
+        if found and opcode in ("copy", "dynamic-slice", "transpose"):
+            moved = math.prod(int(n) for n in found.group(1).split(","))
+            assert (moved < 4 * cfg.d_model * cfg.moe_d_ff
+                    or "wq" in operands), line[:200]
+    assert updates == cfg.n_mamba
+
+
+def test_hybrid_state_space_insert_overwrites_a_row_in_place(chip):
+    """The insert program at the same size: a prefilled row (37.7 MB of
+    state, 12.6 MB of keys and values) is written into the donated
+    pool, which is aliased whole; nothing else is held."""
+    from containerpilot_tpu.models.slots import _jitted_insert
+
+    slots, length = 64, 3072
+    cfg, (_params, pool, _state, row) = _hybrid_ssm_shapes(chip, slots, length)
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    compiled = _jitted_insert(cfg).lower(pool, row, slot).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes > 3.2e9
+    assert memory.temp_size_in_bytes < 64 * 1024 ** 2
+    assert (memory.output_size_in_bytes - memory.alias_size_in_bytes
+            < 1024 ** 2)
