@@ -488,12 +488,50 @@ def decode_chunk(
 import functools
 
 
+#: the sampler's arms, by the index ``sampler_arm`` gives: the named
+#: scope each runs under (in a device trace an operation's path says
+#: which arm it belongs to, and an arm that did not run left no event)
+SAMPLER_ARMS = ("sample.argmax", "sample.draw", "sample.filter")
+
+
+def row_arm(temperature: float, top_k: int, top_p: float) -> int:
+    """What ONE row's knobs ask of the sampler, as an index into
+    ``SAMPLER_ARMS``, from Python numbers (the engine's own record of
+    a request): 0 for a greedy row whatever its filters hold (its
+    token is the argmax), 2 for a row that samples under a filter
+    (``top_k > 0`` or ``0 < top_p < 1``), 1 for one that samples
+    without."""
+    if not temperature > 0.0:
+        return 0
+    return 2 if top_k > 0 or 0.0 < top_p < 1.0 else 1
+
+
+def sampler_arm(temperature, top_k=None, top_p=None, live=None):
+    """What a pool's LIVE rows ask of the sampler: the largest of
+    their ``row_arm``s, on the device. ``live`` ([batch] bool, default
+    all rows) masks rows whose knobs are stale: a retired slot keeps
+    its last occupant's until readmission."""
+    sampling = jnp.asarray(temperature, jnp.float32) > 0.0
+    filters = jnp.zeros((), bool)
+    if top_k is not None:
+        filters |= jnp.asarray(top_k, jnp.int32) > 0
+    if top_p is not None:
+        p = jnp.asarray(top_p, jnp.float32)
+        filters |= (p > 0.0) & (p < 1.0)
+    arms = sampling * (1 + filters.astype(jnp.int32))
+    if live is not None:
+        arms = jnp.where(live, arms, 0)
+    return jnp.max(arms)
+
+
 def sample_logits(
     logits: jax.Array,
     key: jax.Array,
     temperature: jax.Array,
     top_k=None,
     top_p=None,
+    live=None,
+    fold=None,
 ) -> jax.Array:
     """Sample token ids from [batch, vocab] logits.
 
@@ -506,45 +544,94 @@ def sample_logits(
     at the k-th value all survive); nucleus keeps the smallest set of
     tokens whose probability mass reaches p (the top token always
     survives; p outside (0,1) keeps all). ``None`` disables a filter
-    statically, skipping the sort when both are off.
+    statically; with both ``None`` there is no sort in the program.
+
+    With a filter given, the program holds three arms
+    (``SAMPLER_ARMS``) and each call runs ONE of them for the whole
+    batch, chosen on the device by ``sampler_arm`` from what the
+    ``live`` rows ask for: the argmax alone (no sort, no softmax, no
+    key, no noise) where none of them samples; the categorical draw
+    without the sort where some sample and none of those filters; the
+    sort of the whole vocabulary, the masks and the draw only where a
+    live sampling row filters. A row's token does not depend on the
+    arm: a greedy row gets ``argmax`` of the same float32 logits in
+    all three, and a row without filters has nothing masked in the
+    third (its threshold is its own minimum), so it draws there what
+    it draws in the second.
 
     ``key`` is one PRNG key shared by the batch, or [batch] stacked
     per-row keys (``jax.random.split`` output) — per-row keys make each
-    row's draw independent of what it is batched with.
+    row's draw independent of what it is batched with. ``fold``
+    ([batch] int) is folded into the per-row keys inside the arms that
+    draw, so a call that draws nothing folds nothing.
     """
     b, vocab = logits.shape
     t = jnp.broadcast_to(
         jnp.asarray(temperature, jnp.float32), (b,)
     )[:, None]
     raw = logits.astype(jnp.float32)
-    x = raw / jnp.maximum(t, 1e-6)
-    if top_k is not None or top_p is not None:
-        sorted_logits = jnp.sort(x, axis=-1)[:, ::-1]
-        keep = jnp.ones(sorted_logits.shape, bool)
-        if top_k is not None:
-            k = jnp.broadcast_to(
-                jnp.asarray(top_k, jnp.int32), (b,)
-            )[:, None]
-            k = jnp.where(k > 0, k, vocab)
-            keep &= jnp.arange(vocab)[None, :] < k
-        if top_p is not None:
-            p = jnp.broadcast_to(
-                jnp.asarray(top_p, jnp.float32), (b,)
-            )[:, None]
-            p = jnp.where((p > 0.0) & (p < 1.0), p, 1.0)
-            probs = jax.nn.softmax(sorted_logits, axis=-1)
-            keep &= (jnp.cumsum(probs, axis=-1) - probs) < p
-        threshold = jnp.min(
-            jnp.where(keep, sorted_logits, jnp.inf), axis=-1, keepdims=True
-        )
-        x = jnp.where(x < threshold, NEG_INF, x)
-    if key.ndim > 1:  # stacked per-row keys
-        sampled = jax.vmap(
-            lambda k, row: jax.random.categorical(k, row)
-        )(key, x)
-    else:
-        sampled = jax.random.categorical(key, x, axis=-1)
-    return jnp.where(t[:, 0] <= 0.0, jnp.argmax(raw, axis=-1), sampled)
+
+    def draw(filtered: bool):
+        x = raw / jnp.maximum(t, 1e-6)
+        if filtered:
+            x = _mask_filtered(x, top_k, top_p)
+        keys = key
+        if fold is not None:
+            keys = jax.vmap(jax.random.fold_in)(
+                key, jnp.broadcast_to(fold, (b,))
+            )
+        if keys.ndim > 1:  # stacked per-row keys
+            sampled = jax.vmap(
+                lambda k, row: jax.random.categorical(k, row)
+            )(keys, x)
+        else:
+            sampled = jax.random.categorical(keys, x, axis=-1)
+        return jnp.where(
+            t[:, 0] <= 0.0, jnp.argmax(raw, axis=-1), sampled
+        ).astype(jnp.int32)
+
+    if top_k is None and top_p is None:
+        return draw(False)
+    arms = (
+        lambda: jnp.argmax(raw, axis=-1).astype(jnp.int32),
+        functools.partial(draw, False),
+        functools.partial(draw, True),
+    )
+    return lax.switch(
+        sampler_arm(temperature, top_k, top_p, live),
+        [jax.named_scope(name)(arm)
+         for name, arm in zip(SAMPLER_ARMS, arms)],
+    )
+
+
+def _mask_filtered(x: jax.Array, top_k, top_p) -> jax.Array:
+    """NEG_INF every logit of [batch, vocab] ``x`` that its row's
+    top-k / nucleus filter drops (``sample_logits`` has the rules):
+    one descending sort of the whole vocabulary, both masks over it,
+    and the smallest kept value as the row's threshold."""
+    b, vocab = x.shape
+    sorted_logits = jnp.sort(x, axis=-1)[:, ::-1]
+    keep = jnp.ones(sorted_logits.shape, bool)
+    if top_k is not None:
+        k = jnp.broadcast_to(
+            jnp.asarray(top_k, jnp.int32), (b,)
+        )[:, None]
+        k = jnp.where(k > 0, k, vocab)
+        keep &= jnp.arange(vocab)[None, :] < k
+    if top_p is not None:
+        p = jnp.broadcast_to(
+            jnp.asarray(top_p, jnp.float32), (b,)
+        )[:, None]
+        # off is +inf, not 1.0: a float32 cumulative sum can pass 1
+        # before the row's end, and a row that asks for no filter must
+        # have NOTHING masked, as in the arm that does not sort
+        p = jnp.where((p > 0.0) & (p < 1.0), p, jnp.inf)
+        probs = jax.nn.softmax(sorted_logits, axis=-1)
+        keep &= (jnp.cumsum(probs, axis=-1) - probs) < p
+    threshold = jnp.min(
+        jnp.where(keep, sorted_logits, jnp.inf), axis=-1, keepdims=True
+    )
+    return jnp.where(x < threshold, NEG_INF, x)
 
 
 def mask_eos_before_min(

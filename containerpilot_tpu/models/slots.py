@@ -316,7 +316,6 @@ def _round_step_body(params, state, cfg):
             params, pool, tok[:, None], cfg
         )
         with jax.named_scope("sample"):
-            keys = jax.vmap(jax.random.fold_in)(row_keys, idx)
             masked = apply_token_penalties(
                 logits[:, 0, :], counts, state["presence"],
                 state["frequency"],
@@ -329,10 +328,12 @@ def _round_step_body(params, state, cfg):
             masked = mask_eos_before_min(
                 masked, idx, state["min_new"], eos_id
             )
+            # the rows live at this step choose the sampler's arm: a
+            # retired slot keeps its last occupant's knobs
             nxt = sample_logits(
-                masked, keys, state["temperature"], state["top_k"],
-                state["top_p"],
-            ).astype(jnp.int32)
+                masked, row_keys, state["temperature"],
+                state["top_k"], state["top_p"], live=~done, fold=idx,
+            )
             nxt = jnp.where(done, pad_id, nxt)
             done = done | (nxt == eos_id)
             counts = count_token(counts, nxt, ~done)
@@ -506,7 +507,6 @@ def _jitted_first_sample(cfg: TransformerConfig):
         # by construction — identical to generate's first sample.
         # logit_bias DOES apply at sample 0 (generate biases every
         # draw), hence the operands here.
-        key = jax.random.fold_in(row_key, jnp.int32(0))
         masked = apply_logit_bias(
             logits, bias_idx[None], bias_val[None]
         )
@@ -514,9 +514,9 @@ def _jitted_first_sample(cfg: TransformerConfig):
             masked, jnp.int32(0), min_new[None], eos_id[None]
         )
         return sample_logits(
-            masked, key[None], temperature[None], top_k[None],
-            top_p[None],
-        )[0].astype(jnp.int32)
+            masked, row_key[None], temperature[None], top_k[None],
+            top_p[None], fold=jnp.int32(0),
+        )[0]
 
     return jax.jit(first)
 

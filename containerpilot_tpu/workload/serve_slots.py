@@ -51,6 +51,7 @@ from ..models.decode import (
     BIAS_SLOTS_MAX,
     _jitted_prefill,
     normalize_logit_bias,
+    row_arm,
 )
 from ..models.slots import append_chunk
 from ..models.stepprog import make_step_program
@@ -59,6 +60,10 @@ from ..telemetry.goodput import EnginePhases, name_os_thread
 from .serve_prefix import MIN_REUSE as PREFIX_MIN_REUSE
 
 log = logging.getLogger("containerpilot.serve.slots")
+
+#: ``stats["sampler"]``'s keys (``/v1/model`` ``slot_engine.sampler``)
+#: by the index of the sampler's arm (models/decode.py SAMPLER_ARMS)
+SAMPLER_ROUNDS = ("rounds_argmax", "rounds_draw", "rounds_filter")
 
 
 @dataclass
@@ -232,6 +237,13 @@ class SlotEngine:
         # rounds counter tracing already pays.
         self.dispatches = 0
         self.tokens_out = 0
+        # decode rounds (a chunk is one, a fused window as many as it
+        # ran) by the sampler's arm that the live slots' knobs called
+        # for at the dispatch (``_sampler_arm``): the HOST's view. The device chooses step by step from its own
+        # ``done`` flags, so a row that ends inside a round can turn
+        # the rest of a counted ``filter`` round into argmax steps; a
+        # trace's ``sample.*`` scopes are the device's word
+        self.sampler_rounds = dict.fromkeys(SAMPLER_ROUNDS, 0)
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
@@ -369,6 +381,7 @@ class SlotEngine:
             # yardstick): cumulative device dispatches vs tokens out
             "dispatches": self.dispatches,
             "tokens_out": self.tokens_out,
+            "sampler": dict(self.sampler_rounds),
         }
 
     def _program_stats(self, name: str) -> Optional[dict]:
@@ -619,6 +632,17 @@ class SlotEngine:
                 budgets[i] = max(s.req.max_new - len(s.emitted), 0)
         return budgets
 
+    def _sampler_arm(self) -> int:
+        """The sampler's arm the live slots' knobs call for (the
+        largest of their ``row_arm``s), for ``sampler_rounds``."""
+        return max(
+            (
+                row_arm(s.req.temperature, s.req.top_k, s.req.top_p)
+                for s in self._active if s is not None
+            ),
+            default=0,
+        )
+
     def _run(self) -> None:
         # the profiler names this thread's line by its OS name, taken
         # at the thread's first event: before any jax call
@@ -703,6 +727,7 @@ class SlotEngine:
                 )
                 tj = time.perf_counter()
                 phases.dispatched(tj, fused and windowed)
+                arm = self._sampler_arm()
                 try:
                     handle = program.dispatch(self._budgets(), fused)
                 except Exception as exc:  # noqa: BLE001
@@ -710,7 +735,7 @@ class SlotEngine:
                     continue
                 self.dispatches += program.dispatch_cost
             else:
-                handle, pending = pending, None
+                (handle, arm), pending = pending, None
             # one-WINDOW lookahead (the PR 1 one-round lookahead,
             # window-sized): when no admission, cancel, or stop
             # decision is pending, dispatch window N+1 BEFORE
@@ -733,7 +758,10 @@ class SlotEngine:
                 tj = time.perf_counter()
                 phases.dispatched(tj, windowed)
                 try:
-                    pending = program.dispatch(self._budgets(), True)
+                    pending = (
+                        program.dispatch(self._budgets(), True),
+                        self._sampler_arm(),
+                    )
                 except Exception as exc:  # noqa: BLE001
                     self._fail_and_rebuild(exc)
                     pending = None
@@ -750,6 +778,7 @@ class SlotEngine:
                 self._fail_and_rebuild(exc)
                 pending = None
                 continue
+            self.sampler_rounds[SAMPLER_ROUNDS[arm]] += rounds_run
             tj = time.perf_counter()
             # append, notify, harvest (and the next cycle's sweep and
             # free-slot scan, up to its first boundary)

@@ -133,6 +133,52 @@ def test_admissions_and_dispatch_kinds_are_counted(params):
     assert snap["admissions"] == 5
 
 
+def test_sampler_rounds_are_counted_by_the_arm_the_live_slots_ask_for(
+        params):
+    """``stats["sampler"]`` (``/v1/model`` ``slot_engine.sampler``):
+    decode rounds by the sampler's arm that the engine's own record of
+    its live slots' knobs called for at the dispatch. Greedy traffic
+    reads ``rounds_draw = rounds_filter = 0``; a request that filters
+    puts its rounds under ``rounds_filter``, one that samples without
+    a filter under ``rounds_draw``, and a slot retired with such knobs
+    counts for nothing."""
+    eng = _engine(params, max_len=256)
+    try:
+        for i in range(3):
+            eng.submit([1 + i, 2, 3], max_new=40).result(timeout=120)
+        greedy = dict(eng.stats["sampler"])
+        assert set(greedy) == {
+            "rounds_argmax", "rounds_draw", "rounds_filter"}
+        assert greedy["rounds_draw"] == greedy["rounds_filter"] == 0
+        # 39 tokens after the first, 8 a round: at least 5 rounds each
+        assert greedy["rounds_argmax"] >= 15
+        out = eng.submit(
+            [4, 5, 6], max_new=40, temperature=0.9, top_k=5, seed=3,
+        ).result(timeout=120)
+        assert len(out) == 40
+        filtered = dict(eng.stats["sampler"])
+        assert filtered["rounds_filter"] >= 5
+        assert filtered["rounds_draw"] == 0
+        eng.submit(
+            [7, 8, 9], max_new=40, temperature=0.9, seed=4,
+        ).result(timeout=120)
+        drawn = dict(eng.stats["sampler"])
+        assert drawn["rounds_draw"] >= 5
+        # the retired slots keep their knobs on the device; the
+        # engine's record is of LIVE slots: greedy rounds count as such
+        eng.submit([1, 2, 3], max_new=40).result(timeout=120)
+        time.sleep(0.05)
+    finally:
+        eng.stop()
+    after = eng.stats["sampler"]
+    assert after["rounds_argmax"] >= drawn["rounds_argmax"] + 5
+    # at most the one lookahead window dispatched while the sampling
+    # request was still live is fetched after it
+    assert after["rounds_filter"] <= filtered["rounds_filter"] + WINDOW
+    assert after["rounds_draw"] <= drawn["rounds_draw"] + WINDOW
+    assert after == eng.sampler_rounds
+
+
 def test_store_spill_and_readmit_bytes_under_a_prefix_cache(params):
     """With a one-entry prefix cache over a spill tier, a second
     session's admission evicts and spills the first's row, and the
@@ -404,9 +450,10 @@ def _lowered(kind, params):
 @pytest.mark.parametrize("kind,wanted", [
     ("chunk", {"embed", "attn", "attn.qkv", "attn.rope", "attn.kv_write",
                "attn.scores", "attn.out", "mlp", "head", "sample",
-               "steps"}),
+               "sample.argmax", "sample.draw", "sample.filter", "steps"}),
     ("window", {"attn", "attn.kv_write", "attn.scores", "mlp", "head",
-                "sample", "steps", "layers"}),
+                "sample", "sample.argmax", "sample.draw", "sample.filter",
+                "steps", "layers"}),
     ("prefill", {"embed", "attn", "attn.qkv", "attn.kv_write",
                  "attn.scores", "mlp", "head", "layers"}),
     ("extend", {"attn", "attn.kv_write", "attn.scores", "mlp", "head"}),

@@ -284,6 +284,10 @@ def test_inference_server_slot_engine(run, params):
     # one dispatch per token for chunked decode
     assert stats.pop("dispatches") >= 1
     assert stats.pop("tokens_out") >= 1
+    # decode rounds by the sampler's arm: warm-up's request is greedy
+    sampler = stats.pop("sampler")
+    assert sampler.pop("rounds_argmax") >= 1
+    assert sampler == {"rounds_draw": 0, "rounds_filter": 0}
     assert stats == {
         "slots": 2, "chunk": 4, "window": 4, "active": 0,
         "queued": 0,

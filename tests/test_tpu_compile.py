@@ -204,21 +204,29 @@ def test_tp_serving_prefill_needs_shard_map(topology, monkeypatch):
     assert KERNEL in compiled.as_text()
 
 
+def _computations(text):
+    """The lines of every computation of an optimised program by its
+    name, and the entry computation's name."""
+    bodies, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            bodies[name] = []
+            entry = name if head.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    return bodies, entry
+
+
 def _outside_fusions(text):
     """(computation, line) of every instruction of an optimised
     program that is not inside a fused computation: what a fusion
     calls lives inside it and never reaches memory. Also the lines of
     every computation by name."""
-    bodies, name = {}, None
-    for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
-        if head:
-            name = head.group(1)
-            bodies[name] = []
-        elif line.startswith("}"):
-            name = None
-        elif name is not None:
-            bodies[name].append(line)
+    bodies, _entry = _computations(text)
     fused = {
         called for lines in bodies.values() for line in lines
         if " fusion(" in line
@@ -628,7 +636,20 @@ def _hybrid_ssm_shapes(chip, slots, length):
     )
 
 
-def test_hybrid_state_space_step_updates_the_state_where_it_lies(chip):
+@pytest.fixture(scope="module")
+def hybrid_chunk(chip):
+    """(cfg, compiled): the granite configuration's chunk program at
+    its real size, compiled once for the tests that read it."""
+    from containerpilot_tpu.models.slots import _jitted_chunk
+
+    slots, length = 64, 3072
+    cfg, (params, pool, state, _row) = _hybrid_ssm_shapes(chip, slots, length)
+    return cfg, _jitted_chunk(cfg, slots, 8).lower(
+        params, pool, state).compile()
+
+
+def test_hybrid_state_space_step_updates_the_state_where_it_lies(
+        hybrid_chunk):
     """The slot engine's chunk program of the benchmark's
     granite-4.0-h-small configuration at its real size
     (benchmark/configs/granite-4-h-small-serve.json: published widths,
@@ -642,11 +663,8 @@ def test_hybrid_state_space_step_updates_the_state_where_it_lies(chip):
     and no weight as large as four expert matrices is copied, but the
     attention layer's query projection's change of layout, once a
     dispatch."""
-    from containerpilot_tpu.models.slots import _jitted_chunk
-
     slots, length = 64, 3072
-    cfg, (params, pool, state, _row) = _hybrid_ssm_shapes(chip, slots, length)
-    compiled = _jitted_chunk(cfg, slots, 8).lower(params, pool, state).compile()
+    cfg, compiled = hybrid_chunk
     memory = compiled.memory_analysis()
     state_elements = slots * cfg.d_inner * cfg.ssm_state
     pool_bytes = (cfg.n_mamba * state_elements * 4
@@ -697,3 +715,113 @@ def test_hybrid_state_space_insert_overwrites_a_row_in_place(chip):
     assert memory.temp_size_in_bytes < 64 * 1024 ** 2
     assert (memory.output_size_in_bytes - memory.alias_size_in_bytes
             < 1024 ** 2)
+
+
+def _vocabulary_sorts(text, vocab):
+    """The ``sort`` instructions of an optimised program over a
+    dimension of ``vocab``, as (inside, outside): whether the
+    computation that holds one is reached from the entry ONLY through
+    a ``conditional``'s branch (then it runs when the branch is
+    taken), or also by calls, fusions and loops alone (then it runs
+    every time)."""
+    bodies, entry = _computations(text)
+    always, queue = {entry}, [entry]
+    while queue:
+        for line in bodies[queue.pop()]:
+            # every computation a line names but a conditional's arms
+            line = re.sub(
+                r"(branch_computations=\{[^}]*\}"
+                r"|(true|false)_computation=%?[\w.\-]+)", "", line)
+            for called in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
+                if called not in always:
+                    always.add(called)
+                    queue.append(called)
+    inside, outside = [], []
+    for name, lines in bodies.items():
+        for line in lines:
+            made = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = \(?(\w+\[[\d,]*\])\S* .*? sort\(",
+                line)
+            if made and re.search(rf"[\[,]{vocab}[\],]", made.group(2)):
+                (outside if name in always else inside).append(
+                    (name, made.group(1), made.group(2)))
+    return inside, outside
+
+
+@pytest.mark.parametrize("program", ["chunk", "window", "hybrid-ssm chunk"])
+def test_the_vocabulary_is_sorted_only_inside_a_conditionals_branch(
+        chip, program, request):
+    """The sampler's sort of the whole vocabulary (the largest single
+    device operation of four serving cells while every request was
+    greedy: PERF.md, PR 37) stands in a branch computation of a
+    ``conditional`` in the program the v5e's compiler makes, not in
+    the step loop's body: a pool whose live rows are all greedy does
+    not run it. The flagship's chunk and fused-window programs at cut
+    widths, and the granite configuration's chunk program at its real
+    size (a file-described family through the same
+    ``_round_step_body``)."""
+    from containerpilot_tpu.models.slots import (
+        _jitted_chunk,
+        _jitted_window,
+        slot_cache,
+    )
+
+    if program == "hybrid-ssm chunk":
+        cfg, compiled = request.getfixturevalue("hybrid_chunk")
+    else:
+        cfg, slots, shapes, _keys = _cell_decode_shapes(chip, slot_cache)
+        if program == "chunk":
+            lowered = _jitted_chunk(cfg, slots, 8).lower(*shapes)
+        else:
+            budget = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+            lowered = _jitted_window(cfg, slots, 8, 4).lower(*shapes, budget)
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) >= 1
+    inside, outside = _vocabulary_sorts(text, cfg.vocab_size)
+    assert outside == []
+    assert inside, "no sort of the vocabulary found at all"
+
+
+def test_vocabulary_sort_check_sees_the_old_form(chip):
+    """The same check on the sampler as it was (sort, masks and draw
+    for every row at every step, then the argmax for the greedy rows)
+    inside a loop of steps finds the sort outside any branch, so the
+    test above cannot pass by looking past it."""
+    from containerpilot_tpu.models.decode import NEG_INF
+
+    rows, vocab = 16, 1024
+
+    def old_sample(logits, keys, t, top_k, top_p):
+        x = logits / jnp.maximum(t[:, None], 1e-6)
+        ordered = jnp.sort(x, axis=-1)[:, ::-1]
+        k = jnp.where(top_k > 0, top_k, vocab)[:, None]
+        keep = jnp.arange(vocab)[None, :] < k
+        p = jnp.where((top_p > 0.0) & (top_p < 1.0), top_p, 1.0)[:, None]
+        probs = jax.nn.softmax(ordered, axis=-1)
+        keep &= (jnp.cumsum(probs, axis=-1) - probs) < p
+        threshold = jnp.min(
+            jnp.where(keep, ordered, jnp.inf), axis=-1, keepdims=True)
+        x = jnp.where(x < threshold, NEG_INF, x)
+        drawn = jax.vmap(jax.random.categorical)(keys, x)
+        return jnp.where(t <= 0.0, jnp.argmax(logits, axis=-1), drawn)
+
+    def run(logits, keys, t, top_k, top_p):
+        def body(tok, idx):
+            folded = jax.vmap(jax.random.fold_in)(keys, idx + tok)
+            return old_sample(
+                logits + tok[:, None], folded, t, top_k, top_p), tok
+        return jax.lax.scan(
+            body, jnp.zeros((rows,), jnp.int32), jnp.arange(8))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    text = jax.jit(run).lower(
+        shape((rows, vocab), jnp.float32), shape((rows, 2), jnp.uint32),
+        shape((rows,), jnp.float32), shape((rows,), jnp.int32),
+        shape((rows,), jnp.float32),
+    ).compile().as_text()
+    inside, outside = _vocabulary_sorts(text, vocab)
+    assert inside == [] and outside, (inside, outside)
