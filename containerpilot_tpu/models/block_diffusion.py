@@ -62,6 +62,7 @@ from .decode import _grouped_attention
 from .mla_moe import STATS_HEAD, TOP, VOCAB_BLOCK, _count, _draw
 from .quantized import embed_lookup
 from .slots import retire_slot
+from .stepprog import phase_span
 from .transformer import _rms_norm, _rope
 
 Params = Dict[str, Any]
@@ -645,6 +646,8 @@ class BlockStepProgram:
 
     supports_lookahead = True
     dispatch_cost = 1
+    #: the engine's EnginePhases once ``attach_phases`` was called
+    phases = None
 
     def __init__(self, cfg: BlockDiffusionConfig, params: Params,
                  max_len: int, slots: int, chunk: int, rounds: int = 1,
@@ -695,21 +698,27 @@ class BlockStepProgram:
                 np.any(np.asarray(req.bias_idx) >= 0)),
         })
 
+    def attach_phases(self, phases) -> None:
+        self.phases = phases
+
     def admit(self, slot: int, req, logits, row_cache) -> Optional[int]:
         """Write the prefilled row and its first block; a request's
         first tokens come with its first whole block, so there is no
-        token to return."""
+        token to return, nothing is fetched, and of the children of
+        ``engine.admit.first_token`` (models/stepprog.py) only
+        ``insert`` opens: ONE program writes the row and its state."""
         del logits
         cfg = self.cfg
         size = cfg.block_length
-        given = len(req.tokens) % size
-        blk = np.full((size,), cfg.mask_token_id, np.int32)
-        blk[:given] = req.tokens[len(req.tokens) - given:]
-        hidden = np.arange(size) >= given
-        self._pool, self._state = _jitted_admit(cfg)(
-            self._pool, self._state, row_cache,
-            jnp.asarray(slot, jnp.int32), jnp.asarray(blk),
-            jnp.asarray(hidden))
+        with phase_span(self.phases)("engine.admit.first_token.insert"):
+            given = len(req.tokens) % size
+            blk = np.full((size,), cfg.mask_token_id, np.int32)
+            blk[:given] = req.tokens[len(req.tokens) - given:]
+            hidden = np.arange(size) >= given
+            self._pool, self._state = _jitted_admit(cfg)(
+                self._pool, self._state, row_cache,
+                jnp.asarray(slot, jnp.int32), jnp.asarray(blk),
+                jnp.asarray(hidden))
         self._given[slot] = given
         return None
 

@@ -63,10 +63,18 @@ multi-token emission per dispatch) and
 several tokens of a row's block, or none; blocks are handed over
 whole). ``make_step_program`` picks the right default for a params
 pytree. A program may also bring ``validate(req)`` (refuse what it
-cannot serve, at submit) and ``warm_new`` (the new tokens a warm-up
-request needs to reach the fused window).
+cannot serve, at submit), ``warm_new`` (the new tokens a warm-up
+request needs to reach the fused window) and ``attach_phases(phases)``:
+the engine hands it its ``EnginePhases`` (telemetry/goodput.py), and
+``admit`` then opens the children of ``engine.admit.first_token``
+where the work happens (``.sample``, ``.sync``, ``.insert``,
+``.state``: which of an admission's milliseconds wait for the device
+and which are the host's own). A program that was handed none, or
+brings no such member, records nothing and admits as before.
 """
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import numpy as np
@@ -84,6 +92,13 @@ from .slots import (
 from .transformer import Params, TransformerConfig
 
 
+def phase_span(phases):
+    """``phases.span`` (``with span(name):`` adds a child phase's
+    seconds and count and annotates the trace), or a stand-in that
+    records nothing for a program no engine handed its phases to."""
+    return contextlib.nullcontext if phases is None else phases.span
+
+
 class PlainStepProgram:
     """The plain transformer's step program: the slot pool + the
     device-resident sampling state, advanced by decode_slots_chunk
@@ -94,6 +109,8 @@ class PlainStepProgram:
 
     supports_lookahead = True
     dispatch_cost = 1
+    #: the engine's EnginePhases once ``attach_phases`` was called
+    phases = None
 
     def __init__(
         self,
@@ -123,35 +140,46 @@ class PlainStepProgram:
         self._pool = slot_cache(self.cfg, self.slots, self.max_len)
         self._state = init_slot_state(self.cfg, self.slots)
 
+    def attach_phases(self, phases) -> None:
+        self.phases = phases
+
     def admit(self, slot: int, req, logits, row_cache) -> int:
         """Sample token 0 with the server key convention (row
         ``req.row`` of ``req.seed``), write the prefilled row + the
         whole sampling state row in two dispatches, return the first
-        token."""
+        token. The four children of ``engine.admit.first_token``
+        tile it: in ``sample``, ``insert`` and ``state`` the thread
+        issues puts and dispatches, in ``sync`` it is blocked on the
+        device (the first token's fetch waits out the prefill)."""
         cfg = self.cfg
-        row_key = jax.random.fold_in(
-            jax.random.PRNGKey(req.seed), req.row
-        )
-        first = first_sample(
-            logits, row_key, req.temperature, req.top_k, req.top_p,
-            cfg, eos_id=req.eos_id, min_new=req.min_new,
-            bias_idx=req.bias_idx, bias_val=req.bias_val,
-        )
-        first_host = int(jax.device_get(first))
-        self._pool = insert_row(
-            self._pool, row_cache, slot, cfg, self.out_sharding
-        )
+        span = phase_span(self.phases)
+        with span("engine.admit.first_token.sample"):
+            row_key = jax.random.fold_in(
+                jax.random.PRNGKey(req.seed), req.row
+            )
+            first = first_sample(
+                logits, row_key, req.temperature, req.top_k, req.top_p,
+                cfg, eos_id=req.eos_id, min_new=req.min_new,
+                bias_idx=req.bias_idx, bias_val=req.bias_val,
+            )
+        with span("engine.admit.first_token.sync"):
+            first_host = int(jax.device_get(first))
+        with span("engine.admit.first_token.insert"):
+            self._pool = insert_row(
+                self._pool, row_cache, slot, cfg, self.out_sharding
+            )
         done = first_host == req.eos_id or req.max_new <= 1
-        self._state = admit_slot_state(
-            self._state, slot, cfg,
-            last=first, key=row_key,
-            temperature=req.temperature, top_k=req.top_k,
-            top_p=req.top_p, eos_id=req.eos_id, pad_id=req.pad_id,
-            min_new=req.min_new, presence=req.presence,
-            frequency=req.frequency, bias_idx=req.bias_idx,
-            bias_val=req.bias_val, done=done,
-            out_sharding=self.out_sharding,
-        )
+        with span("engine.admit.first_token.state"):
+            self._state = admit_slot_state(
+                self._state, slot, cfg,
+                last=first, key=row_key,
+                temperature=req.temperature, top_k=req.top_k,
+                top_p=req.top_p, eos_id=req.eos_id, pad_id=req.pad_id,
+                min_new=req.min_new, presence=req.presence,
+                frequency=req.frequency, bias_idx=req.bias_idx,
+                bias_val=req.bias_val, done=done,
+                out_sharding=self.out_sharding,
+            )
         return first_host
 
     def retire(self, slot: int) -> None:

@@ -75,6 +75,7 @@ __all__ = [
     "ENGINE_CYCLE_PHASES",
     "ENGINE_PHASES",
     "EnginePhases",
+    "FIRST_TOKEN_PHASES",
     "NOTE_FIELDS",
     "PRODUCTIVE_STAGES",
     "STAGES",
@@ -118,14 +119,27 @@ ENGINE_CYCLE_PHASES = (
     "engine.wait_work", "engine.admit", "engine.dispatch",
     "engine.fetch", "engine.deliver",
 )
+#: the children that tile ``engine.admit.first_token``, opened by the
+#: step program where the work happens (models/stepprog.py ``admit``):
+#: ``sample`` (the row key, the first sample's puts and dispatch),
+#: ``insert`` (the row's write into the pool) and ``state`` (the slot
+#: state's puts and write) are the thread ISSUING work to the device;
+#: ``sync`` is the thread BLOCKED on it (the fetch of the first token,
+#: which waits out the prefill). A program that fetches no first token
+#: (models/block_diffusion.py) opens no ``sync``
+FIRST_TOKEN_PHASES = (
+    "engine.admit.first_token.sample", "engine.admit.first_token.sync",
+    "engine.admit.first_token.insert", "engine.admit.first_token.state",
+)
 #: every phase name: the cycle phases, then the children nested
-#: inside ``engine.admit`` (``kvtier.readmit`` inside ``reuse``), and
-#: ``kvtier.spill``, which runs beside them on the tier's ``kv-spill``
-#: thread since the spill is deferred (kvtier/spill.py)
+#: inside ``engine.admit`` (``kvtier.readmit`` inside ``reuse``, the
+#: four above inside ``first_token``), and ``kvtier.spill``, which
+#: runs beside them on the tier's ``kv-spill`` thread since the spill
+#: is deferred (kvtier/spill.py)
 ENGINE_PHASES = ENGINE_CYCLE_PHASES + (
     "engine.admit.reuse", "kvtier.readmit", "engine.admit.prefill",
     "engine.admit.store", "kvtier.spill", "engine.admit.first_token",
-)
+) + FIRST_TOKEN_PHASES
 #: the accumulator's plain counters, in ``snapshot()`` order
 _ENGINE_COUNTERS = (
     "admissions", "queue_wait_s", "dispatches_fused",
@@ -180,9 +194,11 @@ class EnginePhases:
     ``perf_counter`` read as the boundary, so the cycle phases tile
     the worker's wall time with no gap (one add and one count per
     boundary). ``span`` is a context manager for the children inside
-    an admission. Both open a ``jax.profiler.TraceAnnotation`` of the
-    phase's name (a flag test when no trace is running; absent where
-    jax is). Written by the engine's worker thread, read by the HTTP
+    an admission (the step program opens ``first_token``'s). Both open
+    a ``jax.profiler.TraceAnnotation`` of the phase's name, with the
+    keyword arguments as the event's arguments (what caused the span
+    and its size: a trace-only record, never in ``snapshot``); a flag
+    test when no trace is running, absent where jax is. Written by the engine's worker thread, read by the HTTP
     thread through ``snapshot``: plain float adds under the GIL, no
     lock on the decode loop. ``span`` alone has a second writer (the
     spill tier's ``kv-spill`` thread closes ``kvtier.spill`` while the
@@ -230,10 +246,14 @@ class EnginePhases:
         self._open, self._since = phase, now
         self._open_annotation = self._annotate(phase, **args)
 
-    def dispatched(self, now: float, fused: bool) -> None:
+    def dispatched(self, now: float, fused: bool, live: int) -> None:
         """Open ``engine.dispatch`` at ``now`` and count the dispatch
-        as the fused window program's or the single chunk's."""
-        self.switch("engine.dispatch", now, fused=int(fused))
+        as the fused window program's or the single chunk's. ``live``
+        is the pool's live rows at this dispatch (an upper bound inside
+        a fused window, where rows finish before its end): it rides on
+        the trace's event beside ``fused``, inside the traced window
+        where the device's times are."""
+        self.switch("engine.dispatch", now, fused=int(fused), live=live)
         if fused:
             self.dispatches_fused += 1
         else:

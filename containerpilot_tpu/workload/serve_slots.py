@@ -56,6 +56,7 @@ from ..models.decode import (
 from ..models.slots import append_chunk
 from ..models.stepprog import make_step_program
 from ..models.transformer import TransformerConfig
+from ..telemetry import tracing
 from ..telemetry.goodput import EnginePhases, name_os_thread
 from .serve_prefix import MIN_REUSE as PREFIX_MIN_REUSE
 
@@ -104,6 +105,11 @@ class _Request:
     # allocation-free; the caller converts the stamps to spans once,
     # after the future resolves (tracing.add_engine_spans).
     timings: Optional[dict] = None
+    # the id of the trace the request was submitted under ("" = none):
+    # its ``engine.admit`` event in a profiler trace carries it, so
+    # the request's spans on tracing's clock and the engine's span on
+    # the device's clock are the same request by name
+    trace: str = ""
     # submit's perf_counter stamp (the engine's clock): one float per
     # request, read once at admission for the phases' queue_wait_s
     enqueued: float = 0.0
@@ -261,6 +267,12 @@ class SlotEngine:
                 cfg, params, max_len, slots, chunk, rounds=window
             )
         self.program = program
+        # the program opens ``engine.admit.first_token``'s children
+        # where the work happens (an optional member of the contract:
+        # the speculative program brings none and records nothing)
+        attach = getattr(program, "attach_phases", None)
+        if attach is not None:
+            attach(self.phases)
         self.slots = program.slots
         self.chunk = program.chunk
         self.window = getattr(program, "rounds", 1)
@@ -305,7 +317,9 @@ class SlotEngine:
         client disconnect) frees the slot at the next chunk boundary
         — the future then resolves with whatever was emitted.
         ``timings`` (tracing) is stamped at request boundaries only —
-        see _Request.timings."""
+        see _Request.timings. A caller inside an active trace
+        (telemetry/tracing.py) has its trace id noted here, on its own
+        thread, for the admission's event in a profiler trace."""
         if max_new < 1:
             raise ValueError("max_new must be >= 1")
         if not 0 <= min_new <= max_new:
@@ -332,6 +346,7 @@ class SlotEngine:
             frequency=float(frequency_penalty),
             bias_idx=bias_idx, bias_val=bias_val,
             on_tokens=on_tokens, cancel=cancel, timings=timings,
+            trace=tracing.current_trace_id(),
         )
         validate = getattr(self.program, "validate", None)
         if validate is not None:
@@ -529,10 +544,13 @@ class SlotEngine:
         if first and (first_host == req.eos_id or req.max_new <= 1):
             state.finished = True
         self._active[slot_id] = state
-        # one admission = one prefill's worth of dispatches (the
-        # prefill program + first-sample/insert/admit ride together);
-        # counted as ONE toward dispatches/token so the series tracks
-        # the steady-state decode shape the megakernel work targets
+        # an admission is two to three dozen small device programs
+        # issued one by one (the prefill, the first sample, the row's
+        # insert, the state's write and a ``convert_element_type`` per
+        # scalar put: ``admit_device_programs_per_admission``), with
+        # ONE sync among them; it counts as ONE toward
+        # dispatches/token so the series tracks the steady-state
+        # decode shape the megakernel work targets
         self.dispatches += 1
         self.tokens_out += len(first)
         if req.timings is not None:
@@ -693,7 +711,14 @@ class SlotEngine:
                             return
                         block = False
                         t0 = time.perf_counter()  # exclude idle wait
-                        phases.switch("engine.admit", t0)
+                        # the span names its cause and its size: the
+                        # request (by its trace, where it came with
+                        # one), its prompt, the slot it takes
+                        cause = {"trace": req.trace} if req.trace else {}
+                        phases.switch(
+                            "engine.admit", t0, prompt=len(req.tokens),
+                            slot=free[0], **cause,
+                        )
                         admitted = True
                         if (
                             req.cancel is not None
@@ -712,7 +737,8 @@ class SlotEngine:
                 for i, s in enumerate(self._active):
                     if s is not None and s.finished:
                         self._harvest(i)
-                if not any(s is not None for s in self._active):
+                live = sum(s is not None for s in self._active)
+                if not live:
                     continue
                 # fuse K rounds only when no host decision can be
                 # pending: an admission just landed (more queued
@@ -726,7 +752,7 @@ class SlotEngine:
                     and not self._cancel_pending()
                 )
                 tj = time.perf_counter()
-                phases.dispatched(tj, fused and windowed)
+                phases.dispatched(tj, fused and windowed, live)
                 arm = self._sampler_arm()
                 try:
                     handle = program.dispatch(self._budgets(), fused)
@@ -749,14 +775,15 @@ class SlotEngine:
             # upper bound, see _budgets. Programs whose next dispatch
             # depends on this window's tokens (speculative
             # acceptance) opt out via supports_lookahead.
+            live = sum(s is not None for s in self._active)
             if (
                 program.supports_lookahead
-                and any(s is not None for s in self._active)
+                and live
                 and self._queue.empty()
                 and not self._cancel_pending()
             ):
                 tj = time.perf_counter()
-                phases.dispatched(tj, windowed)
+                phases.dispatched(tj, windowed, live)
                 try:
                     pending = (
                         program.dispatch(self._budgets(), True),

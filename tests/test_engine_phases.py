@@ -15,7 +15,12 @@ import jax.numpy as jnp
 import pytest
 
 from containerpilot_tpu.kvtier import HostSpillTier
-from containerpilot_tpu.models.decode import _jitted_extend, _jitted_prefill
+from containerpilot_tpu.models.decode import (
+    BIAS_SLOTS_MAX,
+    _jitted_extend,
+    _jitted_prefill,
+    normalize_logit_bias,
+)
 from containerpilot_tpu.models.slots import (
     _jitted_chunk,
     _jitted_window,
@@ -29,14 +34,17 @@ from containerpilot_tpu.models.transformer import (
 from containerpilot_tpu.telemetry.goodput import (
     ENGINE_CYCLE_PHASES,
     ENGINE_PHASES,
+    FIRST_TOKEN_PHASES,
     DeviceTimeLedger,
     EnginePhases,
     goodput_payload,
     process_start_monotonic,
 )
+from containerpilot_tpu.models.stepprog import PlainStepProgram
+from containerpilot_tpu.telemetry import tracing
 from containerpilot_tpu.telemetry.tracing import TraceRecorder
 from containerpilot_tpu.workload.serve_prefix import PrefixCache
-from containerpilot_tpu.workload.serve_slots import SlotEngine
+from containerpilot_tpu.workload.serve_slots import SlotEngine, _Request
 
 CFG = TransformerConfig(
     vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
@@ -107,6 +115,14 @@ def test_counts_move_per_window_not_per_token(params):
     assert moved["engine.admit"] == 1, moved
     assert moved["engine.admit.first_token"] == 1, moved
     assert moved["engine.admit.prefill"] == 1, moved
+    # the step program's children of ``first_token``: a constant an
+    # admission, whatever the tokens that follow
+    for child in FIRST_TOKEN_PHASES:
+        assert moved[child] == 1, (child, moved)
+    # admit, prefill, first_token and its four children: seven counts
+    # an admission, and the dispatches carry one ``live`` each
+    assert sum(n for p, n in moved.items()
+               if p.startswith("engine.admit")) == 7, moved
 
 
 def test_admissions_and_dispatch_kinds_are_counted(params):
@@ -290,6 +306,98 @@ def test_profiler_trace_holds_the_spill_on_the_kv_spill_line(
     assert "kvtier.spill" not in lines["slot-engine"]
 
 
+def test_first_tokens_children_tile_it_and_count_admissions(params):
+    """The step program opens four children inside
+    ``engine.admit.first_token`` (sample, sync, insert, state): their
+    seconds sum to the parent's within 2 %, as the cycle phases tile
+    the thread, and each is counted once an admission. Prompts of 200
+    tokens, so that ``sync`` has a prefill to wait out."""
+    eng = _engine(params, max_len=256)
+    try:
+        eng.submit([1, 2, 3], max_new=2).result(timeout=120)  # compile
+        eng.submit(list(range(1, 201)), max_new=2).result(timeout=120)
+        warm = {p: eng.phases.phase_s[p] for p in ENGINE_PHASES}
+        futures = [
+            eng.submit([1 + (i + j) % 60 for j in range(200)], max_new=3)
+            for i in range(6)
+        ]
+        for f in futures:
+            f.result(timeout=300)
+    finally:
+        eng.stop()
+    phases = eng.phases
+    assert phases.admissions == 8
+    # once everything is compiled an admission is a few ms here, and
+    # its five span boundaries some tens of us of Python: loose on a
+    # busy CPU, 2 % over the whole life (and on the chip: PERF.md)
+    moved = {p: phases.phase_s[p] - warm[p] for p in ENGINE_PHASES}
+    assert sum(moved[c] for c in FIRST_TOKEN_PHASES) >= (
+        0.9 * moved["engine.admit.first_token"]), moved
+    for child in FIRST_TOKEN_PHASES:
+        assert phases.phase_n[child] == phases.admissions, child
+        assert phases.phase_s[child] > 0.0, child
+    whole = phases.phase_s["engine.admit.first_token"]
+    parts = sum(phases.phase_s[c] for c in FIRST_TOKEN_PHASES)
+    assert parts <= whole
+    assert parts >= 0.98 * whole, (parts, whole, phases.phase_s)
+    snap = phases.snapshot()
+    assert set(FIRST_TOKEN_PHASES) <= set(snap["phase_s"])
+    assert set(FIRST_TOKEN_PHASES) <= set(snap["phase_n"])
+
+
+def test_a_block_diffusion_engine_records_insert_and_no_sync():
+    """models/block_diffusion.py's ``admit`` fetches no first token:
+    one program writes the row and its state (``insert``), nothing
+    waits for the prefill, and the other three children stay at 0."""
+    from test_block_diffusion import TOY, engine_for, ids, widened
+
+    cfg, weights = widened(TOY)
+    eng = engine_for(cfg, weights)
+    try:
+        with jax.default_matmul_precision("highest"):
+            eng.submit(ids(8, seed=61), 8).result(timeout=600)
+            eng.submit(ids(6, seed=62), 4).result(timeout=600)
+    finally:
+        eng.stop()
+    phases = eng.phases
+    assert phases.admissions == 2
+    assert phases.phase_n["engine.admit.first_token"] == 2
+    assert phases.phase_n["engine.admit.first_token.insert"] == 2
+    assert phases.phase_s["engine.admit.first_token.insert"] > 0.0
+    for child in ("sample", "sync", "state"):
+        name = f"engine.admit.first_token.{child}"
+        assert phases.phase_n[name] == 0 and phases.phase_s[name] == 0.0
+    assert phases.phase_s["engine.admit.first_token.insert"] >= (
+        0.9 * phases.phase_s["engine.admit.first_token"])
+
+
+def test_a_program_given_no_phases_admits_as_before(params):
+    """``attach_phases`` is an optional member of the step-program
+    contract: a program nobody handed an ``EnginePhases`` records
+    nothing, and writes the same row and returns the same token."""
+    prompt = jnp.asarray([[5, 6, 7, 8]], jnp.int32)
+    idx, val = normalize_logit_bias(CFG, 1, None, slots=BIAS_SLOTS_MAX)
+    req = _Request(
+        tokens=[5, 6, 7, 8], max_new=8, temperature=0.0, top_k=0,
+        top_p=0.0, eos_id=-1, pad_id=0, seed=3,
+        bias_idx=idx[0], bias_val=val[0],
+    )
+    firsts, pools = [], []
+    watched = EnginePhases()
+    for phases in (None, watched):
+        program = PlainStepProgram(CFG, params, 64, slots=2, chunk=CHUNK)
+        if phases is not None:
+            program.attach_phases(phases)
+        logits, row = _jitted_prefill(CFG, 64)(params, prompt)
+        firsts.append(program.admit(1, req, logits, row))
+        pools.append(jax.device_get((program._pool, program._state)))
+    assert firsts[0] == firsts[1]
+    same = jax.tree.map(lambda a, b: bool((a == b).all()), *pools)
+    assert all(jax.tree.leaves(same))
+    assert [watched.phase_n[c] for c in FIRST_TOKEN_PHASES] == [1, 1, 1, 1]
+    assert PlainStepProgram.phases is None  # the class hands out none
+
+
 OLD_GOODPUT_KEYS = {
     "stage", "uptime_s", "stages_s", "productive_s",
     "productive_fraction", "transitions", "first_productive_at",
@@ -339,24 +447,43 @@ def test_ledger_boot_starts_with_the_process():
     assert snap["uptime_s"] >= now - started - 0.01
 
 
-def _host_lines(trace_dir):
-    """line name -> event names, over the host plane of the newest
-    trace under ``trace_dir``."""
+def _host_planes(trace_dir):
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(
         f"{trace_dir}/plugins/profile/*/*.xplane.pb"
     ))
     assert paths, f"no trace under {trace_dir}"
+    return [plane for plane in ProfileData.from_file(paths[-1]).planes
+            if plane.name.startswith("/host:CPU")]
+
+
+def _host_lines(trace_dir):
+    """line name -> event names, over the host plane of the newest
+    trace under ``trace_dir``."""
     lines = {}
-    for plane in ProfileData.from_file(paths[-1]).planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
+    for plane in _host_planes(trace_dir):
         for line in plane.lines:
             lines.setdefault(line.name, set()).update(
                 ev.name for ev in line.events
             )
     return lines
+
+
+def _engine_events(trace_dir):
+    """The ``slot-engine`` line's events in order of their start, as
+    (name, start_ns, end_ns, {argument: value})."""
+    events = []
+    for plane in _host_planes(trace_dir):
+        for line in plane.lines:
+            if line.name != "slot-engine":
+                continue
+            for ev in line.events:
+                events.append((
+                    ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats),
+                ))
+    return sorted(events, key=lambda e: (e[1], -e[2]))
 
 
 def test_profiler_trace_holds_the_phases_on_the_slot_engine_line(
@@ -381,6 +508,61 @@ def test_profiler_trace_holds_the_phases_on_the_slot_engine_line(
                   "engine.fetch", "engine.deliver"):
         assert phase in names, (phase, sorted(
             n for n in names if n.startswith("engine")))
+
+
+def test_profiler_trace_nests_the_children_and_carries_the_arguments(
+    params, tmp_path
+):
+    """In a profiler trace the four children lie on the ``slot-engine``
+    line inside their ``engine.admit.first_token``, back to back and
+    in order; ``engine.admit`` says which request caused it (its
+    prompt's tokens, its slot and, where the request was submitted
+    under a trace of telemetry/tracing.py, that trace's id), and
+    ``engine.dispatch`` how many rows were live: the two the test
+    admitted."""
+    eng = _engine(params)
+    recorder = TraceRecorder("replica")
+    try:
+        eng.submit([1, 2, 3], max_new=2).result(timeout=120)  # compile
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            trace = recorder.start("feedc0dedeadbeef", "/v1/generate")
+            token = tracing.activate(trace)
+            try:
+                traced = eng.submit([4, 5, 6, 7], max_new=40)
+            finally:
+                tracing.deactivate(token)
+            bare = eng.submit([8, 9], max_new=40)
+            assert len(traced.result(timeout=120)) == 40
+            assert len(bare.result(timeout=120)) == 40
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    events = _engine_events(str(tmp_path))
+    admits = [e for e in events if e[0] == "engine.admit"]
+    assert [a[3]["prompt"] for a in admits] == [4, 2], admits
+    assert sorted(a[3]["slot"] for a in admits) == [0, 1], admits
+    assert admits[0][3]["trace"] == "feedc0dedeadbeef"
+    assert "trace" not in admits[1][3]  # submitted under no trace
+    parents = [e for e in events if e[0] == "engine.admit.first_token"]
+    assert len(parents) == 2
+    for admit, parent in zip(admits, parents):
+        assert admit[1] <= parent[1] and parent[2] <= admit[2]
+        inside = [e for e in events
+                  if e[0] in FIRST_TOKEN_PHASES
+                  and parent[1] <= e[1] and e[2] <= parent[2]]
+        assert [e[0] for e in inside] == list(FIRST_TOKEN_PHASES)
+        for before, after in zip(inside, inside[1:]):
+            assert before[2] <= after[1]  # back to back, never nested
+        assert not any(e[3] for e in inside)  # the admission names them
+    dispatches = [e for e in events if e[0] == "engine.dispatch"]
+    assert len(dispatches) >= 2
+    assert {d[3]["fused"] for d in dispatches} <= {0, 1}
+    # both requests were queued before the first dispatch and run 40
+    # tokens: every dispatch but the last ones sees both rows live
+    assert dispatches[0][3]["live"] == 2
+    assert {d[3]["live"] for d in dispatches} <= {1, 2}
 
 
 def test_profiler_trace_holds_train_step_for_a_two_step_trainer(tmp_path):

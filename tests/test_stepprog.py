@@ -4,7 +4,10 @@ models/speculative.py::SpeculativeStepProgram): byte parity between
 fused and sequential decode at the models level AND the engine level,
 speculative-as-step-program parity with speculative_generate,
 cancel-mid-window retirement with the PR 9 decode-accounting
-contract, and honest dispatch counters under fusion."""
+contract, honest dispatch counters under fusion, and the contract's
+optional ``attach_phases`` member (a program that takes the engine's
+phases opens ``engine.admit.first_token``'s children; one that brings
+no such member records none)."""
 import threading
 import time
 
@@ -31,6 +34,7 @@ from containerpilot_tpu.models.transformer import (
     TransformerConfig,
     init_params,
 )
+from containerpilot_tpu.telemetry.goodput import FIRST_TOKEN_PHASES
 from containerpilot_tpu.workload.serve_slots import SlotEngine
 
 CFG = TransformerConfig(
@@ -370,6 +374,30 @@ def test_speculative_program_matches_speculative_generate():
         assert eng.dispatches == len(cases) + 2 * ref_rounds
     finally:
         eng.stop()
+    # the program brings no ``attach_phases`` (an optional member):
+    # the engine records the admission's own phases and no child of
+    # ``engine.admit.first_token``
+    assert not hasattr(SpeculativeStepProgram, "attach_phases")
+    assert eng.phases.phase_n["engine.admit.first_token"] == len(cases)
+    assert not any(
+        eng.phases.phase_n[child] for child in FIRST_TOKEN_PHASES)
+
+
+def test_the_engine_hands_its_phases_to_a_program_that_takes_them(params):
+    """The contract's optional member ``attach_phases``: the default
+    program is handed the engine's ``EnginePhases`` at construction
+    (the ledger's, where the engine has one), and its ``admit`` opens
+    the children of ``engine.admit.first_token`` on it."""
+    from containerpilot_tpu.telemetry.goodput import DeviceTimeLedger
+
+    ledger = DeviceTimeLedger()
+    eng = SlotEngine(CFG, params, MAX_LEN, slots=2, chunk=4, ledger=ledger)
+    try:
+        assert eng.program.phases is eng.phases is ledger.engine
+        eng.submit([1, 2, 3], max_new=3).result(timeout=120)
+    finally:
+        eng.stop()
+    assert [ledger.engine.phase_n[c] for c in FIRST_TOKEN_PHASES] == [1] * 4
 
 
 def test_speculative_program_rejects_bad_shapes():
