@@ -329,7 +329,8 @@ def _grouped_attention(
 
 
 def decode_chunk(
-    params: Params, cache: Cache, tokens: jax.Array, cfg: TransformerConfig
+    params: Params, cache: Cache, tokens: jax.Array, cfg: TransformerConfig,
+    read_len: Optional[int] = None,
 ) -> Tuple[jax.Array, Cache]:
     """Process m tokens against the cache in ONE forward — the verify
     step of speculative decoding (m = speculate+1), and the general
@@ -365,8 +366,25 @@ def decode_chunk(
     ``max_len``: repeating it to n_heads and widening it to float32 in
     memory first made every step write and re-read about twenty times
     the cache's size (PERF.md, PR 26), so neither copy is ever built.
+
+    ``read_len`` (static; a linear cache only) cuts what attention
+    READS to each leaf's first ``read_len`` positions; the chunk's
+    keys and values are still written into the whole leaf, in place.
+    The caller promises that no row whose output it keeps reaches past
+    it (``pos + m <= read_len``: the slot pool's step program counts
+    that on the host, models/stepprog.py). A masked position weighs
+    exactly 0.0 in the float32 softmax, so the cut drops exact zeros
+    from the sums and nothing else: the same mathematics at the same
+    precision, and a step that reads a pool of 4,096-position rows to
+    the 1,024th reads a quarter of the bytes. None (every other
+    caller) reads whole leaves: the program it always was.
     """
     family = getattr(cfg, "family", None)
+    if read_len is not None and (family is not None or cfg.window > 0):
+        raise ValueError(
+            "read_len cuts a linear cache's read: this configuration's "
+            "cache is its family's own or a ring"
+        )
     if family is not None:
         return family.decode_chunk(params, cache, tokens, cfg)
     pos = cache["pos"]
@@ -385,6 +403,12 @@ def decode_chunk(
         raise ValueError(
             f"decode chunk of {m} tokens exceeds the {length}-slot "
             "window ring; chunk at most `window` tokens"
+        )
+    # how far attention reads a leaf: all of it, or ``read_len``
+    reach = length if read_len is None else read_len
+    if not 0 < reach <= length:
+        raise ValueError(
+            f"read_len {read_len} outside the cache's {length} positions"
         )
     x = embed_lookup(params, tokens, cfg.dtype)  # [b, m, d]
     # one position for every row, or one per row: [1 or b, m]
@@ -413,7 +437,7 @@ def decode_chunk(
         )
         write_idx = jnp.mod(q_pos, length)
     else:
-        valid = jnp.arange(length) <= q_pos[:, :, None]  # [1 or b, m, length]
+        valid = jnp.arange(reach) <= q_pos[:, :, None]  # [1 or b, m, reach]
         write_idx = q_pos
     rows = jnp.arange(b)[:, None]
     # int8-quantized dense models run their projections through the
@@ -434,12 +458,17 @@ def decode_chunk(
             )
         return leaf.at[rows, write_idx].set(new, mode="drop")
 
+    def cut(leaf):
+        """A leaf as far as attention reads it (``read_len``)."""
+        return leaf if reach == length else leaf[:, :reach]
+
     def read(layer, name):
         """The layer's keys or values as attention contracts them."""
         if not kv_int8:
-            return kv[name][layer]
+            return cut(kv[name][layer])
         return _kv_dequant(
-            kv[name][layer], kv[name + "_scale"][layer], cfg.dtype
+            cut(kv[name][layer]), cut(kv[name + "_scale"][layer]),
+            cfg.dtype,
         )
 
     with jax.named_scope("layers"):

@@ -28,7 +28,7 @@ makes; PERF.md, PR 28, has what the stacked form cost). Rows keep
 ``prefill``'s format (stacked, one row): ``insert_row`` writes each
 layer's part at the slot, and rows never leave the pool.
 
-Every step reads the whole pool (live positions or not), so what a
+Every step reads every row of the pool, dead or live, so what a
 step costs is what attention does with those bytes: ``decode_chunk``
 contracts the query heads, grouped as [kv_heads, group], with each
 layer's keys and values as the pool stores them (bf16, kv heads only,
@@ -36,6 +36,13 @@ float32 accumulation). Nothing the size of the pool is repeated to
 n_heads or written out in float32 on the way: tests/test_decode_gqa.py
 pins that on the chunk program's lowered text, tests/
 test_tpu_compile.py on the program the v5e's compiler makes of it.
+How FAR a step reads each row is the programs' static ``read_len``,
+one of a short ladder of lengths (``read_ladder``): the step program
+picks, at every dispatch, the shortest rung that the longest live row
+will not pass, from a count it keeps on the host (models/stepprog.py),
+so a pool of 4,096-position rows whose live rows stand under 1,024
+reads a quarter of its bytes. Writes go to the whole leaf whatever the
+rung. A family's pool and a ring have the one rung ``max_len``.
 
 Sampling reproduces ``generate``'s schedule exactly: per-row key =
 ``jax.random.split(PRNGKey(seed), 1)[0]``, sample i uses
@@ -296,15 +303,54 @@ def _zero_stats(pool: Cache) -> Cache:
     return {**pool, "stats": jnp.zeros_like(pool["stats"])}
 
 
-def _round_step_body(params, state, cfg):
+#: the shortest read length of a linear pool's ladder, and the ratio
+#: of a rung to the one below it
+READ_LADDER_BASE = 1024
+READ_LADDER_STEP = 4
+
+
+def read_ladder(cfg: TransformerConfig, max_len: int) -> Tuple[int, ...]:
+    """The read lengths a pool's decode programs are compiled for,
+    shortest first, from what the configuration's CACHE FORM allows
+    and ``max_len`` alone: a linear cache takes ``READ_LADDER_BASE``
+    times ``READ_LADDER_STEP`` while under ``max_len``, then
+    ``max_len`` itself (4,096: 1,024 and 4,096); a family's own cache
+    or a ring is read whole, the one rung ``max_len``.
+
+    Few and wide on purpose. Each rung is a chunk program and a
+    fused-window program that a boot lowers (a second of the
+    interpreter each on the benchmark's host, which no thread hides)
+    and compiles or loads: at 512 doubling, eight programs where two
+    stood, the flagship's warm set-up took 17 % longer; at these two
+    rungs, 7 % (PERF.md, PR 41). What the wider rung gives
+    away is small where the step is bound by what it reads beside the
+    pool: 3.50 ms a step at 1,024 against 3.04 at 512 and 4.37 at
+    4,096 (same place)."""
+    if getattr(cfg, "family", None) is not None or cfg.window > 0:
+        return (max_len,)
+    rungs = []
+    rung = READ_LADDER_BASE
+    while rung < max_len:
+        rungs.append(rung)
+        rung *= READ_LADDER_STEP
+    return (*rungs, max_len)
+
+
+def _round_step_body(params, state, cfg, read_len=None):
     """The ONE per-token step body (scan shape) shared by the chunk
     program and the fused K-round window program: both trace exactly
     this function, so a fused window is the same computation as K
-    sequential chunk rounds token for token — the byte-parity
-    contract between them holds by construction, not by numerical
-    luck. The step is one ``decode_chunk`` over the pool, a cache of
+    sequential chunk rounds token for token — the parity contract
+    between them holds by construction, not by numerical luck: byte
+    for byte at equal ``read_len``. A window and the chunk dispatches
+    it stands for may run different rungs of the ladder (the step
+    program picks one a dispatch); across rungs the sums differ by
+    exact zeros only, so the logits agree to the rounding of the
+    compiler's own reduction tiling and the tokens agree token for
+    token. The step is one ``decode_chunk`` over the pool, a cache of
     S rows each at its own ``pos``: tokens [S, 1] -> logits [S, 1, V],
-    every layer's keys and values written and read where they lie.
+    every layer's keys and values written where they lie and read as
+    far as ``read_len`` (None: whole rows).
     Carry: (pool, last_token, done, step_idx, counts)."""
     row_keys = state["keys"]
     pad_id = state["pad_id"]
@@ -313,7 +359,7 @@ def _round_step_body(params, state, cfg):
     def body(carry, _):
         pool, tok, done, idx, counts = carry
         logits, pool = decode_chunk(  # [S, 1, V]
-            params, pool, tok[:, None], cfg
+            params, pool, tok[:, None], cfg, read_len=read_len
         )
         with jax.named_scope("sample"):
             masked = apply_token_penalties(
@@ -342,10 +388,14 @@ def _round_step_body(params, state, cfg):
     return body
 
 
-@functools.lru_cache(maxsize=8)
+# (both caches hold a whole ladder for every configuration alive in
+# the process: a rung that fell out would compile again under traffic)
+@functools.lru_cache(maxsize=64)
 def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
-                  out_sharding=None):
-    """One compiled program advancing every slot ``chunk`` tokens.
+                  out_sharding=None, read_len=None):
+    """One compiled program advancing every slot ``chunk`` tokens,
+    attention reading each row's first ``read_len`` positions (None:
+    whole rows; see ``read_ladder``).
 
     Operands: the pool cache and the per-slot sampling-state dict
     (SLOT_STATE_KEYS), BOTH donated — the per-round dispatch ships
@@ -359,7 +409,7 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
 
     def run(params, pool, state):
         pool = _zero_stats(pool)
-        body = _round_step_body(params, state, cfg)
+        body = _round_step_body(params, state, cfg, read_len)
         # ``steps`` names what the step loop itself does around its
         # body (nothing the size of the pool: PERF.md s.5)
         with jax.named_scope("steps"):
@@ -380,16 +430,17 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
     )
 
 
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=64)
 def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
-                   rounds: int, out_sharding=None):
+                   rounds: int, out_sharding=None, read_len=None):
     """K = ``rounds`` chunk-rounds fused into ONE dispatched program:
     a device-side ``lax.while_loop`` whose body is the exact per-step
     scan ``_jitted_chunk`` runs (``_round_step_body``), so the tokens
     a window emits are byte-identical to K sequential chunk
-    dispatches. The loop exits EARLY when no slot is live — a slot is
-    live while its device ``done`` flag is clear AND it still has
-    window budget (``budget`` [S] int32, the host's remaining
+    dispatches of the same ``read_len`` (token for token across
+    rungs: see ``_round_step_body``). The loop exits EARLY when no
+    slot is live — a slot is live while its device ``done`` flag is
+    clear AND it still has window budget (``budget`` [S] int32, the host's remaining
     max_new allowance per slot). Budget gates ONLY the exit test,
     never the emission: a slot past its budget keeps decoding real
     (append-discarded) tokens exactly like the sequential engine
@@ -403,7 +454,7 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
 
     def run(params, pool, state, budget):
         pool = _zero_stats(pool)
-        body = _round_step_body(params, state, cfg)
+        body = _round_step_body(params, state, cfg, read_len)
         pad = state["pad_id"].astype(jnp.int32)
         out0 = jnp.broadcast_to(
             pad[:, None], (slots, rounds * chunk)
@@ -442,6 +493,47 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
     )
 
 
+def compile_decode_programs(
+    params: Params,
+    pool: Cache,
+    state: dict,
+    cfg: TransformerConfig,
+    chunk: int,
+    rounds: int,
+    read_lens,
+    out_sharding=None,
+) -> None:
+    """Compile, or load from the compile cache, the chunk program and
+    (``rounds`` > 1) the fused-window program of every read length in
+    ``read_lens``, SIDE BY SIDE: each is lowered here, one after
+    another (the interpreter's work), and the lowered programs compile
+    on a thread each (the compiler's, outside the interpreter's lock),
+    so a ladder of rungs costs a boot about what one rung does. The
+    arguments give shapes and placement only: nothing runs, nothing is
+    donated. A jitted function's first call then finds its executable
+    made (jax keeps one per lowering, whoever asked for it); where a
+    jax does not, that call compiles as it always did."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    slots = int(state["last"].shape[0])
+    budget = jnp.zeros((slots,), jnp.int32)
+    lowered = []
+    for read_len in read_lens:
+        lowered.append(
+            _jitted_chunk(cfg, slots, chunk, out_sharding, read_len)
+            .lower(params, pool, state)
+        )
+        if rounds > 1:
+            lowered.append(
+                _jitted_window(
+                    cfg, slots, chunk, rounds, out_sharding, read_len
+                ).lower(params, pool, state, budget)
+            )
+    with ThreadPoolExecutor(len(lowered)) as threads:
+        for _ in threads.map(lambda low: low.compile(), lowered):
+            pass
+
+
 def decode_slots_chunk(
     params: Params,
     pool: Cache,
@@ -450,6 +542,7 @@ def decode_slots_chunk(
     chunk: int,
     out_sharding=None,
     with_stats: bool = False,
+    read_len=None,
 ):
     """Advance the whole pool ``chunk`` tokens; see _jitted_chunk.
     ``state`` is the device-resident per-slot sampling dict
@@ -460,9 +553,11 @@ def decode_slots_chunk(
     the whole state dict are donated. ``out_sharding`` pins every
     output's placement (see _jitted_insert) — the pod passes
     fully-replicated. ``with_stats`` appends the pool's ``stats``
-    (None where it has none)."""
+    (None where it has none). ``read_len``: how far attention reads
+    each row, a rung of ``read_ladder`` that no live row passes in
+    these ``chunk`` steps (None: whole rows)."""
     slots = int(state["last"].shape[0])
-    out = _jitted_chunk(cfg, slots, chunk, out_sharding)(
+    out = _jitted_chunk(cfg, slots, chunk, out_sharding, read_len)(
         params, pool, state
     )
     return out if with_stats else out[:3]
@@ -478,6 +573,7 @@ def decode_slots_window(
     budget,
     out_sharding=None,
     with_stats: bool = False,
+    read_len=None,
 ):
     """Advance the whole pool up to ``rounds`` chunk-rounds in ONE
     host->device dispatch (see _jitted_window): the device loops over
@@ -486,10 +582,13 @@ def decode_slots_window(
     remaining-token allowances — the one small host->device upload a
     window pays, per K rounds instead of per round). Returns
     (pool, state, tokens [S, rounds*chunk], rounds_run); pool and
-    state are donated, ``out_sharding`` pins output placement exactly
-    like decode_slots_chunk's."""
+    state are donated, ``out_sharding`` pins output placement and
+    ``read_len`` cuts the read exactly like decode_slots_chunk's (no
+    live row may pass it in ``rounds * chunk`` steps)."""
     slots = int(state["last"].shape[0])
-    out = _jitted_window(cfg, slots, chunk, rounds, out_sharding)(
+    out = _jitted_window(
+        cfg, slots, chunk, rounds, out_sharding, read_len
+    )(
         params, pool, state, jnp.asarray(budget, jnp.int32)
     )
     return out if with_stats else out[:4]

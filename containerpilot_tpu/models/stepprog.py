@@ -64,7 +64,11 @@ several tokens of a row's block, or none; blocks are handed over
 whole). ``make_step_program`` picks the right default for a params
 pytree. A program may also bring ``validate(req)`` (refuse what it
 cannot serve, at submit), ``warm_new`` (the new tokens a warm-up
-request needs to reach the fused window) and ``attach_phases(phases)``:
+request needs to reach the fused window), ``ladder`` with
+``read_len(fused)`` and ``warm_ladder()`` (the read lengths its decode
+programs are compiled for, the one the next dispatch will run, and a
+run of each on the idle pool: :class:`PlainStepProgram`; a program
+without them reads whole rows) and ``attach_phases(phases)``:
 the engine hands it its ``EnginePhases`` (telemetry/goodput.py), and
 ``admit`` then opens the children of ``engine.admit.first_token``
 where the work happens (``.sample``, ``.sync``, ``.insert``,
@@ -81,11 +85,13 @@ import numpy as np
 
 from .slots import (
     admit_slot_state,
+    compile_decode_programs,
     decode_slots_chunk,
     decode_slots_window,
     first_sample,
     init_slot_state,
     insert_row,
+    read_ladder,
     retire_slot,
     slot_cache,
 )
@@ -105,7 +111,21 @@ class PlainStepProgram:
     (``fused=False``) or the K-round fused window
     (``decode_slots_window``, ``fused=True``) — one device dispatch
     either way. ``out_sharding`` pins output placement (the pod's
-    mirror passes fully-replicated)."""
+    mirror passes fully-replicated).
+
+    Every dispatch reads the pool's rows only as far as the longest
+    LIVE row will reach in it: ``ladder`` (models/slots.py
+    ``read_ladder``) holds the read lengths the two programs are
+    compiled for, and ``read_len`` picks the shortest that is enough
+    from ``_reach``, one host integer a slot: an upper bound of the
+    row's device ``pos``, set at ``admit`` to the prefilled prompt's
+    length, advanced at every ``dispatch`` by the steps it MAY run,
+    cleared at ``retire``. A fused window that exits early leaves the
+    bound too large, never too small (every row that was live in such
+    a window has ended, and the engine retires it at that window's
+    fetch); a retired slot decodes pads on and its device ``pos`` runs
+    away, but its output is discarded, so it does not count. No device
+    read, no sync and no operand of its own."""
 
     supports_lookahead = True
     dispatch_cost = 1
@@ -134,11 +154,17 @@ class PlainStepProgram:
         #: the pool's ``stats`` leaf summed over every fetched round
         #: (None until a round brings one)
         self.stats_total = None
+        #: the read lengths the decode programs are compiled for,
+        #: shortest first, the last ``max_len`` (one rung: whole rows)
+        self.ladder = read_ladder(cfg, max_len)
         self.reset()
 
     def reset(self) -> None:
         self._pool = slot_cache(self.cfg, self.slots, self.max_len)
         self._state = init_slot_state(self.cfg, self.slots)
+        # per slot, an upper bound of the row's ``pos`` on the device
+        # (0: a free slot, whose row counts for nothing)
+        self._reach = [0] * self.slots
 
     def attach_phases(self, phases) -> None:
         self.phases = phases
@@ -180,27 +206,85 @@ class PlainStepProgram:
                 bias_val=req.bias_val, done=done,
                 out_sharding=self.out_sharding,
             )
+        # the prefilled row stands at the end of its whole prompt,
+        # whatever part of it a reused prefix supplied
+        self._reach[slot] = len(req.tokens)
         return first_host
 
     def retire(self, slot: int) -> None:
         self._state = retire_slot(
             self._state, slot, self.out_sharding
         )
+        self._reach[slot] = 0
+
+    def _steps(self, fused: bool) -> int:
+        """The steps a dispatch may run at most."""
+        return self.chunk * (self.rounds if fused else 1)
+
+    def read_len(self, fused: bool) -> int:
+        """The rung a dispatch issued NOW would read: the shortest
+        that the longest occupied row does not pass in the dispatch's
+        own steps, ``max_len`` where none is enough (a row decoding
+        past its budget behind the engine's lookahead: the leaf takes
+        no write past its end)."""
+        need = max(self._reach) + self._steps(fused)
+        for rung in self.ladder:
+            if rung >= need:
+                return rung
+        return self.max_len
+
+    def warm_ladder(self) -> None:
+        """Compile every rung's chunk program and fused-window program
+        side by side (``compile_decode_programs``), then run each once
+        on the IDLE pool (every slot retired: the chunk program steps
+        dead rows, the window program exits at once), so that no rung
+        compiles, or loads from the compile cache, under traffic: a
+        warm-up request reaches the first rung only."""
+        if any(self._reach):
+            raise RuntimeError("warm_ladder needs an idle pool")
+        compile_decode_programs(
+            self.params, self._pool, self._state, self.cfg, self.chunk,
+            self.rounds, [self._read_len(rung) for rung in self.ladder],
+            self.out_sharding,
+        )
+        no_budget = np.zeros((self.slots,), np.int32)
+        for rung in self.ladder:
+            jax.device_get(self._run(no_budget, False, rung)[0])
+            if self.rounds > 1:
+                jax.device_get(self._run(no_budget, True, rung)[0])
+
+    def _read_len(self, rung: int):
+        """A rung as the programs' static ``read_len``: the last rung
+        is the whole row, today's program to the letter (None)."""
+        return None if rung == self.max_len else rung
 
     # cpcheck: hotpath — the fused window dispatch: one device call,
     # zero host syncs (the budgets upload is async and per-window)
     def dispatch(self, budgets, fused: bool):
-        if fused and self.rounds > 1:
+        fused = fused and self.rounds > 1
+        rung = self.read_len(fused)
+        steps = self._steps(fused)
+        self._reach = [
+            reach + steps if reach else 0 for reach in self._reach
+        ]
+        return self._run(budgets, fused, rung)
+
+    def _run(self, budgets, fused: bool, rung: int):
+        """One dispatch of the window (``fused``) or chunk program
+        that reads ``rung`` positions a row."""
+        read_len = self._read_len(rung)
+        if fused:
             (self._pool, self._state, toks, run,
              stats) = decode_slots_window(
                 self.params, self._pool, self._state, self.cfg,
                 self.chunk, self.rounds, budgets, self.out_sharding,
-                with_stats=True,
+                with_stats=True, read_len=read_len,
             )
             return toks, run, stats
         self._pool, self._state, toks, stats = decode_slots_chunk(
             self.params, self._pool, self._state, self.cfg,
             self.chunk, self.out_sharding, with_stats=True,
+            read_len=read_len,
         )
         return toks, None, stats
 

@@ -213,6 +213,8 @@ class EnginePhases:
         self.queue_wait_s = 0.0
         self.dispatches_fused = 0
         self.dispatches_single = 0
+        #: dispatches by the length each read of the pool's rows
+        self.read_len_dispatches: Dict[int, int] = {}
         self.store_bytes = 0
         self.spill_bytes = 0
         self.readmit_bytes = 0
@@ -246,18 +248,28 @@ class EnginePhases:
         self._open, self._since = phase, now
         self._open_annotation = self._annotate(phase, **args)
 
-    def dispatched(self, now: float, fused: bool, live: int) -> None:
+    def dispatched(
+        self, now: float, fused: bool, live: int, read_len: int
+    ) -> None:
         """Open ``engine.dispatch`` at ``now`` and count the dispatch
-        as the fused window program's or the single chunk's. ``live``
-        is the pool's live rows at this dispatch (an upper bound inside
-        a fused window, where rows finish before its end): it rides on
-        the trace's event beside ``fused``, inside the traced window
-        where the device's times are."""
-        self.switch("engine.dispatch", now, fused=int(fused), live=live)
+        as the fused window program's or the single chunk's, and under
+        the length it reads of every row of the pool (``read_len``:
+        the step program's rung, ``max_len`` for one that reads whole
+        rows). ``live`` is the pool's live rows at this dispatch (an
+        upper bound inside a fused window, where rows finish before
+        its end): both ride on the trace's event beside ``fused``,
+        inside the traced window where the device's times are."""
+        self.switch(
+            "engine.dispatch", now, fused=int(fused), live=live,
+            read_len=read_len,
+        )
         if fused:
             self.dispatches_fused += 1
         else:
             self.dispatches_single += 1
+        self.read_len_dispatches[read_len] = (
+            self.read_len_dispatches.get(read_len, 0) + 1
+        )
 
     def close(self, now: float) -> None:
         """Close the open cycle phase, if any (the worker's exit)."""
@@ -296,6 +308,12 @@ class EnginePhases:
         for name in _ENGINE_COUNTERS:
             value = getattr(self, name)
             out[name] = round(value, 6) if isinstance(value, float) else value
+        # (copied whole first: the worker adds a key at a rung's first
+        # dispatch)
+        out["read_len_dispatches"] = {
+            str(rung): n
+            for rung, n in sorted(dict(self.read_len_dispatches).items())
+        }
         return out
 
 

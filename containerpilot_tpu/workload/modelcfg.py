@@ -6,7 +6,7 @@ apart and score/serve a differently-shaped model than was trained.
 from __future__ import annotations
 
 import os
-from typing import Any, Iterable, Optional, Tuple
+from typing import Any, Iterable, Optional, Sequence, Tuple
 
 import jax
 
@@ -83,6 +83,7 @@ def warmup_fingerprint(
     draft_layers: int = 0,
     speculate: int = 0,
     mesh: Optional[dict] = None,
+    read_ladder: Sequence[int] = (),
 ) -> str:
     """Stable hash of everything that shapes the warmup program set:
     a marker written under one fingerprint must never skip warmup for
@@ -126,6 +127,11 @@ def warmup_fingerprint(
             # marker must never skip the fused program a K=4 launch
             # needs
             "slot_window": slot_window,
+            # the read lengths the decode programs are compiled for
+            # (models/slots.py read_ladder): a marker written before
+            # the ladder, or for another one, vouches for other
+            # programs
+            "read_ladder": list(read_ladder),
             "draft_layers": draft_layers,
             "speculate": speculate,
             # the mesh the params are sharded over: a --tp 4 server's

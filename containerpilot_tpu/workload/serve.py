@@ -1902,6 +1902,8 @@ class InferenceServer:
             # program a K=4 launch needs (PR 13's compile-cache skip
             # stays correct only if K is part of the identity)
             slot_window=engine.window,
+            # ...and so do the read lengths its decode programs come in
+            read_ladder=engine.read_ladder,
             draft_layers=(
                 self.draft_cfg.n_layers
                 if self.draft_cfg is not None else 0
@@ -1960,6 +1962,14 @@ class InferenceServer:
                 None, load_warm_buckets,
                 self.compile_cache_dir, fingerprint,
             )
+        # the decode programs come in a ladder of read lengths, and a
+        # warm-up request reaches the first only: the step program
+        # compiles them side by side and runs each once on the idle
+        # pool (models/stepprog.py warm_ladder). Marked warm or not: a
+        # rung that met its first dispatch under traffic would stall a
+        # window on a compile, or on the cache's load of one. First,
+        # so that the request below finds its two programs made
+        await asyncio.wrap_future(self.slot_engine.warm_programs())
         buckets = {"slots"}
         if "slots" not in warm:
             # one dummy request through the engine compiles its whole

@@ -117,6 +117,13 @@ class _Request:
 
 
 @dataclass
+class _Warm:
+    """``warm_programs``'s place in the queue: the worker runs the
+    step program's own warm-up when no slot is occupied."""
+    future: Future = field(default_factory=Future)
+
+
+@dataclass
 class _Slot:
     req: _Request
     emitted: List[int] = field(default_factory=list)
@@ -276,6 +283,13 @@ class SlotEngine:
         self.slots = program.slots
         self.chunk = program.chunk
         self.window = getattr(program, "rounds", 1)
+        # how far a dispatch reads the pool's rows is the program's to
+        # say (``ladder``, ``read_len``: optional members); one that
+        # does not say reads them whole
+        self.read_ladder = tuple(getattr(program, "ladder", (max_len,)))
+        self._read_len = getattr(
+            program, "read_len", lambda fused: max_len
+        )
         self._active: List[Optional[_Slot]] = [None] * self.slots
         self._queue: "queue.Queue[Optional[_Request]]" = queue.Queue()
         self._submit_lock = threading.Lock()
@@ -362,6 +376,23 @@ class SlotEngine:
             self._queue.put(req)
         return req.future
 
+    def warm_programs(self) -> Future:
+        """Have the step program run what a warm-up REQUEST does not
+        reach (``warm_ladder``: every read length of its decode
+        programs, models/stepprog.py) on the worker thread, the one
+        owner of the donated pool, at its first cycle with no slot
+        occupied. Resolves when that is done; at once for a program
+        that brings no such member."""
+        item = _Warm()
+        if getattr(self.program, "warm_ladder", None) is None:
+            item.future.set_result(None)
+            return item.future
+        with self._submit_lock:
+            if self._stopped.is_set():
+                raise RuntimeError("engine is stopped")
+            self._queue.put(item)
+        return item.future
+
     def stop(self) -> None:
         with self._submit_lock:
             self._stopped.set()
@@ -384,6 +415,7 @@ class SlotEngine:
 
     @property
     def stats(self) -> dict:
+        counted = dict(self.phases.read_len_dispatches)
         return {
             "slots": self.slots,
             "chunk": self.chunk,
@@ -397,6 +429,17 @@ class SlotEngine:
             "dispatches": self.dispatches,
             "tokens_out": self.tokens_out,
             "sampler": dict(self.sampler_rounds),
+            # how far the decode programs read each row of the pool:
+            # the lengths they are compiled for and the dispatches
+            # that ran each (one rung, ``max_len``, for a program that
+            # reads whole rows)
+            "read_len": {
+                "ladder": list(self.read_ladder),
+                "dispatches": {
+                    str(rung): counted.get(rung, 0)
+                    for rung in self.read_ladder
+                },
+            },
         }
 
     def _program_stats(self, name: str) -> Optional[dict]:
@@ -562,6 +605,23 @@ class SlotEngine:
         if first:
             self._notify(req, first)
 
+    def _warm(self, item: _Warm) -> bool:
+        """Run the program's warm-up if the pool is idle (True), else
+        put the item back behind what is queued (False): a chunk
+        program of a short rung would step live rows past its read."""
+        if any(s is not None for s in self._active):
+            self._queue.put(item)
+            return False
+        try:
+            self.program.warm_ladder()
+        except Exception as exc:  # noqa: BLE001
+            # the failed dispatch donated the pool
+            self.program.reset()
+            item.future.set_exception(exc)
+        else:
+            item.future.set_result(None)
+        return True
+
     def _harvest(self, slot_id: int) -> None:
         state = self._active[slot_id]
         req = state.req
@@ -709,6 +769,11 @@ class SlotEngine:
                         req = self._queue.get(block=block, timeout=None)
                         if req is None:  # stop sentinel
                             return
+                        if isinstance(req, _Warm):
+                            if not self._warm(req):
+                                break  # occupied: decode on, then again
+                            t0 = time.perf_counter()
+                            continue
                         block = False
                         t0 = time.perf_counter()  # exclude idle wait
                         # the span names its cause and its size: the
@@ -752,7 +817,9 @@ class SlotEngine:
                     and not self._cancel_pending()
                 )
                 tj = time.perf_counter()
-                phases.dispatched(tj, fused and windowed, live)
+                phases.dispatched(
+                    tj, fused and windowed, live, self._read_len(fused)
+                )
                 arm = self._sampler_arm()
                 try:
                     handle = program.dispatch(self._budgets(), fused)
@@ -783,7 +850,9 @@ class SlotEngine:
                 and not self._cancel_pending()
             ):
                 tj = time.perf_counter()
-                phases.dispatched(tj, windowed, live)
+                phases.dispatched(
+                    tj, windowed, live, self._read_len(True)
+                )
                 try:
                     pending = (
                         program.dispatch(self._budgets(), True),

@@ -426,8 +426,15 @@ def test_goodput_body_keeps_every_old_key_and_gains_engine():
         "dispatches_fused", "dispatches_single", "store_bytes",
         "spill_bytes", "readmit_bytes", "latent_store_bytes",
         "latent_spill_bytes", "latent_readmit_bytes",
+        "read_len_dispatches",
     }
     assert engine["phase_n"]["engine.dispatch"] == 1
+    assert engine["read_len_dispatches"] == {}  # no ``dispatched`` yet
+    ledger.engine.dispatched(time.perf_counter(), False, 2, 1024)
+    ledger.engine.dispatched(time.perf_counter(), True, 2, 512)
+    ledger.engine.dispatched(time.perf_counter(), True, 1, 1024)
+    assert ledger.engine.snapshot()["read_len_dispatches"] == {
+        "512": 1, "1024": 2}
 
 
 def test_ledger_boot_starts_with_the_process():
@@ -563,6 +570,52 @@ def test_profiler_trace_nests_the_children_and_carries_the_arguments(
     # tokens: every dispatch but the last ones sees both rows live
     assert dispatches[0][3]["live"] == 2
     assert {d[3]["live"] for d in dispatches} <= {1, 2}
+    # ...and how far it read the pool's rows: a pool of 64 positions
+    # has the one rung
+    assert {d[3]["read_len"] for d in dispatches} == {64}
+
+
+def test_read_len_rides_the_dispatch_span_and_v1_model(
+        params, tmp_path, monkeypatch):
+    """``engine.dispatch``'s event carries ``read_len=<rung>`` beside
+    ``fused`` and ``live``, ``stats["read_len"]`` (``/v1/model``
+    ``slot_engine.read_len``) the ladder and the dispatches by rung,
+    and ``/v1/goodput``'s ``engine.read_len_dispatches`` the same
+    counts for a reader that takes deltas: a row decoding from
+    position 3 to 100 of a pool whose ladder starts at 16 climbs it."""
+    from containerpilot_tpu.models import slots as slots_mod
+
+    monkeypatch.setattr(slots_mod, "READ_LADDER_BASE", 16)
+    eng = _engine(params, max_len=128)
+    assert eng.stats["read_len"] == {
+        "ladder": [16, 64, 128],
+        "dispatches": {"16": 0, "64": 0, "128": 0},
+    }
+    try:
+        eng.warm_programs().result(timeout=300)  # compile every rung
+        before = eng.phases.snapshot()["read_len_dispatches"]
+        assert before == {}  # a warm-up's dispatches are no traffic
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            out = eng.submit([1, 2, 3], max_new=97).result(timeout=300)
+        finally:
+            jax.profiler.stop_trace()
+        assert len(out) == 97
+        stats = eng.stats["read_len"]
+        counted = eng.phases.snapshot()["read_len_dispatches"]
+    finally:
+        eng.stop()
+    dispatches = [e[3] for e in _engine_events(str(tmp_path))
+                  if e[0] == "engine.dispatch"]
+    rungs = [d["read_len"] for d in dispatches]
+    assert rungs == sorted(rungs), rungs  # one row, only ever longer
+    assert set(rungs) == {16, 64, 128}
+    # the first dispatch follows an admission: the chunk program,
+    # 3 + 8 positions, the first rung
+    assert dispatches[0] == {"fused": 0, "live": 1, "read_len": 16}
+    by_rung = {str(r): rungs.count(r) for r in (16, 64, 128)}
+    assert stats == {"ladder": [16, 64, 128], "dispatches": by_rung}
+    assert counted == by_rung
 
 
 def test_profiler_trace_holds_train_step_for_a_two_step_trainer(tmp_path):

@@ -288,6 +288,11 @@ def test_inference_server_slot_engine(run, params):
     sampler = stats.pop("sampler")
     assert sampler.pop("rounds_argmax") >= 1
     assert sampler == {"rounds_draw": 0, "rounds_filter": 0}
+    # how far the dispatches read the pool's rows: a pool this short
+    # has the one rung, and every counted dispatch ran it
+    read_len = stats.pop("read_len")
+    assert read_len["ladder"] == [MAX_LEN]
+    assert read_len["dispatches"][str(MAX_LEN)] >= 1
     assert stats == {
         "slots": 2, "chunk": 4, "window": 4, "active": 0,
         "queued": 0,

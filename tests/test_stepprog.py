@@ -124,6 +124,46 @@ def test_window_matches_sequential_chunks(params):
         ), f"state leaf {name} diverged"
 
 
+@pytest.mark.parametrize("window_reads,chunks_read", [
+    (32, 32), (32, None), (None, 32), (16, 32),
+], ids=["32-32", "32-whole", "whole-32", "16-32"])
+def test_window_matches_sequential_chunks_rung_for_rung(
+        params, window_reads, chunks_read):
+    """The parity contract under a ladder of read lengths (ISSUE 41):
+    a window and the chunk dispatches it stands for may run different
+    rungs. At EQUAL ``read_len`` they are the same computation, tokens
+    and every state leaf bit for bit; across rungs that all cover the
+    row (4 prompt tokens + 12 steps = 16 positions) the sums differ by
+    exact zeros, the sampled tokens are the same token for token and
+    the penalty counts, a function of the tokens alone, with them."""
+    chunk, k_rounds = 3, 4
+    pool, state = _admitted_pool(params, [1, 2, 3, 4])
+    seq_toks = []
+    for _ in range(k_rounds):
+        pool, state, toks = decode_slots_chunk(
+            params, pool, state, CFG, chunk, read_len=chunks_read
+        )
+        seq_toks.append(np.asarray(jax.device_get(toks)))
+    sequential = np.concatenate(seq_toks, axis=1)
+    pool2, state2 = _admitted_pool(params, [1, 2, 3, 4])
+    budget = np.asarray([chunk * k_rounds, 0], np.int32)
+    pool2, state2, toks, run = decode_slots_window(
+        params, pool2, state2, CFG, chunk, k_rounds, budget,
+        read_len=window_reads,
+    )
+    assert int(jax.device_get(run)) == k_rounds
+    assert np.array_equal(np.asarray(jax.device_get(toks)), sequential)
+    for name, leaf in state2.items():
+        assert np.array_equal(
+            np.asarray(jax.device_get(leaf)),
+            np.asarray(jax.device_get(state[name])),
+        ), f"state leaf {name} diverged"
+    if window_reads == chunks_read:
+        for name in ("k", "v"):
+            for a, b in zip(pool[name], pool2[name]):
+                assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_window_early_exit_on_budget_and_done(params):
     """The device loop stops once every slot is done or out of
     budget: a 2-token budget exits after one 3-token round, and the

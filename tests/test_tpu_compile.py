@@ -501,6 +501,96 @@ def test_decode_step_check_sees_the_old_form(chip):
             > weights_bf16 + 2 * layer_keys * 2)
 
 
+def _attention_reads(text, cfg, slots):
+    """The types of the keys and values that the attention fusions of
+    an optimised decode program take as operands (a fusion outside
+    every fused computation, named under the scope ``attn.scores``; an
+    operand of slots x N x kv_heads x head_dim): how far a step READS
+    each row of the pool."""
+    outside, bodies = _outside_fusions(text)
+    types = {}
+    for lines in bodies.values():
+        for line in lines:
+            made = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])", line)
+            if made:
+                types[made.group(1)] = made.group(2)
+    rows = re.compile(
+        rf"\w+\[{slots},\d+,{cfg.kv_heads},{cfg.head_dim}\]$")
+    reads = set()
+    for _computation, line in outside:
+        made = re.match(
+            r"\s*(?:ROOT )?%?[\w.\-]+ = .*? fusion\((.*?)\), kind=", line)
+        if not made or "/attn.scores/" not in line:
+            continue
+        for operand in re.findall(r"%([\w.\-]+)", made.group(1)):
+            if rows.match(types.get(operand, "")):
+                reads.add(types[operand])
+    return reads
+
+
+@pytest.mark.parametrize("program", ["chunk", "window"])
+def test_a_cut_read_reads_the_pools_rows_to_read_len(chip, program):
+    """The decode programs at ``read_len`` 1,024 of 4,096, at the
+    benchmark's cache shapes, compiled for the v5e: attention's
+    fusions take each layer's keys and values as
+    ``bf16[16,1024,8,128]``, a quarter of the leaf; outside its fused
+    computations the program still produces nothing with as many
+    elements as a layer's WHOLE leaf but the in-place update, and the
+    largest thing it does produce there is a cut leaf (the compiler
+    makes the cut a ``slice`` of its own and does not fuse it into the
+    contraction: PERF.md, PR 41); the pool is aliased whole (writes go
+    to the whole leaf where it lies) and the temporaries stay under
+    one layer's keys and values, as for the whole-row program."""
+    from containerpilot_tpu.models.slots import (
+        _jitted_chunk,
+        _jitted_window,
+        slot_cache,
+    )
+
+    read_len = 1024
+    cfg, slots, shapes, layer_keys = _cell_decode_shapes(chip, slot_cache)
+    shapes = (
+        jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, cfg.dtype, sharding=chip),
+            shapes[0]),
+        *shapes[1:],
+    )
+    if program == "chunk":
+        lowered = _jitted_chunk(cfg, slots, 8, None, read_len).lower(*shapes)
+    else:
+        budget = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+        lowered = _jitted_window(
+            cfg, slots, 8, 4, None, read_len).lower(*shapes, budget)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_run")
+    cut = f"bf16[{slots},{read_len},{cfg.kv_heads},{cfg.head_dim}]"
+    assert _attention_reads(text, cfg, slots) == {cut}
+    assert _pool_sized_outside_fusions(text, layer_keys) == []
+    cut_leaf = layer_keys * read_len // cfg.max_seq_len
+    # (a stacked weight's change of layout, once a dispatch, has as
+    # many elements at these widths: ``bf16[4,4096,8,128]``)
+    assert {kind for _name, _opcode, kind in _pool_sized_outside_fusions(
+        text, cut_leaf) if f"[{slots}," in kind} <= {cut}
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * cfg.n_layers * layer_keys * 2
+    assert memory.temp_size_in_bytes < 2 * layer_keys * 2
+
+
+def test_read_length_check_sees_a_whole_leaf_read(chip):
+    """The same reading of the whole-row chunk program (no
+    ``read_len``: the pod's, and every rung-less pool's) finds the
+    whole leaf at attention's fusions, so the test above cannot pass
+    by looking past the read."""
+    from containerpilot_tpu.models.slots import _jitted_chunk, slot_cache
+
+    cfg, slots, shapes, _layer_keys = _cell_decode_shapes(chip, slot_cache)
+    text = _jitted_chunk(cfg, slots, 8).lower(*shapes).compile().as_text()
+    assert _attention_reads(text, cfg, slots) == {
+        f"bf16[{slots},{cfg.max_seq_len},{cfg.kv_heads},{cfg.head_dim}]"}
+
+
 def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
     """The slot engine's chunk program of the benchmark's A.X-K1
     configuration at its real size (benchmark/configs/ax-k1-serve.json:

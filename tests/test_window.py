@@ -302,3 +302,36 @@ def test_full_ring_decodes_past_length():
         params, cache, logits, cfg, max_new_tokens=16, pos=4
     )
     assert out.shape == (1, 16)
+
+
+def test_a_ring_pool_is_read_whole_at_every_dispatch(monkeypatch):
+    """A sliding-window configuration's slot pool is a ring per row:
+    live context wraps, so no head of it can be cut off. Its step
+    program has the one rung ``max_len`` whatever the ladder's base
+    (models/slots.py ``read_ladder``) and dispatches the programs it
+    always did: a long row through a pool of ring rows decodes what a
+    solo ``generate`` does."""
+    from containerpilot_tpu.models import slots as slots_mod
+    from containerpilot_tpu.models.stepprog import PlainStepProgram
+    from containerpilot_tpu.workload.serve_slots import SlotEngine
+
+    monkeypatch.setattr(slots_mod, "READ_LADDER_BASE", 8)
+    cfg = _cfg(window=16)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    max_len = 64
+    program = PlainStepProgram(cfg, params, max_len, 2, 4, rounds=2)
+    assert program.ladder == (max_len,)
+    assert program.read_len(True) == max_len
+    eng = SlotEngine(cfg, params, max_len, program=program)
+    try:
+        out = eng.submit([5, 6, 7], max_new=40).result(timeout=300)
+        stats = eng.stats["read_len"]
+    finally:
+        eng.stop()
+    solo = generate(
+        params, jnp.asarray([[5, 6, 7]], jnp.int32), cfg, 40, max_len,
+        rng=jnp.stack([jax.random.fold_in(jax.random.PRNGKey(0), 0)]),
+    )
+    assert out == [int(t) for t in np.asarray(solo)[0]]
+    assert stats["ladder"] == [max_len]
+    assert stats["dispatches"][str(max_len)] >= 5
