@@ -50,8 +50,9 @@ supplies the cache and the step (models/slots.py); a family whose step
 counts something per round (the routed experts of models/mla_moe.py)
 returns the counts with the tokens, and ``tokens`` adds them up on the
 host (``expert_stats``; ``state_stats`` for the stepped rows of
-models/hybrid_ssm.py's recurrent state): no dispatch and no sync of
-their own.
+models/hybrid_ssm.py's recurrent state; ``loop_stats`` for the passes
+models/looped.py ran over its rows): no dispatch and no sync of their
+own.
 
 Implementations: :class:`PlainStepProgram` (models/slots.py's chunk +
 fused-window programs), ``models.quantized.QuantizedStepProgram``
@@ -328,6 +329,13 @@ class PlainStepProgram:
         decode rounds fetched so far stepped it (``/v1/model``
         ``state``); None for a family without recurrent state."""
         return self._described("describe_state")
+
+    def loop_stats(self):
+        """How often the layers run a token, the planes of keys and
+        values that costs and the passes the decode rounds fetched so
+        far ran (``/v1/model`` ``loop``); None for a family whose
+        layers run once."""
+        return self._described("describe_loop")
 
 
 def make_step_program(

@@ -228,8 +228,10 @@ def load_model_file(path: str, max_len: int) -> Any:
     (models/mla_moe.py), a ``diffusion`` group beside ``num_experts``
     is generation by diffusion over blocks (models/block_diffusion.py),
     ``layer_types`` beside ``mamba_n_heads`` is state-space layers among
-    attention layers (models/hybrid_ssm.py). A file of another
-    architecture is refused with its name; nothing is guessed."""
+    attention layers (models/hybrid_ssm.py), ``total_ut_steps`` is a
+    stack of layers run several times a token (models/looped.py). A
+    file of another architecture is refused with its name; nothing is
+    guessed."""
     import hashlib
     import json as json_mod
 
@@ -245,14 +247,16 @@ def load_model_file(path: str, max_len: int) -> Any:
         from ..models.block_diffusion import from_published
     elif "layer_types" in config and "mamba_n_heads" in config:
         from ..models.hybrid_ssm import from_published
+    elif "total_ut_steps" in config:
+        from ..models.looped import from_published
     else:
         raise SystemExit(
             f"--model-config {path}: model_type "
             f"{config.get('model_type')!r} has no builder here (latent "
             "attention with routed experts, block diffusion over routed "
-            "experts and state-space layers among attention layers are "
-            "the families read from a file; the flagship block still "
-            "takes its flags)"
+            "experts, state-space layers among attention layers and "
+            "layers run several times a token are the families read "
+            "from a file; the flagship block still takes its flags)"
         )
 
     digest = hashlib.blake2b(raw, digest_size=8).hexdigest()

@@ -1070,6 +1070,11 @@ class InferenceServer:
                 # a row's bytes of it and the steps taken over it
                 # (models/hybrid_ssm.py); else None
                 "state": self.slot_engine.state_stats(),
+                # layers run several times a token: the passes, the
+                # planes of keys and values a position holds for them
+                # and the passes run so far (models/looped.py); else
+                # None
+                "loop": self.slot_engine.loop_stats(),
                 # SSE streaming rides the slot engine's chunks
                 "stream": True,
                 "draining": self.draining,

@@ -462,6 +462,12 @@ class SlotEngine:
         (``/v1/model`` ``state``); None for a model without any."""
         return self._program_stats("state_stats")
 
+    def loop_stats(self) -> Optional[dict]:
+        """The passes a looped model's layers run a token and the
+        decode rounds' count of them (``/v1/model`` ``loop``); None
+        for a model whose layers run once."""
+        return self._program_stats("loop_stats")
+
     # ----------------------------------------------------------- worker
 
     def _prefill(self, req: _Request):

@@ -239,9 +239,12 @@ def _outside_fusions(text):
     return outside, bodies
 
 
-def _pool_sized_outside_fusions(text, elements):
+def _pool_sized_outside_fusions(text, elements, min_rank=0):
     """The instructions outside fused computations that produce a
-    tensor of at least ``elements`` elements, as (name, opcode, type)
+    tensor of at least ``elements`` elements (in ``min_rank`` or more
+    dimensions: the compiler's own prefetches of 2-D weight matrices
+    into its fast memory can be as large as a small cache's plane and
+    are not the cache), as (name, opcode, type)
     — but for what moves nothing (parameters, tuples and their
     elements, bitcasts, the loops themselves), what only prepares
     WEIGHTS once a dispatch (every operand a ``params`` argument: the
@@ -270,6 +273,7 @@ def _pool_sized_outside_fusions(text, elements):
         sizes = [
             math.prod(int(n) for n in dims.split(",") if n)
             for dims in re.findall(r"\w+\[([\d,]*)\]", kind)
+            if dims.count(",") + 1 >= min_rank
         ]
         if not sizes or max(sizes) < elements:
             continue
@@ -915,3 +919,145 @@ def test_vocabulary_sort_check_sees_the_old_form(chip):
     ).compile().as_text()
     inside, outside = _vocabulary_sorts(text, vocab)
     assert inside == [] and outside, (inside, outside)
+
+
+# -- the looped family at its published size (PR 42) ----------------------
+
+GIB = 1024 ** 3
+#: what the chip's compiler allows a program (PERF.md, PR 42)
+LOOPED_ROOM = 15.75 * GIB
+
+
+def _looped_shapes(chip, slots, length):
+    from containerpilot_tpu.models.slots import init_slot_state, slot_cache
+    from containerpilot_tpu.workload.modelcfg import load_model_file
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = load_model_file(
+        os.path.join(root, "benchmark", "configs", "ouro-2.6b-serve.json"),
+        length)
+    shapes = jax.eval_shape(
+        lambda: (
+            cfg.family.init_params(None, cfg),
+            slot_cache(cfg, slots, length),
+            init_slot_state(cfg, slots),
+            cfg.family.init_cache(cfg, 1, length),
+        )
+    )
+    return cfg, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+
+
+@pytest.mark.parametrize("program", ["chunk", "window"])
+def test_looped_step_reads_each_passes_plane_where_it_lies(chip, program):
+    """The slot engine's decode programs of the benchmark's Ouro-2.6B
+    configuration at its PUBLISHED size (benchmark/configs/
+    ouro-2.6b-serve.json: 48 layers run 4 times, nothing reduced; 16
+    slots x 320 positions), compiled for the v5e: weights (4.97 GiB)
+    and pool (7.50 GiB: 192 planes of 16 x 320 positions) are the
+    arguments, the WHOLE pool is aliased to its output (no second
+    pool), arguments and temporaries fit under the 15.75 GiB the
+    chip's compiler allows with a gibibyte to spare for a row in
+    flight; every matrix is bfloat16 (no float32 copy of the model);
+    the program holds 48 layer bodies, not 192: each layer's two
+    leaves ``[4, 16, 320, 16, 128]`` are written by ONE scatter each
+    and read through a ``dynamic-slice`` at the pass loop's index that
+    lives INSIDE the fusion that contracts it, so that outside fused
+    computations nothing the size of a plane is produced but the
+    in-place writes, and no weight is copied into another layout."""
+    from containerpilot_tpu.models.slots import _jitted_chunk, _jitted_window
+
+    slots, length = 16, 320
+    cfg, (params, pool, state, _row) = _looped_shapes(chip, slots, length)
+    assert all(x.dtype == jnp.bfloat16
+               for x in jax.tree.leaves(params) if x.ndim >= 2)
+    if program == "chunk":
+        lowered = _jitted_chunk(cfg, slots, 8).lower(params, pool, state)
+    else:
+        budget = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+        lowered = _jitted_window(cfg, slots, 8, 4).lower(
+            params, pool, state, budget)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    plane = slots * length * cfg.n_kv_heads * cfg.head_dim
+    pool_bytes = cfg.cache_planes * 2 * plane * 2
+    assert pool_bytes == slots * length * 1_572_864 == 7.5 * GIB
+    assert 12.4 * GIB < memory.argument_size_in_bytes < 12.5 * GIB
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 1024 ** 2
+    assert memory.temp_size_in_bytes < 0.5 * GIB
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < LOOPED_ROOM - GIB
+    text = compiled.as_text()
+    leaf = f"bf16[{cfg.passes},{slots},{length},{cfg.n_kv_heads},{cfg.head_dim}]"
+    _outside, bodies = _outside_fusions(text)
+    scatters = sum(
+        1 for lines in bodies.values() for line in lines
+        if " scatter(" in line and f"= {leaf}" in line)
+    assert scatters == 2 * cfg.n_layers  # one a leaf: 48 bodies, not 192
+    reads = [
+        line for lines in bodies.values() for line in lines
+        if " dynamic-slice(" in line and leaf.replace(
+            f"[{cfg.passes},", "[1,") in line]
+    assert len(reads) >= 2 * cfg.n_layers
+    assert _pool_sized_outside_fusions(text, plane, min_rank=4) == []
+    # the scopes a device trace splits a step by, in the operations' paths
+    assert "loop.pass/layers/attn/attn.scores" in text
+    assert "loop.pass/layers/mlp" in text and "loop.norm_out/norm" in text
+    weight = cfg.d_model * cfg.n_heads * cfg.head_dim
+    copies = [
+        line for _c, line in _outside_fusions(text)[0]
+        if re.search(r" copy\(", line)
+        and any(math.prod(int(n) for n in dims.split(",") if n) >= weight
+                for dims in re.findall(r"bf16\[([\d,]*)\]", line.split("=")[1]))]
+    assert copies == []
+
+
+def test_looped_check_sees_a_plane_copied_out(chip):
+    """The same check on a step that slices the plane out of its leaf
+    BEFORE a contraction that wants another layout (the stacked cache's
+    fault: PERF.md, PR 28) finds the copy, so the test above cannot
+    pass by looking past it."""
+    leaf = jax.ShapeDtypeStruct((4, 16, 320, 16, 128), jnp.bfloat16,
+                                sharding=chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def copied(keys, t):
+        plane = jax.lax.dynamic_index_in_dim(keys, t, 0, keepdims=False)
+        return jnp.transpose(plane, (2, 0, 1, 3)) * 2
+
+    text = jax.jit(copied).lower(leaf, t).compile().as_text()
+    assert _pool_sized_outside_fusions(
+        text, 16 * 320 * 16 * 128, min_rank=4)
+
+
+def test_looped_insert_and_prefill_fit_beside_the_pool(chip):
+    """The other two programs an admission runs at the published size:
+    the insert writes a prefilled row's 192 planes (0.47 GiB) into the
+    donated pool, which is aliased whole; a 128-token prefill holds the
+    weights, its row and under half a gibibyte of temporaries, so that
+    weights + pool + a row in flight + either program's temporaries
+    stay under what the compiler allows."""
+    from containerpilot_tpu.models.decode import prefill
+    from containerpilot_tpu.models.slots import _jitted_insert
+
+    slots, length = 16, 320
+    cfg, (params, pool, _state, row) = _looped_shapes(chip, slots, length)
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    memory = _jitted_insert(cfg).lower(pool, row, slot).compile().memory_analysis()
+    assert memory.alias_size_in_bytes >= 7.5 * GIB
+    assert memory.temp_size_in_bytes < 64 * 1024 ** 2
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 1024 ** 2
+    tokens = jax.ShapeDtypeStruct((1, 128), jnp.int32, sharding=chip)
+    memory = jax.jit(lambda p, t: prefill(p, t, cfg, length)).lower(
+        params, tokens).compile().memory_analysis()
+    row_bytes = length * 1_572_864
+    assert row_bytes <= memory.output_size_in_bytes < row_bytes + 1024 ** 2
+    assert memory.temp_size_in_bytes < 0.5 * GIB
+    weights = memory.argument_size_in_bytes
+    assert 4.9 * GIB < weights < 5.0 * GIB
+    assert (weights + 7.5 * GIB + row_bytes + memory.temp_size_in_bytes
+            ) < LOOPED_ROOM - GIB
