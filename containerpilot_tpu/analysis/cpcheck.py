@@ -360,8 +360,8 @@ class DonateRule(Rule):
     bindings discovered in the module, plus this repo's known donating
     entry points (models/slots.py): ``insert_row``,
     ``admit_slot_state`` and ``retire_slot`` donate argument 0,
-    ``decode_slots_chunk`` and ``decode_slots_window`` donate
-    arguments 1 and 2. A donated operand is cleared by being a
+    ``admit_row`` arguments 0 and 1, ``decode_slots_chunk`` and
+    ``decode_slots_window`` arguments 1 and 2. A donated operand is cleared by being a
     target of the same call's assignment (``state = step(state, x)``);
     any later *read* of a still-donated name in the same function body
     is flagged, any later rebind heals it.
@@ -373,6 +373,7 @@ class DonateRule(Rule):
         "insert_row": (0,),
         "admit_slot_state": (0,),
         "retire_slot": (0,),
+        "admit_row": (0, 1),
         "decode_slots_chunk": (1, 2),
         "decode_slots_window": (1, 2),
     }

@@ -28,6 +28,17 @@ makes; PERF.md, PR 28, has what the stacked form cost). Rows keep
 ``prefill``'s format (stacked, one row): ``insert_row`` writes each
 layer's part at the slot, and rows never leave the pool.
 
+An admission is ONE program over ONE packed host row (``admit_row``,
+``pack_admission``): the row key, the first sample, the row's write
+and the slot's whole sampling state happen in a single dispatch, and
+every per-request number crosses to the device in one int32 row built
+with numpy, so the host issues nothing else and need not see the
+first token before the state is written. ``first_sample``,
+``insert_row`` and ``admit_slot_state`` are the same three steps as
+programs of their own (the pod's mirror engine and the tests issue
+them one by one); each step has ONE traceable body that both forms
+trace.
+
 Every step reads every row of the pool, dead or live, so what a
 step costs is what attention does with those bytes: ``decode_chunk``
 contracts the query heads, grouped as [kv_heads, group], with each
@@ -79,6 +90,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .decode import (
@@ -151,38 +163,44 @@ def init_slot_state(cfg: TransformerConfig, slots: int) -> dict:
     }
 
 
+def _write_state_row(state, slot, last, key, step_idx, temperature,
+                     top_k, top_p, eos_id, pad_id, min_new, presence,
+                     frequency, bias_idx, bias_val, done):
+    """One admission's row written into every leaf of the state dict
+    (single-row .at[slot].set per leaf: with the dict donated, no
+    full-array copies). The counts row seeds on device
+    (seed_counts_row) from the first sample. The ONE body of the
+    state's write: ``admit_slot_state``'s program and ``admit_row``'s
+    both trace it."""
+    vocab = state["counts"].shape[1]
+    row = {
+        "last": last, "keys": key, "step_idx": step_idx,
+        "temperature": temperature, "top_k": top_k,
+        "top_p": top_p, "eos_id": eos_id, "pad_id": pad_id,
+        "min_new": min_new, "presence": presence,
+        "frequency": frequency, "bias_idx": bias_idx,
+        "bias_val": bias_val,
+        "counts": seed_counts_row(vocab, last, eos_id),
+        "done": done,
+    }
+    return {
+        name: state[name].at[slot].set(
+            row[name].astype(state[name].dtype)
+        )
+        for name in state
+    }
+
+
 @functools.lru_cache(maxsize=8)
 def _jitted_admit(cfg: TransformerConfig, out_sharding=None):
     """ONE dispatch writing a whole admission's row into every state
-    leaf (the state dict is donated — single-row .at[slot].set per
-    leaf, no full-array copies). The counts row seeds on device
-    (seed_counts_row) from the first sample, so admission needs no
-    extra host round trip for it. ``out_sharding`` pins the output
-    placement exactly like _jitted_insert's."""
-
-    def admit(state, slot, last, key, step_idx, temperature, top_k,
-              top_p, eos_id, pad_id, min_new, presence, frequency,
-              bias_idx, bias_val, done):
-        vocab = state["counts"].shape[1]
-        row = {
-            "last": last, "keys": key, "step_idx": step_idx,
-            "temperature": temperature, "top_k": top_k,
-            "top_p": top_p, "eos_id": eos_id, "pad_id": pad_id,
-            "min_new": min_new, "presence": presence,
-            "frequency": frequency, "bias_idx": bias_idx,
-            "bias_val": bias_val,
-            "counts": seed_counts_row(vocab, last, eos_id),
-            "done": done,
-        }
-        return {
-            name: state[name].at[slot].set(
-                row[name].astype(state[name].dtype)
-            )
-            for name in state
-        }
-
+    leaf (``_write_state_row``; the state dict is donated), so
+    admission needs no extra host round trip for the counts.
+    ``out_sharding`` pins the output placement exactly like
+    _jitted_insert's."""
     return jax.jit(
-        admit, donate_argnums=(0,), out_shardings=out_sharding
+        _write_state_row, donate_argnums=(0,),
+        out_shardings=out_sharding,
     )
 
 
@@ -228,8 +246,10 @@ def retire_slot(state: dict, slot: int, out_sharding=None) -> dict:
     re-admission). Only the done leaf is touched; the rest of the
     state rides along untouched until the next admission."""
     new = dict(state)
+    # the index rides with the call as a numpy scalar: one transfer,
+    # no put and no ``convert_element_type`` program of its own
     new["done"] = _jitted_retire(out_sharding)(
-        state["done"], jnp.asarray(slot, jnp.int32)
+        state["done"], np.int32(slot)
     )
     return new
 
@@ -251,17 +271,12 @@ def slot_cache(cfg: TransformerConfig, slots: int, max_len: int) -> Cache:
     return pool
 
 
-@functools.lru_cache(maxsize=8)
-def _jitted_insert(cfg: TransformerConfig, out_sharding=None):
-    """(pool, row_cache, slot) -> pool with the row written at slot.
-    donate the pool: insertion must not copy S full cache rows.
-
-    ``out_sharding`` (a NamedSharding, hashable) pins the output
-    placement — multi-process serving passes fully-replicated so the
-    pool NEVER drifts into whatever sharding GSPMD would pick for
-    this program (a drifting pool re-enters the next donating program
-    under a different layout; pinning keeps every process's copy
-    bit-identical by construction)."""
+def _insert_body(cfg: TransformerConfig):
+    """``(pool, row_cache, slot) -> pool`` with the row written at
+    ``slot``, traceable: the family's ``insert_row`` where ``cfg``
+    brings one, else the linear pool's. The ONE body of the row's
+    write: ``insert_row``'s program and ``admit_row``'s both trace
+    it."""
 
     def insert(pool: Cache, row: Cache, slot: jax.Array) -> Cache:
         new = {"pos": lax.dynamic_update_slice(
@@ -279,11 +294,24 @@ def _jitted_insert(cfg: TransformerConfig, out_sharding=None):
         return new
 
     family = getattr(cfg, "family", None)
-    if family is not None:
-        insert = family.insert_row
+    return insert if family is None else family.insert_row
 
+
+@functools.lru_cache(maxsize=8)
+def _jitted_insert(cfg: TransformerConfig, out_sharding=None):
+    """(pool, row_cache, slot) -> pool with the row written at slot
+    (``_insert_body``). donate the pool: insertion must not copy S
+    full cache rows.
+
+    ``out_sharding`` (a NamedSharding, hashable) pins the output
+    placement — multi-process serving passes fully-replicated so the
+    pool NEVER drifts into whatever sharding GSPMD would pick for
+    this program (a drifting pool re-enters the next donating program
+    under a different layout; pinning keeps every process's copy
+    bit-identical by construction)."""
     return jax.jit(
-        insert, donate_argnums=(0,), out_shardings=out_sharding
+        _insert_body(cfg), donate_argnums=(0,),
+        out_shardings=out_sharding,
     )
 
 
@@ -293,6 +321,115 @@ def insert_row(pool: Cache, row: Cache, slot: int,
     The pool buffer is donated (in-place update)."""
     return _jitted_insert(cfg, out_sharding)(
         pool, row, jnp.asarray(slot, jnp.int32)
+    )
+
+
+#: the packed admission row (``pack_admission``): one int32 word per
+#: name, the floats as their bits, then the two logit_bias rows
+ADMIT_ROW_INTS = (
+    "slot", "seed", "row", "step_idx", "top_k", "eos_id", "pad_id",
+    "min_new", "max_new",
+)
+ADMIT_ROW_FLOATS = ("temperature", "top_p", "presence", "frequency")
+_ADMIT_ROW_SCALARS = len(ADMIT_ROW_INTS) + len(ADMIT_ROW_FLOATS)
+_ADMIT_ROW_BIAS_IDX = slice(
+    _ADMIT_ROW_SCALARS, _ADMIT_ROW_SCALARS + BIAS_SLOTS_MAX)
+_ADMIT_ROW_BIAS_VAL = slice(
+    _ADMIT_ROW_BIAS_IDX.stop, _ADMIT_ROW_BIAS_IDX.stop + BIAS_SLOTS_MAX)
+ADMIT_ROW_WIDTH = _ADMIT_ROW_BIAS_VAL.stop
+
+
+def pack_admission(*, bias_idx=None, bias_val=None, step_idx: int = 1,
+                   **scalars) -> np.ndarray:
+    """Every per-request number an admission needs as ONE host row of
+    ``ADMIT_ROW_WIDTH`` int32 words, built with numpy alone (nothing
+    here touches the device; the row crosses in one transfer as
+    ``admit_row``'s operand): ``ADMIT_ROW_INTS``, then
+    ``ADMIT_ROW_FLOATS`` and after ``bias_idx`` the ``bias_val`` row
+    as their float32 bits. One static width whatever the request
+    carries (``bias_idx``/``bias_val``: [BIAS_SLOTS_MAX] rows, None =
+    no bias), so one program serves every request.
+
+    ``seed`` is any Python integer ``jax.random.PRNGKey`` takes: its
+    low 32 bits ride, which is all ``PRNGKey`` keeps of it where
+    64-bit types are off (the process's setting; tested)."""
+    scalars["step_idx"] = step_idx
+    packed = np.empty((ADMIT_ROW_WIDTH,), np.int32)
+    floats = packed.view(np.float32)
+    n_ints = len(ADMIT_ROW_INTS)
+    # C-cast, as jnp.asarray(np.int64(seed)) does with 64 bits off
+    packed[:n_ints] = np.asarray(
+        [scalars[name] for name in ADMIT_ROW_INTS], np.int64
+    ).astype(np.int32)
+    floats[n_ints:_ADMIT_ROW_SCALARS] = [
+        scalars[name] for name in ADMIT_ROW_FLOATS
+    ]
+    packed[_ADMIT_ROW_BIAS_IDX] = -1 if bias_idx is None else bias_idx
+    floats[_ADMIT_ROW_BIAS_VAL] = 0.0 if bias_idx is None else bias_val
+    return packed
+
+
+def _unpack_admission(packed: jax.Array) -> dict:
+    """``pack_admission``'s row as named device values (traceable):
+    int32 scalars, float32 scalars, and the two bias rows."""
+    n_ints = len(ADMIT_ROW_INTS)
+    floats = lax.bitcast_convert_type(packed, jnp.float32)
+    out = {name: packed[i] for i, name in enumerate(ADMIT_ROW_INTS)}
+    out.update(
+        (name, floats[n_ints + i])
+        for i, name in enumerate(ADMIT_ROW_FLOATS)
+    )
+    out["bias_idx"] = packed[_ADMIT_ROW_BIAS_IDX]
+    out["bias_val"] = floats[_ADMIT_ROW_BIAS_VAL]
+    return out
+
+
+@functools.lru_cache(maxsize=8)
+def _jitted_admit_row(cfg: TransformerConfig, out_sharding=None):
+    """Everything the device does with a prefilled row, as ONE program
+    over one packed operand: the row key from ``seed`` and ``row``
+    (``fold_in(PRNGKey(seed), row)``, the server's key convention),
+    token 0 (``_first_token``), the row's write into the pool at
+    ``slot`` (``_insert_body``), ``done`` from the token (so the host
+    need not see it before the state is written) and the whole state
+    row (``_write_state_row``). Pool and state are donated;
+    ``out_sharding`` pins every output's placement like
+    _jitted_insert's."""
+    insert = _insert_body(cfg)
+
+    def admit_row(pool, state, logits, row_cache, packed):
+        r = _unpack_admission(packed)
+        key = jax.random.fold_in(jax.random.PRNGKey(r["seed"]), r["row"])
+        first = _first_token(
+            logits, key, r["temperature"], r["top_k"], r["top_p"],
+            r["eos_id"], r["min_new"], r["bias_idx"], r["bias_val"],
+        )
+        pool = insert(pool, row_cache, r["slot"])
+        done = (first == r["eos_id"]) | (r["max_new"] <= 1)
+        state = _write_state_row(
+            state, r["slot"], first, key, r["step_idx"],
+            r["temperature"], r["top_k"], r["top_p"], r["eos_id"],
+            r["pad_id"], r["min_new"], r["presence"], r["frequency"],
+            r["bias_idx"], r["bias_val"], done,
+        )
+        return pool, state, first
+
+    return jax.jit(
+        admit_row, donate_argnums=(0, 1), out_shardings=out_sharding
+    )
+
+
+def admit_row(pool: Cache, state: dict, logits, row_cache: Cache,
+              packed, cfg: TransformerConfig, out_sharding=None):
+    """Admit one prefilled request in a single dispatch: ``logits``
+    [1, vocab] and ``row_cache`` as prefill returned them, ``packed``
+    the request's ``pack_admission`` row. Returns ``(pool, state,
+    first)``, token for token and leaf for leaf what ``first_sample``
+    -> ``insert_row`` -> ``admit_slot_state`` give (tested); the pool
+    and the state dict are donated, ``first`` stays on the device
+    until someone fetches it."""
+    return _jitted_admit_row(cfg, out_sharding)(
+        pool, state, logits, row_cache, packed
     )
 
 
@@ -594,30 +731,32 @@ def decode_slots_window(
     return out if with_stats else out[:4]
 
 
+@jax.named_scope("sample")
+def _first_token(logits, row_key, temperature, top_k, top_p, eos_id,
+                 min_new, bias_idx, bias_val):
+    """Token 0 from prefill logits [1, vocab] with generate's key
+    schedule (fold_in(row_key, 0)). The ONE body of the first draw:
+    ``first_sample``'s program and ``admit_row``'s both trace it."""
+    # counts are empty at sample 0, so penalties are a no-op here
+    # by construction — identical to generate's first sample.
+    # logit_bias DOES apply at sample 0 (generate biases every
+    # draw), hence the operands here.
+    masked = apply_logit_bias(
+        logits, bias_idx[None], bias_val[None]
+    )
+    masked = mask_eos_before_min(
+        masked, jnp.int32(0), min_new[None], eos_id[None]
+    )
+    return sample_logits(
+        masked, row_key[None], temperature[None], top_k[None],
+        top_p[None], fold=jnp.int32(0),
+    )[0]
+
+
 @functools.lru_cache(maxsize=8)
 def _jitted_first_sample(cfg: TransformerConfig):
-    """Sample token 0 from prefill logits with generate's key
-    schedule (fold_in(row_key, 0))."""
-
-    @jax.named_scope("sample")
-    def first(logits, row_key, temperature, top_k, top_p, eos_id,
-              min_new, bias_idx, bias_val):
-        # counts are empty at sample 0, so penalties are a no-op here
-        # by construction — identical to generate's first sample.
-        # logit_bias DOES apply at sample 0 (generate biases every
-        # draw), hence the operands here.
-        masked = apply_logit_bias(
-            logits, bias_idx[None], bias_val[None]
-        )
-        masked = mask_eos_before_min(
-            masked, jnp.int32(0), min_new[None], eos_id[None]
-        )
-        return sample_logits(
-            masked, row_key[None], temperature[None], top_k[None],
-            top_p[None], fold=jnp.int32(0),
-        )[0]
-
-    return jax.jit(first)
+    """Sample token 0 from prefill logits (``_first_token``)."""
+    return jax.jit(_first_token)
 
 
 def first_sample(logits, row_key, temperature, top_k, top_p,

@@ -72,9 +72,9 @@ run of each on the idle pool: :class:`PlainStepProgram`; a program
 without them reads whole rows) and ``attach_phases(phases)``:
 the engine hands it its ``EnginePhases`` (telemetry/goodput.py), and
 ``admit`` then opens the children of ``engine.admit.first_token``
-where the work happens (``.sample``, ``.sync``, ``.insert``,
-``.state``: which of an admission's milliseconds wait for the device
-and which are the host's own). A program that was handed none, or
+where the work happens (``.sample``, ``.insert``, ``.state``,
+``.sync``: which of an admission's milliseconds are the host's own
+and which wait for the device). A program that was handed none, or
 brings no such member, records nothing and admits as before.
 """
 from __future__ import annotations
@@ -85,13 +85,12 @@ import jax
 import numpy as np
 
 from .slots import (
-    admit_slot_state,
+    admit_row,
     compile_decode_programs,
     decode_slots_chunk,
     decode_slots_window,
-    first_sample,
     init_slot_state,
-    insert_row,
+    pack_admission,
     read_ladder,
     retire_slot,
     slot_cache,
@@ -171,46 +170,39 @@ class PlainStepProgram:
         self.phases = phases
 
     def admit(self, slot: int, req, logits, row_cache) -> int:
-        """Sample token 0 with the server key convention (row
-        ``req.row`` of ``req.seed``), write the prefilled row + the
-        whole sampling state row in two dispatches, return the first
-        token. The four children of ``engine.admit.first_token``
-        tile it: in ``sample``, ``insert`` and ``state`` the thread
-        issues puts and dispatches, in ``sync`` it is blocked on the
-        device (the first token's fetch waits out the prefill)."""
-        cfg = self.cfg
+        """Admit the prefilled request in ONE dispatch
+        (models/slots.py ``admit_row``): every number of the request
+        crosses as one packed host row, and the device derives the row
+        key (row ``req.row`` of ``req.seed``, the server key
+        convention), samples token 0, writes the row into the pool and
+        the whole sampling state row, ``done`` included. Returns the
+        first token. The four children of
+        ``engine.admit.first_token`` tile it: ``sample`` packs the
+        row (numpy alone), ``insert`` issues the program, ``state``
+        is the host's own bookkeeping, and in ``sync`` the thread is
+        blocked on the device (the first token's fetch waits out the
+        prefill and the program)."""
         span = phase_span(self.phases)
         with span("engine.admit.first_token.sample"):
-            row_key = jax.random.fold_in(
-                jax.random.PRNGKey(req.seed), req.row
-            )
-            first = first_sample(
-                logits, row_key, req.temperature, req.top_k, req.top_p,
-                cfg, eos_id=req.eos_id, min_new=req.min_new,
+            packed = pack_admission(
+                slot=slot, seed=req.seed, row=req.row,
+                top_k=req.top_k, eos_id=req.eos_id, pad_id=req.pad_id,
+                min_new=req.min_new, max_new=req.max_new,
+                temperature=req.temperature, top_p=req.top_p,
+                presence=req.presence, frequency=req.frequency,
                 bias_idx=req.bias_idx, bias_val=req.bias_val,
             )
-        with span("engine.admit.first_token.sync"):
-            first_host = int(jax.device_get(first))
         with span("engine.admit.first_token.insert"):
-            self._pool = insert_row(
-                self._pool, row_cache, slot, cfg, self.out_sharding
+            self._pool, self._state, first = admit_row(
+                self._pool, self._state, logits, row_cache, packed,
+                self.cfg, self.out_sharding,
             )
-        done = first_host == req.eos_id or req.max_new <= 1
         with span("engine.admit.first_token.state"):
-            self._state = admit_slot_state(
-                self._state, slot, cfg,
-                last=first, key=row_key,
-                temperature=req.temperature, top_k=req.top_k,
-                top_p=req.top_p, eos_id=req.eos_id, pad_id=req.pad_id,
-                min_new=req.min_new, presence=req.presence,
-                frequency=req.frequency, bias_idx=req.bias_idx,
-                bias_val=req.bias_val, done=done,
-                out_sharding=self.out_sharding,
-            )
-        # the prefilled row stands at the end of its whole prompt,
-        # whatever part of it a reused prefix supplied
-        self._reach[slot] = len(req.tokens)
-        return first_host
+            # the prefilled row stands at the end of its whole prompt,
+            # whatever part of it a reused prefix supplied
+            self._reach[slot] = len(req.tokens)
+        with span("engine.admit.first_token.sync"):
+            return int(jax.device_get(first))
 
     def retire(self, slot: int) -> None:
         self._state = retire_slot(

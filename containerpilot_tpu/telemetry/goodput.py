@@ -119,17 +119,19 @@ ENGINE_CYCLE_PHASES = (
     "engine.wait_work", "engine.admit", "engine.dispatch",
     "engine.fetch", "engine.deliver",
 )
-#: the children that tile ``engine.admit.first_token``, opened by the
-#: step program where the work happens (models/stepprog.py ``admit``):
-#: ``sample`` (the row key, the first sample's puts and dispatch),
-#: ``insert`` (the row's write into the pool) and ``state`` (the slot
-#: state's puts and write) are the thread ISSUING work to the device;
-#: ``sync`` is the thread BLOCKED on it (the fetch of the first token,
-#: which waits out the prefill). A program that fetches no first token
+#: the children that tile ``engine.admit.first_token``, in the order
+#: the step program opens them where the work happens
+#: (models/stepprog.py ``admit``): ``sample`` (every number of the
+#: request packed into one host row), ``insert`` (the issue of the ONE
+#: program that samples token 0 and writes the row and its state) and
+#: ``state`` (the host's own bookkeeping) are the thread at work while
+#: the device may stand idle; ``sync`` is the thread BLOCKED on the
+#: device (the fetch of the first token, which waits out the prefill
+#: and that program). A program that fetches no first token
 #: (models/block_diffusion.py) opens no ``sync``
 FIRST_TOKEN_PHASES = (
-    "engine.admit.first_token.sample", "engine.admit.first_token.sync",
-    "engine.admit.first_token.insert", "engine.admit.first_token.state",
+    "engine.admit.first_token.sample", "engine.admit.first_token.insert",
+    "engine.admit.first_token.state", "engine.admit.first_token.sync",
 )
 #: every phase name: the cycle phases, then the children nested
 #: inside ``engine.admit`` (``kvtier.readmit`` inside ``reuse``, the

@@ -560,8 +560,9 @@ class SlotEngine:
                 cfg, self.max_len, chunk_len=self.prefill_chunk,
             )
         else:
-            # host->device transfer only on the path that uses it
-            prompt = jnp.asarray([req.tokens], jnp.int32)
+            # the prompt rides with the call as a numpy row: one
+            # transfer, on the path that uses it, no program of its own
+            prompt = np.asarray([req.tokens], np.int32)  # cpcheck: disable=CP-HOTREACH a list of Python ints: nothing is fetched
             logits, row_cache = _jitted_prefill(
                 cfg, self.max_len
             )(self.params, prompt)
@@ -570,8 +571,8 @@ class SlotEngine:
     def _admit(self, slot_id: int, req: _Request, now: float) -> None:
         """Prefill the prompt (engine policy) and hand the result to
         the step program, which samples token 0 with generate's exact
-        key schedule and writes the whole admission row into its
-        device-resident state in one dispatch. ``now`` is the
+        key schedule and writes the row and the whole admission row of
+        its device-resident state in one dispatch. ``now`` is the
         worker's perf_counter read at the admission's start (the
         ``engine.admit`` boundary)."""
         if req.timings is not None:
@@ -593,13 +594,13 @@ class SlotEngine:
         if first and (first_host == req.eos_id or req.max_new <= 1):
             state.finished = True
         self._active[slot_id] = state
-        # an admission is two to three dozen small device programs
-        # issued one by one (the prefill, the first sample, the row's
-        # insert, the state's write and a ``convert_element_type`` per
-        # scalar put: ``admit_device_programs_per_admission``), with
-        # ONE sync among them; it counts as ONE toward
-        # dispatches/token so the series tracks the steady-state
-        # decode shape the megakernel work targets
+        # an admission is two device programs (the prefill, and the
+        # step program's one for the first sample, the row's insert
+        # and the state's write: ``admit_device_programs_per_admission``
+        # counts a harvested row's ``retire`` with them), with ONE
+        # sync after them; it counts as ONE toward dispatches/token so
+        # the series tracks the steady-state decode shape the
+        # megakernel work targets
         self.dispatches += 1
         self.tokens_out += len(first)
         if req.timings is not None:

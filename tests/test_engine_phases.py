@@ -575,6 +575,50 @@ def test_profiler_trace_nests_the_children_and_carries_the_arguments(
     assert {d[3]["read_len"] for d in dispatches} == {64}
 
 
+def test_the_one_program_is_issued_in_insert_and_sync_comes_last(
+    params, tmp_path
+):
+    """An admission's device work is ONE program (models/slots.py
+    ``admit_row``), issued inside ``.insert``; ``.sample`` before it
+    packs the request's numbers on the host and ``.state`` after it is
+    the host's bookkeeping: neither runs a program; ``.sync``, the
+    fetch of the first token, comes after all three, so nothing the
+    host still has to issue waits for the device."""
+    eng = _engine(params)
+    try:
+        eng.submit([1, 2, 3], max_new=2).result(timeout=120)  # compile
+        eng.submit([4, 5, 6, 7], max_new=2).result(timeout=120)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.submit([8, 9, 10], max_new=3, temperature=0.8, top_k=4,
+                       seed=9).result(timeout=120)
+            eng.submit([4, 5, 6, 7], max_new=3).result(timeout=120)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.stop()
+    events = _engine_events(str(tmp_path))
+    child = {name: [e for e in events if e[0] == name]
+             for name in FIRST_TOKEN_PHASES}
+    sample, insert, state, sync = (child[n] for n in FIRST_TOKEN_PHASES)
+    assert len(sample) == len(insert) == len(state) == len(sync) == 2
+    for a, b, c, d in zip(sample, insert, state, sync):
+        assert a[2] <= b[1] and b[2] <= c[1] and c[2] <= d[1]
+
+    def inside(spans, name):
+        return [e for e in events if e[0] == name
+                and any(s[1] <= e[1] and e[2] <= s[2] for s in spans)]
+
+    ran = "PjRtCpuExecutable::Execute"
+    assert len(inside(insert, ran)) == 2  # one an admission
+    assert len(inside(insert, "PjitFunction(admit_row)")) >= 2
+    for spans in (sample, state, sync):
+        assert not inside(spans, ran), spans[0][0]
+    # and beside the prefill nothing else of the admission runs one
+    admits = [e for e in events if e[0] == "engine.admit"]
+    assert len(inside(admits, ran)) == 2 * 2
+
+
 def test_read_len_rides_the_dispatch_span_and_v1_model(
         params, tmp_path, monkeypatch):
     """``engine.dispatch``'s event carries ``read_len=<rung>`` beside

@@ -1014,3 +1014,201 @@ def test_pool_rows_decode_at_their_own_positions(ring, kv_int8, m):
                 np.testing.assert_array_equal(
                     np.asarray(new[name][layer][slot]),
                     np.asarray(want[name][layer, 0]))
+
+
+# --- an admission as ONE program (ISSUE 43): ``admit_row`` over one
+# packed host row against the three-call sequence it stands for
+
+def _three_calls(pool, state, slot, logits, row_cache, cfg, req):
+    """The admission as it was issued before ``admit_row``: the row
+    key on the host, ``first_sample``, a fetch of the token for
+    ``done``, ``insert_row``, ``admit_slot_state``."""
+    from containerpilot_tpu.models.slots import (
+        admit_slot_state,
+        first_sample,
+        insert_row,
+    )
+
+    key = jax.random.fold_in(jax.random.PRNGKey(req["seed"]), req["row"])
+    first = first_sample(
+        logits, key, req["temperature"], req["top_k"], req["top_p"], cfg,
+        eos_id=req["eos_id"], min_new=req["min_new"],
+        bias_idx=req["bias_idx"], bias_val=req["bias_val"],
+    )
+    first_host = int(jax.device_get(first))
+    pool = insert_row(pool, row_cache, slot, cfg)
+    state = admit_slot_state(
+        state, slot, cfg, last=first, key=key,
+        done=first_host == req["eos_id"] or req["max_new"] <= 1,
+        **{k: req[k] for k in (
+            "temperature", "top_k", "top_p", "eos_id", "pad_id",
+            "min_new", "presence", "frequency", "bias_idx", "bias_val")},
+    )
+    return pool, state, first_host
+
+
+def _request(cfg, logit_bias=None, **kw):
+    from containerpilot_tpu.models.decode import (
+        BIAS_SLOTS_MAX,
+        normalize_logit_bias,
+    )
+
+    idx, val = normalize_logit_bias(cfg, 1, logit_bias, slots=BIAS_SLOTS_MAX)
+    req = dict(
+        seed=0, row=0, temperature=0.0, top_k=0, top_p=0.0, eos_id=-1,
+        pad_id=0, min_new=0, max_new=8, presence=0.0, frequency=0.0,
+        bias_idx=idx[0], bias_val=val[0],
+    )
+    req.update(kw)
+    return req
+
+
+def _assert_admissions_agree(cfg, params, max_len, slots, prompt, req,
+                             neighbour):
+    """Both ways admit ``neighbour`` at slot 0 (so that the pool and
+    the state hold something to leave alone), then ``req`` at the last
+    slot; first token, every pool leaf and every state leaf must be
+    the same bits. Returns the admitted state's row and the token."""
+    from containerpilot_tpu.models.decode import _jitted_prefill
+    from containerpilot_tpu.models.slots import (
+        admit_row,
+        init_slot_state,
+        pack_admission,
+        slot_cache,
+    )
+
+    prefill = _jitted_prefill(cfg, max_len)
+    near_logits, near_row = prefill(
+        params, np.asarray([[3, 1, 4, 1, 5, 9]], np.int32))
+    logits, row = prefill(params, np.asarray([prompt], np.int32))
+    slot = slots - 1
+    got = []
+    for one_program in (False, True):
+        pool, state, _ = _three_calls(
+            slot_cache(cfg, slots, max_len), init_slot_state(cfg, slots),
+            0, near_logits, near_row, cfg, neighbour)
+        if one_program:
+            pool, state, first = admit_row(
+                pool, state, logits, row,
+                pack_admission(slot=slot, **req), cfg)
+            first = int(jax.device_get(first))
+        else:
+            pool, state, first = _three_calls(
+                pool, state, slot, logits, row, cfg, req)
+        got.append((first, jax.device_get(pool), jax.device_get(state)))
+    (want_first, want_pool, want_state), (first, pool, state) = got
+    assert first == want_first
+    assert set(state) == set(want_state)
+    for name in want_state:
+        np.testing.assert_array_equal(state[name], want_state[name], name)
+        assert state[name].dtype == want_state[name].dtype, name
+    assert jax.tree.structure(pool) == jax.tree.structure(want_pool)
+    for mine, theirs in zip(jax.tree.leaves(pool), jax.tree.leaves(want_pool)):
+        assert mine.dtype == theirs.dtype
+        np.testing.assert_array_equal(
+            np.asarray(mine, np.float32), np.asarray(theirs, np.float32))
+    return {name: leaf[slot] for name, leaf in state.items()}, first
+
+
+def _greedy_first(params, prompt):
+    from containerpilot_tpu.models.decode import _jitted_prefill
+
+    logits, _ = _jitted_prefill(CFG, MAX_LEN)(
+        params, np.asarray([prompt], np.int32))
+    return int(np.argmax(np.asarray(logits)[0]))
+
+
+ADMIT_PROMPT = [7, 8, 9, 10, 11]
+ADMISSIONS = {
+    "greedy": dict(),
+    "temperature": dict(temperature=0.9, seed=11),
+    "top-k-top-p": dict(temperature=0.8, top_k=7, top_p=0.85, seed=5, row=2),
+    # seeds past 31 and 32 bits, and a negative one: PRNGKey on the
+    # host keeps their low 32 bits, and so does the packed row
+    "seed-past-31-bits": dict(temperature=1.1, seed=2147484001, row=1),
+    "seed-past-32-bits": dict(temperature=1.1, seed=2 ** 40 + 9),
+    "seed-negative": dict(temperature=1.1, seed=-3),
+    # eos is what greedy would draw first: under the floor it is masked
+    "min-new-eos": dict(eos_id="greedy", min_new=2),
+    # ...and without the floor the row ends at token 0
+    "eos-at-once": dict(eos_id="greedy", pad_id=63),
+    "max-new-1": dict(max_new=1),
+    "logit-bias": dict(logit_bias={40: 100.0, 3: -50.0}),
+    "logit-bias-sampled": dict(
+        logit_bias={n: 2.5 for n in range(5, 45)}, temperature=0.7,
+        top_p=0.9, seed=21),
+    "penalties": dict(presence=0.4, frequency=0.3, temperature=0.6, seed=2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ADMISSIONS))
+def test_one_program_admission_is_the_three_calls_bit_for_bit(params, case):
+    """``admit_row`` over ``pack_admission``'s row gives the first
+    token, the pool and every state leaf (``counts``, ``done`` and
+    ``keys`` included) that ``first_sample`` -> ``insert_row`` ->
+    ``admit_slot_state`` give with the key and ``done`` computed on
+    the host, for every kind of request."""
+    kw = dict(ADMISSIONS[case])
+    greedy = _greedy_first(params, ADMIT_PROMPT)
+    if kw.get("eos_id") == "greedy":
+        kw["eos_id"] = greedy
+    req = _request(CFG, **kw)
+    neighbour = _request(CFG, temperature=0.5, top_k=3, seed=1, presence=0.2)
+    row, first = _assert_admissions_agree(
+        CFG, params, MAX_LEN, 3, ADMIT_PROMPT, req, neighbour)
+    # what each case is there for happened
+    ended = case in ("eos-at-once", "max-new-1")
+    assert bool(row["done"]) == ended
+    assert int(row["step_idx"]) == 1 and int(row["last"]) == first
+    assert row["counts"].sum() == (0.0 if case == "eos-at-once" else 1.0)
+    if case == "min-new-eos":
+        assert first != greedy
+    if case == "eos-at-once":
+        assert first == greedy
+    if case == "logit-bias":
+        assert first == 40
+    if case == "penalties":
+        assert (float(row["presence"]), float(row["frequency"])) == (
+            np.float32(0.4), np.float32(0.3))
+
+
+@pytest.mark.parametrize("toy", ["toy-axk1", "toy-granite", "toy-ouro"])
+def test_one_program_admission_writes_a_familys_pool_as_the_three_calls(toy):
+    """The same over the pools the families bring (latents and
+    counters, recurrent state beside keys, a plane per pass and
+    layer): the row enters through the family's ``insert_row`` in both
+    ways."""
+    import os
+
+    from containerpilot_tpu.workload import modelcfg
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "tests", "toy", toy + ".json")
+    cfg = modelcfg.load_model_file(path, MAX_LEN)
+    weights = cfg.family.init_params(jax.random.PRNGKey(0), cfg)
+    req = _request(cfg, temperature=0.8, top_k=9, top_p=0.9, seed=17, row=1,
+                   eos_id=2, min_new=1, presence=0.1,
+                   logit_bias={5: 1.5, 11: -2.0})
+    row, first = _assert_admissions_agree(
+        cfg, weights, MAX_LEN, 3, [21, 22, 23, 24, 25, 26, 27], req,
+        _request(cfg))
+    assert not bool(row["done"]) and int(row["last"]) == first
+
+
+def test_packed_row_has_one_static_shape_whatever_the_request():
+    from containerpilot_tpu.models.slots import (
+        ADMIT_ROW_WIDTH,
+        pack_admission,
+    )
+
+    plain = _request(CFG)
+    biased = _request(CFG, logit_bias={n: 1.0 for n in range(40)},
+                      temperature=0.3, seed=2 ** 33)
+    for req in (plain, biased):
+        packed = pack_admission(slot=1, **req)
+        assert isinstance(packed, np.ndarray)  # nothing on the device yet
+        assert packed.shape == (ADMIT_ROW_WIDTH,) and packed.dtype == np.int32
+    no_bias = dict(plain, bias_idx=None, bias_val=None)
+    np.testing.assert_array_equal(
+        pack_admission(slot=1, **no_bias), pack_admission(slot=1, **plain))

@@ -539,3 +539,98 @@ def test_warmup_fingerprint_includes_window():
     b = warmup_fingerprint(CFG, MAX_LEN, slots=2, slot_chunk=4,
                            slot_window=4)
     assert a != b
+
+
+# --- an admission is one packed host row and one device program
+# (ISSUE 43): counted as the benchmark's
+# ``admit_device_programs_per_admission`` counts it, from a profiler
+# trace; the CPU client leaves one ``PjRtCpuExecutable::Execute`` event
+# on the calling thread's line per program it runs, whoever issued it
+# (a jitted function, or the ``convert_element_type`` of a
+# ``jnp.asarray`` of a Python scalar)
+
+def _programs_run(trace_dir) -> int:
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb"))
+    assert paths, f"no trace under {trace_dir}"
+    return sum(
+        event.name == "PjRtCpuExecutable::Execute"
+        for plane in ProfileData.from_file(paths[-1]).planes
+        for line in plane.lines for event in line.events
+    )
+
+
+def _traced(trace_dir, work) -> int:
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        jax.block_until_ready(work())
+    finally:
+        jax.profiler.stop_trace()
+    return _programs_run(trace_dir)
+
+
+@pytest.mark.parametrize("kind", ["greedy", "sampled-biased"])
+def test_an_admission_is_two_device_programs_and_a_retire_one(
+        params, tmp_path, kind):
+    """Prefill + ``admit_row``: every number of the request crosses in
+    the packed row, so nothing else runs (no put of a scalar, no key
+    built on the host), and ``retire`` is its one write. The three
+    calls the program stands for, issued as they were, run an order of
+    magnitude more (``_admitted_pool``): the count sees what it is
+    there to see."""
+    from containerpilot_tpu.models.decode import normalize_logit_bias
+    from containerpilot_tpu.models.stepprog import PlainStepProgram
+    from containerpilot_tpu.workload.serve_slots import _Request
+
+    bias = {9: 4.0, 17: -3.0} if kind != "greedy" else None
+    idx, val = normalize_logit_bias(CFG, 1, bias, slots=BIAS_SLOTS_MAX)
+    knobs = {} if kind == "greedy" else dict(
+        temperature=0.8, top_k=5, top_p=0.9, presence=0.2, min_new=1)
+    program = PlainStepProgram(CFG, params, MAX_LEN, slots=2, chunk=3)
+    prefill = _jitted_prefill(CFG, MAX_LEN)
+
+    def request(seed):
+        base = dict(temperature=0.0, top_k=0, top_p=0.0)
+        base.update(knobs)
+        return _Request(
+            tokens=[5, 6, 7, 8], max_new=8, eos_id=3, pad_id=0, seed=seed,
+            bias_idx=idx[0], bias_val=val[0], **base)
+
+    def admit(slot, seed):
+        req = request(seed)
+        logits, row = prefill(params, np.asarray([req.tokens], np.int32))
+        return program.admit(slot, req, logits, row)
+
+    # compile everything outside the traces
+    admit(0, 1), program.retire(0), _admitted_pool(params, [5, 6, 7, 8])
+    assert _traced(tmp_path / "admit", lambda: admit(1, 2)) == 2
+    assert _traced(
+        tmp_path / "retire",
+        lambda: program.retire(1) or program._state) == 1
+    # slot_cache and init_slot_state run programs of their own (zeros)
+    old = _traced(
+        tmp_path / "old", lambda: _admitted_pool(params, [5, 6, 7, 8]))
+    assert old >= 20, old
+
+
+def test_the_engine_admits_with_two_programs_a_request(params, tmp_path):
+    """The same through the engine: between the start of a traced
+    window and the requests' ends, two requests of one token each run
+    2 programs an admission and 1 a retire and NO decode program,
+    whatever thread issued them."""
+    eng = SlotEngine(CFG, params, MAX_LEN, slots=2, chunk=3)
+    try:
+        eng.submit([1, 2, 3], max_new=1).result(timeout=120)  # compile
+        eng.submit([1, 2, 3, 4], max_new=1).result(timeout=120)
+
+        def work():
+            for prompt in ([4, 5, 6], [7, 8, 9, 10]):
+                eng.submit(prompt, max_new=1, temperature=0.9, top_k=4,
+                           seed=5).result(timeout=120)
+
+        assert _traced(tmp_path, work) == 2 * (2 + 1)
+    finally:
+        eng.stop()

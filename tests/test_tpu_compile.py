@@ -811,6 +811,74 @@ def test_hybrid_state_space_insert_overwrites_a_row_in_place(chip):
             < 1024 ** 2)
 
 
+@pytest.mark.parametrize("which", ["flagship", "hybrid-ssm"])
+def test_admit_row_writes_the_row_and_the_state_where_they_lie(chip, which):
+    """An admission's ONE program (models/slots.py ``admit_row``: row
+    key, first sample, the row's write, the state's write) at the
+    benchmark's pool shapes (Mistral: 16 slots x 4,096 positions;
+    granite: 64 x 3,072 with its recurrent state, through the family's
+    ``insert_row``), compiled for the v5e: pool and state are donated
+    and aliased whole, the only new output is the first token, next to
+    nothing is held besides, and outside fused computations no
+    instruction produces a tensor as large as the pool's smallest big
+    leaf but the in-place writes of the row (a fusion around a
+    dynamic-update-slice over the pool's own parameters)."""
+    from containerpilot_tpu.models.decode import _jitted_prefill
+    from containerpilot_tpu.models.slots import (
+        ADMIT_ROW_WIDTH,
+        _jitted_admit_row,
+        slot_cache,
+    )
+
+    if which == "flagship":
+        cfg, _slots, (params, pool, state), _keys = _cell_decode_shapes(
+            chip, slot_cache)
+        length = 4096
+    else:
+        length = 3072
+        cfg, (params, pool, state, _row) = _hybrid_ssm_shapes(
+            chip, 64, length)
+    prompt = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=chip)
+    logits, row = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(_jitted_prefill(cfg, length), params, prompt))
+    packed = jax.ShapeDtypeStruct(
+        (ADMIT_ROW_WIDTH,), jnp.int32, sharding=chip)
+    compiled = _jitted_admit_row(cfg).lower(
+        pool, state, logits, row, packed).compile()
+    donated = sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves((pool, state)))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= donated
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 4096
+    assert memory.temp_size_in_bytes < 64 * 1024 ** 2
+    big = min(
+        size for size in (math.prod(leaf.shape)
+                          for leaf in jax.tree.leaves(pool))
+        if size >= 2 ** 24)
+    outside, bodies = _outside_fusions(compiled.as_text())
+    writes = 0
+    for _computation, line in outside:
+        made = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)", line)
+        if not made:
+            continue
+        _name, kind, opcode, operands = made.groups()
+        if opcode in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            continue
+        sizes = [math.prod(int(n) for n in dims.split(",") if n)
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", kind)]
+        if not sizes or max(sizes) < big:
+            continue
+        assert opcode == "fusion", line[:200]
+        called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+        assert " dynamic-update-slice(" in "\n".join(bodies[called]), line[:200]
+        assert "%pool_" in operands, line[:200]
+        writes += 1
+    assert writes >= 1
+
+
 def _vocabulary_sorts(text, vocab):
     """The ``sort`` instructions of an optimised program over a
     dimension of ``vocab``, as (inside, outside): whether the
