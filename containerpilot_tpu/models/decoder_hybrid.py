@@ -1,0 +1,926 @@
+"""A sixth model family: a decoder-hybrid-decoder (``model_type``
+``phi4flash``: Phi-4-mini-flash-reasoning, the "SambaY" of
+arXiv:2507.06607). Serving only.
+
+Four kinds of layer from ONE declared pattern (``layer_kinds``: for
+``n`` layers, Mamba-1 at even ``i <= n/2``, window attention at odd
+``i < n/2``, full attention at ``i = n/2 + 1``, a gated memory unit at
+even ``i >= n/2 + 2``, cross attention at odd ``i >= n/2 + 3``). Every
+layer is ``h = h + mixer(LN1(h)); h = h + MLP(LN2(h))`` with
+``nn.LayerNorm`` (weight and bias) and ``MLP(x) = W2(up * silu(gate))``;
+no position encoding anywhere. The mixers:
+
+- **Mamba-1** (arXiv:2312.00752): ``[x, z] = W_in u``; ``x =
+  silu(conv4(x) + b)``; ``[dr, B, C] = W_x x``; ``delta = softplus(W_dt
+  dr + b_dt)``; ``A = -exp(A_log)``, a decay per channel AND state
+  index; ``S_t = exp(delta_t * A) * S_{t-1} + (delta_t x_t) (x) B_t``;
+  ``y_t = S_t C_t + D x_t``; the mixer gives ``W_out(y * silu(z))``.
+  The layer at ``n/2`` also hands ``y`` on, before the gate: the
+  MEMORY. A prompt runs the recurrence itself, ``ssm_chunk`` positions
+  to a loop step (``_ssm_seq``, scope ``ssm.scan``: inside a step the
+  positions are unrolled, so the chain of states fuses and the loop's
+  own cost is paid once a block; a decay per state index has no
+  matrix form as Mamba-2's scalar decay has); a decode step is one
+  position (``_ssm_step``, ``ssm.update``). ``S``, ``delta``, ``B``,
+  ``C`` and the step's products are float32.
+- **differential attention** (arXiv:2410.05258), the form of EVERY
+  attention layer: consecutive query heads pair up ``(q1, q2)``,
+  consecutive key/value heads pair up ``(k1, k2)``, ``(v1, v2)``; a
+  pair of pairs is one head with ``V = [v1 ; v2]``; ``a = softmax(q1
+  k1^T / sqrt(hd)) V - lambda softmax(q2 k2^T / sqrt(hd)) V``,
+  ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``,
+  ``lambda_init = 0.8 - 0.6 exp(-0.3 i)``; ``o = RMSNorm(a) (1 -
+  lambda_init)`` over the ``2 hd`` of a head, with a learned weight.
+  **window** layers see the last ``attn_window`` positions (self
+  included), the **full** layer everything, and a **cross** layer has
+  only a query and an output projection: its keys and values are the
+  full layer's.
+- **gated memory unit**: ``W_out(silu(W_in u) * m_t)``, ``m_t`` the
+  memory at the SAME position. No cache, no state.
+
+**The cache**, four shapes in one row: per Mamba layer ``ssm[j]``
+[rows, state, d_inner] float32 (held state-major: the channels are the
+lanes' axis) and ``conv[j]`` [rows, d_conv - 1, d_inner]; per window
+layer a ring ``ring_k[j]`` / ``ring_v[j]`` [rows, kv_pairs, window,
+2 hd], written at ``position mod window`` and masked by position while
+the row is shorter than the window (without positions an attention
+needs to know WHICH slots hold something, not in which order); ONE
+plane ``k[0]`` / ``v[0]`` [rows, kv_pairs, length, 2 hd] that the full
+layer writes and ``1 + n_cross`` layers read where it lies; nothing
+for the gated memory and cross layers. Keys and values are held
+pair-major, a pair's two heads side by side on the last axis: the
+bytes of [length, kv_heads, hd], in the layout the contraction reads
+as it lies (a head width of 64 would fill half the chip's lanes), and
+``q1`` / ``q2`` meet ``k1`` / ``k2`` as ``[q1 ; 0]`` / ``[0 ; q2]``
+against ``[k1 ; k2]``: the zeros add exact zeros. State cannot be
+rewound (``recurrent_state``: no prefix cache, no spill tier).
+
+**Admission** (``prefill``) is the published one: the self-decoder
+(layers ``0 .. n/2 + 1``) over the whole prompt fills every cache there
+is; the cross-decoder, the final norm and the head run for the LAST
+position only. ``forward`` runs every layer at every position.
+
+A pool carries ``stats`` (models/slots.py): ``ssm_row_steps``,
+``ring_row_steps``, ``ring_rows_wrapped``, ``shared_plane_reads``
+(every row of the pool steps, a retired one too), then
+``prefill_positions_self`` and ``prefill_positions_cross``, which a
+prefilled row brings in its ``admitted`` leaf, ``insert_row`` adds to
+the pool's and the next decode step moves into ``stats``.
+
+Weights follow models/mla_moe.py's recipe (made leaf by leaf and held
+in bf16, a key per leaf and vocabulary block); the embedding is also
+the head and is seeded small, as models/hybrid_ssm.py's.
+"""
+from __future__ import annotations
+
+import contextlib
+import functools
+import math
+from dataclasses import dataclass
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .decode import NEG_INF
+from .mla_moe import TOP, VOCAB_BLOCK, _draw, _swiglu
+from .quantized import embed_lookup
+
+Params = Dict[str, Any]
+Cache = Dict[str, Any]
+
+#: query rows of a prompt's attention worked on at once
+Q_BLOCK = 512
+KINDS = ("mamba", "window", "full", "gmu", "cross")
+F32 = jnp.float32
+HIGHEST = lax.Precision.HIGHEST
+#: a pool's counters, in the order of its ``stats`` leaf
+STATS = ("ssm_row_steps", "ring_row_steps", "ring_rows_wrapped",
+         "shared_plane_reads", "prefill_positions_self",
+         "prefill_positions_cross")
+
+
+@functools.lru_cache(maxsize=None)
+def layer_kinds(n_layers: int) -> Tuple[str, ...]:
+    """The kind of each layer, by the published rule."""
+    half = n_layers // 2
+    kinds = []
+    for i in range(n_layers):
+        if i % 2 == 0:
+            kinds.append("mamba" if i <= half else "gmu")
+        elif i < half:
+            kinds.append("window")
+        else:
+            kinds.append("full" if i == half + 1 else "cross")
+    return tuple(kinds)
+
+
+@dataclass(frozen=True)
+class DecoderHybridConfig:
+    vocab_size: int = 512
+    d_model: int = 64
+    n_layers: int = 12
+    n_heads: int = 8
+    n_kv_heads: int = 4
+    d_ff: int = 128
+    attn_window: int = 8
+    ssm_state: int = 4
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 4
+    #: positions of a prompt's recurrence unrolled into one loop step
+    ssm_chunk: int = 16
+    ln_eps: float = 1e-5
+    max_seq_len: int = 2048
+    dtype: Any = jnp.bfloat16
+    #: digest of the file the configuration was read from (part of a
+    #: server's warm-up fingerprint, workload/modelcfg.py)
+    source_digest: str = ""
+
+    def __post_init__(self) -> None:
+        if self.n_layers < 8 or self.n_layers % 4:
+            raise ValueError(
+                "num_hidden_layers must be a multiple of 4, at least 8: "
+                "the layer kinds go by the halves of an even half")
+        if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
+            raise ValueError("num_attention_heads must divide hidden_size "
+                             "and num_key_value_heads the heads")
+        if self.n_kv_heads % 2 or self.n_heads % 2:
+            raise ValueError("differential attention pairs the heads up: "
+                             "both counts must be even")
+        if self.ssm_conv < 2 or self.ssm_chunk < 1 or self.attn_window < 1:
+            raise ValueError("mamba_d_conv must be >= 2, the scan's block "
+                             "and sliding_window >= 1")
+
+    # what the serving code asks of any configuration (``window`` is the
+    # flagship's ring over EVERY layer, which this is not)
+    window = 0
+    kv_int8 = False
+    #: a row's state cannot be cut back to a shorter prefix
+    recurrent_state = True
+    #: ``decode_chunk`` takes one token a row: no prompt in pieces
+    one_token_steps = True
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return layer_kinds(self.n_layers)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def kv_pairs(self) -> int:
+        return self.n_kv_heads // 2
+
+    @property
+    def pair_dim(self) -> int:
+        """A differential head's value width: ``[v1 ; v2]``."""
+        return 2 * self.head_dim
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_expand * self.d_model
+
+    @property
+    def memory_layer(self) -> int:
+        return self.n_layers // 2
+
+    def count(self, kind: str) -> int:
+        return self.kinds.count(kind)
+
+    @property
+    def plane_readers(self) -> int:
+        """Layers that read the one plane in a step: the full layer
+        and every cross layer."""
+        return 1 + self.count("cross")
+
+    @property
+    def family(self):
+        import sys
+
+        return sys.modules[__name__]
+
+
+def from_published(config: Dict[str, Any], max_seq_len: int,
+                   source_digest: str = "") -> DecoderHybridConfig:
+    """The configuration from a published ``config.json``'s own keys
+    (``phi4flash``'s); what that file does not key is read from the
+    file's ``assumed`` group, else the family's published defaults."""
+    for key, want in (("hidden_act", "silu"), ("tie_word_embeddings", True),
+                      ("mlp_bias", False), ("lm_head_bias", False)):
+        if config.get(key, want) != want:
+            raise ValueError(f"{key} {config[key]!r}: only {want!r}")
+    if int(config.get("mb_per_layer", 2)) != 2:
+        raise ValueError("mb_per_layer: only 2 (a Mamba layer every "
+                         "second layer of the self-decoder)")
+    assumed = config.get("assumed", {})
+    d = int(config["hidden_size"])
+    return DecoderHybridConfig(
+        vocab_size=int(config["vocab_size"]),
+        d_model=d,
+        n_layers=int(config["num_hidden_layers"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]),
+        d_ff=int(config["intermediate_size"]),
+        attn_window=int(config["sliding_window"]),
+        ssm_state=int(assumed.get("mamba_d_state", 16)),
+        ssm_conv=int(assumed.get("mamba_d_conv", 4)),
+        ssm_expand=int(assumed.get("mamba_expand", 2)),
+        ssm_dt_rank=int(assumed.get("mamba_dt_rank", math.ceil(d / 16))),
+        ln_eps=float(config["layer_norm_eps"]),
+        max_seq_len=max_seq_len, source_digest=source_digest,
+    )
+
+
+# -- weights ------------------------------------------------------------
+
+#: a leaf's key is PRNGKey(0) folded with its layer (TOP for the
+#: embedding) and then with its number here; a block of 128 vocabulary
+#: rows with its block index after that
+LEAF = {name: i for i, name in enumerate((
+    "w_in", "conv_w", "conv_b", "w_x", "w_dt", "dt_bias", "w_out",
+    "w_qkv", "b_qkv", "w_q", "b_q", "w_o", "b_o", "lambdas",
+    "g_in", "g_out", "w_gate", "w_up", "w_down", "embed",
+))}
+#: the embedding's scale: it is also the head (models/hybrid_ssm.py
+#: ``EMBED_SCALE`` has the reason)
+EMBED_SCALE = 0.001
+#: a bias is drawn at this scale, the four lambda vectors at the
+#: differential-attention recipe's
+BIAS_SCALE = 0.02
+LAMBDA_SCALE = 0.1
+#: Delta's bias is the inverse softplus of a step drawn log-uniformly
+#: in this range (Mamba-1's own initialisation)
+DT_RANGE = (0.001, 0.1)
+
+
+def _leaf_key(layer: int, name: str):
+    return jax.random.fold_in(
+        jax.random.fold_in(jax.random.PRNGKey(0), layer), LEAF[name])
+
+
+def lambda_init(layer: int) -> float:
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def _layer_leaves(cfg: DecoderHybridConfig, layer: int) -> Dict[str, Any]:
+    d, di, hd = cfg.d_model, cfg.d_inner, cfg.head_dim
+    kind = cfg.kinds[layer]
+    q_width, kv_width = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    shapes: Dict[str, Any] = {
+        "w_gate": ((d, cfg.d_ff), d), "w_up": ((d, cfg.d_ff), d),
+        "w_down": ((cfg.d_ff, d), cfg.d_ff),
+    }
+    biases: Dict[str, Tuple[int, ...]] = {}
+    if kind == "mamba":
+        rank, n = cfg.ssm_dt_rank, cfg.ssm_state
+        shapes.update({
+            "w_in": ((d, 2 * di), d),
+            "conv_w": ((cfg.ssm_conv, di), cfg.ssm_conv),
+            "w_x": ((di, rank + 2 * n), di), "w_dt": ((rank, di), rank),
+            "w_out": ((di, d), di),
+        })
+        biases["conv_b"] = (di,)
+    elif kind == "gmu":
+        shapes.update({"g_in": ((d, di), d), "g_out": ((di, d), di)})
+    else:
+        if kind == "cross":
+            shapes["w_q"] = ((d, q_width), d)
+            biases["b_q"] = (q_width,)
+        else:
+            shapes["w_qkv"] = ((d, q_width + 2 * kv_width), d)
+            biases["b_qkv"] = (q_width + 2 * kv_width,)
+        shapes["w_o"] = ((q_width, d), q_width)
+        biases["b_o"] = (d,)
+    out = {
+        name: _draw(_leaf_key(layer, name), shape, fan_in ** -0.5, cfg.dtype)
+        for name, (shape, fan_in) in shapes.items()
+    }
+    for name, shape in biases.items():
+        out[name] = _draw(_leaf_key(layer, name), shape, BIAS_SCALE,
+                          cfg.dtype)
+    if kind == "mamba":
+        n = cfg.ssm_state
+        # S4D-real: A = -(1 .. state) for every channel
+        out["a_log"] = jnp.broadcast_to(
+            jnp.log(jnp.arange(1, n + 1, dtype=F32))[:, None], (n, di))
+        lo, hi = (math.log(v) for v in DT_RANGE)
+        step = jnp.exp(jax.random.uniform(
+            _leaf_key(layer, "dt_bias"), (di,), F32, lo, hi))
+        out["dt_bias"] = jnp.log(jnp.expm1(step))
+        out["d_skip"] = jnp.ones((di,), F32)
+    elif kind != "gmu":
+        # lq1, lk1, lq2, lk2
+        out["lambdas"] = jax.random.normal(
+            _leaf_key(layer, "lambdas"), (4, hd), F32) * LAMBDA_SCALE
+        out["subln"] = jnp.ones((cfg.pair_dim,), F32)
+    for name in ("ln1", "ln2"):
+        out[name + "_w"] = jnp.ones((d,), F32)
+        out[name + "_b"] = jnp.zeros((d,), F32)
+    return out
+
+
+def init_params(rng: Any, cfg: DecoderHybridConfig) -> Params:
+    """Seeded weights, made leaf by leaf and held in ``cfg.dtype``
+    (``rng`` is unused: see models/mla_moe.py ``init_params``). The
+    embedding is also the head."""
+    del rng
+    if cfg.vocab_size % VOCAB_BLOCK:
+        raise ValueError(f"vocab_size must be a multiple of {VOCAB_BLOCK}")
+    key = _leaf_key(TOP, "embed")
+    rows = jax.vmap(lambda b: _draw(
+        jax.random.fold_in(key, b), (VOCAB_BLOCK, cfg.d_model), EMBED_SCALE,
+        cfg.dtype))(jnp.arange(cfg.vocab_size // VOCAB_BLOCK))
+    return {
+        "embed": rows.reshape(cfg.vocab_size, cfg.d_model),
+        "layers": [_layer_leaves(cfg, i) for i in range(cfg.n_layers)],
+        "ln_out_w": jnp.ones((cfg.d_model,), F32),
+        "ln_out_b": jnp.zeros((cfg.d_model,), F32),
+    }
+
+
+# -- pieces of a layer ----------------------------------------------------
+
+
+def _layer_norm(x, weight, bias, eps: float):
+    """``nn.LayerNorm`` over the last axis, its statistics float32."""
+    with jax.named_scope("norm"):
+        xf = x.astype(F32)
+        mean = jnp.mean(xf, axis=-1, keepdims=True)
+        centred = xf - mean
+        var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+        return (centred * lax.rsqrt(var + eps) * weight + bias).astype(x.dtype)
+
+
+def _matmul(x, w, dt, out=None):
+    """x [..., a] @ w [a, c] in ``dt`` with float32 accumulation,
+    rounded to ``out`` (``dt`` unless given)."""
+    return jnp.einsum("...a,ac->...c", x.astype(dt), w.astype(dt),
+                      preferred_element_type=F32).astype(out or dt)
+
+
+def _residual(x, out):
+    """``x + out`` (``out`` float32), rounded once."""
+    return (x.astype(F32) + out.astype(F32)).astype(x.dtype)
+
+
+def _mlp(x, lp, cfg: DecoderHybridConfig):
+    """LN2, the SwiGLU block and the residual."""
+    h = _layer_norm(x, lp["ln2_w"], lp["ln2_b"], cfg.ln_eps)
+    with jax.named_scope("mlp"):
+        out = _swiglu(h.reshape(-1, h.shape[-1]), lp["w_gate"], lp["w_up"],
+                      lp["w_down"], cfg.dtype)
+    return _residual(x, out.reshape(x.shape))
+
+
+def _conv(x, tail, lp, cfg: DecoderHybridConfig):
+    """The causal depthwise convolution, its bias and silu over x [b,
+    m, d_inner], the row's last ``d_conv - 1`` inputs (``tail``) ahead
+    of it. Returns (the activation [b, m, d_inner], the new tail)."""
+    m = x.shape[1]
+    with jax.named_scope("ssm"), jax.named_scope("ssm.conv"):
+        padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+        weights = lp["conv_w"].astype(F32)
+        out = lp["conv_b"].astype(F32)
+        for j in range(cfg.ssm_conv):
+            out = out + weights[j] * padded[:, j:j + m].astype(F32)
+        return jax.nn.silu(out).astype(cfg.dtype), padded[:, m:]
+
+
+def _ssm_inputs(act, lp, cfg: DecoderHybridConfig):
+    """What the recurrence reads, all float32: x [b, m, d_inner],
+    delta [b, m, d_inner], B and C [b, m, state]."""
+    rank, n = cfg.ssm_dt_rank, cfg.ssm_state
+    with jax.named_scope("ssm"), jax.named_scope("ssm.x_proj"):
+        dbc = _matmul(act, lp["w_x"], cfg.dtype, F32)
+        step = _matmul(dbc[..., :rank], lp["w_dt"], cfg.dtype, F32)
+        delta = jax.nn.softplus(step + lp["dt_bias"])
+    return (act.astype(F32), delta, dbc[..., rank:rank + n],
+            dbc[..., rank + n:])
+
+
+def _ssm_step(x, delta, b_in, c_out, state, lp):
+    """One position of the recurrence for every row: x and delta [b,
+    d_inner], B and C [b, state], ``state`` [b, state, d_inner]
+    float32, read once and written once where it lies. Returns (y [b,
+    d_inner] float32, the new state)."""
+    with jax.named_scope("ssm"), jax.named_scope("ssm.update"):
+        rate = -jnp.exp(lp["a_log"])                      # [n, di]
+        state = (jnp.exp(delta[:, None, :] * rate) * state
+                 + b_in[:, :, None] * (delta * x)[:, None, :])
+        y = jnp.sum(state * c_out[:, :, None], axis=1)
+        return y + lp["d_skip"] * x, state
+
+
+def _ssm_seq(x, delta, b_in, c_out, state, lp, cfg: DecoderHybridConfig):
+    """m positions of the recurrence from ``state``, ``ssm_chunk``
+    positions to a loop step, unrolled inside it: x and delta [b, m,
+    d_inner], B and C [b, m, state]. A tail short of a block is padded
+    with delta 0, which leaves the state as it is. Returns (y [b, m,
+    d_inner] float32, the state after the last position)."""
+    with jax.named_scope("ssm"), jax.named_scope("ssm.scan"):
+        b, m = x.shape[:2]
+        size = min(cfg.ssm_chunk, m)
+        pad = -m % size
+
+        def blocks(v):
+            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+            return jnp.moveaxis(
+                v.reshape(b, (m + pad) // size, size, v.shape[-1]), 1, 0)
+
+        rate = -jnp.exp(lp["a_log"])                      # [n, di]
+
+        def body(carry, inputs):
+            xc, dc, bc, cc = inputs   # [b, T, di] x 2, [b, T, n] x 2
+            decay = jnp.exp(dc[:, :, None, :] * rate)     # [b, T, n, di]
+            fed = bc[:, :, :, None] * (dc * xc)[:, :, None, :]
+            states = []
+            for t in range(size):
+                carry = decay[:, t] * carry + fed[:, t]
+                states.append(carry)
+            y = jnp.sum(jnp.stack(states, axis=1) * cc[..., None], axis=2)
+            return carry, y
+
+        state, ys = lax.scan(
+            body, state, (blocks(x), blocks(delta), blocks(b_in),
+                          blocks(c_out)))
+        y = jnp.moveaxis(ys, 0, 1).reshape(b, m + pad, -1)[:, :m]
+        return y + lp["d_skip"] * x, state
+
+
+def _mamba(u, lp, cfg: DecoderHybridConfig, state, tail):
+    """A Mamba-1 mixer over m positions of ``u`` (normed) from
+    (``state``, ``tail``): the recurrence itself for one position, in
+    blocks for more. Returns (the mixer's output float32, y before the
+    gate in the compute dtype: the MEMORY, state, tail)."""
+    dt, di = cfg.dtype, cfg.d_inner
+    with jax.named_scope("ssm"), jax.named_scope("ssm.in_proj"):
+        xz = _matmul(u, lp["w_in"], dt)
+    act, tail = _conv(xz[..., :di], tail, lp, cfg)
+    x, delta, b_in, c_out = _ssm_inputs(act, lp, cfg)
+    if u.shape[1] == 1:
+        y, state = _ssm_step(x[:, 0], delta[:, 0], b_in[:, 0], c_out[:, 0],
+                             state, lp)
+        y = y[:, None]
+    else:
+        y, state = _ssm_seq(x, delta, b_in, c_out, state, lp, cfg)
+    with jax.named_scope("ssm"), jax.named_scope("ssm.out_proj"):
+        gated = (y * jax.nn.silu(xz[..., di:].astype(F32))).astype(dt)
+        out = _matmul(gated, lp["w_out"], dt, F32)
+    return out, y.astype(dt), state, tail
+
+
+def _gmu(u, memory, lp, cfg: DecoderHybridConfig):
+    """The gated memory unit over ``u`` (normed) and the memory of the
+    same positions. Returns the mixer's output float32."""
+    dt = cfg.dtype
+    with jax.named_scope("gmu"):
+        gate = jax.nn.silu(_matmul(u, lp["g_in"], dt, F32))
+        return _matmul((gate * memory.astype(F32)).astype(dt), lp["g_out"],
+                       dt, F32)
+
+
+def _padded_queries(q, cfg: DecoderHybridConfig):
+    """q [b, m, heads x hd] as [b, m, kv_pairs, 2 x group, 2 hd]: head
+    ``2 j`` as ``[q1 ; 0]`` and head ``2 j + 1`` as ``[0 ; q2]``, so
+    that each meets its own half of a pair's ``[k1 ; k2]``."""
+    b, m = q.shape[:2]
+    pairs, hd = cfg.kv_pairs, cfg.head_dim
+    group = cfg.n_heads // cfg.n_kv_heads
+    q = q.reshape(b, m, pairs, group, 2, hd)
+    zero = jnp.zeros_like(q[..., 0, :])
+    first = jnp.concatenate([q[..., 0, :], zero], axis=-1)
+    second = jnp.concatenate([zero, q[..., 1, :]], axis=-1)
+    return jnp.stack([first, second], axis=-2).reshape(
+        b, m, pairs, 2 * group, 2 * hd)
+
+
+def _pairs(kv, cfg: DecoderHybridConfig):
+    """k or v [b, m, kv_heads x hd] pair-major: [b, kv_pairs, m, 2 hd]."""
+    b, m = kv.shape[:2]
+    return jnp.swapaxes(kv.reshape(b, m, cfg.kv_pairs, cfg.pair_dim), 1, 2)
+
+
+def _pair_attention(q, keys, values, valid, cfg: DecoderHybridConfig):
+    """Masked attention of padded queries q [b, m, kv_pairs, maps, 2
+    hd] over keys / values [b, kv_pairs, length, 2 hd] AS THE CACHE
+    STORES THEM; valid is [b or 1, m, length]. The precision of
+    models/decode.py ``_grouped_attention``: stored dtype into float32
+    scores, float32 softmax weights, the values at HIGHEST. Returns
+    float32 [b, m, kv_pairs, maps, 2 hd]."""
+    scores = jnp.einsum("bqpjd,bpkd->bpjqk", q, keys,
+                        preferred_element_type=F32) * cfg.head_dim ** -0.5
+    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
+    weights = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bpjqk,bpkd->bqpjd", weights, values,
+                      preferred_element_type=F32, precision=HIGHEST)
+
+
+def _difference(o, lp, layer: int, cfg: DecoderHybridConfig):
+    """The two maps' difference, its norm per head and the heads
+    joined: o [b, m, kv_pairs, 2 x group, 2 hd] float32 -> [b, m,
+    heads x hd] in the compute dtype."""
+    with jax.named_scope("attn"), jax.named_scope("attn.diff"):
+        b, m, pairs = o.shape[:3]
+        lq1, lk1, lq2, lk2 = lp["lambdas"]
+        start = lambda_init(layer)
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + start
+        o = o.reshape(b, m, pairs, -1, 2, cfg.pair_dim)
+        a = o[..., 0, :] - lam * o[..., 1, :]
+        var = jnp.mean(jnp.square(a), axis=-1, keepdims=True)
+        a = a * lax.rsqrt(var + cfg.ln_eps) * lp["subln"] * (1.0 - start)
+        return a.astype(cfg.dtype).reshape(b, m, -1)
+
+
+def _attn_out(a, lp, cfg: DecoderHybridConfig):
+    with jax.named_scope("attn"), jax.named_scope("attn.out"):
+        return (_matmul(a, lp["w_o"], cfg.dtype, F32)
+                + lp["b_o"].astype(F32))
+
+
+def _qkv(u, lp, cfg: DecoderHybridConfig):
+    """The one projection of a window or full layer: padded queries
+    and the new keys and values pair-major ([b, kv_pairs, m, 2 hd])."""
+    q_width = cfg.n_heads * cfg.head_dim
+    kv_width = cfg.n_kv_heads * cfg.head_dim
+    with jax.named_scope("attn"), jax.named_scope("attn.qkv"):
+        qkv = (_matmul(u, lp["w_qkv"], cfg.dtype, F32)
+               + lp["b_qkv"].astype(F32)).astype(cfg.dtype)
+        return (_padded_queries(qkv[..., :q_width], cfg),
+                _pairs(qkv[..., q_width:q_width + kv_width], cfg),
+                _pairs(qkv[..., q_width + kv_width:], cfg))
+
+
+def _cross_queries(u, lp, cfg: DecoderHybridConfig):
+    with jax.named_scope("attn"), jax.named_scope("attn.qkv"):
+        q = (_matmul(u, lp["w_q"], cfg.dtype, F32)
+             + lp["b_q"].astype(F32)).astype(cfg.dtype)
+        return _padded_queries(q, cfg)
+
+
+def _sequence_attention(q, keys, values, window: int,
+                        cfg: DecoderHybridConfig):
+    """Attention of a whole sequence from position 0 over its own keys
+    and values, ``Q_BLOCK`` query rows at a time where it is long:
+    causal, and banded to the last ``window`` positions (self
+    included) where ``window`` > 0."""
+    b, s = q.shape[:2]
+    step = Q_BLOCK if s > Q_BLOCK and s % Q_BLOCK == 0 else s
+    cols = jnp.arange(s)
+
+    def rows(start):
+        qs = lax.dynamic_slice_in_dim(q, start, step, axis=1)
+        at = (start + jnp.arange(step))[:, None]
+        valid = cols[None, :] <= at
+        if window:
+            valid = valid & (cols[None, :] > at - window)
+        return _pair_attention(qs, keys, values, valid[None], cfg)
+
+    if step == s:
+        return rows(0)
+    out = lax.map(rows, jnp.arange(0, s, step))  # [n, b, step, ...]
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, *out.shape[3:])
+
+
+def _logits(params: Params, x: jax.Array, cfg: DecoderHybridConfig):
+    with jax.named_scope("head"):
+        x = _layer_norm(x, params["ln_out_w"], params["ln_out_b"], cfg.ln_eps)
+        return jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(cfg.dtype),
+                          preferred_element_type=F32)
+
+
+# -- the cache ------------------------------------------------------------
+
+#: a cache's per-layer leaves, by the kind of layer that KEEPS them (a
+#: cross layer reads the full layer's, a gated memory unit none)
+LEAVES = {"mamba": ("ssm", "conv"), "window": ("ring_k", "ring_v"),
+          "full": ("k", "v")}
+ALL_LEAVES = tuple(name for names in LEAVES.values() for name in names)
+
+
+def init_cache(cfg: DecoderHybridConfig, batch: int, max_len: int) -> Cache:
+    """Zeroed cache (the module's note has the shapes); ``pos`` one
+    number until a pool makes it one per row; ``admitted`` what a
+    prefill ran to make the row ([positions through the self-decoder,
+    positions through the cross-decoder])."""
+    dt = cfg.dtype
+    pairs, width = cfg.kv_pairs, cfg.pair_dim
+    ring = (batch, pairs, min(cfg.attn_window, max_len), width)
+    n_mamba, n_window = cfg.count("mamba"), cfg.count("window")
+    return {
+        "pos": jnp.zeros((), jnp.int32),
+        "admitted": jnp.zeros((2,), jnp.int32),
+        "ssm": [jnp.zeros((batch, cfg.ssm_state, cfg.d_inner), F32)
+                for _ in range(n_mamba)],
+        "conv": [jnp.zeros((batch, cfg.ssm_conv - 1, cfg.d_inner), dt)
+                 for _ in range(n_mamba)],
+        "ring_k": [jnp.zeros(ring, dt) for _ in range(n_window)],
+        "ring_v": [jnp.zeros(ring, dt) for _ in range(n_window)],
+        "k": [jnp.zeros((batch, pairs, max_len, width), dt)],
+        "v": [jnp.zeros((batch, pairs, max_len, width), dt)],
+    }
+
+
+def slot_cache(cfg: DecoderHybridConfig, slots: int, max_len: int) -> Cache:
+    """The serving pool: the cache with one row and one position per
+    slot, and the counters (see the module's note)."""
+    pool = init_cache(cfg, slots, max_len)
+    pool["pos"] = jnp.zeros((slots,), jnp.int32)
+    pool["stats"] = jnp.zeros((len(STATS),), jnp.int32)
+    return pool
+
+
+def insert_row(pool: Cache, row: Cache, slot: jax.Array) -> Cache:
+    """Write a one-row cache (``prefill``'s) into ``slot``: the whole
+    state and tail, every slot of every ring, every position of the
+    plane and the row's position, so nothing of the slot's last
+    occupant is left; what the prefill ran joins ``admitted``."""
+    new = dict(pool)
+    for name in ALL_LEAVES:
+        new[name] = [
+            lax.dynamic_update_slice(
+                big, small.astype(big.dtype),
+                (slot,) + (0,) * (big.ndim - 1))
+            for big, small in zip(pool[name], row[name])]
+    new["pos"] = lax.dynamic_update_slice(
+        pool["pos"], jnp.reshape(row["pos"], (1,)).astype(jnp.int32), (slot,))
+    new["admitted"] = pool["admitted"] + row["admitted"]
+    return new
+
+
+# -- forward, prefill, decode --------------------------------------------
+
+
+def _run(params: Params, cache: Cache, x: jax.Array,
+         cfg: DecoderHybridConfig, layers, attend, memory=None):
+    """``x`` [b, m, d] through the layers ``layers`` (a range), each
+    Mamba layer's leaves of ``cache`` read and replaced; ``attend(kind,
+    j, layer, u, lp)`` is the j-th layer of its kind of attention over
+    its keys and values: it returns (the heads' output float32, the
+    leaves it replaced: a dict by name). Returns (x, the replaced
+    leaves by name and index, the memory)."""
+    new: Dict[str, Dict[int, jax.Array]] = {name: {} for name in ALL_LEAVES}
+    seen = {kind: cfg.kinds[:layers.start].count(kind) for kind in KINDS}
+    with jax.named_scope("layers"):
+        for i in layers:
+            kind, lp = cfg.kinds[i], params["layers"][i]
+            j = seen[kind]
+            seen[kind] += 1
+            u = _layer_norm(x, lp["ln1_w"], lp["ln1_b"], cfg.ln_eps)
+            if kind == "mamba":
+                out, y, state, tail = _mamba(
+                    u, lp, cfg, cache["ssm"][j], cache["conv"][j])
+                new["ssm"][j], new["conv"][j] = state, tail
+                if i == cfg.memory_layer:
+                    memory = y
+            elif kind == "gmu":
+                out = _gmu(u, memory, lp, cfg)
+            else:
+                o, leaves = attend(kind, j, u, lp)
+                for name, leaf in leaves.items():
+                    new[name][j] = leaf
+                out = _attn_out(_difference(o, lp, i, cfg), lp, cfg)
+            x = _mlp(_residual(x, out), lp, cfg)
+    return x, new, memory
+
+
+def _replaced(cache: Cache, new) -> Cache:
+    """``cache`` with the leaves ``_run`` replaced."""
+    out = dict(cache)
+    for name, leaves in new.items():
+        if leaves:
+            out[name] = [leaves.get(j, leaf)
+                         for j, leaf in enumerate(cache[name])]
+    return out
+
+
+@contextlib.contextmanager
+def _scoped(kind: str):
+    """``attn`` > ``attn.<kind>``: a layer's keys and values written
+    and read."""
+    with jax.named_scope("attn"), jax.named_scope(f"attn.{kind}"):
+        yield
+
+
+def _self_decoder(params: Params, tokens: jax.Array,
+                  cfg: DecoderHybridConfig, layers):
+    """tokens [b, s] from position 0 through ``layers`` (the
+    self-decoder, or every layer: then a cross layer attends causally
+    to the full layer's keys). Returns (hidden [b, s, d], a cache of
+    length s, the memory [b, s, d_inner])."""
+    b, s = tokens.shape
+    plane: Dict[str, jax.Array] = {}
+
+    def attend(kind, _j, u, lp):
+        if kind == "cross":
+            q = _cross_queries(u, lp, cfg)
+            with _scoped(kind):
+                return _sequence_attention(
+                    q, plane["k"], plane["v"], 0, cfg), {}
+        q, k, v = _qkv(u, lp, cfg)
+        with _scoped(kind):
+            if kind == "full":
+                plane["k"], plane["v"] = k, v
+                return _sequence_attention(q, k, v, 0, cfg), {"k": k, "v": v}
+            o = _sequence_attention(q, k, v, cfg.attn_window, cfg)
+            return o, {"ring_k": _ring_of(k, cfg), "ring_v": _ring_of(v, cfg)}
+
+    cache = init_cache(cfg, b, 0)
+    x, new, memory = _run(
+        params, cache, embed_lookup(params, tokens, cfg.dtype), cfg, layers,
+        attend)
+    return x, _replaced(cache, new), memory
+
+
+def _ring_of(kv, cfg: DecoderHybridConfig):
+    """A window layer's ring after a prompt: kv [b, kv_pairs, s, 2 hd]
+    of positions 0 .. s-1, the last ``window`` of them each at its
+    position mod ``window`` (a shorter prompt: as it lies)."""
+    s, window = kv.shape[2], cfg.attn_window
+    if s <= window:
+        return kv
+    return jnp.roll(kv[:, :, s - window:], (s - window) % window, axis=2)
+
+
+def forward(params: Params, tokens: jax.Array, cfg: DecoderHybridConfig):
+    """tokens [b, s] -> logits [b, s, vocab] float32: every layer at
+    every position (nothing trimmed)."""
+    x, _cache, _memory = _self_decoder(
+        params, tokens, cfg, range(cfg.n_layers))
+    return _logits(params, x, cfg)
+
+
+def prefill(params: Params, tokens: jax.Array, cfg: DecoderHybridConfig,
+            max_len: int) -> Tuple[jax.Array, Cache]:
+    """Process the prompt; returns (logits of the last position, the
+    cache). The self-decoder runs over the whole prompt and fills
+    every cache there is; the cross-decoder, the final norm and the
+    head run for the LAST position only, over the full layer's keys
+    and values of every position."""
+    b, s = tokens.shape
+    first_cross = cfg.memory_layer + 2
+    x, cache, memory = _self_decoder(params, tokens, cfg, range(first_cross))
+    everything = jnp.ones((1, 1, s), jnp.bool_)
+
+    def attend(kind, _j, u, lp):
+        q = _cross_queries(u, lp, cfg)
+        with _scoped(kind):
+            return _pair_attention(
+                q, cache["k"][0], cache["v"][0], everything, cfg), {}
+
+    x, _new, _memory = _run(
+        params, cache, x[:, -1:], cfg, range(first_cross, cfg.n_layers),
+        attend, memory[:, -1:])
+    # the row as the pool holds it: the plane in ``max_len`` positions,
+    # a ring in ``attn_window`` (a shorter prompt leaves the rest zero)
+    for kind, length in (("full", max_len),
+                         ("window", min(cfg.attn_window, max_len))):
+        with _scoped(kind):
+            for name in LEAVES[kind]:
+                cache[name] = [
+                    jnp.pad(leaf, ((0, 0), (0, 0),
+                                   (0, length - leaf.shape[2]), (0, 0)))
+                    for leaf in cache[name]]
+    cache["pos"] = jnp.asarray(s, jnp.int32)
+    cache["admitted"] = jnp.asarray([b * s, b], jnp.int32)
+    return _logits(params, x, cfg)[:, 0, :], cache
+
+
+def _write(leaf, new, at):
+    """A ring or the plane, [rows, kv_pairs, length, 2 hd], with each
+    row's ``new`` [rows, kv_pairs, 1, 2 hd] written at its own ``at``
+    (past the end: dropped). The scatter goes over the two LEADING
+    axes of the leaf seen as [rows x kv_pairs, length, 2 hd], the form
+    the v5e's compiler writes in place; indexed by row and position
+    with the pairs between them it transposed the whole leaf there and
+    back at every step (tests/test_tpu_compile.py)."""
+    rows, pairs, length, width = leaf.shape
+    flat = leaf.reshape(rows * pairs, length, width)
+    flat = flat.at[jnp.arange(rows * pairs), jnp.repeat(at, pairs)].set(
+        new.reshape(rows * pairs, width), mode="drop")
+    return flat.reshape(leaf.shape)
+
+
+def decode_chunk(params: Params, cache: Cache, tokens: jax.Array,
+                 cfg: DecoderHybridConfig) -> Tuple[jax.Array, Cache]:
+    """ONE token per row against the cache (the slot engine's step;
+    ``pos`` is one number or one per row): every Mamba layer's state
+    is read once and written once where it lies, every window layer's
+    keys and values are written into its ring at ``pos mod window``,
+    the full layer's into the plane at ``pos``, and the plane is read
+    where it lies by the full layer and by every cross layer. More
+    than one token a row is refused: a ring is written before it is
+    read, so a chunk's earlier queries would miss what its later
+    tokens overwrote."""
+    b, m = tokens.shape
+    if m != 1:
+        raise ValueError(
+            f"decode_chunk takes one token a row, not {m}: a ring is "
+            "written before it is read")
+    pos = cache["pos"]
+    at = jnp.broadcast_to(pos, (b,))
+    window = cache["ring_k"][0].shape[2]
+    in_ring = (jnp.arange(window)[None, :] <= at[:, None])[:, None, :]
+    in_plane = (jnp.arange(cache["k"][0].shape[2])[None, :]
+                <= at[:, None])[:, None, :]
+    plane: Dict[str, jax.Array] = {}
+
+    def attend(kind, j, u, lp):
+        if kind == "cross":
+            q = _cross_queries(u, lp, cfg)
+            with _scoped(kind):
+                return _pair_attention(
+                    q, plane["k"], plane["v"], in_plane, cfg), {}
+        q, k, v = _qkv(u, lp, cfg)
+        with _scoped(kind):
+            if kind == "full":
+                # a dead slot decodes on past the end: dropped there
+                plane["k"] = _write(cache["k"][0], k, at)
+                plane["v"] = _write(cache["v"][0], v, at)
+                o = _pair_attention(q, plane["k"], plane["v"], in_plane, cfg)
+                return o, dict(plane)
+            keys = _write(cache["ring_k"][j], k, at % window)
+            values = _write(cache["ring_v"][j], v, at % window)
+            o = _pair_attention(q, keys, values, in_ring, cfg)
+            return o, {"ring_k": keys, "ring_v": values}
+
+    x, new, _memory = _run(
+        params, cache, embed_lookup(params, tokens, cfg.dtype), cfg,
+        range(cfg.n_layers), attend)
+    out = _replaced(cache, new)
+    out["pos"] = pos + 1
+    if "stats" in cache:
+        stepped = jnp.stack([
+            jnp.int32(b * cfg.count("mamba")),
+            jnp.int32(b * cfg.count("window")),
+            jnp.sum(at >= window, dtype=jnp.int32),
+            jnp.int32(b * cfg.plane_readers)])
+        out["stats"] = cache["stats"] + jnp.concatenate(
+            [stepped, cache["admitted"]])
+        out["admitted"] = jnp.zeros_like(cache["admitted"])
+    return _logits(params, x, cfg), out
+
+
+# -- what the server publishes ---------------------------------------------
+
+
+def _row_bytes(cfg: DecoderHybridConfig) -> Dict[str, int]:
+    item = jnp.dtype(cfg.dtype).itemsize
+    state = cfg.ssm_state * cfg.d_inner * 4
+    tail = (cfg.ssm_conv - 1) * cfg.d_inner * item
+    position = 2 * cfg.n_kv_heads * cfg.head_dim * item
+    return {
+        "state_bytes_per_slot": cfg.count("mamba") * (state + tail),
+        "ring_bytes_per_slot": (
+            cfg.count("window") * cfg.attn_window * position),
+        "plane_bytes_per_position": position,
+    }
+
+
+def _layer_counts(cfg: DecoderHybridConfig) -> Dict[str, int]:
+    return {kind: cfg.count(kind) for kind in KINDS}
+
+
+def describe_state(cfg: DecoderHybridConfig, total) -> Dict[str, Any]:
+    """``/v1/model`` ``state``, models/hybrid_ssm.py's schema: what a
+    row keeps that is not keys and values, and how often the decode
+    rounds fetched so far stepped it."""
+    sizes = _row_bytes(cfg)
+    return {
+        "layer_kinds": _layer_counts(cfg),
+        "state_bytes_per_slot": sizes["state_bytes_per_slot"],
+        "kv_bytes_per_position": sizes["plane_bytes_per_position"],
+        "ssm_row_steps": 0 if total is None else int(total[0]),
+    }
+
+
+def describe_hybrid_decoder(cfg: DecoderHybridConfig, total) -> Dict[str, Any]:
+    """``/v1/model`` ``hybrid_decoder``: the four cache shapes of a
+    row and what the decode rounds fetched so far counted
+    (docs/90-observability.md)."""
+    counted = [0] * len(STATS) if total is None else [int(n) for n in total]
+    return {
+        "layer_kinds": _layer_counts(cfg),
+        "window": cfg.attn_window,
+        "memory_layer": cfg.memory_layer,
+        "plane_readers": cfg.plane_readers,
+        **_row_bytes(cfg),
+        **dict(zip(STATS, counted)),
+    }
+
+
+def refuse_request(knobs: Dict[str, Any]) -> None:
+    """What this family does not take, under the server's names for a
+    request's knobs: beam search reorders a cache's rows along an axis
+    this family's cache does not have. Raises ValueError (the server's
+    422)."""
+    if knobs.get("beam_width"):
+        raise ValueError(
+            "beam_width is refused: a cache of recurrent state, rings "
+            "and a shared plane is not reordered by beams")

@@ -51,8 +51,9 @@ counts something per round (the routed experts of models/mla_moe.py)
 returns the counts with the tokens, and ``tokens`` adds them up on the
 host (``expert_stats``; ``state_stats`` for the stepped rows of
 models/hybrid_ssm.py's recurrent state; ``loop_stats`` for the passes
-models/looped.py ran over its rows): no dispatch and no sync of their
-own.
+models/looped.py ran over its rows; ``hybrid_decoder_stats`` for the
+four cache shapes of models/decoder_hybrid.py): no dispatch and no
+sync of their own.
 
 Implementations: :class:`PlainStepProgram` (models/slots.py's chunk +
 fused-window programs), ``models.quantized.QuantizedStepProgram``
@@ -328,6 +329,13 @@ class PlainStepProgram:
         far ran (``/v1/model`` ``loop``); None for a family whose
         layers run once."""
         return self._described("describe_loop")
+
+    def hybrid_decoder_stats(self):
+        """The four cache shapes of a decoder-hybrid-decoder's row and
+        what the decode rounds fetched so far stepped, wrapped and
+        read of them (``/v1/model`` ``hybrid_decoder``); None for any
+        other family."""
+        return self._described("describe_hybrid_decoder")
 
 
 def make_step_program(

@@ -229,9 +229,10 @@ def load_model_file(path: str, max_len: int) -> Any:
     is generation by diffusion over blocks (models/block_diffusion.py),
     ``layer_types`` beside ``mamba_n_heads`` is state-space layers among
     attention layers (models/hybrid_ssm.py), ``total_ut_steps`` is a
-    stack of layers run several times a token (models/looped.py). A
-    file of another architecture is refused with its name; nothing is
-    guessed."""
+    stack of layers run several times a token (models/looped.py),
+    ``model_type`` ``phi4flash`` is a decoder-hybrid-decoder
+    (models/decoder_hybrid.py). A file of another architecture is
+    refused with its name; nothing is guessed."""
     import hashlib
     import json as json_mod
 
@@ -249,14 +250,17 @@ def load_model_file(path: str, max_len: int) -> Any:
         from ..models.hybrid_ssm import from_published
     elif "total_ut_steps" in config:
         from ..models.looped import from_published
+    elif config.get("model_type") == "phi4flash":
+        from ..models.decoder_hybrid import from_published
     else:
         raise SystemExit(
             f"--model-config {path}: model_type "
             f"{config.get('model_type')!r} has no builder here (latent "
             "attention with routed experts, block diffusion over routed "
-            "experts, state-space layers among attention layers and "
-            "layers run several times a token are the families read "
-            "from a file; the flagship block still takes its flags)"
+            "experts, state-space layers among attention layers, "
+            "layers run several times a token and a "
+            "decoder-hybrid-decoder are the families read from a file; "
+            "the flagship block still takes its flags)"
         )
 
     digest = hashlib.blake2b(raw, digest_size=8).hexdigest()

@@ -254,6 +254,13 @@ class InferenceServer:
                         "prefix, and no row of it is stored, spilled "
                         "or handed off yet"
                     )
+        if prefill_chunk > 0 and getattr(cfg, "one_token_steps", False):
+            raise ValueError(
+                "--prefill-chunk does not compose with this model: its "
+                "decode step takes one token a row (a window layer's "
+                "ring is written before it is read), so a prompt is "
+                "not extended in pieces yet"
+            )
         if kv_spill_bytes > 0 and prefix_cache_entries <= 0:
             raise ValueError(
                 "--kv-spill requires --prefix-cache (the spill tier "
@@ -1075,6 +1082,11 @@ class InferenceServer:
                 # and the passes run so far (models/looped.py); else
                 # None
                 "loop": self.slot_engine.loop_stats(),
+                # a decoder-hybrid-decoder: state, window rings, one
+                # plane of keys and values that several layers read,
+                # and the counts over them
+                # (models/decoder_hybrid.py); else None
+                "hybrid_decoder": self.slot_engine.hybrid_decoder_stats(),
                 # SSE streaming rides the slot engine's chunks
                 "stream": True,
                 "draining": self.draining,

@@ -468,6 +468,12 @@ class SlotEngine:
         for a model whose layers run once."""
         return self._program_stats("loop_stats")
 
+    def hybrid_decoder_stats(self) -> Optional[dict]:
+        """A decoder-hybrid-decoder's cache shapes and the decode
+        rounds' counts over them (``/v1/model`` ``hybrid_decoder``);
+        None for any other model."""
+        return self._program_stats("hybrid_decoder_stats")
+
     # ----------------------------------------------------------- worker
 
     def _prefill(self, req: _Request):

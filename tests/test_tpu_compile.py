@@ -1129,3 +1129,162 @@ def test_looped_insert_and_prefill_fit_beside_the_pool(chip):
     assert 4.9 * GIB < weights < 5.0 * GIB
     assert (weights + 7.5 * GIB + row_bytes + memory.temp_size_in_bytes
             ) < LOOPED_ROOM - GIB
+
+
+# -- the decoder-hybrid-decoder family at its published size -----------------
+
+
+def _decoder_hybrid_shapes(chip, slots, length):
+    from containerpilot_tpu.models.slots import init_slot_state, slot_cache
+    from containerpilot_tpu.workload.modelcfg import load_model_file
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = load_model_file(
+        os.path.join(root, "benchmark", "configs",
+                     "phi-4-mini-flash-serve.json"), length)
+    shapes = jax.eval_shape(
+        lambda: (
+            cfg.family.init_params(None, cfg),
+            slot_cache(cfg, slots, length),
+            init_slot_state(cfg, slots),
+        )
+    )
+    return cfg, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        shapes,
+    )
+
+
+#: a row of the cell's pool: nine states and tails, eight rings of 512
+#: positions, one plane of 3,072 (ISSUE 45's arithmetic)
+DECODER_HYBRID_ROW = 3_225_600 + 20_971_520 + 15_728_640
+
+
+@pytest.mark.parametrize("program", ["chunk", "window"])
+def test_decoder_hybrid_step_writes_and_reads_its_caches_where_they_lie(
+        chip, program):
+    """The slot engine's decode programs of the benchmark's
+    Phi-4-mini-flash configuration at its PUBLISHED size
+    (benchmark/configs/phi-4-mini-flash-serve.json: 32 layers, nothing
+    reduced; 64 slots x 3,072 positions), compiled for the v5e: weights
+    (7.705 GB) and pool (64 rows of 39.9 MB) are the arguments, the
+    WHOLE pool is aliased to its output, temporaries stay under half a
+    gibibyte and everything under 0.8 of the chip; every matrix is
+    bfloat16 and every recurrent state float32; each of the eight rings
+    and the ONE plane is written by a scatter over the leaf seen as
+    [rows x pairs, length, 128] (in place), and outside fused
+    computations nothing the size of a ring is copied, transposed or
+    sliced out: the seven cross layers read layer 17's plane where it
+    lies, no copy a layer, none a step."""
+    from containerpilot_tpu.models.slots import _jitted_chunk, _jitted_window
+
+    slots, length = 64, 3072
+    cfg, (params, pool, state) = _decoder_hybrid_shapes(chip, slots, length)
+    assert params["embed"].dtype == jnp.bfloat16
+    assert all(x.dtype == jnp.bfloat16
+               for layer in params["layers"] for name, x in layer.items()
+               if name.startswith(("w_", "g_", "b_", "conv_")))
+    assert all(x.dtype == jnp.float32 and x.shape == (slots, 16, 5120)
+               for x in pool["ssm"])
+    assert len(pool["k"]) == 1 and len(pool["ring_k"]) == 8
+    if program == "chunk":
+        lowered = _jitted_chunk(cfg, slots, 8).lower(params, pool, state)
+    else:
+        budget = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+        lowered = _jitted_window(cfg, slots, 8, 4).lower(
+            params, pool, state, budget)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    pool_bytes = slots * DECODER_HYBRID_ROW
+    assert 10.2e9 < memory.argument_size_in_bytes < 10.4e9
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 0.5 * GIB
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < 0.8 * HBM_BYTES
+    text = compiled.as_text()
+    _outside, bodies = _outside_fusions(text)
+    pairs, width = cfg.kv_pairs, cfg.pair_dim
+    for rows, count in ((512, 2 * 8), (length, 2)):
+        leaf = f"bf16[{slots * pairs},{rows},{width}]"
+        scatters = sum(
+            1 for lines in bodies.values() for line in lines
+            if " scatter(" in line and f"= {leaf}" in line)
+        assert scatters == count, leaf
+    # (the compiler's own prefetches of a ring into its fast memory,
+    # ``slice-start`` / ``copy-start`` to memory space 1, are not copies)
+    ring = slots * pairs * 512 * width
+    for _computation, line in _outside:
+        found = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* "
+            r"(copy|dynamic-slice|transpose)\(", line)
+        if found:
+            moved = math.prod(int(n) for n in found.group(2).split(","))
+            assert moved < ring, (
+                f"{found.group(1)}: {found.group(3)} of bf16"
+                f"[{found.group(2)}] outside a fusion")
+    # the scopes a device trace splits a step by, in the operations' paths
+    for scope in ("layers/attn/attn.window", "layers/attn/attn.full",
+                  "layers/attn/attn.cross", "layers/attn/attn.diff",
+                  "layers/ssm/ssm.update", "layers/gmu", "layers/mlp",
+                  "sample"):
+        assert scope in text, scope
+
+
+def test_decoder_hybrid_check_sees_a_leaf_transposed_for_its_write(chip):
+    """The same check on the write this family had first (a scatter
+    indexed by row and position ACROSS the pairs' axis, for which the
+    compiler transposed the whole leaf there and back at every step:
+    4.7 GB of copies a step, PERF.md PR 45) finds the copy, and does not
+    find one for ``_write``: the test above cannot pass by looking past
+    it."""
+    from containerpilot_tpu.models import decoder_hybrid as dh
+
+    slots, pairs, rows, width = 64, 10, 512, 128
+    leaf = jax.ShapeDtypeStruct((slots, pairs, rows, width), jnp.bfloat16,
+                                sharding=chip)
+    new = jax.ShapeDtypeStruct((slots, pairs, 1, width), jnp.bfloat16,
+                               sharding=chip)
+    at = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+
+    def across(leaf, new, at):
+        return leaf.at[jnp.arange(slots), :, at].set(new[:, :, 0])
+
+    def moved(compiled):
+        """Ring-sized results of a copy or a transpose outside fused
+        computations."""
+        return [
+            line for _c, line in _outside_fusions(compiled.as_text())[0]
+            if re.search(r" (copy|transpose)\(", line)
+            and f"bf16[{slots},{pairs},{rows},{width}]" in line.split("=")[1]]
+
+    ring_bytes = slots * pairs * rows * width * 2
+    old = jax.jit(across, donate_argnums=(0,)).lower(leaf, new, at).compile()
+    assert moved(old)
+    assert old.memory_analysis().temp_size_in_bytes >= ring_bytes
+    now = jax.jit(dh._write, donate_argnums=(0,)).lower(leaf, new, at).compile()
+    assert moved(now) == []
+    assert now.memory_analysis().temp_size_in_bytes < 1024 ** 2
+
+
+@pytest.mark.parametrize("prompt", [512, 1536])
+def test_decoder_hybrid_prefill_fits_beside_the_pool(chip, prompt):
+    """The cell's two prefill programs at the published size: the
+    weights, a row of 39.9 MB and under a gibibyte of temporaries (the
+    scan runs in blocks of 16 positions and holds no [prompt, 5120, 16]
+    array: 0.5 GB a layer at 1,536), so that weights + pool + a row in
+    flight + the program's temporaries stay under 0.8 of the chip."""
+    from containerpilot_tpu.models.decode import prefill
+
+    slots, length = 64, 3072
+    cfg, (params, _pool, _state) = _decoder_hybrid_shapes(chip, slots, length)
+    tokens = jax.ShapeDtypeStruct((1, prompt), jnp.int32, sharding=chip)
+    memory = jax.jit(lambda p, t: prefill(p, t, cfg, length)).lower(
+        params, tokens).compile().memory_analysis()
+    assert (DECODER_HYBRID_ROW <= memory.output_size_in_bytes
+            < DECODER_HYBRID_ROW + 2 * 1024 ** 2)
+    assert memory.temp_size_in_bytes < 1.0 * GIB
+    weights = memory.argument_size_in_bytes
+    assert 7.70e9 < weights < 7.72e9
+    assert (weights + slots * DECODER_HYBRID_ROW + DECODER_HYBRID_ROW
+            + memory.temp_size_in_bytes) < 0.8 * HBM_BYTES
