@@ -527,6 +527,15 @@ def _round_step_body(params, state, cfg, read_len=None):
 
 # (both caches hold a whole ladder for every configuration alive in
 # the process: a rung that fell out would compile again under traffic)
+def _compiler_options(cfg: TransformerConfig):
+    """What a family asks of the compiler of its decode programs
+    (``cfg.family.decode_compiler_options``); None for the flagship
+    block and for a family that asks nothing."""
+    ask = getattr(getattr(cfg, "family", None), "decode_compiler_options",
+                  None)
+    return ask() if ask else None
+
+
 @functools.lru_cache(maxsize=64)
 def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
                   out_sharding=None, read_len=None):
@@ -563,7 +572,8 @@ def _jitted_chunk(cfg: TransformerConfig, slots: int, chunk: int,
         return pool, new_state, toks.T, pool.get("stats")  # [S, chunk]
 
     return jax.jit(
-        run, donate_argnums=(1, 2), out_shardings=out_sharding
+        run, donate_argnums=(1, 2), out_shardings=out_sharding,
+        compiler_options=_compiler_options(cfg),
     )
 
 
@@ -626,7 +636,8 @@ def _jitted_window(cfg: TransformerConfig, slots: int, chunk: int,
         return pool, new_state, out, r, pool.get("stats")
 
     return jax.jit(
-        run, donate_argnums=(1, 2), out_shardings=out_sharding
+        run, donate_argnums=(1, 2), out_shardings=out_sharding,
+        compiler_options=_compiler_options(cfg),
     )
 
 

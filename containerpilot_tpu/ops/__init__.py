@@ -1,6 +1,8 @@
-"""Op library for the TPU workload: attention four ways — XLA einsum,
+"""Op library for the TPU workload: attention five ways — XLA einsum,
 pallas flash (fwd+bwd, differentiable), memory-efficient XLA training
-fallback (custom VJP), and ring/context-parallel."""
+fallback (custom VJP), ring/context-parallel, and a pallas decode read
+over rows of ragged length (one query a row, each row's keys only as
+far as it is live)."""
 from .attention import causal_attention
 from .flash import flash_attention, flash_attention_forward
 from .flash_training import memory_efficient_attention
@@ -10,6 +12,7 @@ from .quant import (
     int8_matmul_pallas,
     quantize_int8,
 )
+from .ragged_decode import ragged_decode_attention
 from .ring_attention import ring_attention
 
 __all__ = [
@@ -18,6 +21,7 @@ __all__ = [
     "flash_attention_forward",
     "memory_efficient_attention",
     "ring_attention",
+    "ragged_decode_attention",
     "quantize_int8",
     "int8_matmul",
     "int8_matmul_pallas",

@@ -41,6 +41,7 @@ from containerpilot_tpu.models import decoder_hybrid as dh
 from containerpilot_tpu.models import slots as slots_mod
 from containerpilot_tpu.models.decode import _jitted_prefill, generate
 from containerpilot_tpu.models.stepprog import PlainStepProgram, make_step_program
+from containerpilot_tpu.ops import ragged_decode
 from containerpilot_tpu.workload import modelcfg
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,6 +55,9 @@ WINDOW = 8
 #: every sequence the programs see whole has this length (it wraps a
 #: ring of 8 three times), so each is compiled once
 SEQ = 29
+#: positions of a key block of the plane's reads here: a plane of 64
+#: positions is eight blocks, so rows end in different ones
+BLOCK = 8
 
 
 def _reference():
@@ -98,6 +102,13 @@ class Programs:
         row = np.zeros((max(SEQ, len(toks)),), np.int32)
         row[: len(toks)] = toks
         return np.asarray(self._reference(row))[: len(toks)]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def small_blocks():
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ragged_decode, "BLOCK_LEN", BLOCK)
+        yield
 
 
 @pytest.fixture(scope="module")
@@ -347,6 +358,15 @@ def _admit(prog, pool, state, slot, prompt):
     return pool, state, first
 
 
+def _covered(prompts, first, steps):
+    """Positions the blocks of one reader cover over ``steps`` steps
+    from the ``first``: the prompts' rows and the pool's empty slots
+    (which stand at the step's number)."""
+    starts = [len(p) for p in prompts] + [0] * (SLOTS - len(prompts))
+    return sum(((start + step) // BLOCK + 1) * BLOCK
+               for start in starts for step in range(first, first + steps))
+
+
 def _served_is_the_references_best(prog, prompt, served):
     """The served tokens' logit gaps under the reference, as the
     benchmark's ``correct`` judges them: none."""
@@ -367,8 +387,9 @@ def test_pool_programs_match_the_reference(prog, program):
     emitted token is the reference's best at its position. The counters
     of the last program call: every row of the pool steps 4 Mamba
     layers and 3 rings and reads the plane 3 times; a row counts as
-    wrapped once it stands at the window or past it; what the two
-    prefills ran rides with the first step."""
+    wrapped once it stands at the window or past it; the plane's reads
+    cover every row to the end of the block of 8 that holds its
+    position; what the two prefills ran rides with the first step."""
     cfg, rounds = prog.cfg, 2
     pool = slots_mod.slot_cache(cfg, SLOTS, MAX_LEN)
     state = slots_mod.init_slot_state(cfg, SLOTS)
@@ -390,8 +411,11 @@ def test_pool_programs_match_the_reference(prog, program):
         # row 0 stands at 5, 6, 7, 8 and then 9 .. 12; row 1 past the
         # window throughout; the empty slot at 0 .. 7
         assert [int(c[2]) for c in counted] == [1 + 4, 4 + 4]
-        assert [int(n) for n in counted[0][4:]] == [18, 2]
-        assert [int(n) for n in counted[1][4:]] == [0, 0]
+        assert [int(n) for n in counted[0][5:]] == [18, 2]
+        assert [int(n) for n in counted[1][5:]] == [0, 0]
+        assert [int(c[4]) for c in counted] == [
+            3 * _covered(prompts, first, CHUNK)
+            for first in (0, CHUNK)]
         stats = counted[-1]
     else:
         pool, state, toks, run, stats = slots_mod.decode_slots_window(
@@ -400,7 +424,8 @@ def test_pool_programs_match_the_reference(prog, program):
         assert int(run) == rounds
         toks, stats = np.asarray(toks), np.asarray(stats)
         steps = CHUNK * rounds
-        assert int(stats[2]) == 5 + 8 and [int(n) for n in stats[4:]] == [18, 2]
+        assert int(stats[2]) == 5 + 8 and [int(n) for n in stats[5:]] == [18, 2]
+        assert int(stats[4]) == 3 * _covered(prompts, 0, steps)
     assert int(stats[0]) == steps * SLOTS * 4
     assert int(stats[1]) == steps * SLOTS * 3
     assert int(stats[3]) == steps * SLOTS * 3
@@ -410,6 +435,79 @@ def test_pool_programs_match_the_reference(prog, program):
         assert _served_is_the_references_best(prog, prompt, served), slot
     assert list(np.asarray(pool["pos"])[:2]) == [
         len(p) + CHUNK * rounds for p in prompts]
+
+
+def _plain_plane_attention(q, keys, values, at, cfg):
+    """What ``_plane_attention`` stood in for: the plain contraction
+    over every position of every row, masked by position."""
+    valid = (jnp.arange(keys.shape[2])[None, :] <= at[:, None])[:, None, :]
+    return dh._pair_attention(q, keys, values, valid, cfg)
+
+
+@pytest.mark.parametrize("program", ["step", "chunk", "window"])
+def test_decode_reads_the_plane_as_the_plain_contraction_does(
+        prog, program, monkeypatch):
+    """One decode step, the chunk program and the fused window over a
+    pool whose rows end in different key blocks (prompts of 5 and 21, an
+    empty slot), through the kernel and through the plain read of the
+    whole plane (the same programs traced again with ``_pair_attention``
+    in the kernel's place): the same tokens, logits within the decode
+    tests' tolerance, and every leaf of the cache the plain read's to
+    float32 rounding."""
+    plain_cfg = dataclasses.replace(prog.cfg, source_digest="plain read")
+
+    def run(cfg):
+        pool = slots_mod.slot_cache(cfg, SLOTS, MAX_LEN)
+        state = slots_mod.init_slot_state(cfg, SLOTS)
+        for slot, n in enumerate((5, 21)):
+            pool, state, _first = _admit(prog, pool, state, slot, ids(n, seed=n))
+        if program == "step":
+            tokens = jnp.asarray(ids(SLOTS, seed=3))[:, None]
+            logits, cache = jax.jit(
+                lambda p, c, t: dh.decode_chunk(p, c, t, cfg))(
+                    prog.params, pool, tokens)
+            return np.asarray(logits), cache
+        if program == "chunk":
+            pool, _state, toks = slots_mod.decode_slots_chunk(
+                prog.params, pool, state, cfg, CHUNK)
+        else:
+            pool, _state, toks, _run = slots_mod.decode_slots_window(
+                prog.params, pool, state, cfg, CHUNK, 2,
+                np.full((SLOTS,), 100))
+        return np.asarray(toks), pool
+
+    mine, cache = run(prog.cfg)
+    with monkeypatch.context() as patch:
+        patch.setattr(dh, "_plane_attention", _plain_plane_attention)
+        plain, plain_cache = run(plain_cfg)
+    if program == "step":
+        assert close(mine, plain)
+    else:
+        assert np.array_equal(mine, plain)
+    assert np.array_equal(np.asarray(cache["pos"]), np.asarray(plain_cache["pos"]))
+    for name in dh.ALL_LEAVES:
+        for got, want in zip(cache[name], plain_cache[name]):
+            assert close(got, want), name
+
+
+def test_the_plane_positions_read_are_the_blocks_cover_times_the_readers(prog):
+    """A pool of three rows at known positions (3, the last of a block;
+    8, the first of the next; 200, a dead slot past the plane's end)
+    steps once: ``plane_positions_read`` counts, for each of the three
+    layers that read the plane, every block up to the one that holds the
+    row's position (the dead slot's: the whole plane), and
+    ``describe_hybrid_decoder`` publishes it beside
+    ``shared_plane_reads``."""
+    cfg = prog.cfg
+    pool = slots_mod.slot_cache(cfg, SLOTS, MAX_LEN)
+    pool["pos"] = jnp.asarray([BLOCK - 1, BLOCK, 200], jnp.int32)
+    _logits, out = prog.step(prog.params, pool, jnp.ones((SLOTS, 1), jnp.int32))
+    described = dh.describe_hybrid_decoder(cfg, np.asarray(out["stats"]))
+    assert described["shared_plane_reads"] == SLOTS * 3
+    assert described["plane_positions_read"] == 3 * (
+        BLOCK + 2 * BLOCK + MAX_LEN)
+    assert list(described).index("plane_positions_read") == list(
+        described).index("shared_plane_reads") + 1
 
 
 def test_a_row_inserted_over_a_retired_one_keeps_nothing_of_it(prog):
@@ -497,6 +595,8 @@ def test_step_program_returns_the_counters_with_the_tokens():
         "ssm_row_steps": 4 * 2 * 4, "ring_row_steps": 4 * 2 * 3,
         # both rows are empty slots at positions 0 .. 3: none wrapped
         "ring_rows_wrapped": 0, "shared_plane_reads": 4 * 2 * 3,
+        # ... and inside the plane's first block of 8
+        "plane_positions_read": 4 * 2 * 3 * BLOCK,
         "prefill_positions_self": 0, "prefill_positions_cross": 0,
     }
     assert program.state_stats()["ssm_row_steps"] == 4 * 2 * 4
@@ -507,8 +607,10 @@ def test_the_published_pattern_reads_the_plane_eight_times_a_row_step():
         cfg = dh.from_published(json.load(fh), 3072)
     assert cfg.plane_readers == 8 and cfg.count("mamba") == 9
     described = dh.describe_hybrid_decoder(cfg, np.asarray(
-        [64 * 9, 64 * 8, 64, 64 * cfg.plane_readers, 1536, 1]))
+        [64 * 9, 64 * 8, 64, 64 * cfg.plane_readers, 64 * 8 * 1536, 1536, 1]))
     assert described["shared_plane_reads"] == 8 * 64
+    assert described["plane_positions_read"] == 8 * 64 * 1536
+    assert described["prefill_positions_self"] == 1536
     assert described["state_bytes_per_slot"] == 3_225_600
     assert described["ring_bytes_per_slot"] == 20_971_520
     assert described["plane_bytes_per_position"] == 5_120

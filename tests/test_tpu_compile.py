@@ -239,6 +239,19 @@ def _outside_fusions(text):
     return outside, bodies
 
 
+def _result_types(bodies):
+    """The type of every named instruction of an optimised program
+    (``_outside_fusions``' bodies), without its layout."""
+    types = {}
+    for lines in bodies.values():
+        for line in lines:
+            made = re.match(
+                r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])", line)
+            if made:
+                types[made.group(1)] = made.group(2)
+    return types
+
+
 def _pool_sized_outside_fusions(text, elements, min_rank=0):
     """The instructions outside fused computations that produce a
     tensor of at least ``elements`` elements (in ``min_rank`` or more
@@ -253,13 +266,7 @@ def _pool_sized_outside_fusions(text, elements, min_rank=0):
     dynamic-update-slice whose result has its first operand's type,
     that operand being the loop's own carried buffer."""
     outside, bodies = _outside_fusions(text)
-    types = {}
-    for lines in bodies.values():
-        for line in lines:
-            made = re.match(
-                r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])", line)
-            if made:
-                types[made.group(1)] = made.group(2)
+    types = _result_types(bodies)
     found = []
     for _computation, line in outside:
         made = re.match(
@@ -512,13 +519,7 @@ def _attention_reads(text, cfg, slots):
     operand of slots x N x kv_heads x head_dim): how far a step READS
     each row of the pool."""
     outside, bodies = _outside_fusions(text)
-    types = {}
-    for lines in bodies.values():
-        for line in lines:
-            made = re.match(
-                r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])", line)
-            if made:
-                types[made.group(1)] = made.group(2)
+    types = _result_types(bodies)
     rows = re.compile(
         rf"\w+\[{slots},\d+,{cfg.kv_heads},{cfg.head_dim}\]$")
     reads = set()
@@ -1160,9 +1161,38 @@ def _decoder_hybrid_shapes(chip, slots, length):
 DECODER_HYBRID_ROW = 3_225_600 + 20_971_520 + 15_728_640
 
 
+def _plane_contractions(text, plane):
+    """The fusions of an optimised program, outside every fused
+    computation and named under ``attn.full`` or ``attn.cross``, that
+    take an operand of the type ``plane`` (the WHOLE leaf of keys or of
+    values): the plain contraction over every position of every row."""
+    outside, bodies = _outside_fusions(text)
+    types = _result_types(bodies)
+    found = []
+    for _computation, line in outside:
+        made = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = .*? fusion\((.*?)\), kind=", line)
+        if not made or not re.search(r"/attn\.(cross|full)/", line):
+            continue
+        if any(types.get(operand) == plane
+               for operand in re.findall(r"%([\w.\-]+)", made.group(2))):
+            found.append(made.group(1))
+    return found
+
+
+def _plane_kernels(text):
+    """The per-row kernel's calls in an optimised program, by the scope
+    they stand under."""
+    return [
+        re.search(r"/(attn\.\w+)/", line).group(1)
+        for line in text.splitlines()
+        if " custom-call(" in line and KERNEL in line
+        and "ragged_decode_attention" in line]
+
+
 @pytest.mark.parametrize("program", ["chunk", "window"])
 def test_decoder_hybrid_step_writes_and_reads_its_caches_where_they_lie(
-        chip, program):
+        chip, program, monkeypatch):
     """The slot engine's decode programs of the benchmark's
     Phi-4-mini-flash configuration at its PUBLISHED size
     (benchmark/configs/phi-4-mini-flash-serve.json: 32 layers, nothing
@@ -1175,9 +1205,21 @@ def test_decoder_hybrid_step_writes_and_reads_its_caches_where_they_lie(
     [rows x pairs, length, 128] (in place), and outside fused
     computations nothing the size of a ring is copied, transposed or
     sliced out: the seven cross layers read layer 17's plane where it
-    lies, no copy a layer, none a step."""
+    lies, no copy a layer, none a step. The plane's eight reads a step
+    are the per-row kernel (ops/ragged_decode.py, compiled by Mosaic:
+    the model's own interpret default is steered to the chip's), one
+    under ``attn.full`` and seven under ``attn.cross``, and no fusion
+    under those scopes takes the whole plane any more. The family's
+    option for the compiler (none of its asynchronous evictions) is
+    steered on as the kernel is: the CPU backend the tests run on does
+    not take it."""
+    from containerpilot_tpu.models import decoder_hybrid as dh
     from containerpilot_tpu.models.slots import _jitted_chunk, _jitted_window
+    from containerpilot_tpu.ops import flash
 
+    monkeypatch.setattr(flash, "_resolve_interpret", lambda i: False)
+    monkeypatch.setattr(dh, "decode_compiler_options",
+                        lambda: dh.DECODE_COMPILER_OPTIONS)
     slots, length = 64, 3072
     cfg, (params, pool, state) = _decoder_hybrid_shapes(chip, slots, length)
     assert params["embed"].dtype == jnp.bfloat16
@@ -1187,11 +1229,13 @@ def test_decoder_hybrid_step_writes_and_reads_its_caches_where_they_lie(
     assert all(x.dtype == jnp.float32 and x.shape == (slots, 16, 5120)
                for x in pool["ssm"])
     assert len(pool["k"]) == 1 and len(pool["ring_k"]) == 8
+    # (built anew: a cached program holds the options of its first build)
     if program == "chunk":
-        lowered = _jitted_chunk(cfg, slots, 8).lower(params, pool, state)
+        lowered = _jitted_chunk.__wrapped__(cfg, slots, 8).lower(
+            params, pool, state)
     else:
         budget = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
-        lowered = _jitted_window(cfg, slots, 8, 4).lower(
+        lowered = _jitted_window.__wrapped__(cfg, slots, 8, 4).lower(
             params, pool, state, budget)
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
@@ -1205,6 +1249,19 @@ def test_decoder_hybrid_step_writes_and_reads_its_caches_where_they_lie(
     text = compiled.as_text()
     _outside, bodies = _outside_fusions(text)
     pairs, width = cfg.kv_pairs, cfg.pair_dim
+    # every Mamba state is written where it lies by the ONE fusion that
+    # computes it, none copied back out of the compiler's fast memory
+    # behind later operations (``decode_compiler_options``)
+    state = rf"f32\[{slots},16,5120\]"
+    written = re.findall(
+        rf"= \(f32\[{slots},5120\]\S* {state}(\S*)\) fusion\(", text)
+    assert len(written) == 9 and not any("S(1)" in to for to in written)
+    assert not re.search(rf"= \({state}\S* {state}\S* \S+ copy-start\(", text)
+    kernels = _plane_kernels(text)
+    assert sorted(set(kernels)) == ["attn.cross", "attn.full"]
+    assert kernels.count("attn.cross") == 7 * kernels.count("attn.full")
+    assert _plane_contractions(
+        text, f"bf16[{slots},{pairs},{length},{width}]") == []
     for rows, count in ((512, 2 * 8), (length, 2)):
         leaf = f"bf16[{slots * pairs},{rows},{width}]"
         scatters = sum(
@@ -1265,6 +1322,46 @@ def test_decoder_hybrid_check_sees_a_leaf_transposed_for_its_write(chip):
     now = jax.jit(dh._write, donate_argnums=(0,)).lower(leaf, new, at).compile()
     assert moved(now) == []
     assert now.memory_analysis().temp_size_in_bytes < 1024 ** 2
+
+
+def test_decoder_hybrid_check_sees_a_whole_plane_contraction(chip, monkeypatch):
+    """The same reading of the read this family had first (the plain
+    contraction under ``attn.cross``: every row of the plane to its
+    3,072nd position, masked afterwards; 8.05 GB a step of which 3.2
+    were live, PERF.md PR 45) finds the fusions that take the whole
+    plane, and finds none, and one kernel call, for
+    ``_plane_attention``: the test above cannot pass by looking past
+    the read."""
+    from containerpilot_tpu.models import decoder_hybrid as dh
+    from containerpilot_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_resolve_interpret", lambda i: False)
+    slots, length = 64, 3072
+    cfg, _shapes = _decoder_hybrid_shapes(chip, slots, length)
+    pairs, width = cfg.kv_pairs, cfg.pair_dim
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+    q = shape((slots, 1, pairs, 2 * cfg.n_heads // cfg.n_kv_heads, width),
+              jnp.bfloat16)
+    plane = shape((slots, pairs, length, width), jnp.bfloat16)
+    at = shape((slots,), jnp.int32)
+
+    def old(q, keys, values, at):
+        in_plane = (jnp.arange(length)[None, :] <= at[:, None])[:, None, :]
+        with dh._scoped("cross"):
+            return dh._pair_attention(q, keys, values, in_plane, cfg)
+
+    def now(q, keys, values, at):
+        with dh._scoped("cross"):
+            return dh._plane_attention(q, keys, values, at, cfg)
+
+    whole = f"bf16[{slots},{pairs},{length},{width}]"
+    text = jax.jit(old).lower(q, plane, plane, at).compile().as_text()
+    assert _plane_contractions(text, whole) and _plane_kernels(text) == []
+    compiled = jax.jit(now).lower(q, plane, plane, at).compile()
+    text = compiled.as_text()
+    assert _plane_contractions(text, whole) == []
+    assert _plane_kernels(text) == ["attn.cross"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 1024 ** 2
 
 
 @pytest.mark.parametrize("prompt", [512, 1536])
