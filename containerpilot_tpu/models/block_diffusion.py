@@ -59,7 +59,8 @@ from jax import lax
 
 from . import moe
 from .decode import _grouped_attention
-from .mla_moe import STATS_HEAD, TOP, VOCAB_BLOCK, _count, _draw
+from .mla_moe import (
+    STATS_HEAD, TOP, VOCAB_BLOCK, _count, _draw, named_stats)
 from .quantized import embed_lookup
 from .slots import retire_slot
 from .stepprog import phase_span
@@ -457,7 +458,9 @@ def _cached_forward(params: Params, cache: Cache, tokens: jax.Array,
             new_v.append(values)
     new = {**cache, "k": new_k, "v": new_v}
     if "stats" in cache:
-        new["stats"] = _count(cache["stats"], b * m, counts)
+        new["stats"] = _count(
+            cache["stats"], b * m, counts,
+            moe.expert_block(b * m, cfg.experts_per_tok, cfg.n_experts))
     return _logits(params, x, cfg), new
 
 
@@ -805,13 +808,8 @@ def describe_stats(cfg: BlockDiffusionConfig, total) -> Dict[str, Any]:
     """A pool's summed ``stats`` under the names ``/v1/model``
     ``experts`` publishes (models/mla_moe.py's schema; every expert is
     held here)."""
-    head = len(STATS_HEAD)
-    values = [0] * (head + cfg.n_experts) if total is None else [
-        int(v) for v in total]
-    out: Dict[str, Any] = {
+    return {
         "published": cfg.n_experts, "held": [0, cfg.n_experts],
         "per_token": cfg.experts_per_tok,
+        **named_stats(total, cfg.n_experts),
     }
-    out.update(zip(STATS_HEAD, values[:head]))
-    out["load"] = values[head:]
-    return out

@@ -611,7 +611,8 @@ def _run(params: Params, cache: Cache, tokens: jax.Array,
     out = {**cache, **new}
     if "stats" in cache:
         out["stats"] = jnp.concatenate([
-            _count(cache["stats"][:-1], b * m, counts),
+            _count(cache["stats"][:-1], b * m, counts, moe.expert_block(
+                b * m, cfg.experts_per_tok, cfg.router_experts)),
             cache["stats"][-1:] + b * m * cfg.n_mamba])
     return x, out
 

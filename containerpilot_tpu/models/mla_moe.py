@@ -73,7 +73,7 @@ VOCAB_BLOCK = 128
 Q_BLOCK = 512
 #: counters ahead of the per-expert loads in a pool's ``stats`` leaf
 STATS_HEAD = ("rows", "assignments_here", "expert_steps_touched",
-              "expert_steps")
+              "expert_steps", "expert_tiles", "expert_tile_rows")
 
 
 @dataclass(frozen=True)
@@ -527,13 +527,16 @@ def insert_row(pool: Cache, row: Cache, slot: jax.Array) -> Cache:
     return new
 
 
-def _count(stats: jax.Array, rows: int, counts) -> jax.Array:
+def _count(stats: jax.Array, rows: int, counts, block: int) -> jax.Array:
     """Add one chunk's routing to a pool's counters; ``counts`` holds
-    each sparse layer's assignments per held expert ([held_n])."""
+    each sparse layer's assignments per held expert ([held_n]),
+    ``block`` the rows of the row tiles the experts' kernel ran them in
+    (``moe.expert_block`` of the chunk)."""
     counts = jnp.stack(counts)
+    tiles = moe.expert_tiles(counts, block)
     head = jnp.stack([
         jnp.int32(rows * counts.shape[0]), jnp.sum(counts),
-        jnp.sum(counts > 0), jnp.int32(counts.size),
+        jnp.sum(counts > 0), jnp.int32(counts.size), tiles, tiles * block,
     ]).astype(jnp.int32)
     return stats + jnp.concatenate([head, jnp.sum(counts, axis=0)])
 
@@ -632,21 +635,34 @@ def decode_chunk(params: Params, cache: Cache, tokens: jax.Array,
             new_kpe.append(kpe)
     new = {**cache, "ckv": new_ckv, "kpe": new_kpe, "pos": pos + m}
     if "stats" in cache:
-        new["stats"] = _count(cache["stats"], b * m, counts)
+        new["stats"] = _count(
+            cache["stats"], b * m, counts, moe.expert_block(
+                b * m, cfg.experts_per_tok, cfg.router_experts))
     return _logits(params, x, cfg), new
+
+
+def named_stats(total, held_n: int) -> Dict[str, Any]:
+    """A pool's summed ``stats`` by name: the counters of
+    ``STATS_HEAD``, ``tile_fill`` (the share of the experts' row tiles'
+    rows that held a token) and ``load`` (assignments per held
+    expert)."""
+    head = len(STATS_HEAD)
+    values = [0] * (head + held_n) if total is None else [
+        int(v) for v in total]
+    out: Dict[str, Any] = dict(zip(STATS_HEAD, values[:head]))
+    out["tile_fill"] = (
+        out["assignments_here"] / out["expert_tile_rows"]
+        if out["expert_tile_rows"] else None)
+    out["load"] = values[head:]
+    return out
 
 
 def describe_stats(cfg: MlaMoeConfig, total) -> Dict[str, Any]:
     """A pool's summed ``stats`` under the names ``/v1/model``
     ``experts`` publishes (docs/90-observability.md)."""
-    head = len(STATS_HEAD)
-    values = [0] * (head + cfg.held_n) if total is None else [
-        int(v) for v in total]
-    out: Dict[str, Any] = {
+    return {
         "published": cfg.router_experts,
         "held": [cfg.held_lo, cfg.held_lo + cfg.held_n],
         "per_token": cfg.experts_per_tok,
+        **named_stats(total, cfg.held_n),
     }
-    out.update(zip(STATS_HEAD, values[:head]))
-    out["load"] = values[head:]
-    return out

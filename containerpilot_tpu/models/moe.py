@@ -1,13 +1,15 @@
-"""Routed experts for the serving families of ``models/mla_moe.py``
-and ``models/block_diffusion.py``.
+"""Routed experts for the serving families of ``models/mla_moe.py``,
+``models/block_diffusion.py`` and ``models/hybrid_ssm.py``.
 
 ``route_topk``: sigmoid scores over ALL experts in float32, top k,
 renormalise, scale. ``route_softmax``: the same with a softmax over all
 experts for the scores and no scale. ``sparse_experts``: the part of
 the result that the experts HELD here give: assignments sorted by
-expert, cut into blocks of one expert each, one grouped SwiGLU per
-block that exists; no capacity, no token dropped, an expert nobody
-chose is never read.
+expert and laid out in row tiles of one expert each, ONE grouped
+SwiGLU kernel a call over the tiles that exist
+(ops/moe_grouped_matmul.py: the grid's steps are the tiles, the next
+tile's expert is fetched while this one multiplies); no capacity, no
+token dropped, an expert nobody chose is never read.
 """
 from __future__ import annotations
 
@@ -15,6 +17,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..ops import moe_grouped_matmul as grouped
 
 
 def route_topk(
@@ -66,14 +70,23 @@ def route_softmax(
 
 
 def expert_block(n_tokens: int, k: int, n_experts: int) -> int:
-    """Rows of one block of ``sparse_experts``: about twice what an
+    """Rows of one tile of ``sparse_experts``: about twice what an
     expert expects of ``n_tokens`` (so that most experts fill one
-    block), a power of two from 16 (a bf16 tile's rows) to 128."""
+    tile), a power of two from 16 (a bf16 tile's rows) to 128."""
     expected = 2.0 * n_tokens * k / n_experts
     block = 16
     while block < expected and block < 128:
         block *= 2
     return block
+
+
+def expert_tiles(counts: jax.Array, block: int) -> jax.Array:
+    """Row tiles of ``block`` rows that assignment ``counts`` (any
+    shape, one number a held expert) fill, an expert's last one ragged:
+    what ``sparse_experts``' kernel runs (a call cut into row chunks
+    runs up to a ragged tile more an expert and chunk). int32, one
+    number."""
+    return jnp.sum((counts + block - 1) // block, dtype=jnp.int32)
 
 
 def sparse_experts(
@@ -90,12 +103,19 @@ def sparse_experts(
     HELD here, ``held_lo <= e < held_lo + held``. Returns (the sum
     [n, d_model] float32, assignments per held expert [held] int32).
 
-    The (token, expert) assignments are sorted by expert, the ones
-    for experts held elsewhere last. Each held expert's run is cut
-    into blocks of ``expert_block`` rows; a loop over the blocks THAT
-    EXIST (a dynamic trip count) gathers a block's token rows, runs
-    them through that one expert's three matrices and adds the gated
-    result to its tokens' rows. Work and weight bytes follow the
+    The (token, expert) assignments are sorted by expert, the ones for
+    experts held elsewhere last, and each held expert's run is padded
+    to whole tiles of ``expert_block`` rows: a tile belongs to ONE
+    expert. ``mlp.dispatch`` (XLA) makes the tables: each tile's
+    expert and the rows of it that exist, the count of tiles that
+    exist, each row's token and gate. ``mlp.experts`` is ONE kernel
+    whose grid walks the tiles (``ops.moe_grouped_matmul
+    .grouped_swiglu``): a step copies its tile's token rows out of
+    ``h``, runs them through the tile's expert as stored and adds the
+    gated rows onto their tokens' float32 sums, while the pipeline
+    fetches the next tile's expert; steps past the last tile fetch and
+    compute nothing (the tile count is bounded statically by
+    ``tiles_bound``). Work and weight bytes follow the
     assignments: nothing is computed for an expert nobody chose, no
     token is dropped whatever the imbalance, and all shapes are
     static."""
@@ -103,8 +123,21 @@ def sparse_experts(
     k = idx.shape[1]
     held = w_gate.shape[0]
     total = n * k
+    most = grouped.rows_bound(d)
+    if n > most:
+        # a prompt whose float32 sums do not fit the fast memory: row
+        # chunks, each a call of its own (the weights are read again)
+        chunks = -(-n // most)
+        size = -(-n // chunks)
+        parts = [
+            sparse_experts(h[at:at + size], idx[at:at + size],
+                           gate[at:at + size], w_gate, w_up, w_down,
+                           held_lo, n_experts)
+            for at in range(0, n, size)]
+        return (jnp.concatenate([part for part, _ in parts]),
+                sum(counts for _, counts in parts))
     block = expert_block(n, k, n_experts)
-    dt = h.dtype
+    tiles_max = grouped.tiles_bound(n, k, held, block)
     with jax.named_scope("mlp.dispatch"):
         local = idx.reshape(total) - held_lo
         key = jnp.where((local >= 0) & (local < held), local, held)
@@ -113,35 +146,26 @@ def sparse_experts(
             key[:, None] == jnp.arange(held)[None, :], axis=0,
             dtype=jnp.int32)
         starts = jnp.cumsum(counts) - counts
-        blocks = (counts + block - 1) // block
-        block_end = jnp.cumsum(blocks)
-        gates = gate.reshape(total)
-
-    def body(j, out):
-        with jax.named_scope("mlp.dispatch"):
-            e = jnp.sum(j >= block_end).astype(jnp.int32)
-            first = starts[e] + (j - (block_end[e] - blocks[e])) * block
-            offs = first + jnp.arange(block)
-            live = offs < starts[e] + counts[e]
-            assignment = order[jnp.minimum(offs, total - 1)]
-            token = assignment // k
-            rows = h[token]
-        with jax.named_scope("mlp.experts"):
-            def pick(w):
-                return jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
-
-            up = jnp.einsum("bd,df->bf", rows, pick(w_up).astype(dt),
-                            preferred_element_type=jnp.float32)
-            act = jax.nn.silu(jnp.einsum(
-                "bd,df->bf", rows, pick(w_gate).astype(dt),
-                preferred_element_type=jnp.float32)) * up
-            y = jnp.einsum("bf,fd->bd", act.astype(dt),
-                           pick(w_down).astype(dt),
-                           preferred_element_type=jnp.float32)
-        with jax.named_scope("mlp.combine"):
-            weight = jnp.where(live, gates[assignment], 0.0)
-            return out.at[token].add(y * weight[:, None])
-
-    out = jax.lax.fori_loop(
-        0, block_end[-1], body, jnp.zeros((n, d), jnp.float32))
+        tiles = (counts + block - 1) // block
+        tile_end = jnp.cumsum(tiles)
+        # the padded order: tile j is the ``nth`` of its expert e
+        j = jnp.arange(tiles_max, dtype=jnp.int32)
+        e = jnp.minimum(jnp.sum(
+            j[:, None] >= tile_end[None, :], axis=1, dtype=jnp.int32),
+            held - 1)
+        nth = j - (tile_end[e] - tiles[e])
+        # (0 past the last tile: there e is the last expert, nth past it)
+        tile_live = jnp.clip(counts[e] - nth * block, 0, block)
+        rank = (starts[e] + nth * block)[:, None] + jnp.arange(
+            block, dtype=jnp.int32)[None, :]
+        # a tile's rows past ``tile_live`` name some assignment: unread
+        assignment = order[jnp.minimum(rank, total - 1)].reshape(-1)
+        token = assignment // k
+        weight = gate.reshape(total)[assignment][:, None]
+        # (a row of its own tile each: the kernel copies single rows)
+        rows = h.astype(jnp.float32)[:, None]
+    with jax.named_scope("mlp.experts"):
+        out = grouped.grouped_swiglu(
+            rows, token, weight, e, tile_live, tile_end[-1:],
+            w_gate, w_up, w_down, tile_rows=block, dtype=h.dtype)
     return out, counts

@@ -259,8 +259,12 @@ def test_pool_programs_match_the_reference(program):
     steps = CHUNK if program == "chunk" else CHUNK * rounds
     stats = np.asarray(stats)
     assert int(stats[0]) == steps * SLOTS * cfg.n_layers
-    assert int(stats[1]) == int(stats[4:-1].sum()) > 0
+    head = len(hs.STATS_HEAD)
+    assert int(stats[1]) == int(stats[head:-1].sum()) > 0
     assert int(stats[-1]) == steps * SLOTS * cfg.n_mamba
+    tile = moe.expert_block(SLOTS, cfg.experts_per_tok, cfg.router_experts)
+    assert int(stats[2]) <= int(stats[4])
+    assert int(stats[1]) <= int(stats[5]) == int(stats[4]) * tile
     for slot, prompt in enumerate(prompts):
         served = np.asarray([firsts[slot]] + [int(t) for t in toks[slot]])
         assert _served_is_the_references_best(prog, prompt, served), slot

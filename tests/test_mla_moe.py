@@ -166,8 +166,14 @@ def test_pool_programs_match_the_reference(model, program):
     # 4 rows through 2 sparse layers
     steps = chunk if program == "chunk" else chunk * rounds
     assert int(stats[0]) == steps * slots * cfg.n_sparse
-    assert int(stats[1]) == int(np.asarray(stats[4:]).sum()) > 0
+    head = len(mla_moe.STATS_HEAD)
+    assert int(stats[1]) == int(np.asarray(stats[head:]).sum()) > 0
     assert 0 < int(stats[2]) <= int(stats[3]) == steps * cfg.n_sparse * 16
+    # the experts' kernel ran whole row tiles: at least a tile a touched
+    # (expert, layer, step), every assignment in one
+    tile = moe.expert_block(slots, cfg.experts_per_tok, cfg.router_experts)
+    assert int(stats[2]) <= int(stats[4])
+    assert int(stats[1]) <= int(stats[5]) == int(stats[4]) * tile
     for slot, prompt in enumerate(prompts):
         served = [firsts[slot]] + [int(t) for t in toks[slot]]
         row = np.concatenate([prompt, served])[:-1]

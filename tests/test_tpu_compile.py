@@ -596,7 +596,64 @@ def test_read_length_check_sees_a_whole_leaf_read(chip):
         f"bf16[{slots},{cfg.max_seq_len},{cfg.kv_heads},{cfg.head_dim}]"}
 
 
-def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
+def _expert_kernels(text):
+    """(calls of the experts' grouped kernel under ``mlp.experts``, the
+    matrix products under that scope that are NOT the kernel) in an
+    optimised program: the loop that ran one block of one expert a turn
+    (a ``while`` whose body held the three ``dot``s) left products
+    there, the kernel leaves none."""
+    calls, products = [], []
+    for line in text.splitlines():
+        if "/mlp.experts/" not in line:
+            continue
+        if " custom-call(" in line and KERNEL in line:
+            assert "moe_grouped_matmul" in line, line[:200]
+            calls.append(line)
+        elif re.search(r" (dot|convolution|while)\(", line) or re.search(
+                r"/mlp\.experts/[^\"]*dot_general", line):
+            products.append(line[:200])
+    return calls, products
+
+
+@pytest.mark.parametrize("cell,rows,k,d,f,held,experts", [
+    ("sdar-30b-a3b-serve.block-decode", 256, 8, 2048, 768, 128, 128),
+    ("granite-4-h-small-serve.ssm-decode", 64, 10, 4096, 768, 36, 72),
+    ("ax-k1-serve.ep-decode", 64, 8, 7168, 2048, 12, 192),
+    # the same cells' 1,536-token prefills: tiles of 128 rows, and the
+    # tokens' float32 sums kept in fast memory beside the weights
+    ("sdar-30b-a3b-serve prefill", 1536, 8, 2048, 768, 128, 128),
+    ("granite-4-h-small-serve prefill", 1536, 10, 4096, 768, 36, 72),
+    ("ax-k1-serve prefill", 1536, 8, 7168, 2048, 12, 192),
+])
+def test_grouped_experts_kernel_compiles(
+        chip, monkeypatch, cell, rows, k, d, f, held, experts):
+    """``moe.sparse_experts`` at the three expert cells' published
+    shapes (a decode step's rows and a long prompt's), bfloat16,
+    compiled for the v5e by Mosaic (the model's own interpret default is
+    steered to the chip's): ONE custom call, the grouped kernel, and no
+    ``while`` left of the loop it replaces."""
+    from containerpilot_tpu.models import moe
+    from containerpilot_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_resolve_interpret", lambda i: False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    compiled = _compile(
+        lambda h, idx, gate, w_gate, w_up, w_down: moe.sparse_experts(
+            h, idx, gate, w_gate, w_up, w_down, 0, experts),
+        shape((rows, d)), shape((rows, k), jnp.int32),
+        shape((rows, k), jnp.float32), shape((held, d, f)),
+        shape((held, d, f)), shape((held, f, d)))
+    text = compiled.as_text()
+    calls, products = _expert_kernels(text)
+    assert len(calls) == 1 and products == []
+    assert " while(" not in text
+
+
+def test_latent_expert_step_fits_the_chip_and_copies_no_weights(
+        chip, monkeypatch):
     """The slot engine's chunk program of the benchmark's A.X-K1
     configuration at its real size (benchmark/configs/ax-k1-serve.json:
     published widths, 12 held experts of 192, six layers, 64 slots x
@@ -606,14 +663,19 @@ def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
     one dense matrix. A scan over stacked per-layer leaves did (the
     compiler copied each layer's 1.06 GB of experts out of the stack on
     every step, PERF.md PR 27), which is why the family's layers are
-    separate leaves, unrolled."""
+    separate leaves, unrolled. Each sparse layer's routed experts are
+    ONE call of the grouped kernel (ops/moe_grouped_matmul.py, compiled
+    by Mosaic), and no matrix product is left under ``mlp.experts``
+    beside it."""
     from containerpilot_tpu.models.slots import (
         _jitted_chunk,
         init_slot_state,
         slot_cache,
     )
+    from containerpilot_tpu.ops import flash
     from containerpilot_tpu.workload.modelcfg import load_model_file
 
+    monkeypatch.setattr(flash, "_resolve_interpret", lambda i: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     slots, length = 64, 3072
     cfg = load_model_file(
@@ -630,13 +692,18 @@ def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
         shapes,
     )
-    compiled = _jitted_chunk(cfg, slots, 8).lower(*shapes).compile()
+    # (built anew: a cached program holds the kernel of its first build)
+    compiled = _jitted_chunk.__wrapped__(cfg, slots, 8).lower(
+        *shapes).compile()
     memory = compiled.memory_analysis()
     held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
     assert 8.3e9 < memory.argument_size_in_bytes < 10.5e9
     assert held < 0.8 * HBM_BYTES
-    outside, _bodies = _outside_fusions(compiled.as_text())
+    text = compiled.as_text()
+    calls, products = _expert_kernels(text)
+    assert len(calls) == cfg.n_layers - cfg.first_dense and products == []
+    outside, _bodies = _outside_fusions(text)
     one_expert_matrix = cfg.d_model * cfg.moe_d_ff
     for _computation, line in outside:
         found = re.match(
@@ -652,7 +719,8 @@ def test_latent_expert_step_fits_the_chip_and_copies_no_weights(chip):
 
 
 @pytest.mark.parametrize("program", ["chunk", "window"])
-def test_block_diffusion_pool_forward_fits_the_chip(chip, program):
+def test_block_diffusion_pool_forward_fits_the_chip(
+        chip, program, monkeypatch):
     """The block-diffusion step program's two programs at the
     benchmark's real size (benchmark/configs/sdar-30b-a3b-serve.json:
     published widths, all 128 experts of six layers, the whole
@@ -660,10 +728,15 @@ def test_block_diffusion_pool_forward_fits_the_chip(chip, program):
     compiled for the v5e: weights (8.72 GB), pool (2.42 GB) and
     temporaries fit the chip, the pool is updated in place (aliased),
     and outside its fused computations nothing as large as a layer's
-    keys is copied or transposed."""
+    keys is copied or transposed. Each layer's routed experts are ONE
+    call of the grouped kernel (ops/moe_grouped_matmul.py, compiled by
+    Mosaic), and no matrix product is left under ``mlp.experts`` beside
+    it."""
     from containerpilot_tpu.models import block_diffusion as bd
+    from containerpilot_tpu.ops import flash
     from containerpilot_tpu.workload.modelcfg import load_model_file
 
+    monkeypatch.setattr(flash, "_resolve_interpret", lambda i: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     slots, length = 64, 3072
     cfg = load_model_file(
@@ -684,12 +757,24 @@ def test_block_diffusion_pool_forward_fits_the_chip(chip, program):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
         shapes,
     )
+    # (built anew: a cached program holds the kernel of its first build)
     if program == "chunk":
-        compiled = bd._jitted_chunk(cfg, slots, 6).lower(
+        compiled = bd._jitted_chunk.__wrapped__(cfg, slots, 6).lower(
             params, pool, state).compile()
     else:
-        compiled = bd._jitted_window(cfg, slots, 6, 4).lower(
+        compiled = bd._jitted_window.__wrapped__(cfg, slots, 6, 4).lower(
             params, pool, state, budget).compile()
+    text = compiled.as_text()
+    calls, products = _expert_kernels(text)
+    assert len(calls) == cfg.n_layers and products == []
+    # the kernel asks for little more fast memory than it fills
+    # (ops/moe_grouped_matmul.py MARGIN), so that the compiler still
+    # keeps every layer's attention scores (100 MB) there: with 16 MiB
+    # more asked for they fell out, 2.5 ms a forward (PERF.md, PR 47)
+    groups = cfg.n_heads // cfg.n_kv_heads
+    scores = (f"f32[{slots},{cfg.n_kv_heads},{groups},{size},{length}]"
+              "{4,2,3,1,0:T(8,128)S(1)}")
+    assert text.count(f"{scores}) fusion(") == cfg.n_layers
     memory = compiled.memory_analysis()
     layer_keys = slots * length * cfg.n_kv_heads * cfg.head_dim
     assert 11.0e9 < memory.argument_size_in_bytes < 11.3e9
@@ -697,7 +782,7 @@ def test_block_diffusion_pool_forward_fits_the_chip(chip, program):
     held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
     assert held < 0.8 * HBM_BYTES
-    outside, _bodies = _outside_fusions(compiled.as_text())
+    outside, _bodies = _outside_fusions(text)
     for _computation, line in outside:
         found = re.match(
             r"\s*(?:ROOT )?%?([\w.\-]+) = bf16\[([\d,]+)\]\S* "
@@ -736,11 +821,16 @@ def hybrid_chunk(chip):
     """(cfg, compiled): the granite configuration's chunk program at
     its real size, compiled once for the tests that read it."""
     from containerpilot_tpu.models.slots import _jitted_chunk
+    from containerpilot_tpu.ops import flash
 
     slots, length = 64, 3072
     cfg, (params, pool, state, _row) = _hybrid_ssm_shapes(chip, slots, length)
-    return cfg, _jitted_chunk(cfg, slots, 8).lower(
-        params, pool, state).compile()
+    # the experts' kernel as Mosaic compiles it, not interpreted (built
+    # anew: a cached program holds the kernel of its first build)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(flash, "_resolve_interpret", lambda i: False)
+        return cfg, _jitted_chunk.__wrapped__(cfg, slots, 8).lower(
+            params, pool, state).compile()
 
 
 def test_hybrid_state_space_step_updates_the_state_where_it_lies(
@@ -757,7 +847,9 @@ def test_hybrid_state_space_step_updates_the_state_where_it_lies(
     outside fused computations nothing of a state's size is produced
     and no weight as large as four expert matrices is copied, but the
     attention layer's query projection's change of layout, once a
-    dispatch."""
+    dispatch. Each layer's routed experts are ONE call of the grouped
+    kernel (ops/moe_grouped_matmul.py), and no matrix product is left
+    under ``mlp.experts`` beside it."""
     slots, length = 64, 3072
     cfg, compiled = hybrid_chunk
     memory = compiled.memory_analysis()
@@ -793,6 +885,8 @@ def test_hybrid_state_space_step_updates_the_state_where_it_lies(
             assert (moved < 4 * cfg.d_model * cfg.moe_d_ff
                     or "wq" in operands), line[:200]
     assert updates == cfg.n_mamba
+    calls, products = _expert_kernels(text)
+    assert len(calls) == cfg.n_layers and products == []
 
 
 def test_hybrid_state_space_insert_overwrites_a_row_in_place(chip):
